@@ -52,9 +52,13 @@ Warm start (tuning/): --warmup AOT-compiles the fused step before the
 first batch; with the persistent compile cache a SECOND run pays zero
 JIT anywhere in the epoch loop::
 
-    MXT_COMPILE_CACHE_DIR=/tmp/mxt_cache python examples/train_mnist_gluon.py --warmup
-    MXT_COMPILE_CACHE_DIR=/tmp/mxt_cache python examples/train_mnist_gluon.py --warmup
+    MXT_COMPILE_CACHE_DIR=$PWD/.jax_cache python examples/train_mnist_gluon.py --warmup
+    MXT_COMPILE_CACHE_DIR=$PWD/.jax_cache python examples/train_mnist_gluon.py --warmup
     # second run prints: warmup: N compiles (~0.0s XLA, cache N hit / 0 miss)
+
+(one fixed directory — the path is part of the cache key; where
+``JAX_COMPILATION_CACHE_DIR`` is set, that directory is used instead and
+``MXT_COMPILE_CACHE_DIR`` is ignored)
 """
 import argparse
 

@@ -120,9 +120,11 @@ _declare("MXT_COMPILE_CACHE_DIR", str, None,
          "Directory for JAX's persistent compilation cache. When set, "
          "every XLA compile is cached on disk keyed by program+config, "
          "so a resumed trainer or fresh serving replica deserializes "
-         "instead of recompiling (PERF.md: 63 s of attention JIT on a "
-         "4-layer GPT until hand-caching). tuning.warmup() plus this "
-         "cache = zero hot-path JIT in a warm-started process.")
+         "instead of recompiling. tuning.warmup() plus this cache = zero "
+         "hot-path JIT in a warm-started process. Ignored where "
+         "JAX_COMPILATION_CACHE_DIR is set: the cache then lives there "
+         "and no directory is set in code. Give it one fixed path (the "
+         "path is part of the cache key).")
 
 _declare("MXT_BN_PALLAS", bool, False,
          "Use the fused Pallas BatchNorm backward on channel-last "

@@ -21,6 +21,7 @@ __all__ = [
     "current_context",
     "num_gpus",
     "num_tpus",
+    "on_tpu",
 ]
 
 
@@ -158,6 +159,14 @@ def default_context() -> Context:
         else:
             _default_ctx = tpu(0)
     return _default_ctx
+
+
+def on_tpu() -> bool:
+    """The one device predicate: compiled Pallas kernels, on-device
+    autotune measurement and the TPU feature flag all ask this."""
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 def num_gpus() -> int:

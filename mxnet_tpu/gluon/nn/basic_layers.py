@@ -181,6 +181,18 @@ class BatchNorm(HybridBlock):
         for p in (self.gamma, self.beta, self.running_mean, self.running_var):
             p.shape = (c,)
 
+    def cast(self, dtype):
+        """The running statistics stay float32 under a 16-bit cast (ref:
+        basic_layers.py — BatchNorm.cast keeps its parameters float32).
+        The op computes and returns them in float32, so 16-bit storage
+        would change dtype on the first training forward — and a fused
+        step, whose aux inputs are its own aux outputs, would compile
+        its whole program a second time on step 2."""
+        super().cast(dtype)
+        if np.dtype(self.gamma.dtype).itemsize < 4:
+            self.running_mean.cast("float32")
+            self.running_var.cast("float32")
+
     def hybrid_forward(self, F, x, gamma=None, beta=None, running_mean=None,
                        running_var=None):
         train = autograd.is_training()

@@ -17,7 +17,7 @@ import threading
 
 import numpy as np
 
-__all__ = ["available", "NativeRecordReader", "NativePrefetcher",
+__all__ = ["available", "status", "NativeRecordReader", "NativePrefetcher",
            "select_payload_by_starts"]
 
 _HEADER_BYTES = 8  # [magic u32][cflag|len u32] precede every payload
@@ -43,6 +43,7 @@ _SO = os.path.join(_HERE, "src", "libmxt_recordio.so")
 _lib = None
 _lib_lock = threading.Lock()
 _build_err = None
+_built_here = False  # this process compiled the .so (vs found it on disk)
 
 
 def _build():
@@ -58,7 +59,7 @@ def _build():
 
 
 def _load():
-    global _lib, _build_err
+    global _lib, _build_err, _built_here
     if _lib is not None or _build_err is not None:
         return _lib
     with _lib_lock:
@@ -68,6 +69,7 @@ def _load():
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
                 _build()
+                _built_here = True
             lib = ctypes.CDLL(_SO)
         except Exception as e:  # noqa: BLE001 — no toolchain, bad cache, ...
             _build_err = e
@@ -104,6 +106,17 @@ def _load():
 def available():
     """True when the native engine compiled + loaded on this machine."""
     return _load() is not None
+
+
+def status():
+    """Which of the three it was, in words: ``"built"`` (this process
+    compiled the library from src/recordio.cc), ``"found"`` (a library
+    already beside the source was loaded — it may have been copied in
+    with the tree) or ``"absent: <why>"`` (the pure-Python readers run).
+    available() alone cannot tell a fresh build from a stale copy."""
+    if _load() is None:
+        return "absent: %s" % (_build_err,)
+    return "built" if _built_here else "found"
 
 
 def _as_i64_ptr(arr):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from .registry import register
 from .. import random as _random
+from ..context import on_tpu
 
 
 @register("FullyConnected", aliases=("fully_connected",))
@@ -323,7 +324,7 @@ def _bn_core_bwd(eps, red, res, cts):
         n *= x.shape[i]
     if ax == x.ndim - 1:  # channel-last (NHWC): the Pallas fast path
         from . import bn_pallas
-        if bn_pallas.candidate():
+        if on_tpu():  # the compiled kernel exists only there
             c = x.shape[ax]
             # per-shape choice (tuning table / MXT_BN_PALLAS override);
             # an eager backward passes its concrete arrays so an

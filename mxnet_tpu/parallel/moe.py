@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sequence import shard_map  # version-compat resolved alias
 
 from ..base import MXNetError
 
@@ -105,7 +104,7 @@ def moe_apply(expert_fn, expert_params, gate_w, x, mesh, axis="expert",
         return (combined[None], gates[None],
                 dispatch.sum(-1)[None])  # lead axis for out_specs
 
-    sm = shard_map(
+    sm = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(axis),
                                          expert_params), P(), P(axis)),

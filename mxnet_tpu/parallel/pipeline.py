@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sequence import shard_map  # version-compat resolved alias
 
 from ..base import MXNetError
 
@@ -141,7 +140,7 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, axis="pipe",
         return outs.reshape((b,) + xs.shape[1:])[None]  # (1, B, ...)
 
     spec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    sm = shard_map(
+    sm = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec_params, P()), out_specs=P(axis))
     # jit the schedule: eager shard_map dispatches the unrolled tick

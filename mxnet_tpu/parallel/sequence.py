@@ -25,15 +25,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import _attention_reference, _NEG_INF
 
-# jax.shard_map is top-level only from jax 0.4.38 on; this build carries
-# it under jax.experimental (the public home since 0.4.x) — resolve once
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
 __all__ = ["ring_attention", "ulysses_attention", "sequence_scope",
-           "current_sequence_scope", "shard_map"]
+           "current_sequence_scope"]
 
 
 def _ring_hop_scores(qf, k_cur, b_cur, idx, src, Tl, causal, sm_scale):
@@ -221,7 +214,7 @@ def _ring_callable(mesh, seq_axis, causal, scale, n_shards, has_bias):
     for a 4-layer GPT before this cache; one compile per shape after)."""
     qkv_spec = P(None, None, seq_axis, None)
     if has_bias:
-        sm = shard_map(
+        sm = jax.shard_map(
             lambda q_, k_, v_, b_: _ring_core(q_, k_, v_, b_, seq_axis,
                                               causal, scale, n_shards),
             mesh=mesh,
@@ -230,7 +223,7 @@ def _ring_callable(mesh, seq_axis, causal, scale, n_shards, has_bias):
             out_specs=qkv_spec,
         )
     else:
-        sm = shard_map(
+        sm = jax.shard_map(
             lambda q_, k_, v_: _ring_core(q_, k_, v_, None, seq_axis,
                                           causal, scale, n_shards),
             mesh=mesh,
@@ -279,7 +272,7 @@ def _ulysses_callable(mesh, seq_axis, causal, sm_scale):
     """Jitted shard_map program, cached by configuration (same
     recompile-per-call hazard _ring_callable fixes for the ring)."""
     spec = P(None, None, seq_axis, None)
-    sm = shard_map(
+    sm = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=seq_axis,
                           causal=causal, sm_scale=sm_scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -72,7 +72,6 @@ class BaseSparseNDArray(NDArray):
     def wait_to_read(self):
         from .ndarray.ndarray import _device_sync
         for c in self._components():
-            jax.block_until_ready(c)
             _device_sync(c)
         return self
 

@@ -2,13 +2,13 @@
 
 Two jobs:
 
-1. **Point JAX's persistent compilation cache at
-   ``MXT_COMPILE_CACHE_DIR``** (setup()) with the thresholds dropped to
-   zero so every program caches — on CPU tier-1 the compiles are small,
-   and on the chip the 63-second attention compiles (PERF.md) are
-   exactly what must never be paid twice. A second process compiling
-   the same program deserializes from disk instead of running XLA; the
-   r4 outage (crash *mid-compile*) becomes a cheap replay.
+1. **Turn on JAX's persistent compilation cache** (setup()) — at
+   ``JAX_COMPILATION_CACHE_DIR`` where the environment names one, else
+   at ``MXT_COMPILE_CACHE_DIR`` — with the thresholds dropped to zero
+   so every program caches: on CPU tier-1 the compiles are small, and
+   on the chip a whole-step compile is exactly what must never be paid
+   twice. A second process compiling the same program deserializes from
+   disk instead of running XLA.
 
 2. **Count and time every compile** via ``jax.monitoring`` listeners:
    ``/jax/core/compile/*_duration`` duration events feed the
@@ -25,6 +25,7 @@ take the process down, so every handler swallows its own errors.
 """
 from __future__ import annotations
 
+import os
 import threading
 
 _lock = threading.Lock()
@@ -99,11 +100,20 @@ def install_listeners():
 
 
 def setup(cache_dir=None):
-    """Enable the persistent compilation cache. ``cache_dir`` defaults
-    to ``MXT_COMPILE_CACHE_DIR``; returns the active directory or None
-    (unset = feature off, nothing touched). Idempotent per directory."""
+    """Enable the persistent compilation cache; returns the active
+    directory or None (feature off, nothing touched).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and that directory is the answer whatever else is set:
+    no directory is set in code (the path is part of how a machine hands
+    a warm cache from one run to the next). Otherwise ``cache_dir``
+    defaults to ``MXT_COMPILE_CACHE_DIR``. Either way the thresholds
+    drop so every program caches. Idempotent per directory."""
     global _setup_dir
-    if cache_dir is None:
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        cache_dir = env_dir
+    elif cache_dir is None:
         cache_dir = _config().get("MXT_COMPILE_CACHE_DIR")
     if not cache_dir:
         return _setup_dir
@@ -112,7 +122,8 @@ def setup(cache_dir=None):
             return _setup_dir
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     # cache EVERYTHING: the default thresholds skip small/fast programs,
     # but tier-1 runs on CPU where every compile is small — and the
     # zero-JIT-resume contract is per program, not per expensive program

@@ -53,10 +53,7 @@ def device_kind():
     generation must not be served to another (or to CPU)."""
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no backend: still key consistently
-        kind = "unknown"
+    kind = jax.devices()[0].device_kind
     return str(kind).replace(" ", "_").replace("|", "_")
 
 

@@ -1,0 +1,160 @@
+"""Every Pallas kernel in mxnet_tpu/ops compiled for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a device that is
+described, not attached (``v5e:2x2``, device kind ``TPU v5 lite``). It
+refuses what interpret mode lets through — a block the tiling rules
+forbid, a 64-bit index map, more fast memory than a kernel may use — so
+these cases guard every later PR at no chip time. A compile that passes is
+not a chip run: ``chip_smoke.py`` runs the same kernels against their
+references on the device.
+
+The file's name sorts early on purpose: the tier-1 window reaches it.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu import tuning
+from mxnet_tpu.ops import attention as A
+from mxnet_tpu.ops import bn_pallas
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip; compilation cache
+    off around the module (a described-device compile can be written to
+    the persistent cache but never read back without a chip)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip("cannot describe a v5e topology here: %r" % (e,))
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    """Compile ``fn`` for the described chip at the matmul precision the
+    chip runs by default (conftest pins 'highest' for the CPU numerics);
+    returns the optimized HLO text. Raises what the chip's compiler
+    would raise."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash(shape, dtype, causal, bias_shape=None, blocks=None):
+    def case(chip):
+        if blocks is None:
+            cfg = tuning.heuristic_attention(shape, shape[2], dtype, causal)
+            bq, bk = cfg["block_q"], cfg["block_k"]
+        else:
+            bq, bk = blocks
+        sm = shape[3] ** -0.5
+        qkv = [(shape, jnp.dtype(dtype))] * 3
+        if bias_shape is None:
+            return _compile(
+                chip, lambda q, k, v: A._flash_forward_pallas(
+                    q, k, v, None, causal, sm, bq, bk, False), *qkv)
+        return _compile(
+            chip, lambda q, k, v, b: A._flash_forward_pallas(
+                q, k, v, b, causal, sm, bq, bk, False),
+            *qkv, (bias_shape, jnp.float32))
+    return case
+
+
+def _paged(batch, heads, dim, block_h=None, dtype="bfloat16", page=16,
+           max_pages=64):
+    def case(chip):
+        bh = block_h or tuning.heuristic_paged(
+            (batch, heads, dim), page, max_pages, dtype)["block_h"]
+        dt = jnp.dtype(dtype)
+        pool = ((batch * max_pages + 1, page, heads, dim), dt)
+        return _compile(
+            chip, lambda q, k, v, pt, cl: A._paged_decode_pallas(
+                q, k, v, pt, cl, dim ** -0.5, bh, False),
+            ((batch, heads, dim), dt), pool, pool,
+            ((batch, max_pages), jnp.int32), ((batch,), jnp.int32))
+    return case
+
+
+def _bn(m, c, block_rows=None):
+    def case(chip):
+        chan = ((c,), jnp.float32)
+        act = ((m, c), jnp.bfloat16)
+        return _compile(
+            chip, lambda x, dy, mean, inv, g: bn_pallas.bn_bwd_pallas(
+                x, dy, mean, inv, g, block_rows=block_rows),
+            act, act, chan, chan, chan)
+    return case
+
+
+_CASES = {
+    # flash forward: BERT-base (batch 32 x 128) without and with a
+    # padding bias, longer and ragged sequences, explicit big blocks, and
+    # the longest sequence whose whole K/V _kv_fits_vmem still admits
+    "flash_bert": _flash((32, 12, 128, 64), "bfloat16", False),
+    "flash_bert_bias": _flash((32, 12, 128, 64), "bfloat16", False,
+                              bias_shape=(32, 1, 1, 128)),
+    "flash_512_causal": _flash((8, 12, 512, 64), "bfloat16", True),
+    "flash_384": _flash((2, 4, 384, 64), "bfloat16", False),
+    "flash_2048_causal_256x512": _flash((4, 12, 2048, 64), "bfloat16", True,
+                                        blocks=(256, 512)),
+    "flash_maxseq_16384_bias": _flash((1, 2, 16384, 64), "bfloat16", True,
+                                      bias_shape=(1, 1, 1, 16384)),
+    # paged decode at the BERT-base/GPT-2 geometry, block as the
+    # repaired generator picks it
+    "paged_8x12x64": _paged(8, 12, 64),
+    "paged_16x12x64": _paged(16, 12, 64),
+    # BN backward at two ResNet-50 (batch 64, NHWC) activations
+    "bn_200704x64": _bn(200704, 64),
+    "bn_3136x2048": _bn(3136, 2048),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    text = _CASES[name](chip)
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+def test_every_paged_candidate_compiles(chip):
+    """paged_candidates and the kernel must agree: whatever the generator
+    calls legal, the chip's compiler takes (12 heads used to get only
+    blocks it refuses)."""
+    for heads in (2, 4, 12, 16, 32):
+        cands = tuning.paged_candidates(heads, 64, 16, "bfloat16")
+        assert cands and all(heads % bh == 0 for bh in cands)
+        for bh in cands:
+            _paged(8, heads, 64, block_h=bh, max_pages=16)(chip)
+
+
+def test_every_bn_candidate_compiles(chip):
+    for m, c in ((200704, 64), (3136, 2048)):
+        for bm in tuning.bn_candidates(m, c):
+            _bn(m, c, block_rows=bm)(chip)
+
+
+def test_extreme_attention_candidates_compile(chip):
+    """The corners of what attention_candidates emits under _VMEM_BUDGET at
+    the longest resident sequence: smallest and largest tiles."""
+    shape = (1, 2, 16384, 64)
+    cands = tuning.attention_candidates(shape[2], shape[2], shape[3],
+                                        "bfloat16")
+    for blocks in (min(cands), max(cands), (min(cands)[0], max(cands)[1]),
+                   (max(cands)[0], min(cands)[1])):
+        assert blocks in cands
+        _flash(shape, "bfloat16", True, blocks=blocks)(chip)

@@ -72,10 +72,8 @@ def test_runtime_features():
     assert feats.is_enabled("CPU")
     assert not feats.is_enabled("CUDA")
     assert "NATIVE_RECORDIO" in feats
-    # flash-attention probe must agree with the op's own dispatch
-    from mxnet_tpu.ops import attention
-
-    assert feats.is_enabled("FLASH_ATTENTION") == attention._use_pallas()
+    # flash-attention probe must agree with the op's own dispatch gate
+    assert feats.is_enabled("FLASH_ATTENTION") == mx.context.on_tpu()
     lst = mx.runtime.feature_list()
     assert any(f.name == "TPU" for f in lst)
 

@@ -416,6 +416,7 @@ def test_bench_embedding_ab_scaling(monkeypatch):
     in the 1-vs-2-server A/B (in-process fleet)."""
     import bench
 
+    monkeypatch.setattr(bench, "JSONL_PATH", os.devnull)
     monkeypatch.setenv("BENCH_EMB_VOCAB", "20000")
     monkeypatch.setenv("BENCH_EMB_BATCH", "2048")
     monkeypatch.setenv("BENCH_EMB_ITERS", "4")

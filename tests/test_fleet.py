@@ -296,6 +296,9 @@ def test_drain_migrates_queue_and_rejoin_serves_warm(tmp_path,
     cache and serves with ZERO request-path cache-miss compiles."""
     from jax._src import compilation_cache as _cc
 
+    # the JAX variable outranks ours (tuning.setup_compile_cache): clear
+    # it so the test owns its cache directory under either environment
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("MXT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
     _cc.reset_cache()
 

@@ -181,3 +181,13 @@ def test_image_record_iter_prefetch_across_epochs(tmp_path):
         # the tail; the full multiset must be 0..29
         assert sorted(int(v) for v in labels) == list(range(30))
         it.reset()
+
+
+def test_status_says_built_found_or_absent():
+    """available() cannot tell a fresh build from a library copied in with
+    the tree; status() says which (chip_smoke.py prints it)."""
+    st = native.status()
+    if native.available():
+        assert st in ("built", "found")
+    else:
+        assert st.startswith("absent: ")

@@ -242,3 +242,27 @@ def test_wait_and_waitall():
     b.wait_to_read()
     nd.waitall()
     assert (b.asnumpy() == 2).all()
+
+
+def test_wait_to_read_is_one_counted_block_until_ready(monkeypatch):
+    """wait_to_read is jax.block_until_ready plus the host-sync counter —
+    no jitted one-element read-back (one more compile per shape, and a
+    gather on a sharded array)."""
+    import jax
+
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ndarray import ndarray as impl
+
+    waited = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(impl.jax, "block_until_ready",
+                        lambda a: waited.append(a) or real(a))
+    x = nd.ones((3, 5)) * 2
+    s0 = profiler.host_sync_count()
+    assert x.wait_to_read() is x
+    assert profiler.host_sync_count() - s0 == 1
+    assert len(waited) == 1 and waited[0] is x.data
+    assert not hasattr(impl, "_sync_pick")
+    s0 = profiler.host_sync_count()
+    nd.zeros((0, 4)).wait_to_read()  # nothing to wait for, nothing counted
+    assert profiler.host_sync_count() == s0

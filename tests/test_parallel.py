@@ -266,7 +266,7 @@ def test_ring_attention_backward_memory_is_o_t_over_n():
 
     out_specs = [spec] * 4 + [P(None, None, "sp")]  # q,k,v,out + lse
     shapes = jax.eval_shape(
-        seq.shard_map(fwd_residuals, mesh=mesh,
+        jax.shard_map(fwd_residuals, mesh=mesh,
                       in_specs=(spec, spec, spec), out_specs=out_specs),
         *[jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)] * 3)
     total = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
@@ -532,3 +532,33 @@ def test_sharded_step_zero1_composes_with_remat():
         if step._zero_shardings[n] is not None:
             for s in step._states[n]:
                 assert "data" in str(s.sharding.spec)  # survived updates
+
+
+def test_sharded_step_one_device_mesh_compiles_once():
+    """On a one-device mesh a fresh buffer is "equivalent" to its mesh
+    placement, and skipping the placement made the step's inputs change
+    type after step 1: the whole program traced and compiled twice.
+    shard_params now places whatever is not already on a mesh."""
+    from mxnet_tpu import profiler, tuning
+
+    mx.random.seed(0)
+    net = nn.HybridSequential(prefix="onedev_")
+    with net.name_scope():
+        net.add(nn.Dense(16, activation="relu", in_units=8),
+                nn.Dense(4, in_units=16))
+    net.initialize()
+    mesh = parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    step = parallel.ShardedTrainStep(
+        net, mx.gluon.loss.L2Loss(), "adam", {"learning_rate": 1e-2},
+        mesh=mesh)
+    for p in net.collect_params().values():
+        assert isinstance(p.data().data.sharding, jax.sharding.NamedSharding)
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.uniform(-1, 1, (8, 8)).astype(np.float32))
+    y = nd.array(rng.uniform(-1, 1, (8, 4)).astype(np.float32))
+    step(x, y).wait_to_read()
+    c0, l0 = tuning.compile_stats()["compiles"], profiler.launch_count()
+    step(x, y).wait_to_read()
+    assert step._jit._cache_size() == 1
+    assert tuning.compile_stats()["compiles"] == c0
+    assert profiler.launch_count() - l0 == 1

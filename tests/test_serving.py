@@ -412,6 +412,9 @@ def test_aot_warm_decode_zero_cache_misses(tmp_path, monkeypatch):
     request-path program replays from disk."""
     from jax._src import compilation_cache as _cc
 
+    # the JAX variable outranks ours (tuning.setup_compile_cache): clear
+    # it so the test owns its cache directory under either environment
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("MXT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
 
     def traffic(eng):

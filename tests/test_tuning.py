@@ -36,7 +36,7 @@ def _fresh_table(monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# shape-aware configs: legality + odd-shape parity (BENCH_r02 regression)
+# shape-aware configs: legality + odd-shape parity (partial-block regression)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("tq,tk,d", [
     (257, 257, 32),   # the classic non-multiple sequence
@@ -62,7 +62,7 @@ def test_attention_candidates_tiling_legal(tq, tk, d):
 def test_flash_odd_shapes_match_reference(causal, tq, tk):
     """The shape-aware config path must make the Pallas kernel (run in
     interpret mode on CPU) agree with the XLA reference at non-multiple
-    shapes — the BENCH_r02 `partial_errors` class."""
+    shapes — the partial-block error class an early chip run hit."""
     rng = np.random.RandomState(0)
     B, H, D = 1, 2, 32
     q = jnp.asarray(rng.normal(size=(B, H, tq, D)).astype("f4"))
@@ -338,6 +338,9 @@ def test_warmup_second_pass_hits_persistent_cache(tmp_path, monkeypatch):
     serves the compiles from the persistent cache (hits, not misses)."""
     from jax._src import compilation_cache as _cc
 
+    # the JAX variable outranks ours (tuning.setup_compile_cache): clear
+    # it so the test owns its cache directory under either environment
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("MXT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
     # unique shape for this test: other tests may have compiled the
     # common ones already, and JAX's in-memory cache layer would then
@@ -404,6 +407,8 @@ def test_zero_jit_resume_second_process(tmp_path):
                 "MXT_COMPILE_CACHE_DIR": str(tmp_path / "xla"),
                 "MXT_TUNE_TABLE": str(tmp_path / "tune.json")})
     env.pop("XLA_FLAGS", None)  # no 8-device CPU mesh in the children
+    # the JAX variable outranks ours (tuning.setup_compile_cache)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
 
     def run():
         r = subprocess.run(
@@ -426,3 +431,21 @@ def test_zero_jit_resume_second_process(tmp_path):
                                rtol=0, atol=0)
     # the cold process really did pay compiles (sanity of the A/B)
     assert cold["total_misses"] > 0
+
+
+def test_jax_cache_dir_variable_outranks_everything(tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache lives there: setup()
+    sets no directory in code, whatever it is passed, and cache_dir()
+    reports the environment's."""
+    import jax
+
+    from mxnet_tpu.tuning import compile_cache
+
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setenv("MXT_COMPILE_CACHE_DIR", str(tmp_path / "ours"))
+    monkeypatch.setattr(compile_cache, "_setup_dir", None)
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.setup(str(tmp_path / "argument")) == env_dir
+    assert compile_cache.cache_dir() == env_dir
+    assert jax.config.jax_compilation_cache_dir == before
