@@ -8,6 +8,10 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
+import time as _time
+
+_t_import = _time.perf_counter()  # profiler's setup.import: first line to last
+
 import jax as _jax
 
 # MXNet supports float64/int64 tensors as first-class dtypes; JAX gates them
@@ -74,6 +78,8 @@ from . import amp
 from . import contrib
 from . import runtime
 from . import util
+
+profiler._record("setup.import", _time.perf_counter() - _t_import)
 
 __all__ = [
     "nd", "ndarray", "autograd", "random", "context", "Context", "cpu",

@@ -297,7 +297,10 @@ class InflightWindow:
         n = tok.upto - self._consumed
         if n <= 0:
             return
-        value = tok.pv.get()  # blocks until the covered steps finished
+        import jax
+
+        with jax.profiler.TraceAnnotation("mxt.window.retire", upto=tok.upto):
+            value = tok.pv.get()  # blocks until the covered steps finished
         with _lock:
             self._held_bytes -= tok.nbytes
         # retires are the engine's watchdog heartbeat: a frozen counter

@@ -94,6 +94,7 @@ class _TraceDepth(threading.local):
     def __init__(self):
         super().__init__()
         self.depth = 0
+        self.prefix = ""  # of the block whose scope a trace is inside
 
 
 _trace_depth = _TraceDepth()
@@ -246,13 +247,38 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        if _trace_depth.depth and args and isinstance(
+                getattr(args[0], "data", None), jax.core.Tracer):
+            out = self._traced_forward(args, kwargs)
+        else:
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError
+
+    def _traced_forward(self, args, kwargs):
+        """``forward`` under ``jax.named_scope(<this block's name>)``, the
+        parent block's prefix taken off (a block that adds no prefix of its
+        own, ``prefix=""``, goes by its type), so that the compiled
+        program's operations, and the device trace, carry
+        ``forward/resnetv10/stage1/bottleneckv1/conv2d0`` (profiler_trace.py
+        reads it back). Entered only while JAX traces: a compiled step pays
+        nothing and the eager path one comparison."""
+        outer = _trace_depth.prefix
+        name = self._name
+        if self._prefix == outer:
+            name = ""
+        elif outer and name.startswith(outer):
+            name = name[len(outer):]
+        _trace_depth.prefix = self._prefix
+        try:
+            with jax.named_scope(name or self._alias()):
+                return self.forward(*args, **kwargs)
+        finally:
+            _trace_depth.prefix = outer
 
     def summary(self, *inputs):
         out = self(*inputs)
@@ -355,7 +381,9 @@ class HybridBlock(Block):
         )
         if not needs:
             return
-        with ag.pause(train_mode=False):
+        from ..profiler import setup_scope
+
+        with setup_scope("infer_shapes"), ag.pause(train_mode=False):
             _trace_depth.depth += 1
             try:
                 super().__call__(*args)

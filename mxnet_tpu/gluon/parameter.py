@@ -21,6 +21,7 @@ from ..context import current_context
 from ..ndarray import ndarray as _nd
 from ..ndarray.ndarray import NDArray
 from .. import initializer as init_mod
+from ..profiler import setup_scope
 
 __all__ = ["Parameter", "Constant", "ParameterDict", "DeferredInitializationError",
            "param_trace_scope", "tracing_override"]
@@ -148,13 +149,14 @@ class Parameter:
         self._init_impl(initializer, ctx)
 
     def _init_impl(self, initializer, ctx):
-        arr = _nd.zeros(self._shape, ctx=ctx, dtype=self.dtype)
-        if isinstance(initializer, str):
-            initializer = init_mod.create(initializer)
-        # a param-specific init rides in InitDesc attrs and bypasses
-        # name-suffix dispatch (so bias_initializer='ones' actually wins)
-        attrs = {"__init__": self.init} if self.init is not None else {}
-        initializer(init_mod.InitDesc(self.name, attrs), arr)
+        with setup_scope("initialize"):
+            arr = _nd.zeros(self._shape, ctx=ctx, dtype=self.dtype)
+            if isinstance(initializer, str):
+                initializer = init_mod.create(initializer)
+            # a param-specific init rides in InitDesc attrs and bypasses
+            # name-suffix dispatch (so bias_initializer='ones' actually wins)
+            attrs = {"__init__": self.init} if self.init is not None else {}
+            initializer(init_mod.InitDesc(self.name, attrs), arr)
         self._data = arr
         self._deferred_init = None
         if self._grad_req != "null":
@@ -237,7 +239,8 @@ class Parameter:
         # param's own storage, and an aliased buffer would be invalidated
         # for this param when the source param's trainer donates it
         # (jax.jit donate_argnums in _FusedUpdate / ShardedTrainStep)
-        self._data._set_data(jnp.array(data, dtype=self.dtype, copy=True))
+        with setup_scope("place"):
+            self._data._set_data(jnp.array(data, dtype=self.dtype, copy=True))
 
     def _deferred_init_default(self):
         if self._data is None:
@@ -262,10 +265,11 @@ class Parameter:
     def cast(self, dtype):
         self.dtype = get_dtype(dtype)
         if self._data is not None:
-            had_grad = self._data._grad is not None
-            self._data = self._data.astype(self.dtype)
-            if had_grad:
-                self._data.attach_grad(self._grad_req)
+            with setup_scope("cast"):
+                had_grad = self._data._grad is not None
+                self._data = self._data.astype(self.dtype)
+                if had_grad:
+                    self._data.attach_grad(self._grad_req)
 
     def var(self):
         from ..symbol.symbol import var

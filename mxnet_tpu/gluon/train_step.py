@@ -218,7 +218,9 @@ class CachedTrainStep:
         # generalized to plain Blocks)
         if any(p._deferred_init is not None
                for p in net.collect_params().values()):
-            with ag.pause(train_mode=False):
+            from ..profiler import setup_scope
+
+            with setup_scope("infer_shapes"), ag.pause(train_mode=False):
                 _trace_depth.depth += 1
                 try:
                     net(x)
@@ -270,7 +272,8 @@ class CachedTrainStep:
             _trace_depth.depth += 1
             try:
                 with ag.pause(train_mode=True), _random.key_scope(key), \
-                        param_trace_scope(mapping):
+                        param_trace_scope(mapping), \
+                        jax.named_scope("forward"):
                     out = Block.__call__(net, NDArray(xv))
                     outs = list(out) if isinstance(out, (list, tuple)) \
                         else [out]
@@ -297,13 +300,15 @@ class CachedTrainStep:
                 if spike:
                     # seeded chaos: ONE layer's gradient scaled on device
                     # (scale is 1.0 on every non-firing step)
-                    grads = _health.apply_grad_spike(grads, train_names,
-                                                     spike_scale)
+                    with jax.named_scope("grad_post"):
+                        grads = _health.apply_grad_spike(
+                            grads, train_names, spike_scale)
                 new_train, new_states = [], []
-                for f, w, g, s in zip(upds, train_vals, grads, states):
-                    w2, s2 = f(w, g, s, t, lr, wd, rescale)
-                    new_train.append(w2)
-                    new_states.append(s2)
+                with jax.named_scope("optimizer"):
+                    for f, w, g, s in zip(upds, train_vals, grads, states):
+                        w2, s2 = f(w, g, s, t, lr, wd, rescale)
+                        new_train.append(w2)
+                        new_states.append(s2)
                 if health:
                     # per-layer stats packed INSIDE the program — staged
                     # into the window, never read per step
@@ -335,24 +340,29 @@ class CachedTrainStep:
                 if spike:
                     # seeded chaos: ONE layer's gradient scaled on device
                     # (scale is 1.0 on every non-firing step)
-                    grads = _health.apply_grad_spike(grads, train_names,
-                                                     spike_scale)
+                    with jax.named_scope("grad_post"):
+                        grads = _health.apply_grad_spike(
+                            grads, train_names, spike_scale)
 
                 def _apply(_):
                     new_train, new_states = [], []
-                    for f, w, g, s in zip(upds, train_vals, grads, states):
-                        w2, s2 = f(w, g, s, t_upd, lr, wd, rescale)
-                        new_train.append(w2)
-                        new_states.append(s2)
+                    with jax.named_scope("optimizer"):
+                        for f, w, g, s in zip(upds, train_vals, grads,
+                                              states):
+                            w2, s2 = f(w, g, s, t_upd, lr, wd, rescale)
+                            new_train.append(w2)
+                            new_states.append(s2)
                     return tuple(new_train), tuple(new_states), new_aux
 
                 def _skip(_):
                     return (tuple(train_vals), tuple(states),
                             tuple(aux_vals))
 
-                finite = jnp.bool_(True)
-                for g in grads:
-                    finite = jnp.logical_and(finite, jnp.isfinite(g).all())
+                with jax.named_scope("grad_post"):
+                    finite = jnp.bool_(True)
+                    for g in grads:
+                        finite = jnp.logical_and(finite,
+                                                 jnp.isfinite(g).all())
                 new_train, new_states, kept_aux = jax.lax.cond(
                     finite, _apply, _skip, None)
                 t_new = t + jnp.where(finite, 1, 0)
@@ -663,25 +673,27 @@ class CachedTrainStep:
                 self._stream._dispatched + 1)
         row = None
         try:
-            if self._guard:
-                if self._health:
-                    (loss_vec, new_w, new_s, new_aux, outs, t_new,
-                     mask_new, row) = self._jit(
-                        ws, ss, aux, x.data, y.data, self._base_key, t_in,
-                        mask_in, lr, wd, rescale, spike_scale)
+            with jax.profiler.TraceAnnotation(
+                    "mxt.step.dispatch", step=self._stream._dispatched + 1):
+                if self._guard:
+                    if self._health:
+                        (loss_vec, new_w, new_s, new_aux, outs, t_new,
+                         mask_new, row) = self._jit(
+                            ws, ss, aux, x.data, y.data, self._base_key, t_in,
+                            mask_in, lr, wd, rescale, spike_scale)
+                    else:
+                        (loss_vec, new_w, new_s, new_aux, outs, t_new,
+                         mask_new) = self._jit(
+                            ws, ss, aux, x.data, y.data, self._base_key, t_in,
+                            mask_in, lr, wd, rescale, spike_scale)
+                elif self._health:
+                    loss_vec, new_w, new_s, new_aux, outs, row = self._jit(
+                        ws, ss, aux, x.data, y.data, self._base_key, t_in, lr,
+                        wd, rescale, spike_scale)
                 else:
-                    (loss_vec, new_w, new_s, new_aux, outs, t_new,
-                     mask_new) = self._jit(
-                        ws, ss, aux, x.data, y.data, self._base_key, t_in,
-                        mask_in, lr, wd, rescale, spike_scale)
-            elif self._health:
-                loss_vec, new_w, new_s, new_aux, outs, row = self._jit(
-                    ws, ss, aux, x.data, y.data, self._base_key, t_in, lr,
-                    wd, rescale, spike_scale)
-            else:
-                loss_vec, new_w, new_s, new_aux, outs = self._jit(
-                    ws, ss, aux, x.data, y.data, self._base_key, t_in, lr,
-                    wd, rescale, spike_scale)
+                    loss_vec, new_w, new_s, new_aux, outs = self._jit(
+                        ws, ss, aux, x.data, y.data, self._base_key, t_in, lr,
+                        wd, rescale, spike_scale)
         except Exception as e:  # noqa: BLE001 — OOM gets the HBM ledger
             from .. import diagnostics
 
@@ -769,14 +781,21 @@ class CachedTrainStep:
             self._fallback_reason = None
             self._hyper_cache = None
             self._committed = False  # load_states brought fresh buffers
+        result = None
         if self._jit is None and self._fallback_reason is None:
             self._fallback_reason = self.eligible(tr, self._net)
             if self._fallback_reason is None:
-                self._build(x)
-        if self._jit is not None:
+                from ..profiler import setup_scope
+
+                # the build, the step's trace and compile (or the cache's
+                # read) and the return of its first dispatch
+                with setup_scope("step_build"):
+                    self._build(x)
+                    result = self._fused_step(x, y, batch_size)
+        elif self._jit is not None:
             result = self._fused_step(x, y, batch_size)
-            if result is not None:
-                return result
+        if result is not None:
+            return result
         return self._eager_step(x, y, batch_size)
 
 
@@ -806,6 +825,7 @@ class FusedApply:
         upds = [_FusedUpdate._param_update(optimizer, i)
                 for i in self._indices]
 
+        @jax.named_scope("optimizer")
         def step(ws, gs, ss, t, lr, wd, rescale):
             out_w, out_s = [], []
             for f, w, g, s in zip(upds, ws, gs, ss):

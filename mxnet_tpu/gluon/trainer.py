@@ -102,6 +102,7 @@ class _FusedUpdate:
         self._mask_dev = None
         upds = self._upds
 
+        @jax.named_scope("optimizer")
         def step(ws, gs, ss, t, lr, wd, rescale):
             out_w, out_s = [], []
             for f, w, g, s in zip(upds, ws, gs, ss):

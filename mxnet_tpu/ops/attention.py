@@ -236,6 +236,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, Tqp, _LSE_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     out = out.reshape(B, H, Tqp, D)[:, :, :Tq]
     lse = lse[:, :, 0].reshape(B, H, Tqp)[:, :, :Tq]
@@ -384,6 +385,7 @@ def _flash_core(q, k, v, bias, causal, sm_scale):
     return out
 
 
+@jax.named_scope("attention")
 def _flash_fwd(q, k, v, bias, causal, sm_scale):
     _record_flash_signature(q, k, v, bias, causal, sm_scale)
     if not _kv_fits_vmem(k):
@@ -424,6 +426,7 @@ def _record_flash_signature(q, k, v, bias, causal, sm_scale):
 _BWD_SCORE_BYTES = 256 * 1024 * 1024  # peak score-matrix budget in backward
 
 
+@jax.named_scope("attention_bwd")
 def _flash_bwd(causal, sm_scale, res, do):
     q, k, v, bias, out, lse = res
     B, H, Tq, _ = q.shape
@@ -711,6 +714,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, context_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nhb, block_h, D), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(page_table, context_lens, qb, kb, vb)
     return out.reshape(B, H, D)
 

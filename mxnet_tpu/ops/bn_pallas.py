@@ -107,6 +107,7 @@ def bn_bwd_pallas(x2d, dy2d, mean, inv, g, interpret=False,
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         interpret=interpret,
+        name="bn_bwd_reduce",
     )(x2d, dy2d, mean_r, inv_r)
 
     n_scale = 1.0 / float(m)
@@ -118,6 +119,7 @@ def bn_bwd_pallas(x2d, dy2d, mean, inv, g, interpret=False,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
         interpret=interpret,
+        name="bn_bwd_dx",
     )(x2d, dy2d, mean_r, inv_r, g_r, db, dg)
     return dx, dg.reshape(c), db.reshape(c)
 

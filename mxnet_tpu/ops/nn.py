@@ -288,6 +288,7 @@ def _bn_stats(x32, red):
     return mean, var
 
 
+@jax.named_scope("batchnorm")
 def _bn_core_fwd(eps, red, x, g, b):
     x32 = x.astype(jnp.float32)
     mean, var = _bn_stats(x32, red)
@@ -313,6 +314,7 @@ def _bn_core_fwd(eps, red, x, g, b):
     return (out.astype(x.dtype), mean, var), (x, g, mean, inv)
 
 
+@jax.named_scope("batchnorm_bwd")
 def _bn_core_bwd(eps, red, res, cts):
     x, g, mean, inv = res
     ct_out = cts[0]  # mean/var outputs feed stop_gradient paths only
@@ -391,6 +393,7 @@ def batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
             jax.lax.stop_gradient(moving_var))
 
 
+@jax.named_scope("layernorm")
 def _ln_fwd(eps, ax, x, g, b):
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=ax, keepdims=True)
@@ -404,6 +407,7 @@ def _ln_fwd(eps, ax, x, g, b):
     return out.astype(x.dtype), (x, g, mean, inv)
 
 
+@jax.named_scope("layernorm_bwd")
 def _ln_bwd(eps, ax, res, ct):
     x, g, mean, inv = res
     n = x.shape[ax]

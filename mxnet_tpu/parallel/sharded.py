@@ -57,6 +57,7 @@ choice change, never the math (tests assert <=1e-6 over 5 steps).
 from __future__ import annotations
 
 import json
+import contextlib
 import re
 from collections import OrderedDict
 
@@ -71,6 +72,7 @@ from .. import random as _random
 from ..ndarray.ndarray import NDArray
 from ..gluon.block import Block, _trace_depth
 from ..gluon.parameter import param_trace_scope
+from ..profiler import setup_scope
 from .mesh import make_mesh
 
 __all__ = ["ShardedTrainStep", "shard_params", "sharding_rule",
@@ -122,8 +124,9 @@ def shard_params(params, mesh, rules=None, shardings=None):
         targets.append(target)
     if not names:
         return 0
-    for name, v in zip(names, jax.device_put(vals, targets)):
-        params[name].data()._set_data(v)
+    with setup_scope("place"):
+        for name, v in zip(names, jax.device_put(vals, targets)):
+            params[name].data()._set_data(v)
     return len(names)
 
 
@@ -396,7 +399,7 @@ class ShardedTrainStep:
         _trace_depth.depth += 1
         try:
             with ag.pause(train_mode=True), _random.key_scope(key), \
-                    param_trace_scope(mapping):
+                    param_trace_scope(mapping), jax.named_scope("forward"):
                 out = Block.__call__(self.block, NDArray(x))
                 loss = self.loss_fn(out, NDArray(y))
                 loss = loss.mean()
@@ -453,8 +456,9 @@ class ShardedTrainStep:
             if spike:
                 # seeded chaos: ONE layer's gradient scaled on device
                 # (scale is 1.0 on every non-firing step)
-                grads = _health.apply_grad_spike(grads, train_names,
-                                                 spike_scale)
+                with jax.named_scope("grad_post"):
+                    grads = _health.apply_grad_spike(grads, train_names,
+                                                     spike_scale)
             loss = jax.lax.with_sharding_constraint(loss, replicated)
             # aux (BN running stats) pinned to their STORAGE sharding:
             # without this, ZeRO's sharded states pressure the GSPMD
@@ -472,7 +476,8 @@ class ShardedTrainStep:
                     # GSPMD fuses the dp all-reduce into reduce-scatter
                     # and each replica updates only its slice
                     g = jax.lax.with_sharding_constraint(g, z)
-                w2, s2 = self._update(w, g, s, t)
+                with jax.named_scope("optimizer"):
+                    w2, s2 = self._update(w, g, s, t)
                 # optimizer state stays pinned to its STORAGE sharding
                 # across the update (ZeRO slice, or the weight's own
                 # tp/pp/ep layout); the weight returns to ITS storage
@@ -659,20 +664,22 @@ class ShardedTrainStep:
         if self._spike:
             from .. import health as _health
             spike_scale = _health.grad_spike_scale(self._ncalls)
-        if self._health:
-            (loss, new_train, new_states, new_aux, self._t_dev,
-             row) = self._jit(
+        # the first call traces and compiles the step (or reads the cache)
+        build = setup_scope("step_build") if self._ncalls == 1 \
+            else contextlib.nullcontext()
+        with build, jax.profiler.TraceAnnotation("mxt.step.dispatch",
+                                                 step=self._ncalls):
+            out = self._jit(
                 train_vals, states, aux_vals, self._shard_batch(x),
                 self._shard_batch(y), self._ensure_key(), self._t_dev,
                 spike_scale)
+        if self._health:
+            loss, new_train, new_states, new_aux, self._t_dev, row = out
             # stats stage into the window: the ONE deferred read per K
             # steps at retirement covers them, the hot path reads nothing
             self._stream.push(loss, value=row)
         else:
-            loss, new_train, new_states, new_aux, self._t_dev = self._jit(
-                train_vals, states, aux_vals, self._shard_batch(x),
-                self._shard_batch(y), self._ensure_key(), self._t_dev,
-                spike_scale)
+            loss, new_train, new_states, new_aux, self._t_dev = out
         from .. import profiler
         profiler.record_launch()
         for n, v in zip(self._train_names, new_train):

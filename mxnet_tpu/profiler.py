@@ -4,11 +4,26 @@ the engine-level ProfileOperator records collapse into XLA's own op-level
 trace, which the JAX profiler captures as Perfetto/TensorBoard data).
 
 ``set_config(filename=...)`` + ``set_state('run')`` starts a JAX trace; on
-``set_state('stop')``/``dump()`` the Perfetto trace lands under the
-configured directory. User scopes (Task/Frame/Counter/Marker) annotate the
-device trace via ``jax.profiler.TraceAnnotation`` and are also timed
-host-side so ``dumps()`` can print the MXNet-style aggregate table without
-parsing protobufs.
+``set_state('stop')``/``dump()`` the trace lands under the configured
+directory. ``dumps()`` then prints two tables, as the reference's prints time
+per operator::
+
+    mx.profiler.set_state('run')
+    for _ in range(5): step(x, y).wait_to_read()
+    mx.profiler.set_state('stop'); print(mx.profiler.dumps())
+
+- host: user scopes (Task/Frame/Counter/Marker; each also a
+  ``jax.profiler.TraceAnnotation`` in the trace), the program's own set-up
+  phases (``setup.import``, ``.initialize``, ``.cast``, ``.place``,
+  ``.infer_shapes``, ``.step_build``: exclusive of one another, so they add
+  up) and the launch / host-sync / compile counters;
+- device: time by phase (forward, backward, optimizer, grad_post, collective,
+  other), by scope (``forward/resnetv10/stage1``), by Pallas kernel name and
+  by HLO category, the offset between the device's clock and the host's, and
+  the idle gaps named by the ``mxt.*`` span under them. It is read back from
+  the trace by ``profiler_trace.aggregate`` (also ``profiler.aggregate``),
+  from the names the fused steps, the blocks and the ops write with
+  ``jax.named_scope`` while JAX traces them.
 
 Env autostart: ``MXT_PROFILER_AUTOSTART=1`` (ref MXNET_PROFILER_AUTOSTART).
 """
@@ -25,7 +40,8 @@ __all__ = ["set_config", "set_state", "state", "start", "stop", "pause",
            "Marker", "record_launch", "launch_count", "reset_launch_count",
            "counter_value", "record_host_sync", "host_sync_count",
            "reset_host_sync_count", "set_gauge", "gauge_value",
-           "compile_count", "compile_seconds"]
+           "compile_count", "compile_seconds", "aggregate", "setup_scope",
+           "setup_seconds"]
 
 _config = {
     "filename": "profile_output",
@@ -40,6 +56,7 @@ _config = {
 _state = "stop"
 _paused = False
 _trace_dir = None
+_device_table = None  # dumps()' device half, read once per finished trace
 # aggregate table: name -> [count, total_sec, min_sec, max_sec]
 _agg = {}
 # _LOCK guards _agg and the counter/gauge name maps below; the metric
@@ -243,7 +260,7 @@ def state():
 def set_state(new_state="stop"):
     """'run' starts a JAX trace; 'stop' ends it (ref:
     MXSetProcessProfilerState)."""
-    global _state, _trace_dir
+    global _state, _trace_dir, _device_table
     if new_state not in ("run", "stop"):
         raise MXNetError("profiler state must be 'run' or 'stop', got %r"
                          % (new_state,))
@@ -258,7 +275,7 @@ def set_state(new_state="stop"):
         _trace_dir = base[:-5] if base.endswith(".json") else base
         os.makedirs(_trace_dir, exist_ok=True)
         jax.profiler.start_trace(_trace_dir)
-        _state = "run"
+        _state, _device_table = "run", None
     else:
         jax.profiler.stop_trace()
         _state = "stop"
@@ -316,6 +333,8 @@ def dumps(reset=False):
     lines.append("    %-24s value=%d" % ("host_syncs", host_sync_count()))
     lines.append("    %-24s value=%d (%.3fs)"
                  % ("xla_compiles", compile_count(), compile_seconds()))
+    if _state == "stop" and _trace_dir is not None:
+        lines.append(_device_stats())
     if reset:
         with _LOCK:
             _agg.clear()
@@ -324,6 +343,29 @@ def dumps(reset=False):
         reset_launch_count()
         reset_host_sync_count()
     return "\n".join(lines)
+
+
+def aggregate(trace=None, **kwargs):
+    """Device seconds by phase, scope, kernel and HLO category, the clock
+    offset and the named idle gaps of a finished trace (the last one taken
+    with ``set_state`` where ``trace`` is None), as a dict:
+    ``profiler_trace.aggregate``, which also documents ``kwargs``."""
+    from . import profiler_trace
+
+    if trace is None:
+        trace = _trace_dir
+    if trace is None:
+        raise MXNetError("profiler.aggregate: no trace was taken")
+    return profiler_trace.aggregate(trace, **kwargs)
+
+
+def _device_stats():
+    global _device_table
+    if _device_table is None:
+        from . import profiler_trace
+
+        _device_table = profiler_trace.format_table(aggregate())
+    return _device_table
 
 
 def _record(name, dt):
@@ -369,8 +411,11 @@ class _Scope:
             self._ann.__exit__(None, None, None)
             self._ann = None
         if self._t0 is not None:
-            _record(self.name, time.perf_counter() - self._t0)
+            self._done(time.perf_counter() - self._t0)
             self._t0 = None
+
+    def _done(self, dt):
+        _record(self.name, dt)
 
     def __enter__(self):
         return self.start()
@@ -381,6 +426,69 @@ class _Scope:
 
 class Task(_Scope):
     pass
+
+
+class _SetupStack(threading.local):
+    """Per thread: [seconds, compiling seconds] of the phases entered inside
+    each open set-up scope."""
+
+    def __init__(self):
+        super().__init__()
+        self.open = []
+
+
+_setup_stack = _SetupStack()
+
+
+class _SetupScope(_Scope):
+    """One of the program's own set-up phases. Its time is exclusive of the
+    set-up phases entered inside it (a deferred initialisation inside the
+    shape-inferring forward counts once, under ``setup.initialize``), so the
+    phases add up to the seconds set-up took."""
+
+    def start(self):
+        _setup_stack.open.append([0.0, 0.0])
+        self._c0 = _compiling_seconds()
+        return super().start()
+
+    def _done(self, dt):
+        compiling = _compiling_seconds() - self._c0
+        stack = _setup_stack.open
+        inner = stack.pop()
+        if stack:
+            stack[-1][0] += dt
+            stack[-1][1] += compiling
+        _record(self.name, dt - inner[0])
+        with _LOCK:
+            _setup_compiling[self.name] = compiling - inner[1] \
+                + _setup_compiling.get(self.name, 0.0)
+
+
+# scope name -> seconds of it that JAX spent tracing, lowering, compiling
+_setup_compiling = {}
+
+
+def _compiling_seconds():
+    from .tuning import compile_cache
+
+    return compile_cache.total_seconds()
+
+
+def setup_scope(phase):
+    """``with profiler.setup_scope('place'):`` is the scope ``setup.place``."""
+    return _SetupScope("setup." + phase)
+
+
+def setup_seconds(compiling=False):
+    """{phase: seconds so far} of the ``setup.*`` scopes (process totals);
+    with ``compiling`` the part of each that JAX spent tracing, lowering and
+    compiling (``tuning.compile_stats``' clock)."""
+    with _LOCK:
+        if compiling:
+            return {name[len("setup."):]: s
+                    for name, s in _setup_compiling.items()}
+        return {name[len("setup."):]: ent[1] for name, ent in _agg.items()
+                if name.startswith("setup.")}
 
 
 class Frame(_Scope):

@@ -17,7 +17,9 @@ Two jobs:
    / ``cache_misses`` feed ``mxt_compile_cache_{hits,misses}_total``.
    ``compile_stats()`` snapshots all of it for bench deltas and the
    zero-JIT acceptance assert: on a warm start, the hot loop's
-   cache_misses delta is 0.
+   cache_misses delta is 0. jax names the function of every such event
+   (``fun_name``): ``by_function`` lists the ten that cost most, which is
+   how a start-up's hundreds of one-operation programs get their names.
 
 Listeners are installed once at package import (mxnet_tpu/__init__
 imports tuning); they are passive counters — observability must never
@@ -35,7 +37,11 @@ _setup_dir = None
 # module-level mirror of the telemetry counters: cheap consistent
 # snapshots for compile_stats() deltas without walking the registry
 _stats = {"compiles": 0, "compile_seconds": 0.0, "trace_seconds": 0.0,
+          "lower_seconds": 0.0, "small_compiles": 0,
           "cache_hits": 0, "cache_misses": 0}
+_SMALL_SECONDS = 1.0  # a backend compile under this is a "small" one
+# fun_name -> [backend compiles, trace + lower + compile seconds]
+_by_function = {}
 
 _PHASES = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
@@ -65,8 +71,15 @@ def _on_duration(name, secs, **kw):
             if phase == "compile":
                 _stats["compiles"] += 1
                 _stats["compile_seconds"] += secs
-            elif phase == "trace":
-                _stats["trace_seconds"] += secs
+                _stats["small_compiles"] += secs < _SMALL_SECONDS
+            else:
+                _stats[phase + "_seconds"] += secs
+            fun = kw.get("fun_name") or "(unnamed)"
+            if fun.startswith("jit(") and fun.endswith(")"):
+                fun = fun[4:-1]  # lowering says jit(f) where tracing says f
+            ent = _by_function.setdefault(fun, [0, 0.0])
+            ent[0] += phase == "compile"
+            ent[1] += secs
         _telemetry().record_compile(phase, secs)
     except Exception:  # noqa: BLE001 — never break a compile over metrics
         pass
@@ -139,9 +152,23 @@ def cache_dir():
     return _setup_dir
 
 
+def total_seconds():
+    """Trace + lower + compile seconds so far (what a set-up phase of
+    ``mx.profiler`` subtracts to say how much of it was compiling)."""
+    with _lock:
+        return (_stats["trace_seconds"] + _stats["lower_seconds"]
+                + _stats["compile_seconds"])
+
+
 def compile_stats():
     """One consistent snapshot: compiles, compile_seconds,
-    trace_seconds, cache_hits, cache_misses (process totals — diff two
-    snapshots to scope a window)."""
+    trace_seconds, lower_seconds, small_compiles (backend compiles under a
+    second), cache_hits, cache_misses (process totals — diff two snapshots
+    to scope a window), and ``by_function``: the ten functions that cost
+    most, as ``{"name", "compiles", "seconds"}`` (trace + lower + compile)."""
     with _lock:
-        return dict(_stats)
+        out = dict(_stats)
+        top = sorted(_by_function.items(), key=lambda kv: -kv[1][1])[:10]
+    out["by_function"] = [{"name": k, "compiles": v[0], "seconds": v[1]}
+                          for k, v in top]
+    return out

@@ -1,0 +1,105 @@
+#!/usr/bin/env python3
+"""One traced run of a benchmark cell with the device time by scope.
+
+    python3 tools/trace_cell.py --workload resnet50_train_b256 --seed 7 --seconds 30
+
+Runs ``benchmark/run.py --trace 1`` in this process, and before the run's
+trace is deleted reads it back with ``mx.profiler.aggregate``: it prints the
+run's own lines and result, then ``dumps()``'s device table, then one JSON
+line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
+scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
+set-up phases and the compile counters. The benchmark's cells cannot name a
+new per-layer metric without an edit to their files (PERF.md section 7), so
+this is how those numbers are taken meanwhile.
+"""
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+
+import run as bench  # noqa: E402 — benchmark/run.py
+
+SCOPES = ("batchnorm", "batchnorm_bwd", "layernorm", "layernorm_bwd", "attention",
+          "attention_bwd")
+
+
+class Context(bench.Context):
+    """The benchmark's context, reading each trace back before deleting it."""
+
+    tables = []
+    steps = None
+
+    def say(self, **row):
+        if row.get("phase") == "traced":
+            Context.steps = row["steps"]
+        if row.get("phase") in ("built", "first_steps"):  # set-up, up to here
+            from mxnet_tpu import profiler, tuning
+
+            row["program_setup"] = {
+                "seconds": profiler.setup_seconds(),
+                "compiling": profiler.setup_seconds(compiling=True),
+                "since_start": self.since_start(),
+                "compile_stats": tuning.compile_stats()}
+        super().say(**row)
+
+    def cleanup(self):
+        from mxnet_tpu import profiler, tuning
+
+        for path in self._trace_dirs:
+            t0 = time.perf_counter()
+            agg = profiler.aggregate(path, depth=5, window="bench.trace_window",
+                                     top=40)
+            Context.tables.append((agg, time.perf_counter() - t0))
+        Context.setup = profiler.setup_seconds()
+        Context.compile_stats = tuning.compile_stats()
+        super().cleanup()
+
+
+def scoped(agg, steps):
+    """Milliseconds a step: the formulas a per-layer reader would use."""
+    per = 1e3 / steps
+    ph, named, busy = agg["phase_s"], agg["named_s"], agg["busy_s"]
+    out = {"steps": steps, "busy_ms": busy * per,
+           "forward_ms": ph["forward"] * per, "backward_ms": ph["backward"] * per,
+           "optimizer_ms": (ph["optimizer"] + ph["grad_post"]) * per,
+           "collective_ms": ph["collective"] * per, "other_ms": ph["other"] * per,
+           "unattributed_share": 100.0 * ph["other"] / busy,
+           "batchnorm_ms": (named.get("batchnorm", 0) + named.get("batchnorm_bwd", 0)) * per,
+           "attention_fwd_ms": named.get("attention", 0) * per,
+           "attention_bwd_ms": named.get("attention_bwd", 0) * per,
+           "layernorm_ms": (named.get("layernorm", 0) + named.get("layernorm_bwd", 0)) * per,
+           "kernel_ms": {k: v * per for k, v in agg["kernel_s"].items()},
+           "kernel_calls_per_step": {k: v / steps for k, v in agg["kernel_calls"].items()},
+           "category_ms": {k: v * per for k, v in list(agg["category_s"].items())[:12]},
+           "kind_ms": {k: v * per for k, v in list(agg["kind_s"].items())[:24]},
+           "scope_ms": {k: v * per for k, v in list(agg["scope_s"].items())[:40]},
+           "ops_ms": [[o["name"], o["phase"], o["scope"], o["category"],
+                       o["seconds"] * per, o["calls"] / steps] for o in agg["ops"]],
+           "clock_offset_us": agg["clock_offset_us"], "launch_pairs": agg["launch_pairs"],
+           "idle_gaps": agg["idle_gaps"]}
+    return out
+
+
+def main(argv):
+    bench.Context = Context
+    rc = bench.main(list(argv) + ["--trace", "1"])
+    from mxnet_tpu import profiler_trace  # the run has imported the program
+
+    for agg, seconds in Context.tables:
+        row = {"aggregate_seconds": seconds, "setup_seconds": Context.setup,
+               "compile_stats": Context.compile_stats}
+        if agg is None:
+            print("no device operations in the trace")
+        else:
+            print(profiler_trace.format_table(agg, top=40))
+            row.update(scoped(agg, Context.steps))
+        print(json.dumps({"scoped": row}), flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
