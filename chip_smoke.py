@@ -180,18 +180,22 @@ def phase_kernels(args, dev):
     key = jax.random.PRNGKey(args.seed)
     out = dict(phase="kernels", interpret=interp, flash={}, paged={}, bn={})
 
-    # -- flash forward, blocks as the tuning table's cost model picks them
+    # -- flash forward, blocks as the tuning table's cost model picks them,
+    #    and the backward kernel wherever _flash_bwd would take it, at the
+    #    blocks it would take, against the reference's gradient
     if args.rehearse:
         flash_cases = [("bert_bias", (2, 2, 128, 64), False, True),
+                       ("bert_s512", (1, 2, 256, 64), False, False),
                        ("causal_512", (1, 2, 256, 64), True, False)]
     else:
         flash_cases = [("bert_bias", (32, 12, 128, 64), False, True),
+                       ("bert_s512", (32, 12, 512, 64), False, False),
                        ("causal_512", (8, 12, 512, 64), True, False),
                        # largest K/V residency _kv_fits_vmem admits (D=64, bf16)
                        ("causal_maxseq", (1, 2, 16384, 64), True, False)]
     for name, shape, causal, with_bias in flash_cases:
-        ks = jax.random.split(jax.random.fold_in(key, len(name)), 3)
-        q, k, v = (jax.random.normal(s, shape, jnp.bfloat16) for s in ks)
+        ks = jax.random.split(jax.random.fold_in(key, len(name)), 4)
+        q, k, v, do = (jax.random.normal(s, shape, jnp.bfloat16) for s in ks)
         check(A._kv_fits_vmem(k), "%s must take the whole-sequence K/V path" % name)
         bias = None
         if with_bias:
@@ -199,9 +203,13 @@ def phase_kernels(args, dev):
             bias = A.make_padding_bias(jnp.asarray(lens), max_len=shape[2])
         cfg = tuning.heuristic_attention(shape, shape[2], "bfloat16", causal)
         sm = 1.0 / math.sqrt(shape[3])
-        kernel = jax.jit(lambda q, k, v, b: A._flash_forward_pallas(
+        fwd = jax.jit(lambda q, k, v, b: A._flash_forward_pallas(
             q, k, v, b, causal, sm, cfg["block_q"], cfg["block_k"],
-            interpret=interp)[0])
+            interpret=interp))
+
+        def kernel(q, k, v, b):
+            return fwd(q, k, v, b)[0]
+
         xla = jax.jit(lambda q, k, v, b: A._attention_reference(
             q, k, v, b, causal, sm))
         t0 = time.perf_counter()
@@ -215,6 +223,29 @@ def phase_kernels(args, dev):
             xla_ms=median_ms(xla, q, k, v, bias))
         check(bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))), name + ": not finite")
         check(err < 2e-2, "flash %s: rel err %.4f vs reference" % (name, err))
+        if not A._qdo_fits_vmem(q):  # _flash_bwd keeps such a call in XLA
+            continue
+        bq, bk = A._bwd_blocks(shape[2], shape[2])
+        o, lse = fwd(q, k, v, bias)
+        bwd = jax.jit(lambda q, k, v, b, o, lse, do: A._flash_backward_pallas(
+            q, k, v, b, o, lse, do, causal, sm, bq, bk, interpret=interp)[:3])
+        chunk = A._bwd_chunk(shape[0], shape[1], shape[2], shape[2])
+        bwd_xla = jax.jit(lambda q, k, v, b, o, lse, do: A._bwd_chunked(
+            q, k, v, b, o, lse, do, causal, sm, chunk=chunk)[:3])
+        f32 = jnp.float32
+        ref = jax.jit(jax.grad(lambda q, k, v, b, do: jnp.sum(A._attention_reference(
+            q.astype(f32), k.astype(f32), v.astype(f32), b, causal, sm)
+            * do.astype(f32)), argnums=(0, 1, 2)))
+        with jax.default_matmul_precision("highest"):
+            want = ref(q, k, v, bias, do)
+        errs = [rel_err(g, w) for g, w in zip(bwd(q, k, v, bias, o, lse, do), want)]
+        out["flash"][name].update(
+            bwd_block_q=bq, bwd_block_k=bk, bwd_rel_err=errs,
+            bwd_kernel_ms=median_ms(bwd, q, k, v, bias, o, lse, do),
+            bwd_xla_chunk=chunk,
+            bwd_xla_ms=median_ms(bwd_xla, q, k, v, bias, o, lse, do))
+        check(max(errs) < 2e-2, "flash backward %s: rel errs %s vs the reference's "
+              "gradient" % (name, errs))
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks

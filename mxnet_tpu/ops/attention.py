@@ -5,8 +5,12 @@ upgrade).
 
 Forward is an online-softmax Pallas kernel: Q blocks stream over K/V blocks
 held in VMEM, never materializing the (T, T) score matrix in HBM. Backward
-recomputes scores blockwise in XLA from the saved logsumexp (standard
-flash-v2 recipe; XLA fuses the recompute into the dq/dk/dv matmuls).
+recomputes the scores tile by tile from the saved logsumexp (the flash-v2
+recipe): wherever the forward kernel ran, in a Pallas kernel
+(``flash_attention_bwd``: one pass, five matmuls a tile, dq accumulated in
+VMEM); everywhere else in XLA, over 128-aligned K/V chunks when the score
+matrix is too big to materialize. Both take their matmul operands in the
+input dtype and accumulate in float32.
 
 Layout: (B, H, T, D) with D the head dim — MXU-friendly (T, D) @ (D, T)
 tiles, fp32 accumulation via preferred_element_type.
@@ -28,6 +32,7 @@ from .registry import register
 # (tuning/autotune.py) — a bad value fails the attention call with a
 # typed error instead of breaking package import
 from .. import config as _config
+from .. import telemetry as _telemetry
 
 
 def _block_cfg(name):
@@ -244,6 +249,200 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
+# Pallas backward kernel
+# ---------------------------------------------------------------------------
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
+                      dq_ref, dk_ref, dv_ref, db_ref, dq_acc, *,
+                      block_q, causal, sm_scale, kv_len, q_len, kv_pad):
+    """One (batch x head, K/V block) grid step of the backward: Q, dO and
+    the row statistics of the whole head sit in VMEM, the K/V block's
+    scores are recomputed from ``lse`` one Q block at a time, and five
+    matmuls a tile give dv, dp, dk and dq. Operands of every matmul are in
+    the input dtype, the accumulators float32.
+
+    The tile is held key-major, ``s_t[k, q]``: dv and dk are then plain
+    matmuls, ``lse`` and ``delta`` are lane-oriented rows (no lane-padded
+    column per query), and only ds turns once for dq. ``sm_scale`` is
+    applied to ds after its matmuls, on the (T, D) sums."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    ik = pl.program_id(1)
+    block_k = k_ref.shape[1]
+    tq_pad = q_ref.shape[1]
+    k_off = ik * block_k
+    shift = kv_len - q_len  # causal is bottom-right aligned, as the forward
+    sm_scale = f32(sm_scale)
+    neg_inf = f32(_NEG_INF)
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+
+    @pl.when(ik == 0)
+    def _zero():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, f32)
+
+    k = k_ref[0]  # (BK, D)
+    v = v_ref[0]
+    bias_col = None
+    if bias_ref is not None:
+        # the key bias is a lane-oriented row; a key-major tile wants it as
+        # a column: turn a sublane-broadcast (128, BK) tile
+        bias_col = jnp.broadcast_to(
+            bias_ref[0].astype(f32), (_LSE_LANES, block_k)).T[:, :1]
+
+    def body(iq, carry):
+        dk_i, dv_i, db_i = carry
+        q_off = pl.multiple_of(iq * block_q, block_q)
+        q = q_ref[0, pl.ds(q_off, block_q), :]  # (BQ, D)
+        do = do_ref[0, pl.ds(q_off, block_q), :]
+        lse = st_ref[0, 0:1, pl.ds(q_off, block_q)]  # (1, BQ)
+        delta = st_ref[0, 1:2, pl.ds(q_off, block_q)]
+        s_t = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=f32) * sm_scale  # (BK, BQ)
+        if bias_col is not None:
+            s_t = s_t + bias_col
+        masks = []
+        if kv_pad != kv_len or tq_pad != q_len or causal:
+            kcol = k_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            qrow = q_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            if kv_pad != kv_len:  # tail-block padding
+                masks.append(kcol < kv_len)
+            if tq_pad != q_len:
+                masks.append(qrow < q_len)
+            if causal:
+                masks.append(kcol <= qrow + shift)
+            s_t = jnp.where(functools.reduce(jnp.logical_and, masks), s_t,
+                            neg_inf)
+        p_t = jnp.exp(s_t - lse)
+        dv_i = dv_i + jnp.dot(p_t.astype(do.dtype), do,
+                              preferred_element_type=f32)  # (BK, D)
+        dp_t = jax.lax.dot_general(v, do, nt, preferred_element_type=f32)
+        ds_t = p_t * (dp_t - delta)
+        if db_i is not None:
+            db_i = db_i + jnp.sum(ds_t, axis=1, keepdims=True)
+        dk_i = dk_i + jnp.dot(ds_t.astype(q.dtype), q,
+                              preferred_element_type=f32)
+        dq_acc[pl.ds(q_off, block_q), :] += jnp.dot(
+            ds_t.T.astype(k.dtype), k, preferred_element_type=f32)
+        return dk_i, dv_i, db_i
+
+    nq = tq_pad // block_q
+    first = jnp.int32(0)
+    if causal:
+        # Q blocks wholly above the diagonal see none of this K/V block:
+        # their tiles are exactly zero
+        # (lax.div on a non-negative i32: jnp's floor_divide does not lower)
+        first = jnp.minimum(jax.lax.div(
+            jnp.maximum(k_off - shift, 0), jnp.int32(block_q)), nq)
+    zero = jnp.zeros((block_k, k.shape[1]), f32)
+    db0 = None if bias_ref is None else jnp.zeros((block_k, 1), f32)
+    # i32 bounds: under jax_enable_x64 a Python int traces as i64
+    dk, dv, db = jax.lax.fori_loop(first, jnp.int32(nq), body,
+                                   (zero, zero, db0))
+    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if db_ref is not None:
+        db_ref[0] = jnp.broadcast_to(db, (block_k, _LSE_LANES)).T[:1, :]
+
+    @pl.when(ik == pl.num_programs(1) - 1)
+    def _finish():
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _bwd_blocks(Tq, Tk):
+    """(block_q, block_k) of the backward kernel, a fixed function of the
+    shape: the largest of 512 / 256 / 128 that divides the length rounded
+    up to the 128 lanes a tile's minor dimension wants."""
+    def pick(t):
+        t128 = -(-t // 128) * 128
+        return next(b for b in (512, 256, 128) if t128 % b == 0)
+    return pick(Tq), pick(Tk)
+
+
+def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
+                           block_q, block_k, interpret):
+    """dq, dk, dv (and dbias) of flash attention from the forward's
+    residuals, never building a score tensor in HBM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    f32 = jnp.float32
+    delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Tq)
+    stats = jnp.stack([lse.astype(f32), delta], axis=2)  # (B,H,2,Tq)
+    pad_q = (-Tq) % block_q
+    pad_k = (-Tk) % block_k
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+        do = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+        stats = jnp.pad(stats, ((0, 0), (0, 0), (0, 0), (0, pad_q)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    Tqp, Tkp = Tq + pad_q, Tk + pad_k
+    BH = B * H
+
+    # np.int32 zeros in the index maps, as the forward (x64 is on)
+    z = np.int32(0)
+    whole_q = pl.BlockSpec((1, Tqp, D), lambda bh, ik: (bh, z, z),
+                           memory_space=pltpu.VMEM)
+    kv_blk = pl.BlockSpec((1, block_k, D), lambda bh, ik: (bh, ik, z),
+                          memory_space=pltpu.VMEM)
+    key_row = pl.BlockSpec((1, 1, block_k), lambda bh, ik: (bh, z, ik),
+                           memory_space=pltpu.VMEM)
+    in_specs = [whole_q, kv_blk, kv_blk, whole_q,
+                pl.BlockSpec((1, 2, Tqp), lambda bh, ik: (bh, z, z),
+                             memory_space=pltpu.VMEM)]
+    args = [q.reshape(BH, Tqp, D), k.reshape(BH, Tkp, D),
+            v.reshape(BH, Tkp, D), do.reshape(BH, Tqp, D),
+            stats.reshape(BH, 2, Tqp)]
+    out_specs = [whole_q, kv_blk, kv_blk]
+    out_shape = [jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
+                 jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
+                 jax.ShapeDtypeStruct((BH, Tkp, D), v.dtype)]
+    static = dict(block_q=block_q, causal=causal, sm_scale=sm_scale,
+                  kv_len=Tk, q_len=Tq, kv_pad=Tkp)
+    if bias is not None:
+        bflat = jnp.broadcast_to(bias.astype(f32), (B, H, 1, Tk))
+        if pad_k:
+            bflat = jnp.pad(bflat, ((0, 0), (0, 0), (0, 0), (0, pad_k)))
+        in_specs.append(key_row)
+        args.append(bflat.reshape(BH, 1, Tkp))
+        out_specs.append(key_row)
+        out_shape.append(jax.ShapeDtypeStruct((BH, 1, Tkp), f32))
+
+    def kernel(*refs):
+        refs = list(refs)
+        if bias is None:  # no bias in, no dbias out
+            refs.insert(5, None)
+            refs.insert(9, None)
+        _flash_bwd_kernel(*refs, **static)
+
+    outs = pl.pallas_call(
+        kernel,
+        grid=(BH, Tkp // block_k),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((Tqp, D), f32)],
+        # the K/V axis carries the dq accumulator: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )(*args)
+    dq = outs[0].reshape(B, H, Tqp, D)[:, :, :Tq]
+    dk = outs[1].reshape(B, H, Tkp, D)[:, :, :Tk]
+    dv = outs[2].reshape(B, H, Tkp, D)[:, :, :Tk]
+    dbias = None
+    if bias is not None:
+        dbias = _reduce_dbias(outs[3].reshape(B, H, 1, Tkp)[..., :Tk], bias)
+    return dq, dk, dv, dbias
+
+
+# ---------------------------------------------------------------------------
 # chunked-XLA path for long sequences (K/V too big for whole-sequence VMEM
 # residency; lax.scan streams KV chunks with the same online softmax —
 # O(Tq * chunk) memory, fused by XLA)
@@ -317,11 +516,14 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK):
 
 def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
                  chunk=LONG_CHUNK):
+    """Backward over K/V chunks in XLA. Matmul operands stay in the input
+    dtype (``p`` and ``ds`` are cast to it just before their matmuls) and
+    accumulate in float32; ``exp``, ``delta`` and the ``dq`` carry are
+    float32."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    delta = jnp.sum(dof * out.astype(jnp.float32), axis=-1)  # (B,H,Tq)
+    f32 = jnp.float32
+    delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Tq)
     kc, pad = _chunk_kv(k, chunk)
     vc, _ = _chunk_kv(v, chunk)
     nchunks = kc.shape[2]
@@ -337,11 +539,10 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
             k_c, v_c, b_c, idx = xs
         else:
             k_c, v_c, idx = xs
-        kcf = k_c.astype(jnp.float32)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kcf,
-                       preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k_c,
+                       preferred_element_type=f32) * sm_scale
         if bias is not None:
-            s = s + b_c.astype(jnp.float32)
+            s = s + b_c.astype(f32)
         col = idx * chunk + jnp.arange(chunk)
         valid = col[None, :] < Tk
         if causal:
@@ -350,34 +551,49 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
                 valid, col[None, :] <= row[:, None] + (Tk - Tq))
         s = jnp.where(valid[None, None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])
-        dv_c = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", dof, v_c.astype(jnp.float32))
+        dv_c = jnp.einsum("bhqk,bhqd->bhkd", p.astype(do.dtype), do,
+                          preferred_element_type=f32)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do, v_c,
+                        preferred_element_type=f32)
         ds = p * (dp - delta[..., None]) * sm_scale
-        dq_acc = dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds, kcf)
-        dk_c = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
+        ds_c = ds.astype(q.dtype)
+        dq_acc = dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds_c, k_c,
+                                     preferred_element_type=f32)
+        dk_c = jnp.einsum("bhqk,bhqd->bhkd", ds_c, q,
+                          preferred_element_type=f32)
         db_c = jnp.sum(ds, axis=2) / sm_scale  # (B,H,chunk)
-        return dq_acc, (dk_c, dv_c, db_c)
+        # a chunk's dk / dv are final, so this is their one cast
+        return dq_acc, (dk_c.astype(k.dtype), dv_c.astype(v.dtype), db_c)
 
     idxs = jnp.arange(nchunks)
     xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), bc, idxs) \
         if bias is not None else \
         (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), idxs)
-    dq, (dk_s, dv_s, db_s) = jax.lax.scan(body, jnp.zeros_like(qf), xs)
+    dq, (dk_s, dv_s, db_s) = jax.lax.scan(
+        body, jnp.zeros((B, H, Tq, D), f32), xs)
     dk = jnp.moveaxis(dk_s, 0, 2).reshape(B, H, Tk + pad, D)[:, :, :Tk]
     dv = jnp.moveaxis(dv_s, 0, 2).reshape(B, H, Tk + pad, D)[:, :, :Tk]
     dbias = None
     if bias is not None:
         db = jnp.moveaxis(db_s, 0, 2).reshape(B, H, Tk + pad)[:, :, :Tk]
-        dbias = db[:, :, None, :]
-        if bias.shape[1] == 1:
-            dbias = jnp.sum(dbias, axis=1, keepdims=True)
-        dbias = dbias.astype(bias.dtype)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-            dbias)
+        dbias = _reduce_dbias(db[:, :, None, :], bias)
+    return dq.astype(q.dtype), dk, dv, dbias
+
+
+def _reduce_dbias(db, bias):
+    """(B, H, 1, Tk) per-head bias gradient → the bias's own shape and
+    dtype (a (B, 1, 1, Tk) bias is broadcast over heads)."""
+    if bias.shape[1] == 1:
+        db = jnp.sum(db, axis=1, keepdims=True)
+    return db.astype(bias.dtype)
 
 
 # ---------------------------------------------------------------------------
-# custom vjp: pallas forward, XLA-recompute backward
+# custom vjp: both halves recompute nothing they can keep in VMEM. Forward:
+# the Pallas kernel where the tuning table picks it on a TPU, the scan when
+# K/V outgrow VMEM, else the reference. Backward, from the forward's lse:
+# the Pallas kernel wherever the forward kernel ran, else XLA (chunked over
+# K/V when the scores are over _BWD_SCORE_BYTES, else materialised)
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _flash_core(q, k, v, bias, causal, sm_scale):
@@ -424,23 +640,50 @@ def _record_flash_signature(q, k, v, bias, causal, sm_scale):
 
 
 _BWD_SCORE_BYTES = 256 * 1024 * 1024  # peak score-matrix budget in backward
+_VMEM_QDO_BYTES = 1024 * 1024  # per-(batch,head) Q+dO budget, backward kernel
+
+
+def _qdo_fits_vmem(q):
+    return 2 * q.shape[2] * q.shape[3] * q.dtype.itemsize <= _VMEM_QDO_BYTES
+
+
+def _bwd_chunk(B, H, Tq, Tk):
+    """K/V chunk of the XLA backward: the score budget in whole 128-key
+    tiles (at least one), Tk split evenly over the chunks that takes, so a
+    chunk is never ragged and the padding stays under a tile a chunk."""
+    cap = max(1, _BWD_SCORE_BYTES // max(1, B * H * Tq * 4) // 128)
+    tiles = -(-Tk // 128)
+    nchunks = -(-tiles // cap)
+    return -(-tiles // nchunks) * 128
 
 
 @jax.named_scope("attention_bwd")
 def _flash_bwd(causal, sm_scale, res, do):
+    """Backward of ``_flash_core``. The branch it takes is counted
+    (``telemetry.flash_bwd_branches()``): once a trace, so once a compiled
+    program that differentiates the op."""
     q, k, v, bias, out, lse = res
     B, H, Tq, _ = q.shape
     Tk = k.shape[2]
+    if (lse is not None and on_tpu() and _kv_fits_vmem(k)
+            and _qdo_fits_vmem(q)):
+        # the forward kernel ran (its lse is here, its K/V fit VMEM) and a
+        # head's Q and dO fit beside them: same recipe, tiled in VMEM
+        _telemetry.record_flash_bwd("kernel")
+        block_q, block_k = _bwd_blocks(Tq, Tk)
+        return _flash_backward_pallas(q, k, v, bias, out, lse, do, causal,
+                                      sm_scale, block_q, block_k,
+                                      interpret=False)
     score_bytes = B * H * Tq * Tk * 4
     if not _kv_fits_vmem(k) or score_bytes > _BWD_SCORE_BYTES:
         # keep backward O(Tq * chunk): a forward that fit VMEM can still
         # have a score matrix far too big to materialize (e.g. T=8k)
+        _telemetry.record_flash_bwd("chunked")
         if lse is None:
             _, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale)
-        chunk = int(max(128, min(
-            Tk, _BWD_SCORE_BYTES // max(1, B * H * Tq * 4))))
         return _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
-                            chunk=chunk)
+                            chunk=_bwd_chunk(B, H, Tq, Tk))
+    _telemetry.record_flash_bwd("materialised")
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -465,12 +708,8 @@ def _flash_bwd(causal, sm_scale, res, do):
     dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf).astype(k.dtype)
     dbias = None
     if bias is not None:
-        db = ds / sm_scale
-        # reduce over broadcast dims of the (B, H|1, 1, Tk) bias
-        dbias = jnp.sum(db, axis=2, keepdims=True)
-        if bias.shape[1] == 1:
-            dbias = jnp.sum(dbias, axis=1, keepdims=True)
-        dbias = dbias.astype(bias.dtype)
+        dbias = _reduce_dbias(
+            jnp.sum(ds / sm_scale, axis=2, keepdims=True), bias)
     return dq, dk, dv.astype(v.dtype), dbias
 
 

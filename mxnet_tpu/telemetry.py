@@ -69,6 +69,7 @@ __all__ = [
     "add_event_tap", "remove_event_tap",
     "record_phase", "record_dispatch", "record_step_retired",
     "record_compile", "record_compile_cache", "record_tune_lookup",
+    "record_flash_bwd", "flash_bwd_branches",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
     "record_rpc", "rpc_spans", "clear_rpc_spans",
     "record_trace_span", "trace_spans", "clear_trace_spans",
@@ -790,6 +791,32 @@ def record_tune_lookup(hit):
                     "Tuning-table lookups that fell through to "
                     "measurement or the heuristic cost model."))
     _tune_cache_c[0 if hit else 1].inc()
+
+
+_flash_bwd_c = None
+
+
+def record_flash_bwd(branch):
+    """One traced flash-attention backward, by the branch its dispatch took
+    (``mxt_flash_bwd_total{branch=kernel|chunked|materialised}``). Counted
+    at trace time: once per compiled program that differentiates the op,
+    not once a step."""
+    global _flash_bwd_c
+    if _flash_bwd_c is None:
+        _flash_bwd_c = counter(
+            "mxt_flash_bwd_total",
+            "Traced flash-attention backward passes by branch.",
+            ("branch",))
+    _flash_bwd_c.labels(branch).inc()
+
+
+def flash_bwd_branches():
+    """{branch: traces} of :func:`record_flash_bwd` so far."""
+    fam = _REGISTRY.get("mxt_flash_bwd_total")
+    if fam is None:
+        return {}
+    return {values[0]: int(ch.value)
+            for values, ch in sorted(fam.children().items())}
 
 
 # --------------------------------------------------------------------------

@@ -76,6 +76,25 @@ def _flash(shape, dtype, causal, bias_shape=None, blocks=None):
     return case
 
 
+def _flash_bwd(shape, dtype, causal, bias_shape=None, tk=None, blocks=None):
+    """The backward kernel at the blocks the dispatch picks for the shape."""
+    def case(chip):
+        B, H, Tq, D = shape
+        Tk = tk or Tq
+        bq, bk = blocks or A._bwd_blocks(Tq, Tk)
+        sm = D ** -0.5
+        dt = jnp.dtype(dtype)
+        qs, ks = (shape, dt), ((B, H, Tk, D), dt)
+        args = [qs, ks, ks, qs, ((B, H, Tq), jnp.float32), qs]
+        if bias_shape is not None:
+            args.append((bias_shape, jnp.float32))
+        return _compile(
+            chip, lambda q, k, v, out, lse, do, b=None:
+            A._flash_backward_pallas(q, k, v, b, out, lse, do, causal, sm,
+                                     bq, bk, False), *args)
+    return case
+
+
 def _paged(batch, heads, dim, block_h=None, dtype="bfloat16", page=16,
            max_pages=64):
     def case(chip):
@@ -115,6 +134,23 @@ _CASES = {
                                         blocks=(256, 512)),
     "flash_maxseq_16384_bias": _flash((1, 2, 16384, 64), "bfloat16", True,
                                       bias_shape=(1, 1, 1, 16384)),
+    # flash backward: the benchmark's BERT cell (32 x 512), causal, a
+    # padding bias (dbias comes out of the kernel), a longer causal
+    # sequence, a ragged one, and the corner of what _flash_bwd sends it:
+    # the longest Q _qdo_fits_vmem admits against the longest K/V
+    # _kv_fits_vmem admits, in bf16 and in float32
+    "flash_bwd_bert_s512": _flash_bwd((32, 12, 512, 64), "bfloat16", False),
+    "flash_bwd_512_causal": _flash_bwd((8, 12, 512, 64), "bfloat16", True),
+    "flash_bwd_bert_bias": _flash_bwd((32, 12, 128, 64), "bfloat16", False,
+                                      bias_shape=(32, 1, 1, 128)),
+    "flash_bwd_2048_causal": _flash_bwd((4, 12, 2048, 64), "bfloat16", True),
+    "flash_bwd_300_ragged_bias": _flash_bwd((2, 4, 300, 64), "bfloat16",
+                                            True, bias_shape=(2, 4, 1, 300)),
+    "flash_bwd_maxq_4096_maxkv_16384_bias": _flash_bwd(
+        (1, 2, 4096, 64), "bfloat16", True, bias_shape=(1, 1, 1, 16384),
+        tk=16384),
+    "flash_bwd_f32_maxq_2048_maxkv_8192": _flash_bwd(
+        (1, 2, 2048, 64), "float32", True, tk=8192),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
     # repaired generator picks it
     "paged_8x12x64": _paged(8, 12, 64),
@@ -129,6 +165,24 @@ _CASES = {
 def test_kernel_compiles_for_v5e(chip, name):
     text = _CASES[name](chip)
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+def test_flash_bwd_corner_is_what_the_dispatch_admits():
+    """The corner cases above sit on _flash_bwd's gates: one more tile of
+    Q, or of K/V, and the call stays in XLA."""
+    bf16, f32 = jnp.dtype("bfloat16"), jnp.dtype("float32")
+
+    def fits(t, dt, gate):
+        return gate(jax.ShapeDtypeStruct((1, 2, t, 64), dt))
+
+    assert fits(4096, bf16, A._qdo_fits_vmem)
+    assert not fits(4096 + 128, bf16, A._qdo_fits_vmem)
+    assert fits(16384, bf16, A._kv_fits_vmem)
+    assert not fits(16384 + 128, bf16, A._kv_fits_vmem)
+    assert fits(2048, f32, A._qdo_fits_vmem)
+    assert not fits(2048 + 128, f32, A._qdo_fits_vmem)
+    assert fits(8192, f32, A._kv_fits_vmem)
+    assert not fits(8192 + 128, f32, A._kv_fits_vmem)
 
 
 def test_every_paged_candidate_compiles(chip):

@@ -184,6 +184,159 @@ def test_long_sequence_chunked_path():
                         atol=1e-3)
 
 
+def _bwd_case(rng, B, H, Tq, Tk, D, dtype, with_bias):
+    """Seeded q, k, v, do (and a key bias that also masks a tail) in
+    ``dtype``, with the reference's float32 gradient."""
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype("f4")).astype(dtype)
+
+    q, do = arr(B, H, Tq, D), arr(B, H, Tq, D)
+    k, v = arr(B, H, Tk, D), arr(B, H, Tk, D)
+    bias = None
+    if with_bias:
+        bias = jnp.asarray(rng.normal(size=(B, 1, 1, Tk)).astype("f4")) \
+            + A.make_padding_bias(jnp.asarray([Tk - 7] + [Tk] * (B - 1)), Tk)
+    return q, k, v, do, bias
+
+
+def _ref_grads(q, k, v, do, bias, causal, sm_scale):
+    f32 = jnp.float32
+
+    def loss(q_, k_, v_, b_):
+        return jnp.sum(A._attention_reference(
+            q_.astype(f32), k_.astype(f32), v_.astype(f32), b_, causal,
+            sm_scale) * do.astype(f32))
+
+    if bias is None:
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v, None) + (None,)
+    return jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, v, bias)
+
+
+def _assert_grads_close(got, ref, tol):
+    """Each gradient within ``tol`` of the reference's, in units of the
+    reference's largest entry (bf16 rounds relative to the magnitude)."""
+    for name, g, r in zip(("dq", "dk", "dv", "dbias"), got, ref):
+        assert (g is None) == (r is None), name
+        if r is None:
+            continue
+        assert g.shape == r.shape, name
+        g = np.asarray(g.astype(jnp.float32))
+        r = np.asarray(r.astype(jnp.float32))
+        err = np.max(np.abs(g - r)) / (np.max(np.abs(r)) + 1e-30)
+        assert err < tol, "%s: %.3g of the largest entry" % (name, err)
+
+
+_BWD_SHAPES = {
+    # (Tq, Tk, block_q, block_k)
+    "block_multiple": (64, 64, 32, 32),
+    "ragged_80": (80, 80, 32, 32),
+    "tq_ne_tk": (48, 80, 32, 16),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("shape", sorted(_BWD_SHAPES))
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bwd_kernel_vs_reference(causal, with_bias, shape, dtype, tol):
+    """The backward kernel (interpret mode) against jax.grad of the
+    reference: dq, dk, dv and dbias, from the forward kernel's out/lse."""
+    Tq, Tk, bq, bk = _BWD_SHAPES[shape]
+    rng = np.random.RandomState(7)
+    q, k, v, do, bias = _bwd_case(rng, 2, 3, Tq, Tk, 32, dtype, with_bias)
+    out, lse = A._flash_forward_pallas(q, k, v, bias, causal, 0.125,
+                                       32, 32, interpret=True)
+    got = A._flash_backward_pallas(q, k, v, bias, out, lse, do, causal,
+                                   0.125, bq, bk, interpret=True)
+    assert [g.dtype for g in got[:3]] == [q.dtype, k.dtype, v.dtype]
+    _assert_grads_close(got, _ref_grads(q, k, v, do, bias, causal, 0.125),
+                        tol)
+
+
+def test_flash_bwd_kernel_tiles_of_the_dispatch():
+    """At the blocks _bwd_blocks picks (128 lanes at least) ragged lengths
+    are padded and masked: 300 queries and 330 keys in three tiles each."""
+    assert A._bwd_blocks(512, 512) == (512, 512)
+    assert A._bwd_blocks(200, 640) == (256, 128)
+    assert A._bwd_blocks(80, 2048) == (128, 512)
+    assert A._bwd_blocks(300, 330) == (128, 128)
+    rng = np.random.RandomState(8)
+    q, k, v, do, bias = _bwd_case(rng, 1, 2, 300, 330, 32, "float32", True)
+    out, lse = A._flash_forward_pallas(q, k, v, bias, True, 0.125,
+                                       128, 128, interpret=True)
+    got = A._flash_backward_pallas(q, k, v, bias, out, lse, do, True, 0.125,
+                                   128, 128, interpret=True)
+    _assert_grads_close(got, _ref_grads(q, k, v, do, bias, True, 0.125),
+                        1e-4)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("chunk", [16, 24])  # divides Tk = 64 / does not
+@pytest.mark.parametrize("causal", [False, True])
+def test_bwd_chunked_operand_dtype_and_chunk(causal, chunk, dtype, tol):
+    """The XLA chunked backward with operands in the input dtype: a chunk
+    that divides Tk and one that leaves a padded tail."""
+    rng = np.random.RandomState(9)
+    q, k, v, do, bias = _bwd_case(rng, 2, 2, 48, 64, 16, dtype, True)
+    out, lse = A._attention_scan_fwd(q, k, v, bias, causal, 0.25, chunk=16)
+    got = A._bwd_chunked(q, k, v, bias, out, lse, do, causal, 0.25,
+                         chunk=chunk)
+    assert [g.dtype for g in got[:3]] == [q.dtype, k.dtype, v.dtype]
+    _assert_grads_close(got, _ref_grads(q, k, v, do, bias, causal, 0.25),
+                        tol)
+
+
+@pytest.mark.parametrize("shape,expect", [
+    ((32, 12, 512, 512), 256),    # the BERT cell: two chunks, no padding
+    ((32, 12, 512, 640), 256),    # five tiles in three chunks: one padded
+    ((1, 8, 8192, 8192), 1024),
+    ((64, 16, 4096, 100), 128),   # never under a tile
+    ((1, 1, 64, 131072), 131072),  # the budget admits all of Tk: one chunk
+])
+def test_bwd_chunk_is_whole_tiles(shape, expect):
+    B, H, Tq, Tk = shape
+    chunk = A._bwd_chunk(B, H, Tq, Tk)
+    assert chunk == expect and chunk % 128 == 0
+    nchunks = -(-Tk // chunk)
+    # under the score budget unless one tile is already over it
+    assert chunk == 128 or B * H * Tq * chunk * 4 <= A._BWD_SCORE_BYTES
+    assert nchunks * chunk - Tk < 128 * nchunks  # padding under a tile each
+    if shape == (32, 12, 512, 512):
+        assert nchunks * chunk == Tk  # not padded
+
+
+def test_flash_bwd_branch_counter_and_no_kernel_on_cpu(monkeypatch):
+    """_flash_bwd counts the branch it takes, and on the CPU never reaches
+    the kernel, whatever the residuals say."""
+    from mxnet_tpu import telemetry
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("the backward kernel was reached on the CPU")
+
+    monkeypatch.setattr(A, "_flash_backward_pallas", no_kernel)
+    rng = np.random.RandomState(10)
+    q, k, v, do, _ = _bwd_case(rng, 1, 2, 64, 64, 16, "float32", False)
+    out, lse = A._flash_forward_pallas(q, k, v, None, False, 0.25,
+                                       32, 32, interpret=True)
+    ref = _ref_grads(q, k, v, do, None, False, 0.25)
+
+    before = telemetry.flash_bwd_branches()
+    got = A._flash_bwd(False, 0.25, (q, k, v, None, out, lse), do)
+    _assert_grads_close(got, ref, 1e-4)
+    # a score matrix over the budget: the chunked branch, in whole tiles
+    monkeypatch.setattr(A, "_BWD_SCORE_BYTES", 1024)
+    got = A._flash_bwd(False, 0.25, (q, k, v, None, out, lse), do)
+    _assert_grads_close(got, ref, 1e-4)
+    # through the op, differentiated under jit: one count a trace
+    g = jax.jit(jax.grad(lambda q_: jnp.sum(
+        A.flash_attention(q_, k, v, sm_scale=0.25) * do)))
+    g(q), g(q)
+    after = telemetry.flash_bwd_branches()
+    delta = {b: after.get(b, 0) - before.get(b, 0) for b in after}
+    assert delta == {"chunked": 2, "materialised": 1}
+    assert "kernel" not in after
+
+
 @with_seed()
 def test_bert_mlm_weight_tying():
     net = model_zoo.bert_3_64_2(dropout=0.0)

@@ -136,6 +136,39 @@ def test_flash_grads_hardware():
         assert _maxerr(a, b) < 1e-1  # bf16 grads
 
 
+@pytest.mark.parametrize("shape,causal,with_bias", [
+    ((32, 12, 512, 64), False, False),  # the benchmark's BERT cell
+    ((2, 4, 300, 64), True, True),      # ragged, causal, dbias
+])
+def test_flash_bwd_kernel_hardware(shape, causal, with_bias):
+    """The compiled backward kernel within 2e-2 of the reference's
+    gradient (in units of its largest entry), and _flash_bwd takes it."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import attention as A
+    B, H, T, D = shape
+    q, k, v, do = (jax.random.normal(s, shape, jnp.bfloat16)
+                   for s in jax.random.split(jax.random.PRNGKey(6), 4))
+    bias = None
+    if with_bias:
+        bias = A.make_padding_bias(
+            jnp.asarray(np.linspace(T // 2, T, B).astype(np.int32)), T)
+    sm = D ** -0.5
+    f32 = jnp.float32
+    out, lse = A._flash_forward_pallas(q, k, v, bias, causal, sm,
+                                       128, 128, interpret=False)
+    before = telemetry.flash_bwd_branches().get("kernel", 0)
+    got = jax.jit(lambda *a: A._flash_bwd(causal, sm, a[:6], a[6]))(
+        q, k, v, bias, out, lse, do)
+    assert telemetry.flash_bwd_branches().get("kernel", 0) == before + 1
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(
+            A._attention_reference(q_.astype(f32), k_.astype(f32),
+                                   v_.astype(f32), bias, causal, sm)
+            * do.astype(f32)), argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(got, ref):
+        assert _maxerr(g, r) < 2e-2 * float(jnp.max(jnp.abs(r)))
+
+
 def test_flash_long_seq_chunked_hardware():
     """T long enough that K/V exceed the VMEM budget → lax.scan path."""
     from mxnet_tpu.ops import attention as A

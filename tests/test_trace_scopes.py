@@ -174,7 +174,8 @@ def test_kernels_have_names():
 
     from mxnet_tpu.ops import attention, bn_pallas
 
-    for mod, names in ((attention, ("flash_attention_fwd", "paged_decode")),
+    for mod, names in ((attention, ("flash_attention_fwd", "flash_attention_bwd",
+                                    "paged_decode")),
                        (bn_pallas, ("bn_bwd_reduce", "bn_bwd_dx"))):
         src = inspect.getsource(mod)
         assert src.count("pl.pallas_call(") == len(names)

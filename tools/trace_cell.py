@@ -8,9 +8,11 @@ trace is deleted reads it back with ``mx.profiler.aggregate``: it prints the
 run's own lines and result, then ``dumps()``'s device table, then one JSON
 line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
-set-up phases and the compile counters. The benchmark's cells cannot name a
-new per-layer metric without an edit to their files (PERF.md section 7), so
-this is how those numbers are taken meanwhile.
+kernels' calls a step, the branch each traced attention backward took
+(``flash_bwd_branches``), the set-up phases and the compile counters. The
+benchmark's cells cannot name a new per-layer metric without an edit to
+their files (PERF.md section 7), so this is how those numbers are taken
+meanwhile.
 """
 import json
 import os
@@ -32,6 +34,7 @@ class Context(bench.Context):
 
     tables = []
     steps = None
+    flash_bwd = None
 
     def say(self, **row):
         if row.get("phase") == "traced":
@@ -47,7 +50,7 @@ class Context(bench.Context):
         super().say(**row)
 
     def cleanup(self):
-        from mxnet_tpu import profiler, tuning
+        from mxnet_tpu import profiler, telemetry, tuning
 
         for path in self._trace_dirs:
             t0 = time.perf_counter()
@@ -56,6 +59,7 @@ class Context(bench.Context):
             Context.tables.append((agg, time.perf_counter() - t0))
         Context.setup = profiler.setup_seconds()
         Context.compile_stats = tuning.compile_stats()
+        Context.flash_bwd = telemetry.flash_bwd_branches()
         super().cleanup()
 
 
@@ -92,6 +96,8 @@ def main(argv):
     for agg, seconds in Context.tables:
         row = {"aggregate_seconds": seconds, "setup_seconds": Context.setup,
                "compile_stats": Context.compile_stats}
+        if Context.flash_bwd:  # which backward each traced attention took
+            row["flash_bwd_branches"] = Context.flash_bwd
         if agg is None:
             print("no device operations in the trace")
         else:
