@@ -94,6 +94,19 @@ class TrainProgram:
         return jax.jit(lambda st: {k: train_reference.gradient_from_state(opt, v)
                                    for k, v in st.items()})(state)
 
+    @staticmethod
+    def zero_counts():
+        """{name: count} of what must not have happened in the run (token-slots
+        a router dropped, say), read once after the window: each is compared
+        with 0. An adapter that counts such things defines its own."""
+        return {}
+
+    @staticmethod
+    def after_window():
+        """{name: number} that the program published over the run, for the
+        per-layer readers (``run["program"]``). An adapter defines its own."""
+        return {}
+
     def norms(self, tree):
         return {k: float(v) for k, v in jax.device_get(self._norms(tree)).items()}
 

@@ -9,8 +9,9 @@ only the cell's own shapes; measures for ``--seconds``; checks what the timed
 path produced against the plain reference outside the window; prints what it
 likes on earlier lines and, last, one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics`` and ``device`` (and ``breakdown`` when
-traced). With ``--trace 0`` the metrics are the cell's end-to-end metrics,
-with ``--trace 1`` its per-layer metrics.
+traced), then ``compared``: every number compared beside its limit, which are
+the last lines of standard error too. With ``--trace 0`` the metrics are the
+cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics.
 
 This file knows no model, cell or metric by name: everything is found through
 ``BENCHMARK.json`` and the files it names (see README.md beside this file).
@@ -25,6 +26,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -148,7 +150,15 @@ def main(argv=None):
         # a CPU number never stands under a device metric's name
         for m in result["metrics"].values():
             m["value"] = None
+    # every number compared beside its limit: the result's last key, and the
+    # last lines of standard error (what a record keeps of a run not correct)
+    result["compared"] = {
+        row["compared"]: {"value": row["value"] if math.isfinite(row["value"])
+                          else str(row["value"]), "limit": row["limit"]}
+        for row in out["compared"]}
     print(json.dumps(result), flush=True)
+    for name, row in result["compared"].items():
+        print("compared %s %s limit %s" % (name, row["value"], row["limit"]), file=sys.stderr)
     return 3 if args.rehearse else 0
 
 

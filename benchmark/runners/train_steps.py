@@ -7,15 +7,16 @@ Order of a run:
 1. set-up: seeded weights and a pool of seeded batches on the device (the
    reference's own generators), the program built around them, the first
    steps through the window's own call with a host read of each loss, the
-   first gradient read back from the optimizer's state after step one, the
-   parameters' change after the last; two more steps that must compile nothing;
+   first gradient read back from the optimizer's state after step one and
+   taken to the host once its norms are read, the parameters' change after the
+   last; two more steps that must compile nothing;
 2. the window: steps dispatched as the entry point dispatches them, at most
    ``max_inflight`` ahead of the last one waited for, closed by one wait on
    the last step; with ``--trace 1`` the last ``trace_seconds`` of it are a
    window of their own under the profiler;
-3. after the window: the program is freed, the reference follows the same
-   first batches in float32, and every number compared is printed beside
-   its limit.
+3. after the window: the program's own counts are read, the program is
+   freed, the reference follows the same first batches in float32, and every
+   number compared is printed beside its limit.
 """
 from __future__ import annotations
 
@@ -80,6 +81,8 @@ def first_steps(prog, batches, params, traffic):
     """The program's first steps through the window's own call, with what the
     comparison reads of them, and the compiles inside the step's own call from
     its second call on (there must be none)."""
+    import jax
+
     first = {"losses": []}
     n_first = traffic["first_steps"]
     later_compiles = 0
@@ -94,8 +97,14 @@ def first_steps(prog, batches, params, traffic):
         if i < n_first:
             first["losses"].append(prog.loss_value(loss))
         if i == 0:
-            first["first_gradient"] = prog.first_gradient()
-            first["grad_norms"] = prog.norms(first["first_gradient"])
+            gradient = prog.first_gradient()
+            first["grad_norms"] = prog.norms(gradient)
+            # to the host until the reference wants it: the window does not
+            # share the chip with the yardstick's float32 copy
+            t0 = time.perf_counter()
+            first["first_gradient"] = jax.device_get(gradient)
+            first["gradient_to_host_s"] = time.perf_counter() - t0
+            del gradient
         if i == n_first - 1:
             first["delta_norms"] = prog.delta_norms(params)
     return first, later_compiles
@@ -125,6 +134,7 @@ def run(ctx):
     fused = getattr(prog.entry, "fused", True)
     setup_counters = program.counters()  # the process's totals up to the window
     ctx.say(phase="first_steps", losses=first["losses"], fused=bool(fused),
+            gradient_to_host_s=first.pop("gradient_to_host_s"),
             fallback_reason=getattr(prog.entry, "fallback_reason", None),
             setup_compiles=setup_counters["compiles"],
             setup_compile_seconds=setup_counters["compile_seconds"],
@@ -136,7 +146,7 @@ def run(ctx):
     trace_s = float(traffic.get("trace_seconds", 4)) if ctx.trace else 0.0
     trace_s = min(trace_s, ctx.seconds / 2.0)
     win = _drive(prog, batches, ctx.seconds - trace_s, index, traffic["max_inflight"])
-    traced = None
+    traced = trace_dir = None
     if ctx.trace:
         trace_dir = ctx.trace_dir()
         jax.profiler.start_trace(trace_dir)
@@ -172,6 +182,7 @@ def run(ctx):
             **step_time_tail(win["ends"], traffic["max_inflight"]))
 
     # -- 3. after the window: free the program, follow with the reference ---
+    zero_counts, published = prog.zero_counts(), prog.after_window()
     del prog, batches, model
     gc.collect()
     t_ref = time.perf_counter()
@@ -189,6 +200,9 @@ def run(ctx):
     rows.append({"compared": "fused_entry", "value": int(not fused), "limit": 0,
                  "ok": bool(fused), "detail": "the entry point fell back to eager"
                  if not fused else ""})
+    for name, count in zero_counts.items():
+        rows.append({"compared": name, "value": count, "limit": 0, "ok": count == 0,
+                     "detail": "the program's own count over the run"})
     for row in rows:
         ctx.say(phase="compare", **row)
     ctx.say(phase="reference", seconds=time.perf_counter() - t_ref)
@@ -200,10 +214,11 @@ def run(ctx):
                    "samples_per_s_per_chip": rate, **win["counters"]},
         "setup": setup_counters,
         "train_flops_per_sample": flops.train_flops_per_sample(config, traffic),
-        "trace": traced,
+        "trace": traced, "trace_dir": trace_dir, "program": published,
     }
     return {
         "correct": all(r["ok"] for r in rows),
+        "compared": rows,
         "attempted": win["steps"],
         "failed": not_finite,
         "end_to_end": {"train_samples_per_s": rate, "setup_s": setup_s},

@@ -11,7 +11,7 @@ import pytest
 
 from harness import loader
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 RUN = os.path.join(loader.BENCH_DIR, "run.py")
 
 
@@ -23,7 +23,14 @@ def rehearse(cell, trace, seed=7, seconds=2):
          str(seconds), "--trace", str(trace), "--rehearse"],
         capture_output=True, text=True, env=env, timeout=600, cwd=loader.REPO_DIR)
     assert p.returncode == 3, p.stderr[-2000:]  # a rehearsal is never a result
-    return json.loads(p.stdout.strip().splitlines()[-1]), p.stdout
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    # every number compared beside its limit: the line's last key, and the last
+    # lines of standard error
+    assert list(line)[-1] == "compared" and "loss_gap.step1" in line["compared"]
+    assert p.stderr.strip().splitlines()[-len(line["compared"]):] == [
+        "compared %s %s limit %s" % (k, v["value"], v["limit"])
+        for k, v in line["compared"].items()]
+    return line, p.stdout
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -57,7 +64,7 @@ def test_no_chip_is_an_error_and_prints_no_result():
 
 DUMMY = {
     "configs/zz_dummy_config.json": {
-        "name": "zz_dummy_config", "family": "resnet", "source": "none: a test's file",
+        "name": "zz_dummy_config", "family": "zz_dummy", "source": "none: a test's file",
         "published": {"layers": [1, 1], "channels": [8, 16, 32], "classes": 5, "image": 16},
         "reduced": [], "assumed": {}, "dtype": "bfloat16", "layout": "NHWC",
         "optimizer": {"name": "sgd", "learning_rate": 0.1, "rate_per_batch": 256,
@@ -68,7 +75,7 @@ DUMMY = {
     # what BENCHMARK.json's entry would hold stands in the rehearsal's group
     "workloads/zz_dummy_cell.json": {
         "runner": "train_steps",
-        "layer_metrics": ["zz_dummy_metric", "launches_per_step.train"],
+        "layer_metrics": ["zz_dummy_metric", "zz_dummy_published", "launches_per_step.train"],
         "limits": {"loss_gap": 1.0, "grad_norm_gap": 100.0, "grad_leaf_diff": 100.0,
                    "delta_norm_gap": 100.0},
         "rehearse": {"config": "zz_dummy_config", "traffic": "zz_dummy_traffic",
@@ -77,21 +84,56 @@ DUMMY = {
         "NAME, UNIT, LAYER, MOVES, SOURCE = 'zz_dummy_metric', 'steps', 'a test', "
         "'train_samples_per_s', 'program_counter'\n\n\n"
         "def read(run):\n    return (run.get('window') or {}).get('steps')\n"),
+    # a reader of what the program published after the window, which finds the
+    # traced window's directory without a search
+    "layer_metrics/zz_dummy_published.py": (
+        "import os\n\n"
+        "NAME, UNIT, LAYER, MOVES, SOURCE = 'zz_dummy_published', 'tokens', 'a test', "
+        "'train_samples_per_s', 'program_counter'\n\n\n"
+        "def read(run):\n"
+        "    assert os.path.isdir(run['trace_dir']), run['trace_dir']\n"
+        "    return run['program']['tokens_on_the_fullest_expert']\n"),
+    # a family of its own: the ResNet adapter with counts of its own, as a sparse
+    # model's adapter has them
+    "references/zz_dummy.py": (
+        "from harness.loader import load_module\n\n"
+        "_resnet = load_module('references', 'resnet')\n"
+        "init, batches, value_and_grad = _resnet.init, _resnet.batches, "
+        "_resnet.value_and_grad\n"),
+    "flops/zz_dummy.py": (
+        "from harness.loader import load_module\n\n"
+        "train_flops_per_sample = load_module('flops', 'resnet').train_flops_per_sample\n"),
+    "models/zz_dummy.py": (
+        "from harness.loader import load_module\n\n\n"
+        "def build(config, traffic, params, devices, opt):\n"
+        "    prog = load_module('models', 'resnet').build(config, traffic, params, devices, "
+        "opt)\n"
+        "    prog.zero_counts = lambda: {'dropped_token_slots': "
+        "config['assumed'].get('dropped', 0)}\n"
+        "    prog.after_window = lambda: {'tokens_on_the_fullest_expert': 96.0}\n"
+        "    return prog\n"),
 }
 
 
-def test_files_a_later_pr_adds_are_found_and_run_without_an_edit():
+@pytest.mark.parametrize("dropped", [0, 2])
+def test_files_a_later_pr_adds_are_found_and_run_without_an_edit(dropped):
     paths = []
     try:
         for rel, body in DUMMY.items():
             path = os.path.join(loader.BENCH_DIR, rel)
             assert not os.path.exists(path)
             paths.append(path)
+            if rel.startswith("configs/"):
+                body = dict(body, assumed={"dropped": dropped})
             with open(path, "w") as f:
                 f.write(body if isinstance(body, str) else json.dumps(body))
         line, out = rehearse("zz_dummy_cell", 1)
-        assert set(line) == KEYS and line["correct"] is True
-        assert set(line["metrics"]) == {"zz_dummy_metric", "launches_per_step.train"}
+        assert set(line) == KEYS
+        # a count of the program's own that must read 0 is a number compared
+        assert line["compared"]["dropped_token_slots"] == {"value": dropped, "limit": 0}
+        assert line["correct"] is (dropped == 0)
+        assert set(line["metrics"]) == {"zz_dummy_metric", "zz_dummy_published",
+                                        "launches_per_step.train"}
         assert line["metrics"]["zz_dummy_metric"]["unit"] == "steps"
     finally:
         for path in paths:
@@ -164,10 +206,8 @@ def _first_steps_at_the_tiny_size(cell, faults):
         low = train_reference.first_steps(ref, config, opt, params, pool, quant=fault,
                                           keep_gradient=True)
         got = low.pop("first_gradient")
-        against = dict(
-            plain, grad_rel_diff=float(train_reference.global_rel_diff(got, want)),
-            grad_diff_norms={k: float(v) for k, v in
-                             train_reference.leaf_diff_norms(got, want).items()})
+        rel_diff, diff_norms = train_reference.gradient_distance(got, want)
+        against = dict(plain, grad_rel_diff=rel_diff, grad_diff_norms=diff_norms)
         faulty[fault] = compare.training_numbers(low, against)
     return ctx, sound, faulty
 

@@ -10,8 +10,10 @@ further faults that the family's reference can plant in itself (ResNet:
     python3 benchmark/tools/calibrate_training.py --workload <cell> --seeds 101,102,... --control 3
 
 Prints one JSON line a seed and a summary: the sound runs' largest and the
-control's smallest of every number. ``--out`` keeps every leaf's norms and
-distances, so that a statistic over the leaves can be chosen afterwards.
+control's smallest of every number, and beside each reading the allocator's
+peak bytes once the follower has run, to size the next cell by. ``--out``
+keeps every leaf's norms and distances, so that a statistic over the leaves
+can be chosen afterwards.
 ``--budget-seconds`` starts no further seed once that much time has gone.
 The benchmark's own runs never call this.
 """
@@ -30,6 +32,11 @@ sys.path.insert(0, HERE)
 from harness import compare, device, loader, program, train_reference  # noqa: E402
 
 
+def allocator(devs, key):
+    """The allocator's reading on the fullest of the chips used."""
+    return max(int(st.get(key, 0)) for st in device.memory_stats(devs))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -44,8 +51,6 @@ def main():
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d" % cell["chips"]
-    import jax  # after the arguments and the platform: --help needs no device
-
     config = loader.load_json("configs", cell["config"])
     traffic = loader.load_json("traffic", cell["traffic"])
     devs = device.find_devices(cell["chips"], args.rehearse)
@@ -74,13 +79,19 @@ def main():
                               "program": share}), flush=True)
         del prog, batches
         gc.collect()
+        in_use = allocator(devs, "bytes_in_use")
         t1 = time.perf_counter()
         ref_first = train_reference.first_steps(
             ref, config, opt, params, pool, steps=traffic["first_steps"],
             program_gradient=first.pop("first_gradient"), keep_gradient=True, devices=devs)
         ref_gradient = ref_first.pop("first_gradient")
         t2 = time.perf_counter()
+        # the allocator's peak never falls: on a cell's first seed it is the
+        # follower's where that is over the program's own (it counts no
+        # program's temporaries: harness/device.py)
         row = {"seed": seed, "program_s": t1 - t0, "reference_s": t2 - t1,
+               "bytes_in_use_before_reference": in_use,
+               "peak_bytes_after_reference": allocator(devs, "peak_bytes_in_use"),
                "later_compiles": later, "losses": first["losses"],
                "ref_losses": ref_first["losses"], "sound": {}, "sound_leaf": {}}
         for name, value, detail in compare.training_numbers(first, ref_first):
@@ -98,12 +109,8 @@ def main():
                                               steps=traffic["first_steps"], quant=quant,
                                               keep_gradient=True, devices=devs)
             gradient = low.pop("first_gradient")
-            low_ref = dict(
-                ref_first,
-                grad_rel_diff=float(jax.jit(train_reference.global_rel_diff)(
-                    gradient, ref_gradient)),
-                grad_diff_norms={k: float(v) for k, v in jax.device_get(jax.jit(
-                    train_reference.leaf_diff_norms)(gradient, ref_gradient)).items()})
+            rel_diff, diff_norms = train_reference.gradient_distance(gradient, ref_gradient)
+            low_ref = dict(ref_first, grad_rel_diff=rel_diff, grad_diff_norms=diff_norms)
             del gradient
             tag = "control" if quant == "fp8" else quant
             row[tag] = {}
@@ -112,6 +119,7 @@ def main():
                 row[tag][name] = value
                 control.setdefault(tag, {}).setdefault(name.split(".")[0], []).append(value)
             row[tag + "_s"] = time.perf_counter() - t3
+            row[tag + "_peak_bytes"] = allocator(devs, "peak_bytes_in_use")
         print(json.dumps(row), flush=True)
         row["leaves"] = leaves
         rows.append(row)
