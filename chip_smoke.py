@@ -186,17 +186,23 @@ def phase_kernels(args, dev):
     if args.rehearse:
         flash_cases = [("bert_bias", (2, 2, 128, 64), False, True),
                        ("bert_s512", (1, 2, 256, 64), False, False),
-                       ("causal_512", (1, 2, 256, 64), True, False)]
+                       ("causal_512", (1, 2, 256, 64), True, False),
+                       ("mla_causal", (1, 2, 256, 48), True, False, 32)]
     else:
         flash_cases = [("bert_bias", (32, 12, 128, 64), False, True),
                        ("bert_s512", (32, 12, 512, 64), False, False),
                        ("causal_512", (8, 12, 512, 64), True, False),
                        # largest K/V residency _kv_fits_vmem admits (D=64, bf16)
-                       ("causal_maxseq", (1, 2, 16384, 64), True, False)]
-    for name, shape, causal, with_bias in flash_cases:
+                       ("causal_maxseq", (1, 2, 16384, 64), True, False),
+                       # latent attention's head: keys 192, values 128 wide,
+                       # the backward asking for its own scoped VMEM
+                       ("mla_causal_4096", (1, 4, 4096, 192), True, False, 128)]
+    for name, shape, causal, with_bias, *dv in flash_cases:
         ks = jax.random.split(jax.random.fold_in(key, len(name)), 4)
-        q, k, v, do = (jax.random.normal(s, shape, jnp.bfloat16) for s in ks)
-        check(A._kv_fits_vmem(k), "%s must take the whole-sequence K/V path" % name)
+        wide = shape[:3] + tuple(dv or shape[3:])  # values and dO
+        q, k, v, do = (jax.random.normal(s, sh, jnp.bfloat16)
+                       for s, sh in zip(ks, (shape, shape, wide, wide)))
+        check(A._kv_fits_vmem(k, v), "%s must take the whole-sequence K/V path" % name)
         bias = None
         if with_bias:
             lens = np.linspace(shape[2] // 4, shape[2], shape[0]).astype(np.int32)
@@ -223,7 +229,7 @@ def phase_kernels(args, dev):
             xla_ms=median_ms(xla, q, k, v, bias))
         check(bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))), name + ": not finite")
         check(err < 2e-2, "flash %s: rel err %.4f vs reference" % (name, err))
-        if not A._qdo_fits_vmem(q):  # _flash_bwd keeps such a call in XLA
+        if not A._qdo_fits_vmem(q, v):  # _flash_bwd keeps such a call in XLA
             continue
         bq, bk = A._bwd_blocks(shape[2], shape[2])
         o, lse = fwd(q, k, v, bias)

@@ -10,8 +10,11 @@ from . import wide_deep as wide_deep_mod
 from .wide_deep import WideDeep, wide_deep
 from . import gpt
 from .gpt import GPTModel, gpt_mini, gpt_small
+from . import deepseek
+from .deepseek import DeepseekV3Model
 
 __all__ = ["vision", "get_model", "bert", "BERTModel", "BERTEncoder",
            "get_bert_model", "bert_12_768_12", "bert_6_512_8",
            "bert_3_64_2", "WideDeep", "wide_deep",
-           "gpt", "GPTModel", "gpt_mini", "gpt_small"]
+           "gpt", "GPTModel", "gpt_mini", "gpt_small",
+           "deepseek", "DeepseekV3Model"]

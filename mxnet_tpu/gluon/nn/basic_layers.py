@@ -11,7 +11,7 @@ from .layout import resolve_norm_axis
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
            "SyncBatchNorm",
            "Embedding", "Flatten", "Lambda", "HybridLambda", "Activation",
-           "LayerNorm", "InstanceNorm", "GroupNorm"]
+           "LayerNorm", "InstanceNorm", "GroupNorm", "RMSNorm", "SwiGLU"]
 
 
 class Sequential(Block):
@@ -333,6 +333,45 @@ class LayerNorm(HybridBlock):
     def hybrid_forward(self, F, x, gamma=None, beta=None):
         return F.LayerNorm(x, gamma, beta, axis=self._axis,
                            eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square norm with a learned scale and no shift (op:
+    ``RMSNorm``): the norm of pre-norm decoders."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,), init=gamma_initializer,
+                allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape = (x.shape[self._axis],)
+
+    def hybrid_forward(self, F, x, gamma=None):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+
+
+class SwiGLU(HybridBlock):
+    """Gated feed-forward block ``down(silu(gate(x)) * up(x))`` without
+    biases (Shazeer 2020), on the last axis."""
+
+    def __init__(self, units, hidden_size, weight_initializer=None,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            kw = dict(flatten=False, use_bias=False,
+                      weight_initializer=weight_initializer)
+            self.gate = Dense(hidden_size, in_units=units, prefix="gate_", **kw)
+            self.up = Dense(hidden_size, in_units=units, prefix="up_", **kw)
+            self.down = Dense(units, in_units=hidden_size, prefix="down_", **kw)
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.swiglu(self.gate(x), self.up(x)))
 
 
 class InstanceNorm(HybridBlock):

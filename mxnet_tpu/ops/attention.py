@@ -13,7 +13,9 @@ matrix is too big to materialize. Both take their matmul operands in the
 input dtype and accumulate in float32.
 
 Layout: (B, H, T, D) with D the head dim — MXU-friendly (T, D) @ (D, T)
-tiles, fp32 accumulation via preferred_element_type.
+tiles, fp32 accumulation via preferred_element_type. The value's head dim
+may differ from the key's (latent attention: keys 192 wide, values 128):
+every branch takes ``Dv`` from ``v`` and writes an output that wide.
 """
 from __future__ import annotations
 
@@ -121,9 +123,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 
     m = jnp.full((block_q,), neg_inf, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
-    num_kv = pl.cdiv(kv_len, block_k)
+    num_kv = jnp.int32(pl.cdiv(kv_len, block_k))
+    if causal and kv_len >= q_len:
+        # K/V blocks wholly right of the diagonal add exactly zero (every
+        # row has seen a key by then, so exp(-1e30 - m) is 0): stop at the
+        # block that holds this Q block's last visible key
+        last = q_off + jnp.int32(block_q + kv_len - q_len + block_k - 1)
+        num_kv = jnp.minimum(num_kv, jax.lax.div(last, jnp.int32(block_k)))
 
     def body(ik, carry):
         m_i, l_i, acc_i = carry
@@ -157,8 +165,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 
     # i32 bounds: with jax_enable_x64 on (MXNet dtype parity) a plain
     # Python-int loop index traces as i64, which Mosaic cannot lower
-    m, l, acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(num_kv), body,
-                                  (m, l, acc))
+    m, l, acc = jax.lax.fori_loop(jnp.int32(0), num_kv, body, (m, l, acc))
     l = jnp.maximum(l, jnp.float32(1e-30))
     o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
     # lse is stored lane-broadcast as (block_q, 128): Mosaic rejects a
@@ -174,7 +181,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     # pad sequence dims to block multiples: partial blocks would otherwise
@@ -191,7 +198,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     Tqp, Tkp = Tq + pad_q, Tk + pad_k
     qf = q.reshape(B * H, Tqp, D)
     kf = k.reshape(B * H, Tkp, D)
-    vf = v.reshape(B * H, Tkp, D)
+    vf = v.reshape(B * H, Tkp, Dv)
 
     # index maps return np.int32 zeros: under jax_enable_x64 a literal 0
     # traces as i64, which Mosaic rejects in the index-map signature
@@ -201,7 +208,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, Tkp, D), lambda bh, iq: (bh, z, z),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tkp, D), lambda bh, iq: (bh, z, z),
+        pl.BlockSpec((1, Tkp, Dv), lambda bh, iq: (bh, z, z),
                      memory_space=pltpu.VMEM),
     ]
     args = [qf, kf, vf]
@@ -231,19 +238,19 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, iq: (bh, iq, z),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, iq: (bh, iq, z),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, _LSE_LANES), lambda bh, iq: (bh, iq, z),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tqp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tqp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tqp, _LSE_LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
     )(*args)
-    out = out.reshape(B, H, Tqp, D)[:, :, :Tq]
+    out = out.reshape(B, H, Tqp, Dv)[:, :, :Tq]
     lse = lse[:, :, 0].reshape(B, H, Tqp)[:, :, :Tq]
     return out, lse
 
@@ -280,8 +287,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
     def _zero():
         dq_acc[...] = jnp.zeros(dq_acc.shape, f32)
 
-    k = k_ref[0]  # (BK, D)
-    v = v_ref[0]
+    k = k_ref[0]  # (BK, Dk)
+    v = v_ref[0]  # (BK, Dv)
     bias_col = None
     if bias_ref is not None:
         # the key bias is a lane-oriented row; a key-major tile wants it as
@@ -292,8 +299,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
     def body(iq, carry):
         dk_i, dv_i, db_i = carry
         q_off = pl.multiple_of(iq * block_q, block_q)
-        q = q_ref[0, pl.ds(q_off, block_q), :]  # (BQ, D)
-        do = do_ref[0, pl.ds(q_off, block_q), :]
+        q = q_ref[0, pl.ds(q_off, block_q), :]  # (BQ, Dk)
+        do = do_ref[0, pl.ds(q_off, block_q), :]  # (BQ, Dv)
         lse = st_ref[0, 0:1, pl.ds(q_off, block_q)]  # (1, BQ)
         delta = st_ref[0, 1:2, pl.ds(q_off, block_q)]
         s_t = jax.lax.dot_general(
@@ -335,11 +342,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
         # (lax.div on a non-negative i32: jnp's floor_divide does not lower)
         first = jnp.minimum(jax.lax.div(
             jnp.maximum(k_off - shift, 0), jnp.int32(block_q)), nq)
-    zero = jnp.zeros((block_k, k.shape[1]), f32)
     db0 = None if bias_ref is None else jnp.zeros((block_k, 1), f32)
     # i32 bounds: under jax_enable_x64 a Python int traces as i64
-    dk, dv, db = jax.lax.fori_loop(first, jnp.int32(nq), body,
-                                   (zero, zero, db0))
+    dk, dv, db = jax.lax.fori_loop(
+        first, jnp.int32(nq), body,
+        (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32), db0))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
     if db_ref is not None:
@@ -360,6 +367,28 @@ def _bwd_blocks(Tq, Tk):
     return pick(Tq), pick(Tk)
 
 
+_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call unasked
+
+
+def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize):
+    """Scoped VMEM the backward kernel asks for: nothing (the compiler's
+    default) while what it holds fits that with room to spare, as every
+    call within ``_VMEM_QDO_BYTES`` does; else what it holds and a quarter
+    more. Held, minor dimensions rounded up to the 128 lanes: Q, dO and dq
+    whole and double-buffered, the float32 dq accumulator, the K/V and
+    dk/dv blocks, and six float32 (block_k, block_q) tiles."""
+    def lanes(d):
+        return -(-d // 128) * 128
+
+    held = (2 * tq * (2 * lanes(dk) + lanes(dv)) * itemsize
+            + tq * lanes(dk) * 4 + 2 * 8 * tq * 4
+            + 4 * block_k * (lanes(dk) + lanes(dv)) * itemsize
+            + 6 * block_q * block_k * 4)
+    if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
+        return None
+    return held + held // 4
+
+
 def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
                            block_q, block_k, interpret):
     """dq, dk, dv (and dbias) of flash attention from the forward's
@@ -368,7 +397,7 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     f32 = jnp.float32
     delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Tq)
     stats = jnp.stack([lse.astype(f32), delta], axis=2)  # (B,H,2,Tq)
@@ -386,22 +415,26 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
 
     # np.int32 zeros in the index maps, as the forward (x64 is on)
     z = np.int32(0)
-    whole_q = pl.BlockSpec((1, Tqp, D), lambda bh, ik: (bh, z, z),
-                           memory_space=pltpu.VMEM)
-    kv_blk = pl.BlockSpec((1, block_k, D), lambda bh, ik: (bh, ik, z),
-                          memory_space=pltpu.VMEM)
+    def whole(d):
+        return pl.BlockSpec((1, Tqp, d), lambda bh, ik: (bh, z, z),
+                            memory_space=pltpu.VMEM)
+
+    def kv_blk(d):
+        return pl.BlockSpec((1, block_k, d), lambda bh, ik: (bh, ik, z),
+                            memory_space=pltpu.VMEM)
+
     key_row = pl.BlockSpec((1, 1, block_k), lambda bh, ik: (bh, z, ik),
                            memory_space=pltpu.VMEM)
-    in_specs = [whole_q, kv_blk, kv_blk, whole_q,
+    in_specs = [whole(D), kv_blk(D), kv_blk(Dv), whole(Dv),
                 pl.BlockSpec((1, 2, Tqp), lambda bh, ik: (bh, z, z),
                              memory_space=pltpu.VMEM)]
     args = [q.reshape(BH, Tqp, D), k.reshape(BH, Tkp, D),
-            v.reshape(BH, Tkp, D), do.reshape(BH, Tqp, D),
+            v.reshape(BH, Tkp, Dv), do.reshape(BH, Tqp, Dv),
             stats.reshape(BH, 2, Tqp)]
-    out_specs = [whole_q, kv_blk, kv_blk]
+    out_specs = [whole(D), kv_blk(D), kv_blk(Dv)]
     out_shape = [jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
                  jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
-                 jax.ShapeDtypeStruct((BH, Tkp, D), v.dtype)]
+                 jax.ShapeDtypeStruct((BH, Tkp, Dv), v.dtype)]
     static = dict(block_q=block_q, causal=causal, sm_scale=sm_scale,
                   kv_len=Tk, q_len=Tq, kv_pad=Tkp)
     if bias is not None:
@@ -429,13 +462,15 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
         scratch_shapes=[pltpu.VMEM((Tqp, D), f32)],
         # the K/V axis carries the dq accumulator: sequential
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_limit(Tqp, D, Dv, block_q, block_k,
+                                             q.dtype.itemsize)),
         interpret=interpret,
         name="flash_attention_bwd",
     )(*args)
     dq = outs[0].reshape(B, H, Tqp, D)[:, :, :Tq]
     dk = outs[1].reshape(B, H, Tkp, D)[:, :, :Tk]
-    dv = outs[2].reshape(B, H, Tkp, D)[:, :, :Tk]
+    dv = outs[2].reshape(B, H, Tkp, Dv)[:, :, :Tk]
     dbias = None
     if bias is not None:
         dbias = _reduce_dbias(outs[3].reshape(B, H, 1, Tkp)[..., :Tk], bias)
@@ -451,8 +486,10 @@ _VMEM_KV_BYTES = 4 * 1024 * 1024  # per-(batch,head) K+V budget
 LONG_CHUNK = 1024
 
 
-def _kv_fits_vmem(k):
-    return 2 * k.shape[2] * k.shape[3] * k.dtype.itemsize <= _VMEM_KV_BYTES
+def _kv_fits_vmem(k, v=None):
+    dv = k.shape[3] if v is None else v.shape[3]
+    return k.shape[2] * (k.shape[3] + dv) * k.dtype.itemsize \
+        <= _VMEM_KV_BYTES
 
 
 def _chunk_kv(x, chunk):
@@ -504,7 +541,7 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK):
 
     init = (jnp.full((B, H, Tq), _NEG_INF, jnp.float32),
             jnp.zeros((B, H, Tq), jnp.float32),
-            jnp.zeros((B, H, Tq, D), jnp.float32))
+            jnp.zeros((B, H, Tq, v.shape[3]), jnp.float32))
     idxs = jnp.arange(nchunks)
     xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), bc, idxs) \
         if bias is not None else \
@@ -572,7 +609,8 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
     dq, (dk_s, dv_s, db_s) = jax.lax.scan(
         body, jnp.zeros((B, H, Tq, D), f32), xs)
     dk = jnp.moveaxis(dk_s, 0, 2).reshape(B, H, Tk + pad, D)[:, :, :Tk]
-    dv = jnp.moveaxis(dv_s, 0, 2).reshape(B, H, Tk + pad, D)[:, :, :Tk]
+    dv = jnp.moveaxis(dv_s, 0, 2).reshape(
+        B, H, Tk + pad, v.shape[3])[:, :, :Tk]
     dbias = None
     if bias is not None:
         db = jnp.moveaxis(db_s, 0, 2).reshape(B, H, Tk + pad)[:, :, :Tk]
@@ -604,7 +642,7 @@ def _flash_core(q, k, v, bias, causal, sm_scale):
 @jax.named_scope("attention")
 def _flash_fwd(q, k, v, bias, causal, sm_scale):
     _record_flash_signature(q, k, v, bias, causal, sm_scale)
-    if not _kv_fits_vmem(k):
+    if not _kv_fits_vmem(k, v):
         out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale)
     else:
         cfg = _tuned_config(q, k, v, bias, causal, sm_scale)
@@ -640,11 +678,17 @@ def _record_flash_signature(q, k, v, bias, causal, sm_scale):
 
 
 _BWD_SCORE_BYTES = 256 * 1024 * 1024  # peak score-matrix budget in backward
-_VMEM_QDO_BYTES = 1024 * 1024  # per-(batch,head) Q+dO budget, backward kernel
+# per-(batch,head) Q+dO budget of the backward kernel: up to 1 MB (BERT's
+# 128 KB at 512 x 64) the call lives in the scoped VMEM the compiler gives
+# unasked; over that it asks for what it holds (``_bwd_vmem_limit``). 3 MB
+# admits a head of 4096 rows with keys 192 and values 128 wide (2.5 MB).
+_VMEM_QDO_BYTES = 3 * 1024 * 1024
 
 
-def _qdo_fits_vmem(q):
-    return 2 * q.shape[2] * q.shape[3] * q.dtype.itemsize <= _VMEM_QDO_BYTES
+def _qdo_fits_vmem(q, v=None):
+    dv = q.shape[3] if v is None else v.shape[3]
+    return q.shape[2] * (q.shape[3] + dv) * q.dtype.itemsize \
+        <= _VMEM_QDO_BYTES
 
 
 def _bwd_chunk(B, H, Tq, Tk):
@@ -665,8 +709,8 @@ def _flash_bwd(causal, sm_scale, res, do):
     q, k, v, bias, out, lse = res
     B, H, Tq, _ = q.shape
     Tk = k.shape[2]
-    if (lse is not None and on_tpu() and _kv_fits_vmem(k)
-            and _qdo_fits_vmem(q)):
+    if (lse is not None and on_tpu() and _kv_fits_vmem(k, v)
+            and _qdo_fits_vmem(q, v)):
         # the forward kernel ran (its lse is here, its K/V fit VMEM) and a
         # head's Q and dO fit beside them: same recipe, tiled in VMEM
         _telemetry.record_flash_bwd("kernel")
@@ -675,7 +719,7 @@ def _flash_bwd(causal, sm_scale, res, do):
                                       sm_scale, block_q, block_k,
                                       interpret=False)
     score_bytes = B * H * Tq * Tk * 4
-    if not _kv_fits_vmem(k) or score_bytes > _BWD_SCORE_BYTES:
+    if not _kv_fits_vmem(k, v) or score_bytes > _BWD_SCORE_BYTES:
         # keep backward O(Tq * chunk): a forward that fit VMEM can still
         # have a score matrix far too big to materialize (e.g. T=8k)
         _telemetry.record_flash_bwd("chunked")
@@ -719,9 +763,10 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 @register("flash_attention", aliases=("_contrib_flash_attention",))
 def flash_attention(query, key, value, bias=None, causal=False,
                     sm_scale=None):
-    """Fused scaled-dot-product attention. query/key/value: (B, H, T, D);
-    bias: optional additive (B, H|1, 1, Tk) mask (use large negatives to
-    mask). Returns (B, H, Tq, D).
+    """Fused scaled-dot-product attention. query/key: (B, H, T, D);
+    value: (B, H, Tk, Dv), Dv = D unless the model says otherwise (latent
+    attention); bias: optional additive (B, H|1, 1, Tk) mask (use large
+    negatives to mask). Returns (B, H, Tq, Dv).
 
     Inside ``parallel.sequence_scope(mesh, axis, schedule)`` this
     dispatches to a sequence-parallel schedule (ring KV rotation, or

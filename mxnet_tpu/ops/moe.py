@@ -1,0 +1,240 @@
+"""Sparse expert layer without dropped tokens, for a chip that holds a share
+of the experts (DeepSeek-V3's ``DeepseekV3MoE``; Shazeer et al. 2017 for the
+layer, Gale et al. 2022, MegaBlocks, for computing it without a capacity).
+
+One pure function, :func:`moe_ffn`:
+
+* **router** — scores ``sigmoid(x W_g^T)`` over ALL ``n_routed`` experts, the
+  product in float32; the ``top_k`` of ``score + bias`` are chosen (the bias
+  is a buffer with no gradient: the family's load balancing without an
+  auxiliary loss), and weighted by ``scaling * score / sum of the chosen
+  scores`` (from the scores, not from ``score + bias``);
+* **dispatch** — the layer is told which experts it holds,
+  ``experts_held=(first, count)``. Token-slots (token x chosen expert) are
+  sorted by expert, held experts first; the rows of the slots held are
+  gathered, at most a static bound of rows at a time
+  (``default_slots_bound`` of the shapes);
+* **experts** — SwiGLU of each held expert over its own rows: three grouped
+  matmuls (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
+  grouped-matmul kernel and everything else to masked dots);
+* **combine** — every token sums its held slots, weighted; what the experts
+  that are NOT held would have added is left out (it is computed where they
+  live);
+* **shared** — the shared expert's SwiGLU of every token is added.
+
+Nothing is dropped: where more slots are held than that bound, the
+rest are taken in further blocks of that many rows, which run only when
+they hold a row (``lax.cond`` inside a ``lax.scan``, recomputed in the
+backward), so the result is exact for every routing. The function returns
+the tokens each held expert got and the slots it did NOT compute, which
+must read 0: the slots held less the rows that the blocks which ran handed
+to their grouped matmuls.
+
+The two gathers of rows are written so that no pass scatters: each is the
+other's transpose (``_take_rows`` picks rows forward and is summed back
+through the inverse permutation; ``_sum_rows`` the reverse), so forward and
+backward are gathers alike.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register
+
+F32 = jnp.float32
+_HI = jax.lax.Precision.HIGHEST
+_BOUND_TILE = 512  # the bound on the rows is rounded up to this many
+
+
+def _rows(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, valid, back):
+    """``x[idx]`` where ``valid``, else 0: (M, H) -> (C, H). ``back`` (M, k)
+    holds, for every row of ``x``, where in 0..C-1 its copies went (C: no
+    such copy)."""
+    return jnp.where(valid[:, None], _rows(x, idx), jnp.zeros((), x.dtype))
+
+
+def _take_fwd(x, idx, valid, back):
+    return _take_rows(x, idx, valid, back), (idx, valid, back)
+
+
+def _take_bwd(res, ct):
+    idx, valid, back = res
+    return _sum_rows(ct, idx, valid, back), None, None, None
+
+
+@jax.custom_vjp
+def _sum_rows(o, idx, valid, back):
+    """(C, H) -> (M, H): row m is the sum of ``o[back[m, j]]`` over j, a
+    position C standing for a zero row. The transpose of ``_take_rows``."""
+    ext = jnp.concatenate([o, jnp.zeros((1,) + o.shape[1:], o.dtype)])
+    total = _rows(ext, back[:, 0]).astype(F32)
+    for j in range(1, back.shape[1]):
+        total = total + _rows(ext, back[:, j]).astype(F32)
+    return total.astype(o.dtype)
+
+
+def _sum_fwd(o, idx, valid, back):
+    return _sum_rows(o, idx, valid, back), (idx, valid, back)
+
+
+def _sum_bwd(res, ct):
+    idx, valid, back = res
+    return _take_rows(ct, idx, valid, back), None, None, None
+
+
+_take_rows.defvjp(_take_fwd, _take_bwd)
+_sum_rows.defvjp(_sum_fwd, _sum_bwd)
+
+
+def route(x, router_w, router_bias, top_k, scaling):
+    """(N, H) tokens -> chosen experts (N, k) int32 and their weights (N, k)
+    float32. The gradient reaches ``router_w`` and ``x`` through the
+    weights; the choice and the bias carry none."""
+    logits = jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + jax.lax.stop_gradient(router_bias.astype(F32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights
+
+
+def default_slots_bound(tokens, top_k, n_routed, count):
+    """Rows the experts' matmuls are laid out for: twice what an even
+    routing sends to ``count`` of ``n_routed`` experts, in whole tiles, and
+    never more than there are slots."""
+    slots = tokens * top_k
+    if count >= n_routed:
+        return slots
+    even = -(-slots * count // n_routed)
+    return min(slots, -(-2 * even // _BOUND_TILE) * _BOUND_TILE)
+
+
+def _swiglu_rows(xs, sizes, w_gate, w_up, w_down):
+    """SwiGLU of every row by its group's expert: (C, H) -> (C, H)."""
+    h = jax.lax.ragged_dot(xs, w_gate, sizes)
+    u = jax.lax.ragged_dot(xs, w_up, sizes)
+    a = (jax.nn.silu(h.astype(F32)) * u.astype(F32)).astype(xs.dtype)
+    return jax.lax.ragged_dot(a, w_down, sizes)
+
+
+def _block(c, bound, x, wflat, order, inv, is_held, starts, experts):
+    """Rows ``c * bound ...`` of the sorted slots: gathered, through their
+    experts, weighted and summed back to their tokens. Returns (N, H) and
+    the rows its grouped matmuls were handed, int32 ()."""
+    n, k = x.shape[0], inv.shape[0] // x.shape[0]
+    lo = c * bound
+    with jax.named_scope("dispatch"):
+        pos = lo + jnp.arange(bound, dtype=jnp.int32)
+        valid = pos < starts[-1]
+        slot = jax.lax.dynamic_slice_in_dim(order, lo, bound)
+        # where each slot's row sits in this block (bound: not in it)
+        here = jnp.logical_and(is_held, jnp.logical_and(inv >= lo, inv < lo + bound))
+        back = jnp.where(here, inv - lo, bound).astype(jnp.int32)
+        sizes = jnp.clip(starts[1:], lo, lo + bound) - jnp.clip(starts[:-1], lo, lo + bound)
+        xs = _take_rows(x, slot // k, valid, back.reshape(n, k))
+    with jax.named_scope("experts"):
+        o = _swiglu_rows(xs, sizes, *experts)
+    with jax.named_scope("combine"):
+        ws = _take_rows(wflat, slot, valid, back.reshape(n * k, 1))
+        o = jnp.where(valid[:, None], o.astype(F32) * ws, 0.0).astype(x.dtype)
+        y = _sum_rows(o, slot // k, valid, back.reshape(n, k))
+    return y, jnp.sum(sizes, dtype=jnp.int32)
+
+
+def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
+                shared_gate, shared_up, shared_down, *, top_k, n_routed,
+                experts_held, scaling=1.0, slots_bound=None):
+    """The expert layer on (N, H) tokens. ``w_gate`` / ``w_up`` are
+    (count, H, I) and ``w_down`` (count, I, H): the experts held, expert
+    ``first + i`` at row i. ``shared_*`` are the shared expert's weights as
+    ``FullyConnected`` keeps them, (I_s, H), (I_s, H), (H, I_s), or None.
+
+    Returns ``(y, load, lost)``: (N, H); int32 (count,) slots each held
+    expert got; int32 () slots held and not computed: the slots held less
+    the rows that the blocks which ran handed to their grouped matmuls (0:
+    every block that holds a row runs). ``slots_bound`` is for tests: the
+    layers take ``default_slots_bound`` of their shapes."""
+    n, hidden = x.shape
+    first, count = experts_held
+    if w_gate.shape[0] != count:
+        raise ValueError("experts_held says %d experts, the weights hold %d"
+                         % (count, w_gate.shape[0]))
+    slots = n * top_k
+    bound = int(slots_bound or default_slots_bound(n, top_k, n_routed, count))
+    bound = min(bound, slots)
+    blocks = -(-slots // bound)
+    with jax.named_scope("router"):
+        idx, weights = route(x, router_w, router_bias, top_k, scaling)
+    with jax.named_scope("dispatch"):
+        local = idx.reshape(slots) - first
+        is_held = jnp.logical_and(local >= 0, local < count)
+        key = jnp.where(is_held, local, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        # first sorted position of every held expert, and one past the last
+        starts = jnp.searchsorted(key[order], jnp.arange(count + 1, dtype=jnp.int32),
+                                  side="left").astype(jnp.int32)
+        load = starts[1:] - starts[:-1]
+        order = jnp.pad(order, (0, blocks * bound - slots))
+    experts = (w_gate, w_up, w_down)
+    wflat = weights.reshape(slots, 1)
+    block = functools.partial(_block, bound=bound, order=order, inv=inv,
+                              is_held=is_held, starts=starts)
+    y, done = block(0, x=x, wflat=wflat, experts=experts)
+    if blocks > 1:
+        # more slots held than the bound: the rest in further blocks, each
+        # run only if it holds a row, and recomputed in the backward so that
+        # a block that never runs keeps nothing
+        @jax.checkpoint
+        def further(c, x, wflat, experts):
+            return jax.lax.cond(
+                c * bound < starts[-1],
+                lambda: block(c, x=x, wflat=wflat, experts=experts),
+                lambda: (jnp.zeros_like(x), jnp.zeros((), jnp.int32)))
+
+        def body(carry, c):
+            y, done = carry
+            part, ran = further(c, x, wflat, experts)
+            return (y + part, done + ran), None
+
+        (y, done), _ = jax.lax.scan(
+            body, (y, done), jnp.arange(1, blocks, dtype=jnp.int32))
+    lost = starts[-1] - done
+    if shared_gate is not None:
+        with jax.named_scope("shared"):
+            dot = functools.partial(jnp.einsum, "nc,oc->no")
+            a = (jax.nn.silu(dot(x, shared_gate).astype(F32))
+                 * dot(x, shared_up).astype(F32)).astype(x.dtype)
+            y = y + dot(a, shared_down)
+    return y, load, lost
+
+
+@register("moe_ffn", num_outputs=3, wrt=(0, 1, 3, 4, 5, 6, 7, 8))
+def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
+            down_weight, shared_gate_weight=None, shared_up_weight=None,
+            shared_down_weight=None, top_k=1, n_routed=None,
+            experts_held=None, scaling=1.0):
+    """The sparse expert layer of a chip that holds ``experts_held=(first,
+    count)`` of ``n_routed`` experts, on ``data`` (..., H): see the module's
+    docstring. Returns ``(out, load, lost)``; ``load`` and ``lost`` carry no
+    gradient."""
+    n_routed = int(n_routed or router_weight.shape[0])
+    held = tuple(int(v) for v in (experts_held or (0, n_routed)))
+    lead = data.shape[:-1]
+    with jax.named_scope("moe"):
+        y, load, lost = moe_ffn_raw(
+            data.reshape(-1, data.shape[-1]), router_weight, router_bias,
+            gate_weight, up_weight, down_weight, shared_gate_weight,
+            shared_up_weight, shared_down_weight, top_k=int(top_k),
+            n_routed=n_routed, experts_held=held, scaling=float(scaling))
+    return (y.reshape(lead + (y.shape[-1],)), jax.lax.stop_gradient(load),
+            jax.lax.stop_gradient(lost))

@@ -441,6 +441,93 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
     return _ln_core(float(eps), axis % data.ndim, data, gamma, beta)
 
 
+# --------------------------------------------------------------------------
+# RMSNorm, rotary positions, SwiGLU: the blocks of pre-norm decoders that
+# have no bias and no mean to take off
+# --------------------------------------------------------------------------
+@jax.named_scope("rmsnorm")
+def _rms_fwd(eps, ax, x, g):
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=ax, keepdims=True)
+                        + eps)
+    shape = [1] * x.ndim
+    shape[ax] = x.shape[ax]
+    out = x32 * inv * g.astype(jnp.float32).reshape(shape)
+    return out.astype(x.dtype), (x, g, inv)
+
+
+@jax.named_scope("rmsnorm_bwd")
+def _rms_bwd(eps, ax, res, ct):
+    x, g, inv = res
+    shape = [1] * x.ndim
+    shape[ax] = x.shape[ax]
+    ct32 = ct.astype(jnp.float32)
+    xhat = x.astype(jnp.float32) * inv
+    dy = ct32 * g.astype(jnp.float32).reshape(shape)
+    other = tuple(i for i in range(x.ndim) if i != ax)
+    dg = jnp.sum(ct32 * xhat, axis=other)
+    dx = inv * (dy - xhat * jnp.mean(dy * xhat, axis=ax, keepdims=True))
+    return dx.astype(x.dtype), dg.astype(g.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _rms_core(eps, ax, x, g):
+    return _rms_fwd(eps, ax, x, g)[0]
+
+
+_rms_core.defvjp(_rms_fwd, _rms_bwd)
+
+
+@register("RMSNorm", aliases=("rms_norm",))
+def rms_norm(data, gamma, axis=-1, eps=1e-6):
+    """``data / sqrt(mean(data^2) + eps) * gamma`` along one axis (Zhang &
+    Sennrich 2019): statistics in float32, residuals in the input's type
+    (the backward recomputes the normalised rows), as ``LayerNorm``."""
+    return _rms_core(float(eps), axis % data.ndim, data, gamma)
+
+
+@register("rotary_embedding", aliases=("rope",))
+def rotary_embedding(data, theta=10000.0, interleaved=False, seq_axis=-2):
+    """Rotary positions (Su et al. 2021) on the last axis of ``data``, the
+    position counted along ``seq_axis`` from 0. Pair j of the D/2
+    pairs turns by ``pos * theta**(-2j/D)``. ``interleaved=False`` pairs
+    entry j with entry j + D/2 (the rotate-half form). ``interleaved=True``
+    pairs entries (2j, 2j+1): they are first taken apart to ``[evens,
+    odds]`` and then turned in the rotate-half form, so the result is in
+    that order too (what ``apply_rotary_pos_emb_interleave`` of the
+    DeepSeek-V3 family does; queries and keys are reordered alike, so
+    their products are those of the interleaved form)."""
+    d = data.shape[-1]
+    if d % 2:
+        raise ValueError("rotary_embedding needs an even last axis, got %d" % d)
+    half = d // 2
+    ax = seq_axis % data.ndim
+    with jax.named_scope("rope"):
+        x = data.astype(jnp.float32)
+        if interleaved:
+            pairs = x.reshape(x.shape[:-1] + (half, 2))
+            x1, x2 = pairs[..., 0], pairs[..., 1]
+        else:
+            x1, x2 = x[..., :half], x[..., half:]
+        pos = jnp.arange(data.shape[ax], dtype=jnp.float32)
+        freq = jnp.float32(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) * (2.0 / d))
+        angle = pos[:, None] * freq[None, :]  # (T, D/2)
+        shape = [1] * data.ndim
+        shape[ax], shape[-1] = data.shape[ax], half
+        cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
+        return out.astype(data.dtype)
+
+
+@register("swiglu")
+def swiglu(gate, up):
+    """``silu(gate) * up`` (Shazeer 2020), the product in float32."""
+    g = gate.astype(jnp.float32)
+    return (jax.nn.silu(g) * up.astype(jnp.float32)).astype(gate.dtype)
+
+
 @register("InstanceNorm")
 def instance_norm(data, gamma, beta, eps=1e-3):
     red = tuple(range(2, data.ndim))

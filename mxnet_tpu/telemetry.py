@@ -70,6 +70,7 @@ __all__ = [
     "record_phase", "record_dispatch", "record_step_retired",
     "record_compile", "record_compile_cache", "record_tune_lookup",
     "record_flash_bwd", "flash_bwd_branches",
+    "record_moe_counts", "moe_counts",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
     "record_rpc", "rpc_spans", "clear_rpc_spans",
     "record_trace_span", "trace_spans", "clear_trace_spans",
@@ -817,6 +818,39 @@ def flash_bwd_branches():
         return {}
     return {values[0]: int(ch.value)
             for values, ch in sorted(fam.children().items())}
+
+
+def record_moe_counts(expert_load, slots_lost):
+    """The counts an expert-parallel model keeps on the device, as read once
+    a window (``model_zoo.deepseek.publish_moe_counts``): token-slots each
+    held expert of each expert layer got
+    (``mxt_moe_expert_slots{layer,expert}``) and slots held but not computed
+    (``mxt_moe_slots_lost``, which must read 0). Cumulative, so gauges."""
+    g = gauge("mxt_moe_expert_slots",
+              "Cumulative token-slots each held expert got (on-device "
+              "accounting, read once a window).", ("layer", "expert"))
+    for layer, row in enumerate(expert_load):
+        for expert, v in enumerate(row):
+            g.labels(str(layer), str(expert)).set(float(v))  # sync-ok: host value
+    gauge("mxt_moe_slots_lost",
+          "Cumulative token-slots held and not computed; 0 unless the "
+          "expert layer is at fault.").set(float(slots_lost))  # sync-ok: host value
+
+
+def moe_counts():
+    """What :func:`record_moe_counts` last published:
+    ``{"expert_load": [[slots of each held expert] per layer],
+    "slots_lost": n}``, or {}."""
+    fam = _REGISTRY.get("mxt_moe_expert_slots")
+    lost = _REGISTRY.get("mxt_moe_slots_lost")
+    if fam is None or lost is None:
+        return {}
+    rows = {}
+    for (layer, expert), ch in fam.children().items():
+        rows.setdefault(int(layer), {})[int(expert)] = int(ch.value)
+    return {"expert_load": [[row[e] for e in sorted(row)]
+                            for _, row in sorted(rows.items())],
+            "slots_lost": int(lost.value)}
 
 
 # --------------------------------------------------------------------------

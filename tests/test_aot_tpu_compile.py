@@ -56,7 +56,9 @@ def _compile(chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash(shape, dtype, causal, bias_shape=None, blocks=None):
+def _flash(shape, dtype, causal, bias_shape=None, blocks=None, dv=None):
+    """The forward kernel; ``dv`` is the values' width where it is not the
+    keys' (latent attention)."""
     def case(chip):
         if blocks is None:
             cfg = tuning.heuristic_attention(shape, shape[2], dtype, causal)
@@ -64,7 +66,8 @@ def _flash(shape, dtype, causal, bias_shape=None, blocks=None):
         else:
             bq, bk = blocks
         sm = shape[3] ** -0.5
-        qkv = [(shape, jnp.dtype(dtype))] * 3
+        qkv = [(shape, jnp.dtype(dtype))] * 2 + [
+            (shape[:3] + (dv or shape[3],), jnp.dtype(dtype))]
         if bias_shape is None:
             return _compile(
                 chip, lambda q, k, v: A._flash_forward_pallas(
@@ -76,7 +79,8 @@ def _flash(shape, dtype, causal, bias_shape=None, blocks=None):
     return case
 
 
-def _flash_bwd(shape, dtype, causal, bias_shape=None, tk=None, blocks=None):
+def _flash_bwd(shape, dtype, causal, bias_shape=None, tk=None, blocks=None,
+               dv=None):
     """The backward kernel at the blocks the dispatch picks for the shape."""
     def case(chip):
         B, H, Tq, D = shape
@@ -85,7 +89,8 @@ def _flash_bwd(shape, dtype, causal, bias_shape=None, tk=None, blocks=None):
         sm = D ** -0.5
         dt = jnp.dtype(dtype)
         qs, ks = (shape, dt), ((B, H, Tk, D), dt)
-        args = [qs, ks, ks, qs, ((B, H, Tq), jnp.float32), qs]
+        vs, os_ = ((B, H, Tk, dv or D), dt), ((B, H, Tq, dv or D), dt)
+        args = [qs, ks, vs, os_, ((B, H, Tq), jnp.float32), os_]
         if bias_shape is not None:
             args.append((bias_shape, jnp.float32))
         return _compile(
@@ -151,6 +156,18 @@ _CASES = {
         tk=16384),
     "flash_bwd_f32_maxq_2048_maxkv_8192": _flash_bwd(
         (1, 2, 2048, 64), "float32", True, tk=8192),
+    # latent attention as the Kanana cell runs it: 2 x 32 heads of 4096
+    # rows, keys 192 and values 128 wide, causal; the backward holds 2.5 MB
+    # of Q + dO a head and asks for its own scoped VMEM (_bwd_vmem_limit)
+    "flash_mla_4096_k192_v128": _flash((2, 32, 4096, 192), "bfloat16", True,
+                                       dv=128),
+    "flash_bwd_mla_4096_k192_v128": _flash_bwd((2, 32, 4096, 192), "bfloat16",
+                                               True, dv=128),
+    # and the corner of the gate since it admits 3 MB of Q + dO
+    "flash_bwd_maxq_12288_maxkv_16384": _flash_bwd(
+        (1, 2, 12288, 64), "bfloat16", True, tk=16384),
+    "flash_bwd_f32_maxq_6144_maxkv_8192": _flash_bwd(
+        (1, 2, 6144, 64), "float32", True, tk=8192),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
     # repaired generator picks it
     "paged_8x12x64": _paged(8, 12, 64),
@@ -175,12 +192,12 @@ def test_flash_bwd_corner_is_what_the_dispatch_admits():
     def fits(t, dt, gate):
         return gate(jax.ShapeDtypeStruct((1, 2, t, 64), dt))
 
-    assert fits(4096, bf16, A._qdo_fits_vmem)
-    assert not fits(4096 + 128, bf16, A._qdo_fits_vmem)
+    assert fits(12288, bf16, A._qdo_fits_vmem)
+    assert not fits(12288 + 128, bf16, A._qdo_fits_vmem)
     assert fits(16384, bf16, A._kv_fits_vmem)
     assert not fits(16384 + 128, bf16, A._kv_fits_vmem)
-    assert fits(2048, f32, A._qdo_fits_vmem)
-    assert not fits(2048 + 128, f32, A._qdo_fits_vmem)
+    assert fits(6144, f32, A._qdo_fits_vmem)
+    assert not fits(6144 + 128, f32, A._qdo_fits_vmem)
     assert fits(8192, f32, A._kv_fits_vmem)
     assert not fits(8192 + 128, f32, A._kv_fits_vmem)
 

@@ -9,7 +9,9 @@ run's own lines and result, then ``dumps()``'s device table, then one JSON
 line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
 kernels' calls a step, the branch each traced attention backward took
-(``flash_bwd_branches``), the set-up phases and the compile counters. The
+(``flash_bwd_branches``), what an expert-parallel model counted on the device
+(``moe_counts``: slots by layer and held expert, slots lost), the set-up
+phases and the compile counters. The
 benchmark's cells cannot name a new per-layer metric without an edit to
 their files (PERF.md section 7), so this is how those numbers are taken
 meanwhile.
@@ -25,8 +27,9 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import run as bench  # noqa: E402 — benchmark/run.py
 
-SCOPES = ("batchnorm", "batchnorm_bwd", "layernorm", "layernorm_bwd", "attention",
-          "attention_bwd")
+# further single scopes quoted in PERF.md: latent attention and the expert layer
+PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
+         "moe", "router", "dispatch", "experts", "combine", "shared")
 
 
 class Context(bench.Context):
@@ -35,6 +38,7 @@ class Context(bench.Context):
     tables = []
     steps = None
     flash_bwd = None
+    moe = None
 
     def say(self, **row):
         if row.get("phase") == "traced":
@@ -60,6 +64,7 @@ class Context(bench.Context):
         Context.setup = profiler.setup_seconds()
         Context.compile_stats = tuning.compile_stats()
         Context.flash_bwd = telemetry.flash_bwd_branches()
+        Context.moe = telemetry.moe_counts()
         super().cleanup()
 
 
@@ -76,6 +81,7 @@ def scoped(agg, steps):
            "attention_fwd_ms": named.get("attention", 0) * per,
            "attention_bwd_ms": named.get("attention_bwd", 0) * per,
            "layernorm_ms": (named.get("layernorm", 0) + named.get("layernorm_bwd", 0)) * per,
+           "part_ms": {k: named[k] * per for k in PARTS if k in named},
            "kernel_ms": {k: v * per for k, v in agg["kernel_s"].items()},
            "kernel_calls_per_step": {k: v / steps for k, v in agg["kernel_calls"].items()},
            "category_ms": {k: v * per for k, v in list(agg["category_s"].items())[:12]},
@@ -98,6 +104,8 @@ def main(argv):
                "compile_stats": Context.compile_stats}
         if Context.flash_bwd:  # which backward each traced attention took
             row["flash_bwd_branches"] = Context.flash_bwd
+        if Context.moe:  # read once after the window by the cell's adapter
+            row["moe_counts"] = Context.moe
         if agg is None:
             print("no device operations in the trace")
         else:
