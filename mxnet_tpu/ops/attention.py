@@ -4,13 +4,15 @@ O(T^2) memory — no fused kernel exists there, this is the TPU-native
 upgrade).
 
 Forward is an online-softmax Pallas kernel: Q blocks stream over K/V blocks
-held in VMEM, never materializing the (T, T) score matrix in HBM. Backward
-recomputes the scores tile by tile from the saved logsumexp (the flash-v2
-recipe): wherever the forward kernel ran, in a Pallas kernel
-(``flash_attention_bwd``: one pass, five matmuls a tile, dq accumulated in
-VMEM); everywhere else in XLA, over 128-aligned K/V chunks when the score
-matrix is too big to materialize. Both take their matmul operands in the
-input dtype and accumulate in float32.
+held in VMEM, never materializing the (T, T) score matrix in HBM; it writes
+the logsumexp as a row, 4 bytes a query. Backward recomputes the scores
+tile by tile from that saved logsumexp (the flash-v2 recipe): wherever the
+forward kernel ran, in a Pallas kernel (``flash_attention_bwd``: one pass,
+five matmuls a tile, dq accumulated in VMEM); everywhere else in XLA, over
+128-aligned K/V chunks when the score matrix is too big to materialize.
+Both kernels take their matmul operands in the input dtype and accumulate
+in float32. The branch each half of a traced call takes is counted
+(``telemetry.flash_fwd_branches()`` / ``flash_bwd_branches()``).
 
 Layout: (B, H, T, D) with D the head dim — MXU-friendly (T, D) @ (D, T)
 tiles, fp32 accumulation via preferred_element_type. The value's head dim
@@ -20,6 +22,7 @@ every branch takes ``Dv`` from ``v`` and writes an output that wide.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +90,8 @@ def _tuned_config(q, k, v, bias, causal, sm_scale):
         q.shape, k.shape[2], str(q.dtype), causal,
         arrays=(q, k, v, bias, sm_scale))
 _NEG_INF = -1e30
-_LSE_LANES = 128  # lane-pad for the lse output (TPU (8,128) tiling)
+# lanes of a vector register: a column is broadcast over them to turn it
+_LSE_LANES = 128
 
 
 def _attention_reference(q, k, v, bias, causal, sm_scale):
@@ -108,75 +112,140 @@ def _attention_reference(q, k, v, bias, causal, sm_scale):
 # ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
+_KV_INLINE = 4  # K/V blocks of a static loop the kernel writes out in line
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                       block_k, causal, sm_scale, kv_len, q_len):
+    """One (batch x head, Q block) grid step of the forward: the head's K/V
+    sit in VMEM whole, the Q block streams over them block_k keys at a time
+    with the online softmax. Operands of both matmuls are in the input
+    dtype, the accumulators float32.
+
+    What is static here decides the vector work a score element pays:
+    ``sm_scale`` goes onto the Q block once where that is exact (a power of
+    two), else onto the scores; without ``causal`` only the tail block is
+    masked, and only where the keys are padded, the first block has nothing
+    to rescale, and up to ``_KV_INLINE`` blocks are written out in line.
+    Under ``causal`` one loop runs to the diagonal with the mask on every
+    block, two blocks an iteration so that the second block's scores can be
+    issued beside the first's softmax. ``lse`` leaves as a lane-oriented
+    row, 4 bytes a query."""
     from jax.experimental import pallas as pl
 
-    q = q_ref[0].astype(jnp.float32)  # (BQ, D)
-    block_q = q.shape[0]
-    iq = pl.program_id(1)
-    q_off = iq * block_q
+    f32 = jnp.float32
+    i32 = jnp.int32
+    block_q, kv_pad = q_ref.shape[1], k_ref.shape[1]
+    num_kv = kv_pad // block_k
+    q_off = pl.program_id(1) * block_q
+    shift = kv_len - q_len  # causal is bottom-right aligned, as the reference
     # pin scalars to 32-bit: with jax_enable_x64 on, Python floats trace as
     # f64 and Mosaic cannot lower the resulting f64 constants/casts
-    sm_scale = jnp.float32(sm_scale)
-    neg_inf = jnp.float32(_NEG_INF)
+    neg_inf = f32(_NEG_INF)
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
 
-    m = jnp.full((block_q,), neg_inf, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
+    q = q_ref[0]  # (BQ, D)
+    # a power of two scales every product exactly, so scaling the Q block
+    # gives bit for bit the scores the backward recomputes (k @ q.T * scale)
+    fold = math.frexp(sm_scale)[0] == 0.5
+    if fold:
+        q = (q.astype(f32) * f32(sm_scale)).astype(q.dtype)
 
-    num_kv = jnp.int32(pl.cdiv(kv_len, block_k))
-    if causal and kv_len >= q_len:
-        # K/V blocks wholly right of the diagonal add exactly zero (every
-        # row has seen a key by then, so exp(-1e30 - m) is 0): stop at the
-        # block that holds this Q block's last visible key
-        last = q_off + jnp.int32(block_q + kv_len - q_len + block_k - 1)
-        num_kv = jnp.minimum(num_kv, jax.lax.div(last, jnp.int32(block_k)))
-
-    def body(ik, carry):
-        m_i, l_i, acc_i = carry
-        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (BQ, BK)
+    def step(ik, carry, masked):
+        """Online-softmax update with K/V block ``ik``; ``carry`` None is
+        the first block, which has nothing to rescale."""
+        if num_kv == 1:
+            ik = 0  # a lone block is any length: its offset has to be static
+        k_off = ik * block_k
+        if not isinstance(ik, int):
+            k_off = pl.multiple_of(k_off, block_k)
+        k_blk = k_ref[0, pl.ds(k_off, block_k), :]  # (BK, D)
+        v_blk = v_ref[0, pl.ds(k_off, block_k), :]  # (BK, Dv)
+        s = jax.lax.dot_general(q, k_blk, nt,
+                                preferred_element_type=f32)  # (BQ, BK)
+        if not fold:
+            s = s * f32(sm_scale)
         if bias_ref is not None:
-            s = s + bias_ref[0, 0, pl.ds(ik * block_k, block_k)].astype(
-                jnp.float32)[None, :]
-        col = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = col < kv_len  # tail-block padding mask
-        if causal:
-            # bottom-right alignment (matches reference tril(k=Tk-Tq)):
-            # query row i attends keys up to i + (Tk - Tq)
-            row = q_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = jnp.logical_and(valid, col <= row + (kv_len - q_len))
-        s = jnp.where(valid, s, neg_inf)
-
-        m_new = jnp.maximum(m_i, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_i - m_new)
-        l_new = l_i * alpha + jnp.sum(p, axis=1)
-        acc_new = acc_i * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            s = s + bias_ref[0, ik].astype(f32)  # (1, BK), over the rows
+        if masked:
+            col = k_off + jax.lax.broadcasted_iota(
+                i32, (block_q, block_k), 1)
+            masks = []
+            if kv_pad != kv_len:  # tail-block padding
+                masks.append(col < kv_len)
+            if causal:
+                # query row i attends keys up to i + (Tk - Tq)
+                row = q_off + jax.lax.broadcasted_iota(
+                    i32, (block_q, block_k), 0)
+                masks.append(col <= row + shift)
+            s = jnp.where(functools.reduce(jnp.logical_and, masks), s,
+                          neg_inf)
+        m_new = jnp.max(s, axis=1, keepdims=True)  # (BQ, 1)
+        if carry is not None:
+            m_i, l_i, acc_i = carry
+            m_new = jnp.maximum(m_i, m_new)
+        p = jnp.exp(s - m_new)
+        l_new = jnp.sum(p, axis=1, keepdims=True)
+        acc_new = jnp.dot(p.astype(v_blk.dtype), v_blk,
+                          preferred_element_type=f32)  # (BQ, Dv)
+        if carry is not None:
+            alpha = jnp.exp(m_i - m_new)
+            l_new = l_i * alpha + l_new
+            acc_new = acc_i * alpha + acc_new
         return m_new, l_new, acc_new
 
-    # i32 bounds: with jax_enable_x64 on (MXNet dtype parity) a plain
-    # Python-int loop index traces as i64, which Mosaic cannot lower
-    m, l, acc = jax.lax.fori_loop(jnp.int32(0), num_kv, body, (m, l, acc))
-    l = jnp.maximum(l, jnp.float32(1e-30))
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    # lse is stored lane-broadcast as (block_q, 128): Mosaic rejects a
-    # (1, block_q) block on a 2-D output (sublane dim of 1), so we follow
-    # the official TPU flash kernel's MIN_BLOCK_SIZE padding layout
-    lse_ref[0] = jnp.broadcast_to((m + jnp.log(l))[:, None],
-                                  (block_q, _LSE_LANES))
+    def loop(lo, hi, carry, masked):
+        """Blocks lo .. hi - 1, two an iteration and then the odd one. i32
+        bounds: with jax_enable_x64 on (MXNet dtype parity) a plain
+        Python-int loop index traces as i64, which Mosaic cannot lower;
+        lax.div on non-negative i32: jnp's floor_divide does not lower."""
+        lo, hi = i32(lo), i32(hi)
+        pairs = jax.lax.div(hi - lo, i32(2))
+
+        def pair(j, c):
+            return step(lo + 2 * j + 1, step(lo + 2 * j, c, masked), masked)
+
+        carry = jax.lax.fori_loop(i32(0), pairs, pair, carry)
+        return jax.lax.fori_loop(lo + 2 * pairs, hi,
+                                 lambda ik, c: step(ik, c, masked), carry)
+
+    if causal:
+        n_all = i32(num_kv)
+        if shift >= 0:
+            # K/V blocks wholly right of the diagonal add exactly zero
+            # (every row has seen a key by then, so exp(-1e30 - m) is 0):
+            # stop at the block that holds this Q block's last visible key
+            n_all = jnp.minimum(n_all, jax.lax.div(
+                q_off + i32(block_q + shift + block_k - 1), i32(block_k)))
+        carry = (jnp.full((block_q, 1), neg_inf, f32),
+                 jnp.zeros((block_q, 1), f32),
+                 jnp.zeros((block_q, v_ref.shape[2]), f32))
+        m, l, acc = loop(0, n_all, carry, True)
+    else:
+        n_clear = num_kv - (kv_pad != kv_len)  # only the tail block is masked
+        carry = step(0, None, n_clear == 0)
+        if num_kv <= _KV_INLINE:
+            for ik in range(1, num_kv):
+                carry = step(ik, carry, ik >= n_clear)
+        else:
+            carry = loop(1, n_clear, carry, False)
+            if n_clear < num_kv:
+                carry = step(n_clear, carry, True)
+        m, l, acc = carry
+    l = jnp.maximum(l, f32(1e-30))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    lse = m + jnp.log(l)  # (BQ, 1)
+    # lse leaves as a lane-oriented row, the backward kernel's own idiom for
+    # its bias gradient: the (BQ, 1) column is broadcast over the lanes and
+    # turned, and the first row of that is the (1, BQ) block
+    lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LSE_LANES)).T[:1, :]
 
 
 def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                           interpret):
+    """(out, lse) of the forward kernel: ``out`` (B, H, Tq, Dv) in the input
+    dtype, ``lse`` (B, H, Tq) float32. The kernel writes ``lse`` as a
+    (B*H, 1, Tq) array in (1, 1, block_q) row blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -184,6 +253,11 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     Tk, Dv = k.shape[2], v.shape[3]
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
+    if not interpret and block_q < Tq and block_q % _LSE_LANES:
+        # the chip's compiler takes a block of the lse row that is whole
+        # lanes wide, or the whole row: a narrower block (pinned, or the
+        # table's choice for a shorter sequence of the same bucket) widens
+        block_q = min(-(-block_q // _LSE_LANES) * _LSE_LANES, Tq)
     # pad sequence dims to block multiples: partial blocks would otherwise
     # hit dynamic-slice start clamping and read/write shifted rows
     pad_q = (-Tq) % block_q
@@ -213,45 +287,45 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     ]
     args = [qf, kf, vf]
     if bias is not None:
-        # additive key-bias (B, H, 1, Tk) or (B, 1, 1, Tk) → (B*H, 1, Tk);
-        # kept 3-D so the (1, 1, Tkp) block satisfies Mosaic's tiling rule
-        # (a (1, Tkp) block on a 2-D array has an untiled sublane dim)
-        bflat = jnp.broadcast_to(bias, (B, H, 1, Tkp)).reshape(B * H, 1, Tkp)
-        in_specs.append(pl.BlockSpec((1, 1, Tkp), lambda bh, iq: (bh, z, z),
+        # additive key-bias (B, H, 1, Tk) or (B, 1, 1, Tk) → one (1, block_k)
+        # row a K/V block, (B*H, Tkp / block_k, 1, block_k): the kernel
+        # picks a block's row by a leading index, never by a lane offset
+        nkv = Tkp // block_k
+        bflat = jnp.broadcast_to(bias, (B, H, 1, Tkp)).reshape(
+            B * H, nkv, 1, block_k)
+        in_specs.append(pl.BlockSpec((1, nkv, 1, block_k),
+                                     lambda bh, iq: (bh, z, z, z),
                                      memory_space=pltpu.VMEM))
         args.append(bflat)
 
-    if bias is not None:
-        def kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref):
-            _flash_fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                              block_k=block_k, causal=causal,
-                              sm_scale=sm_scale, kv_len=Tk, q_len=Tq)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref):
-            _flash_fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                              block_k=block_k, causal=causal,
-                              sm_scale=sm_scale, kv_len=Tk, q_len=Tq)
+    def kernel(*refs):
+        refs = list(refs)
+        if bias is None:
+            refs.insert(3, None)
+        _flash_fwd_kernel(*refs, block_k=block_k, causal=causal,
+                          sm_scale=sm_scale, kv_len=Tk, q_len=Tq)
 
-    grid = (B * H, Tqp // block_q)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * H, Tqp // block_q),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda bh, iq: (bh, iq, z),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, _LSE_LANES), lambda bh, iq: (bh, iq, z),
+            # the same 3-D trick as the bias: a row block of a (B*H, 1, Tqp)
+            # array, 4 bytes a query
+            pl.BlockSpec((1, 1, block_q), lambda bh, iq: (bh, z, iq),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tqp, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tqp, _LSE_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Tqp), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
     )(*args)
     out = out.reshape(B, H, Tqp, Dv)[:, :, :Tq]
-    lse = lse[:, :, 0].reshape(B, H, Tqp)[:, :, :Tq]
+    lse = lse.reshape(B, H, Tqp)[:, :, :Tq]
     return out, lse
 
 
@@ -641,12 +715,16 @@ def _flash_core(q, k, v, bias, causal, sm_scale):
 
 @jax.named_scope("attention")
 def _flash_fwd(q, k, v, bias, causal, sm_scale):
+    """Forward of ``_flash_core``. The branch it takes is counted
+    (``telemetry.flash_fwd_branches()``), once a trace as the backward's."""
     _record_flash_signature(q, k, v, bias, causal, sm_scale)
     if not _kv_fits_vmem(k, v):
+        _telemetry.record_flash_fwd("scan")
         out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale)
     else:
         cfg = _tuned_config(q, k, v, bias, causal, sm_scale)
         if cfg.get("backend") == "pallas" and on_tpu():
+            _telemetry.record_flash_fwd("kernel")
             out, lse = _flash_forward_pallas(
                 q, k, v, bias, causal, sm_scale,
                 int(cfg["block_q"]), int(cfg["block_k"]), interpret=False)
@@ -654,6 +732,7 @@ def _flash_fwd(q, k, v, bias, causal, sm_scale):
             # per-shape XLA choice (small shapes, or a tuned decision
             # that XLA's fused reference wins here), and every non-TPU
             # backend
+            _telemetry.record_flash_fwd("reference")
             out = _attention_reference(q, k, v, bias, causal, sm_scale)
             lse = None
     return out, (q, k, v, bias, out, lse)

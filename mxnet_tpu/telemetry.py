@@ -69,6 +69,7 @@ __all__ = [
     "add_event_tap", "remove_event_tap",
     "record_phase", "record_dispatch", "record_step_retired",
     "record_compile", "record_compile_cache", "record_tune_lookup",
+    "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
     "record_moe_counts", "moe_counts",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
@@ -794,7 +795,32 @@ def record_tune_lookup(hit):
     _tune_cache_c[0 if hit else 1].inc()
 
 
-_flash_bwd_c = None
+def _record_flash(direction, branch):
+    counter("mxt_flash_%s_total" % direction,
+            "Traced flash-attention %s passes by branch." % (
+                "forward" if direction == "fwd" else "backward"),
+            ("branch",)).labels(branch).inc()
+
+
+def _flash_branches(direction):
+    fam = _REGISTRY.get("mxt_flash_%s_total" % direction)
+    if fam is None:
+        return {}
+    return {values[0]: int(ch.value)
+            for values, ch in sorted(fam.children().items())}
+
+
+def record_flash_fwd(branch):
+    """One traced flash-attention forward, by the branch its dispatch took
+    (``mxt_flash_fwd_total{branch=kernel|scan|reference}``). Counted at
+    trace time, as :func:`record_flash_bwd`: nothing enters the compiled
+    step."""
+    _record_flash("fwd", branch)
+
+
+def flash_fwd_branches():
+    """{branch: traces} of :func:`record_flash_fwd` so far."""
+    return _flash_branches("fwd")
 
 
 def record_flash_bwd(branch):
@@ -802,22 +828,12 @@ def record_flash_bwd(branch):
     (``mxt_flash_bwd_total{branch=kernel|chunked|materialised}``). Counted
     at trace time: once per compiled program that differentiates the op,
     not once a step."""
-    global _flash_bwd_c
-    if _flash_bwd_c is None:
-        _flash_bwd_c = counter(
-            "mxt_flash_bwd_total",
-            "Traced flash-attention backward passes by branch.",
-            ("branch",))
-    _flash_bwd_c.labels(branch).inc()
+    _record_flash("bwd", branch)
 
 
 def flash_bwd_branches():
     """{branch: traces} of :func:`record_flash_bwd` so far."""
-    fam = _REGISTRY.get("mxt_flash_bwd_total")
-    if fam is None:
-        return {}
-    return {values[0]: int(ch.value)
-            for values, ch in sorted(fam.children().items())}
+    return _flash_branches("bwd")
 
 
 def record_moe_counts(expert_load, slots_lost):

@@ -73,25 +73,30 @@ def _itemsize(dtype):
 def attention_candidates(tq, tk, d, dtype):
     """Tiling-legal (block_q, block_k) candidates for a (Tq, Tk, D)
     attention shape. Shape-aware: blocks never exceed the padded
-    sequence, the K/V VMEM residency fits the budget, and a non-multiple
-    shape gets divisor-friendly small blocks among the candidates
-    instead of only worst-case-padding large ones."""
+    sequence, a Q block is whole 128-lane groups of the kernel's lse row
+    or the whole sequence, what the kernel holds in VMEM fits the budget,
+    and a non-multiple shape gets divisor-friendly small K/V blocks among
+    the candidates instead of only worst-case-padding large ones."""
     tq8, tk8 = _round8(tq), _round8(tk)
-    qs = sorted({min(b, tq8) for b in (8, 16, 32, 64, 128, 256, 512)})
+    qs = sorted({min(b, tq8) for b in (128, 256, 512)})
     ks = sorted({min(b, tk8) for b in (32, 64, 128, 256, 512)})
     out = []
     isz = _itemsize(dtype)
     for bq in qs:
         for bk in ks:
             pk = _pad_to(tk, bk)
-            # kernel VMEM residency: q block, full padded K+V, f32 acc +
-            # score tile (matches _flash_forward_pallas's spec layout)
-            vmem = (bq * d + 2 * pk * d) * isz + bq * bk * 4 + bq * d * 4
+            # what _flash_forward_pallas's specs hold, each block twice (the
+            # pipeline's two buffers): the Q and output blocks, the padded
+            # K and V whole in the input dtype, the lse row; and the
+            # kernel's own float32 accumulator and score tile, the tile
+            # once more in the input dtype for the second matmul
+            vmem = (2 * ((2 * bq * d + 2 * pk * d) * isz + bq * 4)
+                    + bq * d * 4 + bq * bk * (8 + isz))
             if vmem > _VMEM_BUDGET:
                 continue
             out.append((bq, bk))
     if not out:  # degenerate (huge D): minimal legal tile
-        out.append((_SUBLANE, _SUBLANE))
+        out.append((min(_LANE, tq8), _SUBLANE))
     return out
 
 

@@ -139,6 +139,15 @@ _CASES = {
                                         blocks=(256, 512)),
     "flash_maxseq_16384_bias": _flash((1, 2, 16384, 64), "bfloat16", True,
                                       bias_shape=(1, 1, 1, 16384)),
+    # the benchmark's two BERT cells, (384, 512, 64) and (1536, 128, 64) a
+    # call, at the tiles the table gives them (the Kanana cell's
+    # (64, 4096, 192 / 128) is flash_mla_4096_k192_v128 below), and a length
+    # that pads Q and K/V under a bias: the diagonal and the tail block
+    "flash_bert_cell_s512": _flash((32, 12, 512, 64), "bfloat16", False),
+    "flash_bert_cell_s128": _flash((128, 12, 128, 64), "bfloat16", False),
+    "flash_300_ragged_bias": _flash((2, 4, 300, 64), "bfloat16", True,
+                                    bias_shape=(2, 1, 1, 300),
+                                    blocks=(128, 128)),
     # flash backward: the benchmark's BERT cell (32 x 512), causal, a
     # padding bias (dbias comes out of the kernel), a longer causal
     # sequence, a ragged one, and the corner of what _flash_bwd sends it:
@@ -178,10 +187,21 @@ _CASES = {
 }
 
 
+# the forward kernel's lse in the compiled program, (B*H, padded Tq): a row
+# of 4 bytes a query, and no (B*H, Tq, 128) lane-broadcast copy of it
+_LSE_ROWS = {"flash_bert_cell_s512": (384, 512),
+             "flash_bert_cell_s128": (1536, 128),
+             "flash_mla_4096_k192_v128": (64, 4096),
+             "flash_300_ragged_bias": (8, 384)}
+
+
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_compiles_for_v5e(chip, name):
     text = _CASES[name](chip)
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    if name in _LSE_ROWS:
+        assert "f32[%d,1,%d]" % _LSE_ROWS[name] in text
+        assert "f32[%d,%d,128]" % _LSE_ROWS[name] not in text
 
 
 def test_flash_bwd_corner_is_what_the_dispatch_admits():
@@ -217,6 +237,20 @@ def test_every_bn_candidate_compiles(chip):
     for m, c in ((200704, 64), (3136, 2048)):
         for bm in tuning.bn_candidates(m, c):
             _bn(m, c, block_rows=bm)(chip)
+
+
+@pytest.mark.parametrize("shape,causal,dv", [
+    ((32, 12, 512, 64), False, None), ((128, 12, 128, 64), False, None),
+    ((2, 32, 4096, 192), True, 128), ((2, 4, 300, 64), True, None)])
+def test_every_attention_candidate_compiles(chip, shape, causal, dv):
+    """tuning.measure_attention sweeps what attention_candidates emits and
+    lets a refusal raise: at the three cells' shapes and a ragged one, the
+    chip's compiler takes every candidate."""
+    cands = tuning.attention_candidates(shape[2], shape[2], shape[3],
+                                        "bfloat16")
+    assert len(cands) >= 3
+    for blocks in cands:
+        _flash(shape, "bfloat16", causal, blocks=blocks, dv=dv)(chip)
 
 
 def test_extreme_attention_candidates_compile(chip):

@@ -13,37 +13,70 @@ from mxnet_tpu.ops import attention as A
 from mxnet_tpu.test_utils import assert_almost_equal, with_seed
 
 
+_FWD_CASES = {
+    # (Tq, Tk, D, Dv, dtype, block_q, block_k, sm_scale, tolerance)
+    "f32_80": (80, 80, 32, 32, "float32", 32, 32, 0.125, 1e-5),
+    # operands stay bf16 into both matmuls
+    "bf16_80": (80, 80, 32, 32, "bfloat16", 32, 32, 0.125, 2e-2),
+    # a scale that is no power of two is applied to the scores, not to Q
+    "f32_scale_0.3": (80, 80, 32, 32, "float32", 32, 32, 0.3, 1e-5),
+    "bf16_scale_0.3": (80, 80, 32, 32, "bfloat16", 32, 32, 0.3, 2e-2),
+    # causal is bottom-right aligned: the diagonal starts 32 keys in
+    "tk_gt_tq": (48, 80, 32, 32, "float32", 16, 32, 0.125, 1e-5),
+    # a length that pads Q alone, and one that pads K/V alone (the split
+    # loop's tail block)
+    "pads_q": (80, 96, 32, 32, "float32", 32, 32, 0.125, 1e-5),
+    "pads_kv": (64, 80, 32, 32, "float32", 32, 32, 0.125, 1e-5),
+    # values narrower than keys
+    "dv_lt_d": (80, 80, 32, 16, "float32", 32, 32, 0.125, 1e-5),
+    # more K/V blocks than the kernel unrolls: its loop, first block peeled
+    "ten_kv_blocks": (80, 80, 32, 32, "float32", 32, 8, 0.125, 1e-5),
+    # one block a side, as BERT's forward at 128 and at 512
+    "one_tile": (80, 80, 32, 32, "bfloat16", 128, 128, 0.125, 2e-2),
+}
+
+
 @with_seed()
+@pytest.mark.parametrize("case", sorted(_FWD_CASES))
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("with_bias", [False, True])
-def test_flash_kernel_vs_reference(causal, with_bias):
-    """Pallas kernel (interpret mode) must match the O(T^2) reference."""
+def test_flash_kernel_vs_reference(causal, with_bias, case):
+    """Pallas kernel (interpret mode) must match the O(T^2) reference:
+    its output, its lse, and the backward that recomputes from that lse."""
+    Tq, Tk, D, Dv, dtype, bq, bk, sm, tol = _FWD_CASES[case]
     rng = np.random.RandomState(0)
-    B, H, T, D = 2, 3, 80, 32
-    q = jnp.asarray(rng.normal(size=(B, H, T, D)).astype("f4"))
-    k = jnp.asarray(rng.normal(size=(B, H, T, D)).astype("f4"))
-    v = jnp.asarray(rng.normal(size=(B, H, T, D)).astype("f4"))
-    bias = A.make_padding_bias(jnp.asarray([37, 80]), T) if with_bias \
+    B, H = 2, 3
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype("f4")).astype(dtype)
+
+    q, k, v, do = (arr(B, H, Tq, D), arr(B, H, Tk, D), arr(B, H, Tk, Dv),
+                   arr(B, H, Tq, Dv))
+    bias = A.make_padding_bias(jnp.asarray([Tk - 43, Tk]), Tk) if with_bias \
         else None
-    ref = A._attention_reference(q, k, v, bias, causal, 0.125)
-    out, lse = A._flash_forward_pallas(q, k, v, bias, causal, 0.125,
-                                       32, 32, interpret=True)
-    assert_almost_equal(np.asarray(out), np.asarray(ref), rtol=1e-5,
-                        atol=1e-5)
+    f32 = jnp.float32
+    ref = A._attention_reference(q.astype(f32), k.astype(f32), v.astype(f32),
+                                 bias, causal, sm)
+    out, lse = A._flash_forward_pallas(q, k, v, bias, causal, sm, bq, bk,
+                                       interpret=True)
+    assert out.dtype == q.dtype and lse.dtype == f32
+    assert out.shape == (B, H, Tq, Dv) and lse.shape == (B, H, Tq)
+    assert_almost_equal(np.asarray(out.astype(f32)), np.asarray(ref),
+                        rtol=tol, atol=tol)
+    # lse is the logsumexp of the reference's masked, biased scores
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(f32), k.astype(f32)) * sm
+    if bias is not None:
+        s = s + bias
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq), s,
+                      A._NEG_INF)
+    assert_almost_equal(np.asarray(lse),
+                        np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                        rtol=1e-5, atol=1e-5)
     # lse-based backward must match autodiff-of-reference
-    do = jnp.asarray(rng.normal(size=(B, H, T, D)).astype("f4"))
-    dq, dk, dv, _ = A._flash_bwd(causal, 0.125,
-                                 (q, k, v, bias, out, lse), do)
-    g_ref = jax.grad(
-        lambda q_, k_, v_: jnp.sum(
-            A._attention_reference(q_, k_, v_, bias, causal, 0.125) * do),
-        argnums=(0, 1, 2))(q, k, v)
-    assert_almost_equal(np.asarray(dq), np.asarray(g_ref[0]), rtol=1e-4,
-                        atol=1e-4)
-    assert_almost_equal(np.asarray(dk), np.asarray(g_ref[1]), rtol=1e-4,
-                        atol=1e-4)
-    assert_almost_equal(np.asarray(dv), np.asarray(g_ref[2]), rtol=1e-4,
-                        atol=1e-4)
+    got = A._flash_bwd(causal, sm, (q, k, v, bias, out, lse), do)
+    _assert_grads_close(got[:3], _ref_grads(q, k, v, do, bias, causal, sm)[:3],
+                        max(tol, 1e-4))
 
 
 @with_seed()
@@ -335,6 +368,88 @@ def test_flash_bwd_branch_counter_and_no_kernel_on_cpu(monkeypatch):
     delta = {b: after.get(b, 0) - before.get(b, 0) for b in after}
     assert delta == {"chunked": 2, "materialised": 1}
     assert "kernel" not in after
+
+
+def test_flash_fwd_branch_counter(monkeypatch):
+    """_flash_fwd counts the branch it takes: the reference on the CPU, the
+    kernel where the device is a TPU and the table says so, the scan once a
+    head's K/V are over _VMEM_KV_BYTES; one count a trace."""
+    from mxnet_tpu import telemetry
+
+    rng = np.random.RandomState(11)
+    q, k, v, do, _ = _bwd_case(rng, 1, 2, 128, 128, 16, "float32", False)
+    ref = A._attention_reference(q, k, v, None, False, 0.25)
+
+    def delta(fn):
+        before = telemetry.flash_fwd_branches()
+        out = fn()
+        after = telemetry.flash_fwd_branches()
+        assert_almost_equal(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                            atol=1e-5)
+        return {b: after[b] - before.get(b, 0) for b in after
+                if after[b] != before.get(b, 0)}
+
+    op = lambda q_: A.flash_attention(q_, k, v, sm_scale=0.25)  # noqa: E731
+    assert delta(lambda: op(q)) == {"reference": 1}
+    # under jit: one count a trace, none a call
+    jitted = jax.jit(op)
+    assert delta(lambda: (jitted(q), jitted(q))[1]) == {"reference": 1}
+    # K/V that do not fit: the scan, whatever the device
+    with monkeypatch.context() as m:
+        m.setattr(A, "_VMEM_KV_BYTES", 1024)
+        assert delta(lambda: op(q)) == {"scan": 1}
+    # a TPU (the kernel in interpret mode here): the kernel
+    fwd = A._flash_forward_pallas
+    monkeypatch.setattr(A, "on_tpu", lambda: True)
+    monkeypatch.setattr(A, "_flash_forward_pallas",
+                        lambda *a, interpret=False: fwd(*a, interpret=True))
+    assert delta(lambda: op(q)) == {"kernel": 1}
+
+
+def _pallas_eqn(jaxpr):
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return eqn
+
+
+def _inner_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _inner_eqns(sub)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_fwd_kernel_lse_row_and_operand_dtype(causal):
+    """What the forward kernel declares, read from its jaxpr so that neither
+    can slide back unseen: lse leaves as a (B*H, 1, Tq) float32 row (4 bytes
+    a query, no lane-broadcast copy), and for bf16 inputs both matmuls take
+    bf16 operands and accumulate in float32."""
+    B, H, T, D, Dv = 2, 3, 320, 32, 16  # 320 pads to 384 = 3 x 128
+    q = jnp.zeros((B, H, T, D), jnp.bfloat16)
+    v = jnp.zeros((B, H, T, Dv), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q_, k_, v_: A._flash_forward_pallas(
+        q_, k_, v_, None, causal, 0.125, 128, 128, interpret=True))(q, q, v)
+    call = _pallas_eqn(jaxpr.jaxpr)
+    out, lse = [x.aval for x in call.outvars]
+    assert (out.shape, out.dtype) == ((B * H, 384, Dv), jnp.bfloat16)
+    assert (lse.shape, lse.dtype) == ((B * H, 1, 384), jnp.float32)
+    # nothing 128 lanes wide comes out, and nothing is sliced to a lane
+    assert not [e for e in jaxpr.jaxpr.eqns
+                for x in e.outvars if x.aval.shape[-1:] == (A._LSE_LANES,)]
+    dots = [e for e in _inner_eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    assert len(dots) >= 2
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [jnp.bfloat16] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+    # no tile is upcast whole: float32 values come from the products alone
+    casts = [e for e in _inner_eqns(call.params["jaxpr"])
+             if e.primitive.name == "convert_element_type"
+             and e.params["new_dtype"] == jnp.float32
+             and e.invars[0].aval.shape[-2:] in ((128, D), (128, Dv))]
+    # the Q block alone, once a grid step, for the exact scale
+    assert len(casts) == 1
 
 
 @with_seed()

@@ -177,7 +177,7 @@ def test_flash_attention_value_width_in_the_kernels(causal, monkeypatch,
     _attention_case(causal, "kernel", monkeypatch)
 
 
-@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 128)])
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 128), (64, 64), (32, 16), (160, 32)])
 def test_causal_forward_kernel_stops_at_the_diagonal(bq, bk):
     """The forward kernel visits no K/V block right of the diagonal; rows of a
     ragged last block, and Tq < Tk (bottom-right aligned), read as before."""

@@ -8,8 +8,10 @@ trace is deleted reads it back with ``mx.profiler.aggregate``: it prints the
 run's own lines and result, then ``dumps()``'s device table, then one JSON
 line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
-kernels' calls a step, the branch each traced attention backward took
-(``flash_bwd_branches``), what an expert-parallel model counted on the device
+kernels' calls a step, the branch each traced attention forward and backward
+took (``flash_fwd_branches``, ``flash_bwd_branches``), the tuning table's
+entries (``tuning_entries``: the tiles each kernel shape ran with), what an
+expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost), the set-up
 phases and the compile counters. The
 benchmark's cells cannot name a new per-layer metric without an edit to
@@ -37,7 +39,9 @@ class Context(bench.Context):
 
     tables = []
     steps = None
+    flash_fwd = None
     flash_bwd = None
+    tuned = None
     moe = None
 
     def say(self, **row):
@@ -63,7 +67,9 @@ class Context(bench.Context):
             Context.tables.append((agg, time.perf_counter() - t0))
         Context.setup = profiler.setup_seconds()
         Context.compile_stats = tuning.compile_stats()
+        Context.flash_fwd = telemetry.flash_fwd_branches()
         Context.flash_bwd = telemetry.flash_bwd_branches()
+        Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
         super().cleanup()
 
@@ -102,8 +108,12 @@ def main(argv):
     for agg, seconds in Context.tables:
         row = {"aggregate_seconds": seconds, "setup_seconds": Context.setup,
                "compile_stats": Context.compile_stats}
-        if Context.flash_bwd:  # which backward each traced attention took
+        if Context.flash_fwd:  # which forward each traced attention took
+            row["flash_fwd_branches"] = Context.flash_fwd
+        if Context.flash_bwd:  # and which backward
             row["flash_bwd_branches"] = Context.flash_bwd
+        if Context.tuned:  # the tiles each kernel shape ran with
+            row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
             row["moe_counts"] = Context.moe
         if agg is None:
