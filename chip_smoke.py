@@ -433,8 +433,8 @@ def phase_resnet50(args, dev):
 
 
 def build_bert(args):
-    """BERT-base MLM as bench.py builds it: token ids in, vocabulary scores
-    out for every position."""
+    """BERT-base MLM as the benchmark builds it (``benchmark/models/bert.py``):
+    token ids in, vocabulary scores out for every position."""
     import mxnet_tpu as mx
     from mxnet_tpu import nd
     from mxnet_tpu.gluon import Block, model_zoo

@@ -27,20 +27,9 @@ def _declare(name, typ, default, doc):
     _REGISTRY[name] = _Var(name, typ, default, doc)
 
 
-_declare("MXT_TEST_SEED", int, None,
-         "Seed forced into @with_seed tests for exact repro "
-         "(ref: MXNET_TEST_SEED).")
 _declare("MXT_PROFILER_AUTOSTART", bool, False,
          "Start a jax.profiler trace at import "
          "(ref: MXNET_PROFILER_AUTOSTART).")
-_declare("MXT_ENGINE_TYPE", str, "XLA",
-         "'NaiveEngine' disables jit for op-by-op debugging "
-         "(ref: MXNET_ENGINE_TYPE=NaiveEngine).")
-_declare("MXT_DEFAULT_DTYPE", str, "float32",
-         "Default dtype for creation ops without an explicit dtype.")
-_declare("MXT_SAFE_ACCUMULATION", bool, True,
-         "Accumulate bf16/f16 reductions in float32 "
-         "(ref: MXNET_SAFE_ACCUMULATION).")
 _declare("MXT_TEST_TPU", bool, False,
          "Enable the hardware test lane (pytest -m tpu).")
 _declare("MXT_COORDINATOR", str, None,
@@ -51,10 +40,6 @@ _declare("MXT_NUM_WORKERS", int, 1,
 _declare("MXT_WORKER_ID", int, 0,
          "This process's rank under tools/launch.py "
          "(ref: DMLC_WORKER_ID).")
-_declare("MXT_KVSTORE_BIGARRAY_BOUND", int, 1000000,
-         "Size above which dist pushes chunk the array "
-         "(ref: MXNET_KVSTORE_BIGARRAY_BOUND; advisory — XLA collectives "
-         "handle chunking internally).")
 
 _declare("MXT_FUSED_TRAINER", bool, True,
          "Fuse Trainer.step's per-parameter optimizer updates into ONE "
@@ -187,7 +172,7 @@ _declare("MXT_HEALTH", bool, False,
          "stats INSIDE its one donated launch and stages them into the "
          "async dispatch window, so K steps of stats cost the SAME one "
          "deferred read the engine already performs (syncs/step is "
-         "bit-equal on vs off — bench training_health_ab asserts it). "
+         "bit-equal on vs off — tests/test_health.py asserts it). "
          "Host-side detectors run at window retirement: loss-spike "
          "(z-score vs EMA), grad-explosion/vanish, dead-layer. Read "
          "when the fused program builds, like MXT_SKIP_NONFINITE.")

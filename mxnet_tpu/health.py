@@ -18,7 +18,7 @@ rebuilds the Monitor's job under the sync budget:
   builders (gluon/train_step.py, parallel/sharded.py) stage the row
   into their InflightWindow, so K steps of stats ride the SAME single
   deferred read the engine already performs: syncs/step is bit-equal
-  with health on vs off (bench ``training_health_ab`` asserts it).
+  with health on vs off (tests/test_health.py asserts it).
 - :class:`HealthMonitor` consumes retired rows host-side (window
   retirement is the one sanctioned materialization point): loss-spike
   (z-score vs a host EMA/variance tracker), grad-explosion/vanish, and

@@ -17,7 +17,7 @@ which is where deferred bookkeeping (optimizer update counts, the
 loss-scale backoff, the skipped-step counter) catches up.
 
 Every materialization records one ``host_syncs`` profiler tick, so
-``bench.py`` can report host_syncs_per_step and
+the benchmark can report ``host_syncs_per_step`` and
 ``tools/check_host_syncs.py`` can treat this module as the ONE sanctioned
 sync funnel for deferred values.
 """

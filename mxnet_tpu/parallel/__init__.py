@@ -12,6 +12,8 @@ translating worker/server push/pull. The KVStore API survives as a facade
   weight-update sharding over the data axis)
 - reshard.py: elastic in-place mesh resharding when membership fences
   a dead host (CheckpointManager shards as the transfer format)
+- sequence.py: sequence parallelism (ring and Ulysses attention) over
+  an sp mesh axis
 - unified.py: 4D composition — pipeline stages + MoE experts as
   rule-sharded stacked params on a dp×tp×pp×ep mesh, trained by the
   SAME one-launch ShardedTrainStep (no eager island dispatch)
@@ -28,8 +30,6 @@ from .reshard import (
 )
 from .sequence import (current_sequence_scope, ring_attention,
                        sequence_scope, ulysses_attention)
-from .pipeline import pipeline_apply, stack_stage_params
-from .moe import moe_apply, stack_expert_params, switch_load_balance_loss
 from .unified import (
     PipelineMoEBlock, pipeline_moe_forward, publish_moe_telemetry,
     moe_capacity, resolve_mesh_axis,
@@ -40,9 +40,7 @@ __all__ = ["make_mesh", "data_parallel_mesh", "init_distributed",
            "sharding_rule", "allreduce_across_processes",
            "ElasticReshardController", "HostDeviceMap",
            "plan_survivor_mesh", "reshard_step", "ring_attention",
-           "ulysses_attention", "pipeline_apply", "stack_stage_params",
-           "moe_apply", "stack_expert_params",
-           "switch_load_balance_loss", "sequence_scope",
+           "ulysses_attention", "sequence_scope",
            "current_sequence_scope", "PipelineMoEBlock",
            "pipeline_moe_forward", "publish_moe_telemetry",
            "moe_capacity", "resolve_mesh_axis"]

@@ -28,7 +28,7 @@ Deadlines: a request carries an optional SLO budget (seconds from
 frees their pages, and counts them in
 ``mxt_serving_requests_total{outcome="evicted"}``.
 
-:class:`StaticBatcher` is the A/B baseline bench.py measures against:
+:class:`StaticBatcher` is the A/B baseline of continuous batching:
 same engine, same requests, but admission only at batch boundaries —
 every slot waits for the batch's longest member, which is exactly the
 waste continuous batching deletes.
@@ -513,7 +513,7 @@ class StaticBatcher(ContinuousBatcher):
     boundaries. A batch of mixed-length requests runs until its longest
     member finishes; short members' slots sit deactivated (no useful
     work, pages still held) — the cost continuous batching removes.
-    Same engine, same requests, same metrics: bench.py's A/B."""
+    Same engine, same requests, same metrics: the A/B's other leg."""
 
     def _admit(self, now):
         if self._slot_req:

@@ -1,62 +1,21 @@
-"""The contract of the two programs that are run on the chip, as far as a
-CPU-only sandbox can hold them to it: no accelerator is an error, never a
-fallback; an unknown device kind has no assumed peak; a configuration that
-would start children under a parent that holds the chip says so in words."""
+"""The contract of ``chip_smoke.py``, as far as a CPU-only sandbox can hold
+it: no accelerator is an error, never a fallback, and the last line never
+says ``ok: true`` off the chip. (``benchmark/tests/`` holds
+``benchmark/run.py`` to the same.)"""
 import json
 import os
 import subprocess
 import sys
 
-import pytest
 
 import mxnet_tpu as mx
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture(scope="module")
-def bench():
-    sys.path.insert(0, ROOT)
-    try:
-        import bench as mod
-    finally:
-        sys.path.pop(0)
-    return mod
-
-
 def test_on_tpu_is_false_on_the_cpu_backend():
     assert mx.context.on_tpu() is False
     assert not mx.runtime.Features().is_enabled("TPU")
-
-
-def test_bench_without_a_chip_is_an_error(bench, monkeypatch):
-    monkeypatch.delenv("BENCH_PLATFORM", raising=False)
-    with pytest.raises(SystemExit) as e:
-        bench._init_backend()
-    assert "no accelerator" in str(e.value)
-    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
-    assert bench._init_backend() == "cpu"
-
-
-def test_bench_peak_is_looked_up_by_device_kind(bench):
-    assert bench._PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
-    assert bench._mfu(100.0, 1e9, "cpu") is None  # a CPU run has no MFU
-    with pytest.raises(RuntimeError, match="no published peak"):
-        bench._mfu(100.0, 1e9, "tpu")  # this sandbox's kind is "cpu"
-
-
-@pytest.mark.parametrize("fn", ["bench_cold_warm", "bench_zero_stages",
-                                "bench_parallel_4d"])
-def test_bench_child_process_configs_do_not_run_under_a_chip(bench, fn):
-    with pytest.raises(bench._NotRun, match="child processes"):
-        getattr(bench, fn)("tpu", "float32")
-
-
-def test_bench_cold_warm_leaves_the_environments_cache_alone(bench,
-                                                             monkeypatch):
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent/cache")
-    with pytest.raises(bench._NotRun, match="JAX_COMPILATION_CACHE_DIR"):
-        bench.bench_cold_warm("cpu", "float32")
 
 
 def test_chip_smoke_fails_without_a_tpu_and_prints_no_result():

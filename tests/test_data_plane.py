@@ -421,13 +421,29 @@ def test_chaos_host_kill_steal_and_zombie_refusal(tmp_path):
         "MXT_FAULT",
         "data_host_kill:host=1,after=2,n=1,seed=%d" % _seed())
     led = ChunkLedger()
-    out = _consume_parallel({
-        0: _loader(man, ledger=led, host=0, hosts=2),
-        1: _loader(man, ledger=led, host=1, hosts=2)})
+    # Host 0 must not drain host 1's queue before host 1 has committed
+    # the two chunks its death waits for (a host that is dry steals from
+    # a LIVE peer's tail too, and a host 1 left with nothing to commit
+    # is never killed). Backpressure is the barrier: with room for one
+    # batch, host 0's worker blocks inside its second chunk until its
+    # consumer drains it, and its consumer waits for host 1 to be dead.
+    survivor = _loader(man, ledger=led, host=0, hosts=2, buffer_batches=1)
+    victim = _loader(man, ledger=led, host=1, hosts=2)
+    out = {0: [], 1: []}
+    t = threading.Thread(target=lambda: out[1].extend(victim))
+    t.start()
+    it = iter(survivor)
+    out[0].append(next(it))  # both fleets lease from the ledger now
+    t.join(60)
+    assert not t.is_alive(), "host consumer hung"
+    assert victim.fleet.killed
+    assert led.stats()["reclaimable"] == 3  # 5 owned less 2 committed
+    out[0].extend(it)
     stats = led.stats()
     assert stats["committed"] == stats["total"]  # epoch completed
-    assert stats["steals"] > 0                   # survivors stole
-    assert 1 in stats["fenced"]
+    assert stats["steals"] == 3                  # survivor took them all
+    assert stats["fenced"] == [1]
+    assert len({b.chunk_id for b in out[1]}) == 2  # its committed prefix
     # exactly-once across the union of what BOTH consumers received
     # (the killed host dies at a chunk-commit boundary, so its consumed
     # prefix is exactly its committed chunks)
@@ -442,10 +458,8 @@ def test_chaos_host_kill_steal_and_zombie_refusal(tmp_path):
 @pytest.mark.chaos
 def test_chaos_worker_slow_triggers_steal_bounded_wait(tmp_path):
     """Slow host -> the healthy peer's steal fires and the epoch
-    completes exactly-once; the healthy host's data_wait stays bounded
-    (it never waits on the slow peer's chunks — it steals them)."""
-    import time as _time
-
+    completes exactly-once; the healthy host never waits on the slow
+    peer's chunks — it steals them."""
     man = ShardManifest(make_shards(tmp_path, per_shard=40),
                         chunk_records=8)
     config.set_default(
@@ -454,50 +468,77 @@ def test_chaos_worker_slow_triggers_steal_bounded_wait(tmp_path):
     led = ChunkLedger()
     loaders = {0: _loader(man, ledger=led, host=0, hosts=2, workers=2),
                1: _loader(man, ledger=led, host=1, hosts=2)}
-    t0 = _time.perf_counter()
     out = _consume_parallel(loaders)
-    dt = _time.perf_counter() - t0
     stats = led.stats()
     assert stats["committed"] == stats["total"]
     assert stats["steals"] > 0, "steal never fired against the slow host"
     union = [i for h in out for b in out[h] for i in b.ids]
     assert sorted(union) == sorted(man.record_ids())
     assert len(union) == len(set(union))
-    # bounded: 10 chunks all decoded at the slow host's 60ms/chunk pace
-    # would cost ~0.6s serial; stealing keeps the wall clock well under
-    # the all-slow ceiling
-    assert dt < 2.0
+    # bounded by what was done, not by a clock: the healthy host served
+    # more chunks than the five it owned (it stole the slow host's tail
+    # instead of waiting for it), and the slow host fewer
+    served = {h: len({b.chunk_id for b in out[h]}) for h in out}
+    assert served[0] > 5 > served[1], served
+    assert served[0] + served[1] == stats["total"] == 10
 
 
 # --------------------------------------------------------------------------
 # integration satellites
 # --------------------------------------------------------------------------
-def test_bench_streaming_input_smoke(monkeypatch):
-    """The streaming_input_ab row runs end-to-end at toy size and
-    reports the acceptance fields (img/s both legs, data_wait per step,
-    steal count, speedup)."""
-    monkeypatch.setenv("BENCH_SIAB_IMAGES", "48")
-    monkeypatch.setenv("BENCH_SIAB_HW", "96")
-    monkeypatch.setenv("BENCH_SIAB_RESIZE", "48")
-    monkeypatch.setenv("BENCH_SIAB_CROP", "32")
-    monkeypatch.setenv("BENCH_SIAB_BATCH", "8")
-    monkeypatch.setenv("BENCH_SIAB_EPOCHS", "1")
-    monkeypatch.setenv("BENCH_SIAB_CHUNK", "8")
-    import importlib.util
+def test_streaming_jpeg_two_hosts_one_epoch(tmp_path):
+    """The whole feed path over a tiny JPEG RecordIO set (48 images of
+    96 x 96 in two shards; resize 48, random crop 32, batches of 8, one
+    chunk a batch) as two in-process hosts on one ledger, the second
+    with one decode worker: every record is delivered once as a float32
+    NHWC batch on the device, each host's ``data_wait`` is recorded, and
+    the ledger carries its steal counter."""
+    from mxnet_tpu import telemetry
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench.JSONL_PATH = os.devnull  # the smoke must not pollute results
-    speedup, row = bench.bench_streaming_input("cpu", "float32")
-    assert row["config"] == "streaming_input_ab"
-    assert row["dataloader_img_per_sec"] > 0
-    assert row["data_plane_img_per_sec"] > 0
-    assert row["data_plane_data_wait_ms_per_step"] > 0
-    assert "steal_count" in row
-    assert row["streaming_input_speedup"] == round(speedup, 4) > 0
+    rng = np.random.RandomState(0)
+    shards, gid = [], 0
+    for s in range(2):
+        rec = str(tmp_path / ("part-%d.rec" % s))
+        w = recordio.MXIndexedRecordIO(
+            str(tmp_path / ("part-%d.idx" % s)), rec, "w")
+        for _ in range(24):
+            img = np.kron(rng.randint(0, 255, (8, 8, 3)),
+                          np.ones((12, 12, 1))).astype(np.uint8)
+            w.write_idx(gid, recordio.pack_img(
+                recordio.IRHeader(0, float(gid % 10), gid, 0), img,
+                img_fmt=".jpg", quality=90))
+            gid += 1
+        w.close()
+        shards.append(rec)
+    man = ShardManifest(shards, chunk_records=8)
+    decoder = ImageDecoder((3, 32, 32), rand_crop=True, resize=48,
+                           layout="NHWC", dtype="float32")
+    led = ChunkLedger()
+
+    def waited(h):
+        fam = telemetry.registry().get("mxt_data_wait_seconds_total")
+        return fam.labels(str(h)).value if fam is not None else 0.0
+
+    before = {h: waited(h) for h in (0, 1)}
+    out = _consume_parallel({
+        h: StreamingDataLoader(man, 8, decoder, host_id=h, num_hosts=2,
+                               ledger=led, seed=0,
+                               num_workers=2 if h == 0 else 1)
+        for h in (0, 1)})
+    union = [i for h in out for b in out[h] for i in b.ids]
+    assert sorted(union) == sorted(man.record_ids())  # every record
+    assert len(union) == len(set(union)) == 48        # once
+    for b in out[0] + out[1]:
+        assert isinstance(b.data, mx.nd.NDArray)
+        assert b.data.shape == (8, 32, 32, 3) and b.data.dtype == np.float32
+        assert [int(v) for v in b.label.asnumpy()] \
+            == [key % 10 for _, key in b.ids]
+    stats = led.stats()
+    assert stats["committed"] == stats["total"] == 6
+    assert isinstance(stats["steals"], int) and stats["steals"] >= 0
+    for h in (0, 1):
+        if out[h]:
+            assert waited(h) > before[h], "host %d: no data_wait" % h
 
 
 def test_check_host_syncs_covers_data_plane():

@@ -21,7 +21,7 @@ The load-bearing properties:
   failure re-raises annotated with the ledger snapshot;
 - goodput arithmetic is exact under injected checkpoint+reshard pauses;
 - diagnostics add ZERO host syncs to a fused 3-step run (armed vs
-  disarmed parity — the bench row's contract, asserted in tier-1).
+  disarmed parity).
 """
 import glob
 import json
@@ -595,7 +595,7 @@ def test_watchdog_abort_is_typed_and_respawnable(tmp_path):
 # zero host syncs + satellites
 # ---------------------------------------------------------------------------
 def test_diagnostics_add_zero_host_syncs():
-    """The bench row's contract in tier-1: a fused 3-step run performs
+    """A fused 3-step run performs
     IDENTICAL device reads with the diagnostics layer fully armed
     (recorder tap + watchdog daemon + ledger) vs disarmed."""
     dg.disable()

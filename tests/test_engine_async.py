@@ -131,7 +131,7 @@ def test_trainer_step_guarded_async_bitexact(monkeypatch):
 def test_at_most_one_host_sync_per_window(monkeypatch):
     """With the guard on and K=4, 8 fused steps cost at most 8/K = 2
     framework host reads before the drain (the host_syncs gauge is the
-    bench's host_syncs_per_step source)."""
+    benchmark's ``host_syncs_per_step.train`` source)."""
     monkeypatch.setenv("MXT_SKIP_NONFINITE", "1")
     net, tr = _make("adam", {"learning_rate": 1e-2})
     step = tr.fuse_step(net, _loss_fn)

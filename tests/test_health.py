@@ -1,7 +1,7 @@
 """Training-health plane (mxnet_tpu/health.py): per-layer stats computed
 INSIDE the donated step, staged through the InflightWindow, anomaly
 detection at retirement, the declarative rules engine, the fleet skew
-watch, and the perf-regression gate.
+watch.
 
 The load-bearing properties:
 
@@ -534,93 +534,6 @@ def test_health_host_sync_lint_enforced():
 
 
 # ---------------------------------------------------------------------------
-# perf-regression gate (tools/bench_regression.py)
-# ---------------------------------------------------------------------------
-def _bench_regression():
-    spec = importlib.util.spec_from_file_location(
-        "bench_regression", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools", "bench_regression.py"))
-    m = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(m)
-    return m
-
-
-def _brow(step_ms=None, tput=None, config="r50", platform="cpu"):
-    row = {"config": config, "platform": platform, "chips": 1,
-           "batch_size": 8, "dtype": "float32"}
-    if step_ms is not None:
-        row["step_time_ms"] = step_ms
-    if tput is not None:
-        row["images_or_tokens_per_sec_per_chip"] = tput
-    return row
-
-
-def test_regression_gate_flags_slowdown():
-    br = _bench_regression()
-    hist = [_brow(step_ms=v) for v in (100.0, 98.0, 102.0, 101.0)]
-    v, = br.judge(hist, [_brow(step_ms=150.0)])  # injected 1.5x
-    assert v["verdict"] == "REGRESSION"
-    v, = br.judge(hist, [_brow(step_ms=103.0)])
-    assert v["verdict"] == "OK"
-    v, = br.judge(hist, [_brow(step_ms=60.0)])
-    assert v["verdict"] == "IMPROVED"          # informational, never fails
-
-
-def test_regression_gate_throughput_direction():
-    br = _bench_regression()
-    hist = [_brow(tput=v) for v in (1000.0, 990.0, 1010.0)]
-    v, = br.judge(hist, [_brow(tput=500.0)])
-    assert v["verdict"] == "REGRESSION"        # lower throughput = worse
-    v, = br.judge(hist, [_brow(tput=1500.0)])
-    assert v["verdict"] == "IMPROVED"
-
-
-def test_regression_gate_keys_and_history_floor():
-    br = _bench_regression()
-    hist = [_brow(step_ms=100.0), _brow(step_ms=100.0)]
-    v, = br.judge(hist, [_brow(step_ms=500.0)])
-    assert v["verdict"] == "INSUFFICIENT_HISTORY"  # 2 prior < 3
-    # keys never cross platforms: tpu history is no cpu baseline
-    hist = [_brow(step_ms=10.0, platform="tpu") for _ in range(5)]
-    v, = br.judge(hist, [_brow(step_ms=100.0, platform="cpu")])
-    assert v["verdict"] == "INSUFFICIENT_HISTORY"
-    v, = br.judge([], [_brow()])
-    assert v["verdict"] == "NO_METRIC"
-
-
-def test_regression_gate_noisy_history_widens_band():
-    br = _bench_regression()
-    # 2x spread in history: rel-MAD * 3 beats the 0.25 default band
-    hist = [_brow(step_ms=v) for v in (50.0, 100.0, 150.0, 100.0)]
-    v, = br.judge(hist, [_brow(step_ms=150.0)])
-    assert v["verdict"] == "OK" and v["band"] > 0.25
-
-
-def test_regression_gate_clean_on_recorded_trajectory(capsys):
-    """The repo's own bench_results.jsonl must pass its own gate — the
-    newest row per key against the trajectory before it."""
-    br = _bench_regression()
-    assert br.main([]) == 0
-
-
-def test_regression_gate_exit_code_on_injected_row(tmp_path):
-    br = _bench_regression()
-    hist = tmp_path / "hist.jsonl"
-    hist.write_text("".join(
-        json.dumps(_brow(step_ms=v)) + "\n"
-        for v in (100.0, 99.0, 101.0, 100.0)))
-    cand = tmp_path / "cand.jsonl"
-    cand.write_text(json.dumps(_brow(step_ms=150.0)) + "\n")
-    assert br.main(["--history", str(hist),
-                    "--candidate", str(cand)]) == 1
-    cand.write_text(json.dumps(_brow(step_ms=101.0)) + "\n")
-    assert br.main(["--history", str(hist),
-                    "--candidate", str(cand)]) == 0
-    assert br.main(["--history", str(tmp_path / "missing.jsonl")]) == 0
-
-
-# ---------------------------------------------------------------------------
 # sharded step parity + the reshard standing item with health armed
 # ---------------------------------------------------------------------------
 def test_sharded_step_health_numeric_parity(monkeypatch):
@@ -682,22 +595,54 @@ def test_reshard_acceptance_with_health_armed():
 
 
 # ---------------------------------------------------------------------------
-# bench row smoke: the A/B asserts its own contract
+# the A/B on adam + softmax cross-entropy over deferred shapes, with the
+# seeded spike as its third leg
 # ---------------------------------------------------------------------------
-def test_bench_training_health_ab_row(monkeypatch):
-    monkeypatch.setenv("BENCH_HAB_BATCH", "8")
-    monkeypatch.setenv("BENCH_HAB_HIDDEN", "32")
-    monkeypatch.setenv("BENCH_HAB_ITERS", "6")
-    monkeypatch.setenv("BENCH_HAB_WARMUP", "2")
-    sys.path.insert(0, os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    monkeypatch.setattr(bench, "JSONL_PATH", os.devnull)
-    _, row = bench.bench_training_health_ab("cpu", "float32")
-    assert row["config"] == "training_health_ab"
-    assert row["sync_parity"] is True
-    assert row["losses_equal"] is True
-    assert row["spike_detected"] is True
+def test_training_health_on_against_off_adam(monkeypatch):
+    """The same fused step (three Dense layers with deferred input
+    widths, adam, softmax cross-entropy, window 4) with the health plane
+    off, on, and on under a seeded ``grad_spike``: the loss streams of
+    off and on are byte-equal, the host syncs of the six counted steps
+    are equal, and the spike is detected."""
+    batch, hidden, warmup, iters = 8, 32, 2, 6
+    monkeypatch.setenv("MXT_CHAOS_SEED", "0")
+
+    def run(tag, armed, fault=None):
+        monkeypatch.setenv("MXT_HEALTH", "1" if armed else "0")
+        if fault:
+            monkeypatch.setenv("MXT_FAULT", fault)
+        else:
+            monkeypatch.delenv("MXT_FAULT", raising=False)
+        resilience.reset_faults()
+        health.reset()
+        mx.random.seed(0)
+        net = nn.Sequential(prefix="hab_%s_" % tag)
+        with net.name_scope():
+            net.add(nn.Dense(hidden, activation="relu"),
+                    nn.Dense(hidden, activation="relu"), nn.Dense(10))
+        net.initialize()
+        tr = Trainer(net.collect_params(), "adam", {"learning_rate": 1e-3})
+        step = tr.fuse_step(net, mx.gluon.loss.SoftmaxCrossEntropyLoss())
+        rng = np.random.RandomState(0)
+        x = nd.array(rng.uniform(-1, 1, (batch, 32)).astype(np.float32))
+        y = nd.array(rng.randint(0, 10, (batch,)).astype(np.float32))
+        with engine.bulk(4):
+            for _ in range(warmup):
+                step(x, y).wait_to_read()
+            h0 = profiler.host_sync_count()
+            losses = [step(x, y) for _ in range(iters)]
+            nd.waitall()
+            syncs = profiler.host_sync_count() - h0
+        blob = b"".join(v.asnumpy().astype(np.float32).tobytes()
+                        for v in losses)
+        mon = step._health_mon
+        return syncs, blob, mon.anomaly_count if mon is not None else 0
+
+    off_syncs, off_blob, _ = run("off", False)
+    on_syncs, on_blob, quiet = run("on", True)
+    assert off_syncs == on_syncs, (off_syncs, on_syncs)
+    assert off_blob == on_blob
+    assert quiet == 0
+    _, _, anomalies = run(
+        "spike", True, fault="grad_spike:layer=0,after=2,scale=1e6,n=1")
+    assert anomalies > 0, "seeded grad spike never detected"

@@ -1,6 +1,7 @@
 """mx.image pipeline + MXT_* config tier + AMP tests (models
 tests/python/unittest/test_image.py and the contrib amp coverage)."""
 import os
+import re
 
 import numpy as np
 import pytest
@@ -130,7 +131,46 @@ def test_config_env_precedence(monkeypatch):
     monkeypatch.setenv("MXT_PROFILER_AUTOSTART", "true")
     assert mx.config.get("MXT_PROFILER_AUTOSTART") is True
     table = mx.config.describe()
-    assert "MXT_ENGINE_TYPE" in table
+    assert "MXT_PROFILER_AUTOSTART" in table
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_every_declared_variable_has_a_reader():
+    """A declared ``MXT_*`` variable that nothing reads is a documented
+    behaviour that does not exist: each name ``config.py`` declares is
+    named by some other source file of the package, the tools, the
+    benchmark or the examples (``MXT_TEST_TPU`` selects the lane of these
+    tests, so its reader is ``tests/conftest.py``)."""
+    with open(os.path.join(_REPO, "tests", "conftest.py")) as f:
+        text = [f.read()]
+    for top in ("mxnet_tpu", "tools", "benchmark", "examples"):
+        for folder, _, files in os.walk(os.path.join(_REPO, top)):
+            for name in files:
+                path = os.path.join(folder, name)
+                if name.endswith((".py", ".sh")) and path != os.path.join(
+                        _REPO, "mxnet_tpu", "config.py"):
+                    with open(path, encoding="utf-8") as f:
+                        text.append(f.read())
+    text = "\n".join(text)
+    unread = [name for name in mx.config.variables()
+              if not re.search(r"\b%s\b" % name, text)]
+    assert unread == []
+
+
+@pytest.mark.parametrize("doc", ["README.md", "MIGRATION.md"])
+def test_documents_name_files_that_exist(doc):
+    """Every back-quoted ``*.py`` / ``*.sh`` name in the document is a
+    file, under the repo root, ``mxnet_tpu/`` or ``tools/``."""
+    with open(os.path.join(_REPO, doc), encoding="utf-8") as f:
+        names = set(re.findall(r"`([^`\s]+\.(?:py|sh))`", f.read()))
+    assert names, "no file names found: the pattern rotted"
+    missing = sorted(
+        n for n in names
+        if not any(os.path.exists(os.path.join(_REPO, base, n))
+                   for base in ("", "mxnet_tpu", "tools")))
+    assert missing == []
 
 
 def test_config_naive_engine_runs_unjitted():

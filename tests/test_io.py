@@ -1,6 +1,7 @@
 """Data pipeline tests (models tests/python/unittest/test_io.py,
 test_recordio.py, and the gluon data portions of test_gluon_data.py)."""
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -542,30 +543,35 @@ def test_thread_worker_error_propagates():
         list(loader)
 
 
-@pytest.mark.skipif(len(getattr(os, "sched_getaffinity", lambda _: [0])(0))
-                    < 4,
-                    reason="needs >=4 schedulable cores for a "
-                           "meaningful A/B")
-def test_process_workers_beat_threads_on_gil_heavy_transform():
-    """The reason the escape hatch exists: a GIL-bound transform chain
-    serializes under threads but scales under processes."""
-    import time
-    ds = _GilHeavyDataset(n=48, work=20000)
+class _WhoRanDataset(_GilHeavyDataset):
+    """The GIL-bound transform, each sample stamped with the process and
+    thread that ran it."""
 
-    def run(thread_pool):
-        t0 = time.perf_counter()
-        for _ in gdata.DataLoader(ds, batch_size=8, num_workers=4,
-                                  thread_pool=thread_pool):
-            pass
-        return time.perf_counter() - t0
+    def __getitem__(self, idx):
+        data, _ = super().__getitem__(idx)
+        return data, np.array([idx, os.getpid(), threading.get_native_id()],
+                              np.int32)
 
-    run(True)  # warm both paths (pool spin-up, imports)
-    # scheduler-dependent timings: take the best of two runs per mode and
-    # allow a small margin — the claim is "processes aren't serialized by
-    # the GIL", not an exact speedup factor
-    t_thread = min(run(True), run(True))
-    t_proc = min(run(False), run(False))
-    assert t_proc < t_thread * 1.1, (t_proc, t_thread)
+
+@pytest.mark.parametrize("thread_pool", [True, False])
+def test_gil_heavy_transform_runs_in_the_worker_kind_asked_for(thread_pool):
+    """The reason the escape hatch exists is that a GIL-bound transform
+    serializes under threads and not under processes. What a shared CPU
+    can hold the loader to is where the transform ran: with
+    ``thread_pool=False`` no sample is transformed in this process, with
+    threads every one is, off the consumer's thread; either way every
+    record arrives once and in order."""
+    ds = _WhoRanDataset(n=48, work=2000)
+    stamps = np.concatenate([
+        label.asnumpy() for _, label in gdata.DataLoader(
+            ds, batch_size=8, num_workers=4, thread_pool=thread_pool)])
+    assert stamps[:, 0].tolist() == list(range(48))
+    in_parent = stamps[:, 1] == os.getpid()
+    if thread_pool:
+        assert in_parent.all()
+        assert threading.get_native_id() not in set(stamps[:, 2].tolist())
+    else:
+        assert not in_parent.any()
 
 
 def test_image_record_iter_nhwc_layout(tmp_path):

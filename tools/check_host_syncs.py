@@ -97,11 +97,7 @@ SCAN = {
     # one donated step program, and the router accounting accumulates in
     # device-resident aux params — the only sanctioned reads are the
     # windowed publish_moe_telemetry transfer (sync-ok marked) and
-    # nothing else. pipeline.py/moe.py are the island building blocks
-    # the unified step subsumes; their shard_map programs must be just
-    # as read-free.
-    "mxnet_tpu/parallel/pipeline.py": _ALL,
-    "mxnet_tpu/parallel/moe.py": _ALL,
+    # nothing else.
     "mxnet_tpu/parallel/unified.py": _ALL,
     # the serving decode loop IS a hot path with an SLO: scheduler ticks
     # and cache bookkeeping run between every decode dispatch, so one
