@@ -18,9 +18,9 @@ that sums the shares' gradients over the chips, has only its own experts'
 part of the router's gradient, and whoever runs it so decides what to do
 about that (the benchmark's cell freezes the router by ``grad_req``).
 Every expert layer counts, in aux state carried through the step like
-BatchNorm's running statistics, the slots each held expert got and the
-slots it did not compute (``moe_counts``): read them once a window, never a
-step.
+BatchNorm's running statistics, the slots each held expert got, the slots
+it did not compute and the blocks of rows it ran past the first
+(``moe_counts``): read them once a window, never a step.
 """
 from __future__ import annotations
 
@@ -132,6 +132,8 @@ class DeepseekMoE(HybridBlock):
                                  init="zeros", grad_req="null")
             self.slots_lost = g("slots_lost", shape=(1,), dtype="int32",
                                 init="zeros", grad_req="null")
+            self.blocks_run = g("blocks_run", shape=(1,), dtype="int32",
+                                init="zeros", grad_req="null")
 
     def cast(self, dtype):
         """The selection bias stays float32 and the counts int32 under a
@@ -141,21 +143,24 @@ class DeepseekMoE(HybridBlock):
         self.router_bias.cast("float32")
         self.expert_load.cast("int32")
         self.slots_lost.cast("int32")
+        self.blocks_run.cast("int32")
 
     def hybrid_forward(self, F, x, router_weight=None, router_bias=None,
                        gate_weight=None, up_weight=None, down_weight=None,
                        shared_gate_weight=None, shared_up_weight=None,
-                       shared_down_weight=None, expert_load=None, slots_lost=None):
+                       shared_down_weight=None, expert_load=None, slots_lost=None,
+                       blocks_run=None):
         ret = F.moe_ffn(x, router_weight, router_bias, gate_weight, up_weight,
                         down_weight, shared_gate_weight, shared_up_weight,
                         shared_down_weight, **self._static)
         if not isinstance(ret, tuple):
             return ret  # symbolic trace: the counts are hidden outputs
-        out, load, lost = ret
+        out, load, lost, ran = ret
         # the BatchNorm running-statistics protocol: _set_data on the traced
         # wrapper rebinds the aux output of the donated step
         expert_load._set_data(expert_load.data + load.data)
         slots_lost._set_data(slots_lost.data + lost.data.reshape(1))
+        blocks_run._set_data(blocks_run.data + ran.data.reshape(1))
         return out
 
 
@@ -239,21 +244,23 @@ class DeepseekV3Model(HybridBlock):
 def moe_counts(model):
     """One read of the counts every expert layer of ``model`` keeps on the
     device: ``{"expert_load": [[slots of each held expert] per layer],
-    "slots_lost": total}``, cumulative since the parameters were made. A
-    host sync: call it once a window (epoch end, a benchmark's teardown),
-    never a step."""
+    "slots_lost": total, "blocks_run": total}``, cumulative since the
+    parameters were made. A host sync: call it once a window (epoch end, a
+    benchmark's teardown), never a step."""
     layers = model.moe_layers()
     load = [np.asarray(m.expert_load.data().data) for m in layers]  # sync-ok: windowed moe accounting read
     lost = sum(int(np.asarray(m.slots_lost.data().data)[0]) for m in layers)  # sync-ok: windowed moe accounting read
+    ran = sum(int(np.asarray(m.blocks_run.data().data)[0]) for m in layers)  # sync-ok: windowed moe accounting read
     return {"expert_load": [[int(v) for v in row] for row in load],
-            "slots_lost": lost}
+            "slots_lost": lost, "blocks_run": ran}
 
 
 def publish_moe_counts(model):
     """``moe_counts`` into telemetry (``mxt_moe_expert_slots{layer,expert}``
-    gauges, ``mxt_moe_slots_lost`` gauge); returns the counts."""
+    gauges, ``mxt_moe_slots_lost`` and ``mxt_moe_blocks_run`` gauges);
+    returns the counts."""
     from ... import telemetry
 
     counts = moe_counts(model)
-    telemetry.record_moe_counts(counts["expert_load"], counts["slots_lost"])
+    telemetry.record_moe_counts(**counts)
     return counts

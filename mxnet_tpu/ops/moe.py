@@ -23,12 +23,18 @@ One pure function, :func:`moe_ffn`:
 * **shared** — the shared expert's SwiGLU of every token is added.
 
 Nothing is dropped: where more slots are held than that bound, the
-rest are taken in further blocks of that many rows, which run only when
-they hold a row (``lax.cond`` inside a ``lax.scan``, recomputed in the
-backward), so the result is exact for every routing. The function returns
-the tokens each held expert got and the slots it did NOT compute, which
-must read 0: the slots held less the rows that the blocks which ran handed
-to their grouped matmuls.
+rest are taken in further blocks of that many rows, so the result is exact
+for every routing. How many further blocks run is read from the data: the
+routed experts' part is one differentiable unit (``_routed``, a
+``custom_vjp``) whose forward runs the first block and then a loop of
+``ceil(slots held / bound) - 1`` trips, counted on the device, and whose
+backward takes the first block's cotangents from its kept residuals and
+then, in the same loop, recomputes each further block that ran and adds
+its cotangents into them. A block that holds no row runs in neither pass
+and writes nothing: no zero part, no zero residual, no zero cotangent. The
+function returns the tokens each held expert got, the slots it did NOT
+compute, which must read 0 (the slots held less the rows that the blocks
+which ran handed to their grouped matmuls), and the further blocks it ran.
 
 The two gathers of rows are written so that no pass scatters: each is the
 other's transpose (``_take_rows`` picks rows forward and is summed back
@@ -126,7 +132,7 @@ def _swiglu_rows(xs, sizes, w_gate, w_up, w_down):
     return jax.lax.ragged_dot(a, w_down, sizes)
 
 
-def _block(c, bound, x, wflat, order, inv, is_held, starts, experts):
+def _block(c, x, wflat, experts, *, bound, order, inv, is_held, starts):
     """Rows ``c * bound ...`` of the sorted slots: gathered, through their
     experts, weighted and summed back to their tokens. Returns (N, H) and
     the rows its grouped matmuls were handed, int32 ()."""
@@ -150,6 +156,81 @@ def _block(c, bound, x, wflat, order, inv, is_held, starts, experts):
     return y, jnp.sum(sizes, dtype=jnp.int32)
 
 
+def _further_blocks(held, bound):
+    """Blocks past the first that hold a row, int32 (): the trip count of the
+    loops over them, read from the slots held."""
+    return jnp.maximum((held + bound - 1) // bound - 1, 0)
+
+
+def _routed_parts(bound, order, inv, is_held, starts):
+    """``_block`` of this routing, and how many further blocks it needs
+    (None where the first block's rows are all the slots there are: such a
+    layer never builds a loop)."""
+    block = functools.partial(_block, bound=bound, order=order, inv=inv,
+                              is_held=is_held, starts=starts)
+    if order.shape[0] <= bound:
+        return block, None
+    return block, _further_blocks(starts[-1], bound)
+
+
+def _further_forward(block, trips, x, wflat, experts, y, done):
+    """Blocks 1..trips added to block 0's ``y`` and ``done``. No trip: both
+    pass through untouched."""
+    if trips is None:
+        return y, done, jnp.zeros((), jnp.int32)
+
+    def body(c, carry):
+        part, rows = block(c, x, wflat, experts)
+        return carry[0] + part, carry[1] + rows
+
+    y, done = jax.lax.fori_loop(1, trips + 1, body, (y, done))
+    return y, done, trips
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(bound, x, wflat, experts, order, inv, is_held, starts):
+    """The routed experts' part of the layer, one differentiable unit: block
+    0, then as many further blocks as hold a row (a loop whose trip count is
+    read on the device). Returns (N, H), the rows handed to the grouped
+    matmuls of the blocks that ran and the further blocks that ran, both
+    int32 (). The backward keeps block 0's residuals, recomputes each further
+    block that ran and adds its cotangents into block 0's: a block that does
+    not run costs nothing in either pass."""
+    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+    y, done = block(0, x, wflat, experts)
+    return _further_forward(block, trips, x, wflat, experts, y, done)
+
+
+def _routed_fwd(bound, x, wflat, experts, order, inv, is_held, starts):
+    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+    y, vjp0, done = jax.vjp(functools.partial(block, 0), x, wflat, experts,
+                            has_aux=True)
+    out = _further_forward(block, trips, x, wflat, experts, y, done)
+    return out, (vjp0, x, wflat, experts, order, inv, is_held, starts)
+
+
+def _routed_bwd(bound, res, cts):
+    vjp0, x, wflat, experts, order, inv, is_held, starts = res
+    ct = cts[0]  # the counts carry no gradient
+    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+    # a hand-written rule names its own operations: device time is read by
+    # scope (``moe``; ``dispatch`` / ``experts`` / ``combine`` come with the
+    # blocks), in this pass as in the forward
+    with jax.named_scope("moe"):
+        grads = vjp0(ct)
+        if trips is not None:
+            def body(c, grads):
+                _, vjp = jax.vjp(lambda *primals: block(c, *primals)[0],
+                                 x, wflat, experts)
+                return jax.tree_util.tree_map(jnp.add, grads, vjp(ct))
+
+            grads = jax.lax.fori_loop(1, trips + 1, body, grads)
+    return grads + (None,) * 4
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                 shared_gate, shared_up, shared_down, *, top_k, n_routed,
                 experts_held, scaling=1.0, slots_bound=None):
@@ -158,11 +239,12 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
     ``first + i`` at row i. ``shared_*`` are the shared expert's weights as
     ``FullyConnected`` keeps them, (I_s, H), (I_s, H), (H, I_s), or None.
 
-    Returns ``(y, load, lost)``: (N, H); int32 (count,) slots each held
+    Returns ``(y, load, lost, ran)``: (N, H); int32 (count,) slots each held
     expert got; int32 () slots held and not computed: the slots held less
     the rows that the blocks which ran handed to their grouped matmuls (0:
-    every block that holds a row runs). ``slots_bound`` is for tests: the
-    layers take ``default_slots_bound`` of their shapes."""
+    every block that holds a row runs); int32 () blocks past the first that
+    ran. ``slots_bound`` is for tests: the layers take
+    ``default_slots_bound`` of their shapes."""
     n, hidden = x.shape
     first, count = experts_held
     if w_gate.shape[0] != count:
@@ -185,29 +267,8 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                                   side="left").astype(jnp.int32)
         load = starts[1:] - starts[:-1]
         order = jnp.pad(order, (0, blocks * bound - slots))
-    experts = (w_gate, w_up, w_down)
-    wflat = weights.reshape(slots, 1)
-    block = functools.partial(_block, bound=bound, order=order, inv=inv,
-                              is_held=is_held, starts=starts)
-    y, done = block(0, x=x, wflat=wflat, experts=experts)
-    if blocks > 1:
-        # more slots held than the bound: the rest in further blocks, each
-        # run only if it holds a row, and recomputed in the backward so that
-        # a block that never runs keeps nothing
-        @jax.checkpoint
-        def further(c, x, wflat, experts):
-            return jax.lax.cond(
-                c * bound < starts[-1],
-                lambda: block(c, x=x, wflat=wflat, experts=experts),
-                lambda: (jnp.zeros_like(x), jnp.zeros((), jnp.int32)))
-
-        def body(carry, c):
-            y, done = carry
-            part, ran = further(c, x, wflat, experts)
-            return (y + part, done + ran), None
-
-        (y, done), _ = jax.lax.scan(
-            body, (y, done), jnp.arange(1, blocks, dtype=jnp.int32))
+    y, done, ran = _routed(bound, x, weights.reshape(slots, 1), (w_gate, w_up, w_down),
+                           order, inv, is_held, starts)
     lost = starts[-1] - done
     if shared_gate is not None:
         with jax.named_scope("shared"):
@@ -215,26 +276,26 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
             a = (jax.nn.silu(dot(x, shared_gate).astype(F32))
                  * dot(x, shared_up).astype(F32)).astype(x.dtype)
             y = y + dot(a, shared_down)
-    return y, load, lost
+    return y, load, lost, ran
 
 
-@register("moe_ffn", num_outputs=3, wrt=(0, 1, 3, 4, 5, 6, 7, 8))
+@register("moe_ffn", num_outputs=4, wrt=(0, 1, 3, 4, 5, 6, 7, 8))
 def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             down_weight, shared_gate_weight=None, shared_up_weight=None,
             shared_down_weight=None, top_k=1, n_routed=None,
             experts_held=None, scaling=1.0):
     """The sparse expert layer of a chip that holds ``experts_held=(first,
     count)`` of ``n_routed`` experts, on ``data`` (..., H): see the module's
-    docstring. Returns ``(out, load, lost)``; ``load`` and ``lost`` carry no
+    docstring. Returns ``(out, load, lost, ran)``; the three counts carry no
     gradient."""
     n_routed = int(n_routed or router_weight.shape[0])
     held = tuple(int(v) for v in (experts_held or (0, n_routed)))
     lead = data.shape[:-1]
     with jax.named_scope("moe"):
-        y, load, lost = moe_ffn_raw(
+        y, *counts = moe_ffn_raw(
             data.reshape(-1, data.shape[-1]), router_weight, router_bias,
             gate_weight, up_weight, down_weight, shared_gate_weight,
             shared_up_weight, shared_down_weight, top_k=int(top_k),
             n_routed=n_routed, experts_held=held, scaling=float(scaling))
-    return (y.reshape(lead + (y.shape[-1],)), jax.lax.stop_gradient(load),
-            jax.lax.stop_gradient(lost))
+    return (y.reshape(lead + (y.shape[-1],)),
+            *(jax.lax.stop_gradient(c) for c in counts))

@@ -836,12 +836,14 @@ def flash_bwd_branches():
     return _flash_branches("bwd")
 
 
-def record_moe_counts(expert_load, slots_lost):
+def record_moe_counts(expert_load, slots_lost, blocks_run):
     """The counts an expert-parallel model keeps on the device, as read once
     a window (``model_zoo.deepseek.publish_moe_counts``): token-slots each
     held expert of each expert layer got
-    (``mxt_moe_expert_slots{layer,expert}``) and slots held but not computed
-    (``mxt_moe_slots_lost``, which must read 0). Cumulative, so gauges."""
+    (``mxt_moe_expert_slots{layer,expert}``), slots held but not computed
+    (``mxt_moe_slots_lost``, which must read 0) and blocks of rows run past
+    each layer's first (``mxt_moe_blocks_run``: how often a routing overflowed
+    the rows its layer is laid out for). Cumulative, so gauges."""
     g = gauge("mxt_moe_expert_slots",
               "Cumulative token-slots each held expert got (on-device "
               "accounting, read once a window).", ("layer", "expert"))
@@ -851,22 +853,26 @@ def record_moe_counts(expert_load, slots_lost):
     gauge("mxt_moe_slots_lost",
           "Cumulative token-slots held and not computed; 0 unless the "
           "expert layer is at fault.").set(float(slots_lost))  # sync-ok: host value
+    gauge("mxt_moe_blocks_run",
+          "Cumulative blocks of rows the expert layers ran past their "
+          "first.").set(float(blocks_run))  # sync-ok: host value
 
 
 def moe_counts():
     """What :func:`record_moe_counts` last published:
     ``{"expert_load": [[slots of each held expert] per layer],
-    "slots_lost": n}``, or {}."""
+    "slots_lost": n, "blocks_run": n}``, or {}."""
     fam = _REGISTRY.get("mxt_moe_expert_slots")
     lost = _REGISTRY.get("mxt_moe_slots_lost")
-    if fam is None or lost is None:
+    ran = _REGISTRY.get("mxt_moe_blocks_run")
+    if fam is None or lost is None or ran is None:
         return {}
     rows = {}
     for (layer, expert), ch in fam.children().items():
         rows.setdefault(int(layer), {})[int(expert)] = int(ch.value)
     return {"expert_load": [[row[e] for e in sorted(row)]
                             for _, row in sorted(rows.items())],
-            "slots_lost": int(lost.value)}
+            "slots_lost": int(lost.value), "blocks_run": int(ran.value)}
 
 
 # --------------------------------------------------------------------------

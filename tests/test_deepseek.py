@@ -15,6 +15,7 @@ benchmark holds a cell: by limits between the sound reading and the control's.
 """
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -285,11 +286,11 @@ def _run_moe(p, x, a, held, bound=None):
     args = [p["router.w"], p["router.bias"], p["experts.gate"], p["experts.up"],
             p["experts.down"], p.get("shared.gate.w"), p.get("shared.up.w"),
             p.get("shared.down.w")]
-    y, load, lost = M.moe_ffn_raw(
+    y, load, lost, ran = M.moe_ffn_raw(
         x.reshape(-1, x.shape[-1]), *args, top_k=a["num_experts_per_tok"],
         n_routed=a["router_width"], experts_held=held,
         scaling=a["routed_scaling_factor"], slots_bound=bound)
-    return y.reshape(x.shape), load, lost
+    return y.reshape(x.shape), load, lost, ran
 
 
 ROUTINGS = {
@@ -312,10 +313,11 @@ def test_expert_layer_against_the_reference(routing, bound):
     ct = _normal(51, x.shape)
     flat = x.reshape(-1, x.shape[-1])
     want, ref_vjp = jax.vjp(lambda pp, xx: ref.moe(pp, xx, a), p, flat)
-    (got, load, lost), vjp = jax.vjp(lambda pp, xx: _run_moe(pp, xx, a, held, bound), p, x)
+    (got, load, lost, ran), vjp = jax.vjp(
+        lambda pp, xx: _run_moe(pp, xx, a, held, bound), p, x)
     _close(got.reshape(want.shape), want, 1e-5)
-    zeros = (jnp.zeros(load.shape, jax.dtypes.float0), jnp.zeros(lost.shape, jax.dtypes.float0))
-    dp, dx = vjp((ct,) + zeros)
+    dp, dx = vjp((ct,) + tuple(jnp.zeros(c.shape, jax.dtypes.float0)
+                               for c in (load, lost, ran)))
     want_dp, want_dx = ref_vjp(ct.reshape(want.shape))
     _close(dx.reshape(want_dx.shape), want_dx, 2e-4)
     for leaf in p:
@@ -336,22 +338,123 @@ def test_expert_layer_against_the_reference(routing, bound):
 
 def test_slots_lost_counts_the_rows_of_a_block_that_did_not_run(monkeypatch):
     """The count is taken from the work done: the slots held less the rows
-    handed to the grouped matmuls of the blocks that ran. With ``lax.cond``
-    made to skip every further block, everything past the first block's 16
-    rows reads as lost, and the result is no longer the reference's."""
+    handed to the grouped matmuls of the blocks that ran. With the trip count
+    of the loop over the further blocks made 0, everything past the first
+    block's 16 rows reads as lost, and the result is no longer the
+    reference's."""
     held = (4, 6)
     a = ref.arch(_config(held))
     p = _moe_params(a, held)
     p["router.bias"] = ROUTINGS["every_slot_held"](p["router.bias"])
     x = _normal(50, (48, a["hidden_size"]))  # 144 slots, all held
     want = ref.moe(p, x, a)
-    y, load, lost = _run_moe(p, x, a, held, 16)
-    assert int(lost) == 0 and int(load.sum()) == 144
+    y, load, lost, ran = _run_moe(p, x, a, held, 16)
+    assert int(lost) == 0 and int(load.sum()) == 144 and int(ran) == 8
     _close(y, want, 1e-5)
-    monkeypatch.setattr(jax.lax, "cond", lambda pred, ran, skipped: skipped())
-    y, load, lost = _run_moe(p, x, a, held, 16)
-    assert int(load.sum()) == 144 and int(lost) == 144 - 16
+    monkeypatch.setattr(M, "_further_blocks", lambda held, bound: jnp.zeros((), jnp.int32))
+    y, load, lost, ran = _run_moe(p, x, a, held, 16)
+    assert int(load.sum()) == 144 and int(lost) == 144 - 16 and int(ran) == 0
     assert not np.allclose(np.asarray(y), np.asarray(want), rtol=1e-2, atol=1e-2)
+
+
+def _routed_case(routing, tokens=48):
+    held = (4, 6)
+    a = ref.arch(_config(held))
+    p = _moe_params(a, held)
+    p["router.bias"] = ROUTINGS[routing](p["router.bias"])
+    return a, held, p, _normal(50, (tokens, a["hidden_size"]))
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_blocks_run_counts_the_further_blocks_that_hold_a_row(routing):
+    """None under the layers' own bound (no tiny size overflows it); with 16
+    rows a block, as many as the slots held need past the first."""
+    a, held, p, x = _routed_case(routing)
+    _, load, lost, ran = _run_moe(p, x, a, held)
+    assert int(ran) == 0 and int(lost) == 0
+    _, load, lost, ran = _run_moe(p, x, a, held, 16)
+    total = int(load.sum())
+    assert int(ran) == max(-(-total // 16) - 1, 0) and int(lost) == 0
+    assert int(ran) == {"every_slot_held": 8, "no_slot_held": 0}.get(routing, int(ran))
+
+
+def _moe_gradient(p, x, a, held, bound):
+    ct = _normal(51, x.shape)
+    return jax.grad(lambda pp, xx: jnp.sum(_run_moe(pp, xx, a, held, bound)[0] * ct),
+                    argnums=(0, 1))(p, x)
+
+
+def test_a_further_block_that_holds_no_row_leaves_the_gradient_untouched():
+    """Two blocks of 72 rows, and a routing whose held slots fill only the
+    first: the loop over the further blocks makes no trip in either pass, so
+    every gradient is, bit for bit, that of one block (its extra rows are
+    zeros that the sums pass over)."""
+    a, held, p, x = _routed_case("some_slots_held")
+    _, load, _, ran = _run_moe(p, x, a, held, 72)
+    assert 0 < int(load.sum()) <= 72 and int(ran) == 0
+    two, one = (_moe_gradient(p, x, a, held, bound) for bound in (72, 144))
+    for got, want in zip(jax.tree_util.tree_leaves(two), jax.tree_util.tree_leaves(one)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _eqns_in_loops(jaxpr, inside=False):
+    """(equation, whether a ``while`` or ``scan`` body holds it) of a jaxpr
+    and every jaxpr nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        loop = inside or eqn.primitive.name in ("while", "scan")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_in_loops(sub, loop)
+
+
+def test_the_gradient_builds_no_zero_weight_stacks_inside_a_loop():
+    """Four blocks of 36 rows. A block that does not run must write nothing:
+    the gradient's loop over the further blocks carries the weight stacks'
+    cotangents and adds to them, and no equation inside a loop broadcasts a
+    value to a weight stack's shape (the zeros a skipped ``cond`` branch
+    wrote, three stacks a block a layer, were 6 ms a layer on the chip)."""
+    a, held, p, x = _routed_case("some_slots_held")
+    jaxpr = jax.make_jaxpr(lambda pp, xx: _moe_gradient(pp, xx, a, held, 36))(p, x)
+    stacks = {p[k].shape for k in ("experts.gate", "experts.up", "experts.down")}
+    eqns = list(_eqns_in_loops(jaxpr.jaxpr))
+    loops = [e for e, _ in eqns if e.primitive.name == "while"]
+    assert len(loops) == 2  # one a pass, their trip count read from the data
+    carried = [{v.aval.shape for v in e.outvars} for e in loops]
+    assert any(stacks <= shapes for shapes in carried)
+    written = [e for e, inside in eqns if inside and e.primitive.name == "broadcast_in_dim"
+               and e.outvars[0].aval.shape in stacks]
+    assert not written, written
+
+
+def test_both_passes_of_the_expert_layer_carry_its_scopes(monkeypatch):
+    """Device time is attributed by the names in the compiled step: every
+    grouped matmul of the forward, of the backward (whose rule is written by
+    hand) and of the blocks recomputed there stands under ``moe`` and
+    ``experts``, and the backward's count as backward."""
+    from mxnet_tpu import profiler_trace
+
+    a, held, p, x = _routed_case("every_slot_held")
+    args = [p[k] for k in ("router.w", "router.bias", "experts.gate", "experts.up",
+                           "experts.down")]
+
+    def loss(x, *args):
+        with jax.named_scope("forward"):
+            y = M.moe_ffn(x, *args, top_k=3, experts_held=held)[0]
+        return jnp.sum(y * y)
+
+    # the op takes the layers' own bound: a tile of 8 rows makes it 112 of 144 slots
+    monkeypatch.setattr(M, "_BOUND_TILE", 8)
+    text = jax.jit(jax.grad(loss, argnums=(0, 3))).lower(x, *args).as_text(debug_info=True)
+    names = [m for m in re.findall(r'loc\("([^"]+)"', text) if "/ragged_dot" in m]
+    assert names
+    phases = {}
+    for name in names:
+        scopes = profiler_trace.scopes_of(name)
+        assert "moe" in scopes and "experts" in scopes, name
+        phases.setdefault(profiler_trace.phase_of("fusion", name), set()).add(
+            "body" in name.split("/"))
+    # both passes, in line (block 0) and in a loop's body (the further blocks)
+    assert phases == {"forward": {False, True}, "backward": {False, True}}, phases
 
 
 def test_shares_of_the_experts_add_up_to_the_uncut_layer():
@@ -368,7 +471,7 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer():
         held = (2 * chip, 2)
         part = {k: (v[2 * chip:2 * chip + 2] if k.startswith("experts.") else v)
                 for k, v in p.items() if not k.startswith("shared.")}
-        y, load, lost = _run_moe(part, x, a, held)
+        y, load, lost, _ = _run_moe(part, x, a, held)
         # the reference given the same share gives the same part
         _close(y, ref.moe(part, x, ref.arch(_config(held))), 1e-5)
         total, slots = total + y, slots + int(load.sum())
@@ -393,6 +496,7 @@ def test_gluon_expert_layer_counts_in_aux_state_and_keeps_its_types():
              moe.collect_params().items()}
     assert types["router_bias"] == "float32" and types["expert_load"] == "int32"
     assert types["slots_lost"] == "int32" and types["gate_weight"] == "bfloat16"
+    assert types["blocks_run"] == "int32"
     # the router is trained whatever the share; the selection bias never is
     assert moe.router_weight.grad_req == "write" and moe.router_bias.grad_req == "null"
     x = nd.array(np.asarray(_normal(70, (2, 8, 32)))).astype("bfloat16")
@@ -487,10 +591,11 @@ def test_the_fp8_control_fails_a_limit_the_program_meets(first_steps):
 def test_overflow_blocks_inside_the_donated_step_change_nothing(monkeypatch):
     """A routing that sends every slot to the experts held overflows the
     layers' own bound (twice an even share; its tile made 8 rows here, since
-    a tile of 512 holds every slot of a tiny size): the further blocks, under
-    ``lax.cond`` in a ``lax.scan`` and recomputed in the backward, train to
-    the same losses through ``ShardedTrainStep`` as one block does, and lose
-    no slot."""
+    a tile of 512 holds every slot of a tiny size): the further blocks, a
+    loop whose trip count the step reads from its own routing, recomputed in
+    the hand-written backward and added into the first block's cotangents,
+    train to the same losses through ``ShardedTrainStep`` as one block does,
+    lose no slot, and are counted in aux state (``blocks_run``)."""
     from mxnet_tpu import parallel
 
     x = nd.array(np.random.RandomState(0).randint(0, 50, (2, 16)).astype("float32"))
@@ -515,6 +620,8 @@ def test_overflow_blocks_inside_the_donated_step_change_nothing(monkeypatch):
         assert counts["slots_lost"] == 0
         got = [sum(b) - sum(a) for a, b in zip(before["expert_load"], counts["expert_load"])]
         assert got == [3 * 96] * 2  # every slot of every step, in both layers
+        # 96 slots over 72 rows: one further block a layer a step, or none
+        assert counts["blocks_run"] - before["blocks_run"] == (6 if tile == 8 else 0)
     assert M.default_slots_bound(32, 3, 16, 6) == 72  # 96 slots: two blocks
     np.testing.assert_allclose(losses[8], losses[512], rtol=1e-5)
     assert losses[512][2] < losses[512][0]
@@ -528,7 +635,8 @@ def test_model_is_built_from_the_configs_keys_and_refuses_what_it_lacks():
     assert net(nd.array(np.zeros((2, 8), "float32"))).shape == (2, 8, 50)
     counts = zoo.publish_moe_counts(net)
     assert counts["slots_lost"] == 0 and len(counts["expert_load"]) == 2
-    assert mx.telemetry.moe_counts()["slots_lost"] == 0
+    assert counts["blocks_run"] == 0
+    assert mx.telemetry.moe_counts() == counts
     for key, bad in (("scoring_func", "softmax"), ("n_group", 8),
                      ("rope_scaling", {"type": "yarn"})):
         with pytest.raises(mx.base.MXNetError):

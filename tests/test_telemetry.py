@@ -332,6 +332,18 @@ def test_render_exposes_acceptance_metrics():
         assert needed in text, "missing %s in exposition" % needed
 
 
+def test_moe_counts_are_published_whole():
+    """What an expert-parallel model counted on the device comes back as it
+    was recorded, and each count is a gauge of the exposition: slots by
+    layer and held expert, slots lost, further blocks run."""
+    counts = {"expert_load": [[3, 0, 5], [1, 2, 4]], "slots_lost": 0, "blocks_run": 7}
+    telemetry.record_moe_counts(**counts)
+    assert telemetry.moe_counts() == counts
+    text = telemetry.render_prometheus()
+    assert 'mxt_moe_expert_slots{layer="1",expert="2"} 4' in text
+    assert "mxt_moe_slots_lost 0" in text and "mxt_moe_blocks_run 7" in text
+
+
 def test_http_endpoint_serves_metrics():
     import urllib.request
 

@@ -12,7 +12,8 @@ kernels' calls a step, the branch each traced attention forward and backward
 took (``flash_fwd_branches``, ``flash_bwd_branches``), the tuning table's
 entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
-(``moe_counts``: slots by layer and held expert, slots lost), the set-up
+(``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
+run past a layer's first), the set-up
 phases and the compile counters. The
 benchmark's cells cannot name a new per-layer metric without an edit to
 their files (PERF.md section 7), so this is how those numbers are taken
