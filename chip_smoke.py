@@ -253,6 +253,69 @@ def phase_kernels(args, dev):
         check(max(errs) < 2e-2, "flash backward %s: rel errs %s vs the reference's "
               "gradient" % (name, errs))
 
+    # -- the learned selection: the indexer's kernel against lax.top_k's set
+    #    (the XLA path: the same float32 scores, a full sort), then grouped
+    #    heads under that mask through both flash kernels against the
+    #    reference's output and gradient; timed at the Keye cell's shape
+    from mxnet_tpu.ops import indexer as X
+
+    sizes = [(1, 2, 1, 256, 32, 2, 16, 64)] if args.rehearse else [
+        (1, 8, 1, 2048, 128, 16, 64, 512), (1, 32, 4, 8192, 128, 16, 64, 2048)]
+    for B, H, G, T, D, Hi, Di, topk in sizes:
+        ks = jax.random.split(jax.random.fold_in(key, T), 7)
+        qi, ki, wi = (jax.random.normal(s, sh, jnp.bfloat16) for s, sh in zip(
+            ks, ((B, T, Hi, Di), (B, T, Di), (B, T, Hi))))
+        if interp:  # the rehearsal: the kernel itself, in interpret mode
+            def select(q, k, w, topk=topk):
+                mask = X._select_pallas(jnp.transpose(q, (0, 2, 1, 3)), k,
+                                        jnp.transpose(w, (0, 2, 1)), topk, True)
+                return mask, jnp.sum(mask, dtype=jnp.int32)
+        else:
+            def select(q, k, w, topk=topk):
+                return X.lightning_indexer(q, k, w, topk=topk)[:2]
+        select = jax.jit(select)
+        mask, selected = select(qi, ki, wi)
+        row = dict(shape=(B, H, G, T, D), topk=topk, selected=int(selected),
+                   select_ms=median_ms(lambda *a: select(*a)[0], qi, ki, wi))
+        check(int(selected) == B * sum(min(t + 1, topk) for t in range(T)),
+              "indexer T=%d: %d pairs selected" % (T, int(selected)))
+        q, k, v, do = (jax.random.normal(s, sh, jnp.bfloat16) for s, sh in zip(
+            ks[3:], ((B, H, T, D), (B, G, T, D), (B, G, T, D), (B, H, T, D))))
+        sm = 1.0 / math.sqrt(D)
+        cfg = tuning.heuristic_attention((B, H, T, D), T, "bfloat16", True)
+        bq, bk = A._bwd_blocks(T, T)
+        fwd = jax.jit(lambda q, k, v, m: A._flash_forward_pallas(
+            q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"],
+            interpret=interp, mask=m))
+        bwd = jax.jit(lambda q, k, v, m, o, lse, do: A._flash_backward_pallas(
+            q, k, v, None, o, lse, do, True, sm, bq, bk, interpret=interp, mask=m)[:3])
+        o, lse = fwd(q, k, v, mask)
+        grads = bwd(q, k, v, mask, o, lse, do)
+        row.update(fwd_ms=median_ms(lambda *a: fwd(*a)[0], q, k, v, mask),
+                   bwd_ms=median_ms(bwd, q, k, v, mask, o, lse, do))
+        if T <= 2048:  # sizes at which the plain forms fit
+            want_mask = jax.jit(lambda q, k, w: X._select_xla(
+                jnp.transpose(q, (0, 2, 1, 3)), k, jnp.transpose(w, (0, 2, 1)), topk))(
+                    qi, ki, wi)
+            row["selection_differs"] = int(jnp.sum(mask != want_mask))
+            check(row["selection_differs"] == 0, "indexer kernel T=%d: %d pairs off "
+                  "lax.top_k's set" % (T, row["selection_differs"]))
+            f32 = jnp.float32
+            ref = jax.jit(lambda q, k, v, m, do: jax.vjp(
+                lambda q_, k_, v_: A._attention_reference(
+                    q_.astype(f32), k_.astype(f32), v_.astype(f32), None, True, sm, m),
+                q, k, v)[1](do.astype(f32)))
+            with jax.default_matmul_precision("highest"):
+                want = ref(q, k, v, mask, do)
+                want_o = A._attention_reference(q.astype(f32), k.astype(f32),
+                                                v.astype(f32), None, True, sm, mask)
+            row["rel_err"] = rel_err(o, want_o)
+            row["bwd_rel_err"] = [rel_err(g, w) for g, w in zip(grads, want)]
+            check(max([row["rel_err"]] + row["bwd_rel_err"]) < 2e-2,
+                  "masked grouped flash T=%d: %s" % (T, row))
+        check(bool(jnp.all(jnp.isfinite(o.astype(jnp.float32)))), "selected flash: not finite")
+        out["flash"]["selected_%d" % T] = row
+
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks
     if args.rehearse:

@@ -12,9 +12,11 @@ from . import gpt
 from .gpt import GPTModel, gpt_mini, gpt_small
 from . import deepseek
 from .deepseek import DeepseekV3Model
+from . import keye
+from .keye import KeyeVL2Model
 
 __all__ = ["vision", "get_model", "bert", "BERTModel", "BERTEncoder",
            "get_bert_model", "bert_12_768_12", "bert_6_512_8",
            "bert_3_64_2", "WideDeep", "wide_deep",
            "gpt", "GPTModel", "gpt_mini", "gpt_small",
-           "deepseek", "DeepseekV3Model"]
+           "deepseek", "DeepseekV3Model", "keye", "KeyeVL2Model"]

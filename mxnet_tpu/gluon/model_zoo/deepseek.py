@@ -98,12 +98,17 @@ class MLAttention(HybridBlock):
 class DeepseekMoE(HybridBlock):
     """The expert layer of a chip that holds ``experts_held=(first, count)``
     of ``n_routed_experts``: op ``moe_ffn``. ``n_shared_experts`` shared
-    experts are one SwiGLU of that many times the width."""
+    experts are one SwiGLU of that many times the width. ``scoring`` is the
+    router's (``"sigmoid"``, or ``"softmax"`` over all the experts);
+    ``selection_bias=False`` is a router without the bias buffer;
+    ``router_gradient=False`` lets no gradient through the chosen experts'
+    weights (op ``moe_ffn``)."""
 
     def __init__(self, units, moe_intermediate_size, n_routed_experts,
                  num_experts_per_tok, n_shared_experts=0,
-                 routed_scaling_factor=1.0, experts_held=None, prefix=None,
-                 params=None):
+                 routed_scaling_factor=1.0, experts_held=None,
+                 scoring="sigmoid", selection_bias=True, router_gradient=True,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = experts_held or (0, n_routed_experts)
         if first < 0 or count < 1 or first + count > n_routed_experts:
@@ -111,15 +116,18 @@ class DeepseekMoE(HybridBlock):
                              % (experts_held, n_routed_experts))
         self._static = dict(top_k=num_experts_per_tok, n_routed=n_routed_experts,
                             experts_held=(first, count),
-                            scaling=routed_scaling_factor)
+                            scaling=routed_scaling_factor, scoring=scoring,
+                            router_gradient=router_gradient)
+        self._bias = bool(selection_bias)
         width, shared = moe_intermediate_size, n_shared_experts * moe_intermediate_size
         with self.name_scope():
             g = self.params.get
             self.router_weight = g("router_weight", shape=(n_routed_experts, units))
             # e_score_correction_bias: a buffer the training recipe moves
             # by its own rule, never by a gradient; float32 as published
-            self.router_bias = g("router_bias", shape=(n_routed_experts,),
-                                 init="zeros", grad_req="null")
+            if self._bias:
+                self.router_bias = g("router_bias", shape=(n_routed_experts,),
+                                     init="zeros", grad_req="null")
             self.gate_weight = g("gate_weight", shape=(count, units, width))
             self.up_weight = g("up_weight", shape=(count, units, width))
             self.down_weight = g("down_weight", shape=(count, width, units))
@@ -140,7 +148,8 @@ class DeepseekMoE(HybridBlock):
         16-bit cast (as BatchNorm's running statistics stay float32); the
         router's weights are cast like the others, trained or not."""
         super().cast(dtype)
-        self.router_bias.cast("float32")
+        if self._bias:
+            self.router_bias.cast("float32")
         self.expert_load.cast("int32")
         self.slots_lost.cast("int32")
         self.blocks_run.cast("int32")

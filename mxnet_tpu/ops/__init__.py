@@ -11,6 +11,7 @@ from . import rnn
 from . import optimizer_ops
 from . import loss_output
 from . import attention
+from . import indexer
 from . import moe
 from . import linalg
 from . import contrib_ops

@@ -18,6 +18,28 @@ Layout: (B, H, T, D) with D the head dim — MXU-friendly (T, D) @ (D, T)
 tiles, fp32 accumulation via preferred_element_type. The value's head dim
 may differ from the key's (latent attention: keys 192 wide, values 128):
 every branch takes ``Dv`` from ``v`` and writes an output that wide.
+
+Grouped heads: K and V may have fewer heads than Q (``Hq % Hkv == 0``);
+query head ``h`` reads K/V head ``h // (Hq // Hkv)``. The kernels pick that
+head in their block maps, so K and V are never copied a group's times in
+HBM; the backward kernel writes ``dk`` / ``dv`` a query head in float32 and
+the group's sum is one XLA reduction. The XLA branches repeat K/V (they are
+the CPU's and the fallback's).
+
+Selection mask: an optional ``mask`` (B, Tq, Tk), true where a (query, key)
+pair may be attended, the same for every head of a sequence, data and not
+shape, with no gradient, combined with ``causal`` and the bias. It reaches a
+kernel as int8 tiles, laid out again for that kernel's blocks (forward
+(B, Tq/bq, Tk/bk, bq, bk), backward key-major (B, Tk/bk, Tq/bq, bk, bq):
+one XLA transpose each, Tq x Tk bytes read and written), and a grid step
+takes the tiles of its row block (column block in the backward), bq x Tk
+bytes, 4 MB at 512 x 8192. Both kernels fetch that block once a query
+head: B x H x Tq x Tk bytes a call, 2 GB at one sequence of 8192 on 32
+heads, behind the matmuls. With a mask the kernels ask for the VMEM they
+hold (``_fwd_vmem_limit``, ``_bwd_vmem_limit``).
+
+A call without a mask and with as many K/V heads as query heads traces to
+the kernels it traced to before either was built.
 """
 from __future__ import annotations
 
@@ -73,12 +95,15 @@ def blocks_pinned():
             or _config.is_set("MXT_FLASH_BLOCK_K"))
 
 
-def _tuned_config(q, k, v, bias, causal, sm_scale):
+def _tuned_config(q, k, v, bias, causal, sm_scale, mask=None):
     """Per-shape kernel decision: pinned blocks win (legacy/global
     behavior), otherwise the tuning table answers — a table hit, or a
-    measured/heuristic autotune pass recorded under this shape bucket.
-    The returned dict carries the XLA-vs-Pallas choice per shape; the
-    device gate (context.on_tpu) still applies on top."""
+    measured/heuristic autotune pass recorded under this shape bucket
+    (the bucket names the K/V head count where it is not the query's, and
+    a selection mask where there is one: an entry tuned for a dense call is
+    never taken for a masked one). The returned dict carries the
+    XLA-vs-Pallas choice per shape; the device gate (context.on_tpu) still
+    applies on top."""
     if str(_config.get("MXT_TUNE_MODE")).lower() == "off" \
             or blocks_pinned():
         bq, bk = default_blocks()
@@ -88,14 +113,34 @@ def _tuned_config(q, k, v, bias, causal, sm_scale):
 
     return tuning.resolve_attention(
         q.shape, k.shape[2], str(q.dtype), causal,
-        arrays=(q, k, v, bias, sm_scale))
+        arrays=(q, k, v, bias, sm_scale), kv_heads=k.shape[1], mask=mask)
 _NEG_INF = -1e30
 # lanes of a vector register: a column is broadcast over them to turn it
 _LSE_LANES = 128
 
 
-def _attention_reference(q, k, v, bias, causal, sm_scale):
+def _kv_per_query_head(q, k, v):
+    """K and V with a head a query head (the XLA branches): a group's K/V
+    head repeated. Unchanged where the heads are as many."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def _sum_kv_group(dx, kv_heads):
+    """(B, Hq, Tk, D) gradient a query head -> (B, Hkv, Tk, D), the sum over
+    each group in float32."""
+    B, H, Tk, D = dx.shape
+    if H == kv_heads:
+        return dx
+    return jnp.sum(dx.reshape(B, kv_heads, H // kv_heads, Tk, D)
+                   .astype(jnp.float32), axis=2).astype(dx.dtype)
+
+
+def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None):
     """Plain-XLA reference (also the CPU path). O(T^2) memory."""
+    k, v = _kv_per_query_head(q, k, v)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
     scores = scores * sm_scale
@@ -103,8 +148,10 @@ def _attention_reference(q, k, v, bias, causal, sm_scale):
         scores = scores + bias.astype(jnp.float32)
     if causal:
         tq, tk = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        scores = jnp.where(mask, scores, _NEG_INF)
+        tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        scores = jnp.where(tril, scores, _NEG_INF)
+    if mask is not None:
+        scores = jnp.where(mask[:, None] != 0, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -115,8 +162,8 @@ def _attention_reference(q, k, v, bias, causal, sm_scale):
 _KV_INLINE = 4  # K/V blocks of a static loop the kernel writes out in line
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                      block_k, causal, sm_scale, kv_len, q_len):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
+                      *, block_k, causal, sm_scale, kv_len, q_len):
     """One (batch x head, Q block) grid step of the forward: the head's K/V
     sit in VMEM whole, the Q block streams over them block_k keys at a time
     with the online softmax. Operands of both matmuls are in the input
@@ -129,8 +176,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     to rescale, and up to ``_KV_INLINE`` blocks are written out in line.
     Under ``causal`` one loop runs to the diagonal with the mask on every
     block, two blocks an iteration so that the second block's scores can be
-    issued beside the first's softmax. ``lse`` leaves as a lane-oriented
-    row, 4 bytes a query."""
+    issued beside the first's softmax. A selection mask (``mask_ref``: this
+    Q block's int8 tiles, one a K/V block, picked by a leading index) joins
+    the other masks on every block. ``lse`` leaves as a lane-oriented row,
+    4 bytes a query."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -167,10 +216,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             s = s * f32(sm_scale)
         if bias_ref is not None:
             s = s + bias_ref[0, ik].astype(f32)  # (1, BK), over the rows
+        masks = []
         if masked:
             col = k_off + jax.lax.broadcasted_iota(
                 i32, (block_q, block_k), 1)
-            masks = []
             if kv_pad != kv_len:  # tail-block padding
                 masks.append(col < kv_len)
             if causal:
@@ -178,6 +227,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 row = q_off + jax.lax.broadcasted_iota(
                     i32, (block_q, block_k), 0)
                 masks.append(col <= row + shift)
+        if mask_ref is not None:  # the selection: data, on every block
+            masks.append(mask_ref[0, 0, ik].astype(i32) != 0)
+        if masks:
             s = jnp.where(functools.reduce(jnp.logical_and, masks), s,
                           neg_inf)
         m_new = jnp.max(s, axis=1, keepdims=True)  # (BQ, 1)
@@ -241,16 +293,60 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LSE_LANES)).T[:1, :]
 
 
+def _lanes(d):
+    return -(-d // 128) * 128
+
+
+def _fwd_vmem_limit(tk, d, dv, block_q, block_k, itemsize):
+    """Scoped VMEM the forward kernel asks for when it carries a selection
+    mask (without one it lives in what the compiler gives unasked, as every
+    call did before the mask was built): what it holds and a quarter more.
+    Held, each block twice for the pipeline's two buffers and minor
+    dimensions rounded up to the 128 lanes: the Q and output blocks, K and V
+    whole, the Q block's mask tiles (block_q x Tk bytes), the float32
+    accumulator and six float32 (block_q, block_k) tiles. One sequence of
+    8192 at 128 + 128 in bfloat16 with 512 x 512 blocks: 8.4 MB of K/V, 8.4
+    of mask, 23.9 MB held, 29.8 MB asked for."""
+    held = (2 * block_q * (_lanes(d) + _lanes(dv)) * itemsize
+            + 2 * tk * (_lanes(d) + _lanes(dv)) * itemsize
+            + 2 * block_q * tk + block_q * _lanes(dv) * 4
+            + 6 * block_q * block_k * 4)
+    return held + held // 4
+
+
+def _mask_tiles(mask, block_q, block_k, pad_q, pad_k, key_major=False):
+    """(B, Tq, Tk) selection -> int8 tiles (B, Tq/bq, Tk/bk, bq, bk), or
+    key-major (B, Tk/bk, Tq/bq, bk, bq) for the backward's turned tile;
+    padding selects nothing."""
+    m = (mask != 0).astype(jnp.int8)
+    if pad_q or pad_k:
+        m = jnp.pad(m, ((0, 0), (0, pad_q), (0, pad_k)))
+    B, Tq, Tk = m.shape
+    m = m.reshape(B, Tq // block_q, block_q, Tk // block_k, block_k)
+    return m.transpose(0, 3, 1, 4, 2) if key_major else m.transpose(0, 1, 3, 2, 4)
+
+
+def _kv_head_of(group):
+    """Flat (batch x query head) index -> flat (batch x K/V head) index, for
+    a block map: query head h reads K/V head h // group, and with Hq = group
+    x Hkv the flat index divides the same way."""
+    if group == 1:
+        return lambda bh: bh
+    # lax.div on a non-negative i32: jnp's floor_divide does not lower
+    return lambda bh: jax.lax.div(bh, np.int32(group))
+
+
 def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                          interpret):
+                          interpret, mask=None):
     """(out, lse) of the forward kernel: ``out`` (B, H, Tq, Dv) in the input
     dtype, ``lse`` (B, H, Tq) float32. The kernel writes ``lse`` as a
-    (B*H, 1, Tq) array in (1, 1, block_q) row blocks."""
+    (B*H, 1, Tq) array in (1, 1, block_q) row blocks. ``k`` / ``v`` hold
+    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
-    Tk, Dv = k.shape[2], v.shape[3]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     if not interpret and block_q < Tq and block_q % _LSE_LANES:
@@ -271,8 +367,9 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             bias = jnp.pad(bias, ((0, 0), (0, 0), (0, 0), (0, pad_k)))
     Tqp, Tkp = Tq + pad_q, Tk + pad_k
     qf = q.reshape(B * H, Tqp, D)
-    kf = k.reshape(B * H, Tkp, D)
-    vf = v.reshape(B * H, Tkp, Dv)
+    kf = k.reshape(B * Hkv, Tkp, D)
+    vf = v.reshape(B * Hkv, Tkp, Dv)
+    kv_head = _kv_head_of(H // Hkv)
 
     # index maps return np.int32 zeros: under jax_enable_x64 a literal 0
     # traces as i64, which Mosaic rejects in the index-map signature
@@ -280,28 +377,42 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, iq: (bh, iq, z),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tkp, D), lambda bh, iq: (bh, z, z),
+        pl.BlockSpec((1, Tkp, D), lambda bh, iq: (kv_head(bh), z, z),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tkp, Dv), lambda bh, iq: (bh, z, z),
+        pl.BlockSpec((1, Tkp, Dv), lambda bh, iq: (kv_head(bh), z, z),
                      memory_space=pltpu.VMEM),
     ]
     args = [qf, kf, vf]
+    nkv = Tkp // block_k
     if bias is not None:
         # additive key-bias (B, H, 1, Tk) or (B, 1, 1, Tk) → one (1, block_k)
         # row a K/V block, (B*H, Tkp / block_k, 1, block_k): the kernel
         # picks a block's row by a leading index, never by a lane offset
-        nkv = Tkp // block_k
         bflat = jnp.broadcast_to(bias, (B, H, 1, Tkp)).reshape(
             B * H, nkv, 1, block_k)
         in_specs.append(pl.BlockSpec((1, nkv, 1, block_k),
                                      lambda bh, iq: (bh, z, z, z),
                                      memory_space=pltpu.VMEM))
         args.append(bflat)
+    extra = {}
+    if mask is not None:
+        # the Q block's tiles, one a K/V block, the same for every head of
+        # the sequence: picked by a leading index as the bias's rows are
+        in_specs.append(pl.BlockSpec(
+            (1, 1, nkv, block_q, block_k),
+            lambda bh, iq: (jax.lax.div(bh, np.int32(H)), iq, z, z, z),
+            memory_space=pltpu.VMEM))
+        args.append(_mask_tiles(mask, block_q, block_k, pad_q, pad_k))
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_fwd_vmem_limit(Tkp, D, Dv, block_q, block_k,
+                                             q.dtype.itemsize))
 
     def kernel(*refs):
         refs = list(refs)
         if bias is None:
             refs.insert(3, None)
+        if mask is None:
+            refs.insert(4, None)
         _flash_fwd_kernel(*refs, block_k=block_k, causal=causal,
                           sm_scale=sm_scale, kv_len=Tk, q_len=Tq)
 
@@ -323,6 +434,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_attention_fwd",
+        **extra,
     )(*args)
     out = out.reshape(B, H, Tqp, Dv)[:, :, :Tq]
     lse = lse.reshape(B, H, Tqp)[:, :, :Tq]
@@ -332,7 +444,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 # Pallas backward kernel
 # ---------------------------------------------------------------------------
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                       dq_ref, dk_ref, dv_ref, db_ref, dq_acc, *,
                       block_q, causal, sm_scale, kv_len, q_len, kv_pad):
     """One (batch x head, K/V block) grid step of the backward: Q, dO and
@@ -344,7 +456,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
     The tile is held key-major, ``s_t[k, q]``: dv and dk are then plain
     matmuls, ``lse`` and ``delta`` are lane-oriented rows (no lane-padded
     column per query), and only ds turns once for dq. ``sm_scale`` is
-    applied to ds after its matmuls, on the (T, D) sums."""
+    applied to ds after its matmuls, on the (T, D) sums. A selection mask
+    (``mask_ref``: this K/V block's int8 tiles, key-major, one a Q block)
+    joins the other masks on every tile. ``dk`` / ``dv`` leave in the type
+    of their blocks: the input's, or float32 a query head where a group of
+    query heads shares the K/V head and XLA sums the group."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -393,6 +509,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref,
                 masks.append(qrow < q_len)
             if causal:
                 masks.append(kcol <= qrow + shift)
+        if mask_ref is not None:  # the selection: data, on every tile
+            masks.append(mask_ref[0, 0, iq].astype(jnp.int32) != 0)
+        if masks:
             s_t = jnp.where(functools.reduce(jnp.logical_and, masks), s_t,
                             neg_inf)
         p_t = jnp.exp(s_t - lse)
@@ -444,34 +563,43 @@ def _bwd_blocks(Tq, Tk):
 _VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call unasked
 
 
-def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize):
+def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
+                    out_itemsize=None):
     """Scoped VMEM the backward kernel asks for: nothing (the compiler's
     default) while what it holds fits that with room to spare, as every
-    call within ``_VMEM_QDO_BYTES`` does; else what it holds and a quarter
+    call of 1 MB of Q + dO does; else what it holds and a quarter
     more. Held, minor dimensions rounded up to the 128 lanes: Q, dO and dq
     whole and double-buffered, the float32 dq accumulator, the K/V and
-    dk/dv blocks, and six float32 (block_k, block_q) tiles."""
-    def lanes(d):
-        return -(-d // 128) * 128
-
+    dk/dv blocks (the latter ``out_itemsize`` wide: float32 a query head
+    under grouped heads), six float32 (block_k, block_q) tiles and, with a
+    selection mask, the K/V block's int8 tiles (Tq x block_k bytes), twice.
+    A head of 4096 rows at 192 + 128 asks for 28.5 MB; one of 8192 rows at
+    128 + 128 holds 24.6 MB and asks for 30.8, and with the mask and
+    float32 dk / dv blocks holds 33.5 MB and asks for 41.9 (of the chip's
+    128 MB)."""
+    lanes, out_itemsize = _lanes, out_itemsize or itemsize
     held = (2 * tq * (2 * lanes(dk) + lanes(dv)) * itemsize
             + tq * lanes(dk) * 4 + 2 * 8 * tq * 4
-            + 4 * block_k * (lanes(dk) + lanes(dv)) * itemsize
+            + 2 * block_k * (lanes(dk) + lanes(dv)) * (itemsize + out_itemsize)
             + 6 * block_q * block_k * 4)
+    if mask:
+        held += 2 * tq * block_k
     if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
         return None
     return held + held // 4
 
 
 def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
-                           block_q, block_k, interpret):
+                           block_q, block_k, interpret, mask=None):
     """dq, dk, dv (and dbias) of flash attention from the forward's
-    residuals, never building a score tensor in HBM."""
+    residuals, never building a score tensor in HBM. ``k`` / ``v`` hold
+    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
-    Tk, Dv = k.shape[2], v.shape[3]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group = H // Hkv
     f32 = jnp.float32
     delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Tq)
     stats = jnp.stack([lse.astype(f32), delta], axis=2)  # (B,H,2,Tq)
@@ -486,6 +614,11 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     Tqp, Tkp = Tq + pad_q, Tk + pad_k
     BH = B * H
+    kv_head = _kv_head_of(group)
+    # a K/V head that a group of query heads shares: each writes its own
+    # dk / dv in float32 and XLA sums the group (the K/V axis of the grid
+    # carries dq, so a group's heads cannot share an output block)
+    part = f32 if group > 1 else None
 
     # np.int32 zeros in the index maps, as the forward (x64 is on)
     z = np.int32(0)
@@ -494,6 +627,11 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
                             memory_space=pltpu.VMEM)
 
     def kv_blk(d):
+        return pl.BlockSpec((1, block_k, d),
+                            lambda bh, ik: (kv_head(bh), ik, z),
+                            memory_space=pltpu.VMEM)
+
+    def dkv_blk(d):
         return pl.BlockSpec((1, block_k, d), lambda bh, ik: (bh, ik, z),
                             memory_space=pltpu.VMEM)
 
@@ -502,13 +640,13 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
     in_specs = [whole(D), kv_blk(D), kv_blk(Dv), whole(Dv),
                 pl.BlockSpec((1, 2, Tqp), lambda bh, ik: (bh, z, z),
                              memory_space=pltpu.VMEM)]
-    args = [q.reshape(BH, Tqp, D), k.reshape(BH, Tkp, D),
-            v.reshape(BH, Tkp, Dv), do.reshape(BH, Tqp, Dv),
+    args = [q.reshape(BH, Tqp, D), k.reshape(B * Hkv, Tkp, D),
+            v.reshape(B * Hkv, Tkp, Dv), do.reshape(BH, Tqp, Dv),
             stats.reshape(BH, 2, Tqp)]
-    out_specs = [whole(D), kv_blk(D), kv_blk(Dv)]
+    out_specs = [whole(D), dkv_blk(D), dkv_blk(Dv)]
     out_shape = [jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
-                 jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
-                 jax.ShapeDtypeStruct((BH, Tkp, Dv), v.dtype)]
+                 jax.ShapeDtypeStruct((BH, Tkp, D), part or k.dtype),
+                 jax.ShapeDtypeStruct((BH, Tkp, Dv), part or v.dtype)]
     static = dict(block_q=block_q, causal=causal, sm_scale=sm_scale,
                   kv_len=Tk, q_len=Tq, kv_pad=Tkp)
     if bias is not None:
@@ -519,12 +657,24 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
         args.append(bflat.reshape(BH, 1, Tkp))
         out_specs.append(key_row)
         out_shape.append(jax.ShapeDtypeStruct((BH, 1, Tkp), f32))
+    if mask is not None:
+        # the K/V block's tiles, key-major as the kernel's own tile, one a
+        # Q block, picked by a leading index
+        in_specs.append(pl.BlockSpec(
+            (1, 1, Tqp // block_q, block_k, block_q),
+            lambda bh, ik: (jax.lax.div(bh, np.int32(H)), ik, z, z, z),
+            memory_space=pltpu.VMEM))
+        args.append(_mask_tiles(mask, block_q, block_k, pad_q, pad_k,
+                                key_major=True))
 
     def kernel(*refs):
         refs = list(refs)
-        if bias is None:  # no bias in, no dbias out
+        if bias is None:  # no bias in
             refs.insert(5, None)
-            refs.insert(9, None)
+        if mask is None:
+            refs.insert(6, None)
+        if bias is None:  # no dbias out
+            refs.insert(10, None)
         _flash_bwd_kernel(*refs, **static)
 
     outs = pl.pallas_call(
@@ -537,14 +687,18 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
         # the K/V axis carries the dq accumulator: sequential
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_limit(Tqp, D, Dv, block_q, block_k,
-                                             q.dtype.itemsize)),
+            vmem_limit_bytes=_bwd_vmem_limit(
+                Tqp, D, Dv, block_q, block_k, q.dtype.itemsize,
+                mask=mask is not None, out_itemsize=4 if part else None)),
         interpret=interpret,
         name="flash_attention_bwd",
     )(*args)
     dq = outs[0].reshape(B, H, Tqp, D)[:, :, :Tq]
     dk = outs[1].reshape(B, H, Tkp, D)[:, :, :Tk]
     dv = outs[2].reshape(B, H, Tkp, Dv)[:, :, :Tk]
+    if part:
+        dk = _sum_kv_group(dk, Hkv).astype(k.dtype)
+        dv = _sum_kv_group(dv, Hkv).astype(v.dtype)
     dbias = None
     if bias is not None:
         dbias = _reduce_dbias(outs[3].reshape(B, H, 1, Tkp)[..., :Tk], bias)
@@ -574,9 +728,19 @@ def _chunk_kv(x, chunk):
     return x.reshape(B, H, (Tk + pad) // chunk, chunk, D), pad
 
 
-def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK):
+def _chunk_mask(mask, chunk, pad):
+    """(B, Tq, Tk) selection -> (nchunks, B, 1, Tq, chunk) booleans, one a
+    K/V chunk of a scan; padding selects nothing."""
+    m = jnp.pad(mask != 0, ((0, 0), (0, 0), (0, pad)))
+    B, Tq, Tkp = m.shape
+    return jnp.moveaxis(m.reshape(B, Tq, Tkp // chunk, chunk), 2, 0)[:, :, None]
+
+
+def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK,
+                        mask=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    k, v = _kv_per_query_head(q, k, v)
     kc, pad = _chunk_kv(k, chunk)
     vc, _ = _chunk_kv(v, chunk)
     nchunks = kc.shape[2]
@@ -590,21 +754,21 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK):
 
     def body(carry, xs):
         m_i, l_i, acc_i = carry
-        if bias is not None:
-            k_c, v_c, b_c, idx = xs
-        else:
-            k_c, v_c, idx = xs
+        k_c, v_c, idx = xs[0], xs[1], xs[-1]
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_c.astype(jnp.float32),
                        preferred_element_type=jnp.float32) * sm_scale
         if bias is not None:
-            s = s + b_c.astype(jnp.float32)
+            s = s + xs[2].astype(jnp.float32)
         col = idx * chunk + jnp.arange(chunk)
         valid = col[None, :] < Tk
         if causal:
             row = jnp.arange(Tq)
             valid = jnp.logical_and(
                 valid, col[None, :] <= row[:, None] + (Tk - Tq))
-        s = jnp.where(valid[None, None], s, _NEG_INF)
+        valid = valid[None, None]
+        if mask is not None:
+            valid = jnp.logical_and(valid, xs[-2])
+        s = jnp.where(valid, s, _NEG_INF)
         m_new = jnp.maximum(m_i, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m_i - m_new)
@@ -616,23 +780,25 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK):
     init = (jnp.full((B, H, Tq), _NEG_INF, jnp.float32),
             jnp.zeros((B, H, Tq), jnp.float32),
             jnp.zeros((B, H, Tq, v.shape[3]), jnp.float32))
-    idxs = jnp.arange(nchunks)
-    xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), bc, idxs) \
-        if bias is not None else \
-        (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), idxs)
-    (m, l, acc), _ = jax.lax.scan(body, init, xs)
+    xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0))
+    if bias is not None:
+        xs += (bc,)
+    if mask is not None:
+        xs += (_chunk_mask(mask, chunk, pad),)
+    (m, l, acc), _ = jax.lax.scan(body, init, xs + (jnp.arange(nchunks),))
     l = jnp.maximum(l, 1e-30)
     return (acc / l[..., None]).astype(q.dtype), m + jnp.log(l)
 
 
 def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
-                 chunk=LONG_CHUNK):
+                 chunk=LONG_CHUNK, mask=None):
     """Backward over K/V chunks in XLA. Matmul operands stay in the input
     dtype (``p`` and ``ds`` are cast to it just before their matmuls) and
     accumulate in float32; ``exp``, ``delta`` and the ``dq`` carry are
     float32."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    kv_heads, Tk = k.shape[1], k.shape[2]
+    k, v = _kv_per_query_head(q, k, v)
     f32 = jnp.float32
     delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Tq)
     kc, pad = _chunk_kv(k, chunk)
@@ -646,21 +812,21 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
             3, 0)
 
     def body(dq_acc, xs):
-        if bias is not None:
-            k_c, v_c, b_c, idx = xs
-        else:
-            k_c, v_c, idx = xs
+        k_c, v_c, idx = xs[0], xs[1], xs[-1]
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k_c,
                        preferred_element_type=f32) * sm_scale
         if bias is not None:
-            s = s + b_c.astype(f32)
+            s = s + xs[2].astype(f32)
         col = idx * chunk + jnp.arange(chunk)
         valid = col[None, :] < Tk
         if causal:
             row = jnp.arange(Tq)
             valid = jnp.logical_and(
                 valid, col[None, :] <= row[:, None] + (Tk - Tq))
-        s = jnp.where(valid[None, None], s, _NEG_INF)
+        valid = valid[None, None]
+        if mask is not None:
+            valid = jnp.logical_and(valid, xs[-2])
+        s = jnp.where(valid, s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])
         dv_c = jnp.einsum("bhqk,bhqd->bhkd", p.astype(do.dtype), do,
                           preferred_element_type=f32)
@@ -676,15 +842,17 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
         # a chunk's dk / dv are final, so this is their one cast
         return dq_acc, (dk_c.astype(k.dtype), dv_c.astype(v.dtype), db_c)
 
-    idxs = jnp.arange(nchunks)
-    xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), bc, idxs) \
-        if bias is not None else \
-        (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0), idxs)
+    xs = (jnp.moveaxis(kc, 2, 0), jnp.moveaxis(vc, 2, 0))
+    if bias is not None:
+        xs += (bc,)
+    if mask is not None:
+        xs += (_chunk_mask(mask, chunk, pad),)
     dq, (dk_s, dv_s, db_s) = jax.lax.scan(
-        body, jnp.zeros((B, H, Tq, D), f32), xs)
+        body, jnp.zeros((B, H, Tq, D), f32), xs + (jnp.arange(nchunks),))
     dk = jnp.moveaxis(dk_s, 0, 2).reshape(B, H, Tk + pad, D)[:, :, :Tk]
     dv = jnp.moveaxis(dv_s, 0, 2).reshape(
         B, H, Tk + pad, v.shape[3])[:, :, :Tk]
+    dk, dv = _sum_kv_group(dk, kv_heads), _sum_kv_group(dv, kv_heads)
     dbias = None
     if bias is not None:
         db = jnp.moveaxis(db_s, 0, 2).reshape(B, H, Tk + pad)[:, :, :Tk]
@@ -707,41 +875,44 @@ def _reduce_dbias(db, bias):
 # the Pallas kernel wherever the forward kernel ran, else XLA (chunked over
 # K/V when the scores are over _BWD_SCORE_BYTES, else materialised)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_core(q, k, v, bias, causal, sm_scale):
-    out, _ = _flash_fwd(q, k, v, bias, causal, sm_scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_core(q, k, v, bias, mask, causal, sm_scale):
+    out, _ = _flash_fwd(q, k, v, bias, mask, causal, sm_scale)
     return out
 
 
 @jax.named_scope("attention")
-def _flash_fwd(q, k, v, bias, causal, sm_scale):
+def _flash_fwd(q, k, v, bias, mask, causal, sm_scale):
     """Forward of ``_flash_core``. The branch it takes is counted
     (``telemetry.flash_fwd_branches()``), once a trace as the backward's."""
-    _record_flash_signature(q, k, v, bias, causal, sm_scale)
+    _record_flash_signature(q, k, v, bias, mask, causal, sm_scale)
     if not _kv_fits_vmem(k, v):
         _telemetry.record_flash_fwd("scan")
-        out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale)
+        out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
+                                       mask=mask)
     else:
-        cfg = _tuned_config(q, k, v, bias, causal, sm_scale)
+        cfg = _tuned_config(q, k, v, bias, causal, sm_scale, mask)
         if cfg.get("backend") == "pallas" and on_tpu():
             _telemetry.record_flash_fwd("kernel")
             out, lse = _flash_forward_pallas(
                 q, k, v, bias, causal, sm_scale,
-                int(cfg["block_q"]), int(cfg["block_k"]), interpret=False)
+                int(cfg["block_q"]), int(cfg["block_k"]), interpret=False,
+                mask=mask)
         else:
             # per-shape XLA choice (small shapes, or a tuned decision
             # that XLA's fused reference wins here), and every non-TPU
             # backend
             _telemetry.record_flash_fwd("reference")
-            out = _attention_reference(q, k, v, bias, causal, sm_scale)
+            out = _attention_reference(q, k, v, bias, causal, sm_scale, mask)
             lse = None
-    return out, (q, k, v, bias, out, lse)
+    return out, (q, k, v, bias, mask, out, lse)
 
 
-def _record_flash_signature(q, k, v, bias, causal, sm_scale):
+def _record_flash_signature(q, k, v, bias, mask, causal, sm_scale):
     """Remember this dispatch's shape signature for tuning.warmup()'s
     AOT replay (deduplicated in the table; a fresh serving replica
-    compiles these ahead of traffic)."""
+    compiles these ahead of traffic). ``k_shape`` carries the K/V head
+    count; ``mask_dtype`` says whether a selection mask is there."""
     try:
         from .. import tuning
 
@@ -750,6 +921,7 @@ def _record_flash_signature(q, k, v, bias, causal, sm_scale):
             "v_shape": list(v.shape),
             "bias_shape": None if bias is None else list(bias.shape),
             "bias_dtype": None if bias is None else str(bias.dtype),
+            "mask_dtype": None if mask is None else str(mask.dtype),
             "dtype": str(q.dtype), "causal": bool(causal),
             "sm_scale": float(sm_scale)})
     except Exception:  # noqa: BLE001 — bookkeeping must not fail the op
@@ -759,9 +931,11 @@ def _record_flash_signature(q, k, v, bias, causal, sm_scale):
 _BWD_SCORE_BYTES = 256 * 1024 * 1024  # peak score-matrix budget in backward
 # per-(batch,head) Q+dO budget of the backward kernel: up to 1 MB (BERT's
 # 128 KB at 512 x 64) the call lives in the scoped VMEM the compiler gives
-# unasked; over that it asks for what it holds (``_bwd_vmem_limit``). 3 MB
-# admits a head of 4096 rows with keys 192 and values 128 wide (2.5 MB).
-_VMEM_QDO_BYTES = 3 * 1024 * 1024
+# unasked; over that it asks for what it holds (``_bwd_vmem_limit``). 4 MB
+# admits a head of 4096 rows with keys 192 and values 128 wide (2.5 MB) and
+# one of 8192 rows at 128 + 128 (4 MB: the call asks for 30.8 MB, and for
+# 41.9 MB with a selection mask and grouped heads).
+_VMEM_QDO_BYTES = 4 * 1024 * 1024
 
 
 def _qdo_fits_vmem(q, v=None):
@@ -785,28 +959,31 @@ def _flash_bwd(causal, sm_scale, res, do):
     """Backward of ``_flash_core``. The branch it takes is counted
     (``telemetry.flash_bwd_branches()``): once a trace, so once a compiled
     program that differentiates the op."""
-    q, k, v, bias, out, lse = res
+    q, k, v, bias, mask, out, lse = res
     B, H, Tq, _ = q.shape
-    Tk = k.shape[2]
+    kv_heads, Tk = k.shape[1], k.shape[2]
     if (lse is not None and on_tpu() and _kv_fits_vmem(k, v)
             and _qdo_fits_vmem(q, v)):
         # the forward kernel ran (its lse is here, its K/V fit VMEM) and a
         # head's Q and dO fit beside them: same recipe, tiled in VMEM
         _telemetry.record_flash_bwd("kernel")
         block_q, block_k = _bwd_blocks(Tq, Tk)
-        return _flash_backward_pallas(q, k, v, bias, out, lse, do, causal,
-                                      sm_scale, block_q, block_k,
-                                      interpret=False)
+        return _flash_backward_pallas(
+            q, k, v, bias, out, lse, do, causal, sm_scale, block_q, block_k,
+            interpret=False, mask=mask) + (None,)
     score_bytes = B * H * Tq * Tk * 4
     if not _kv_fits_vmem(k, v) or score_bytes > _BWD_SCORE_BYTES:
         # keep backward O(Tq * chunk): a forward that fit VMEM can still
         # have a score matrix far too big to materialize (e.g. T=8k)
         _telemetry.record_flash_bwd("chunked")
         if lse is None:
-            _, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale)
+            _, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
+                                         mask=mask)
         return _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
-                            chunk=_bwd_chunk(B, H, Tq, Tk))
+                            chunk=_bwd_chunk(B, H, Tq, Tk),
+                            mask=mask) + (None,)
     _telemetry.record_flash_bwd("materialised")
+    k, v = _kv_per_query_head(q, k, v)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -817,8 +994,10 @@ def _flash_bwd(causal, sm_scale, res, do):
         s = s + bias.astype(jnp.float32)
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        s = jnp.where(mask, s, _NEG_INF)
+        tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        s = jnp.where(tril, s, _NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     if lse is not None:
         p = jnp.exp(s - lse[..., None])
     else:
@@ -833,27 +1012,44 @@ def _flash_bwd(causal, sm_scale, res, do):
     if bias is not None:
         dbias = _reduce_dbias(
             jnp.sum(ds / sm_scale, axis=2, keepdims=True), bias)
-    return dq, dk, dv.astype(v.dtype), dbias
+    return (dq, _sum_kv_group(dk, kv_heads),
+            _sum_kv_group(dv.astype(v.dtype), kv_heads), dbias, None)
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 
 @register("flash_attention", aliases=("_contrib_flash_attention",))
-def flash_attention(query, key, value, bias=None, causal=False,
+def flash_attention(query, key, value, bias=None, mask=None, causal=False,
                     sm_scale=None):
-    """Fused scaled-dot-product attention. query/key: (B, H, T, D);
-    value: (B, H, Tk, Dv), Dv = D unless the model says otherwise (latent
-    attention); bias: optional additive (B, H|1, 1, Tk) mask (use large
-    negatives to mask). Returns (B, H, Tq, Dv).
+    """Fused scaled-dot-product attention. query: (B, H, T, D); key:
+    (B, Hkv, Tk, D) and value: (B, Hkv, Tk, Dv) with ``H % Hkv == 0``: query
+    head ``h`` reads K/V head ``h // (H // Hkv)`` (grouped heads; ``Hkv ==
+    H`` is plain multi-head attention) and ``dk`` / ``dv`` are summed over
+    the group; Dv = D unless the model says otherwise (latent attention);
+    bias: optional additive (B, H|1, 1, Tk) mask (use large negatives to
+    mask); mask: optional (B, Tq, Tk) selection, nonzero where a (query,
+    key) pair may be attended, shared by the heads of a sequence, with no
+    gradient, combined with ``causal`` and ``bias`` (a row that selects
+    nothing is the caller's fault). Returns (B, H, Tq, Dv).
 
     Inside ``parallel.sequence_scope(mesh, axis, schedule)`` this
     dispatches to a sequence-parallel schedule (ring KV rotation, or
     Ulysses head all-to-all when heads divide and there is no bias) —
     the hook that makes every attention user sequence-parallel without
-    model changes."""
+    model changes. Neither schedule takes a selection mask or grouped
+    heads."""
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(query.shape[-1]))
+    if query.shape[1] % key.shape[1] or key.shape[1] != value.shape[1]:
+        raise MXNetError("flash_attention: %d query heads on %d key and %d "
+                         "value heads" % (query.shape[1], key.shape[1],
+                                          value.shape[1]))
+    if mask is not None and tuple(mask.shape) != (
+            query.shape[0], query.shape[2], key.shape[2]):
+        raise MXNetError("flash_attention: mask %s is not (B, Tq, Tk) = %s"
+                         % (tuple(mask.shape), (query.shape[0], query.shape[2],
+                                                key.shape[2])))
     from ..parallel.sequence import current_sequence_scope, ring_attention
 
     scope = current_sequence_scope()
@@ -867,6 +1063,10 @@ def flash_attention(query, key, value, bias=None, causal=False,
                 "sequence_scope's eager dispatch is single-process; on "
                 "multi-host meshes call parallel.ring_attention inside "
                 "your pjit/shard_map program instead")
+        if mask is not None or query.shape[1] != key.shape[1]:
+            raise MXNetError(
+                "sequence_scope: neither schedule takes a selection mask "
+                "or grouped heads")
         from ..parallel.sequence import ulysses_attention
 
         if (schedule == "ulysses" and bias is None
@@ -887,7 +1087,7 @@ def flash_attention(query, key, value, bias=None, causal=False,
             out, jax.sharding.SingleDeviceSharding(
                 mesh.devices.flat[0]))
         return out
-    return _flash_core(query, key, value, bias, bool(causal),
+    return _flash_core(query, key, value, bias, mask, bool(causal),
                        float(sm_scale))
 
 

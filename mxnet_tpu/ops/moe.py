@@ -4,11 +4,14 @@ layer, Gale et al. 2022, MegaBlocks, for computing it without a capacity).
 
 One pure function, :func:`moe_ffn`:
 
-* **router** — scores ``sigmoid(x W_g^T)`` over ALL ``n_routed`` experts, the
-  product in float32; the ``top_k`` of ``score + bias`` are chosen (the bias
-  is a buffer with no gradient: the family's load balancing without an
-  auxiliary loss), and weighted by ``scaling * score / sum of the chosen
-  scores`` (from the scores, not from ``score + bias``);
+* **router** — scores over ALL ``n_routed`` experts, the product in float32:
+  ``sigmoid(x W_g^T)`` (``scoring="sigmoid"``, DeepSeek-V3's) or
+  ``softmax(x W_g^T)`` over the experts (``scoring="softmax"``, the
+  Mixtral / Qwen3-MoE form); the ``top_k`` of ``score + bias`` are chosen
+  (the bias is a buffer with no gradient: the family's load balancing
+  without an auxiliary loss; ``None`` where the model has none), and
+  weighted by ``scaling * score / sum of the chosen scores`` (from the
+  scores, not from ``score + bias``: ``norm_topk_prob``);
 * **dispatch** — the layer is told which experts it holds,
   ``experts_held=(first, count)``. Token-slots (token x chosen expert) are
   sorted by expert, held experts first; the rows of the slots held are
@@ -100,13 +103,22 @@ _take_rows.defvjp(_take_fwd, _take_bwd)
 _sum_rows.defvjp(_sum_fwd, _sum_bwd)
 
 
-def route(x, router_w, router_bias, top_k, scaling):
+def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid"):
     """(N, H) tokens -> chosen experts (N, k) int32 and their weights (N, k)
-    float32. The gradient reaches ``router_w`` and ``x`` through the
-    weights; the choice and the bias carry none."""
+    float32. ``scoring`` is ``"sigmoid"`` (each expert alone) or
+    ``"softmax"`` (over all the experts); ``router_bias`` None is no bias.
+    The gradient reaches ``router_w`` and ``x`` through the weights; the
+    choice and the bias carry none."""
     logits = jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
-    scores = jax.nn.sigmoid(logits)
-    choice = scores + jax.lax.stop_gradient(router_bias.astype(F32))
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError("route: scoring=%r (sigmoid or softmax)" % (scoring,))
+    choice = scores
+    if router_bias is not None:
+        choice = scores + jax.lax.stop_gradient(router_bias.astype(F32))
     _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
@@ -233,7 +245,8 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                 shared_gate, shared_up, shared_down, *, top_k, n_routed,
-                experts_held, scaling=1.0, slots_bound=None):
+                experts_held, scaling=1.0, slots_bound=None,
+                scoring="sigmoid", router_gradient=True):
     """The expert layer on (N, H) tokens. ``w_gate`` / ``w_up`` are
     (count, H, I) and ``w_down`` (count, I, H): the experts held, expert
     ``first + i`` at row i. ``shared_*`` are the shared expert's weights as
@@ -255,7 +268,9 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
     bound = min(bound, slots)
     blocks = -(-slots // bound)
     with jax.named_scope("router"):
-        idx, weights = route(x, router_w, router_bias, top_k, scaling)
+        idx, weights = route(x, router_w, router_bias, top_k, scaling, scoring)
+        if not router_gradient:
+            weights = jax.lax.stop_gradient(weights)
     with jax.named_scope("dispatch"):
         local = idx.reshape(slots) - first
         is_held = jnp.logical_and(local >= 0, local < count)
@@ -283,11 +298,20 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
 def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             down_weight, shared_gate_weight=None, shared_up_weight=None,
             shared_down_weight=None, top_k=1, n_routed=None,
-            experts_held=None, scaling=1.0):
+            experts_held=None, scaling=1.0, scoring="sigmoid",
+            router_gradient=True):
     """The sparse expert layer of a chip that holds ``experts_held=(first,
     count)`` of ``n_routed`` experts, on ``data`` (..., H): see the module's
-    docstring. Returns ``(out, load, lost, ran)``; the three counts carry no
-    gradient."""
+    docstring. ``scoring`` is the router's: ``"sigmoid"`` or ``"softmax"``
+    over all ``n_routed`` logits; ``router_bias=None`` is a router without a
+    selection bias; the weights of the chosen are ``scaling`` times their
+    scores over the chosen scores' sum. ``router_gradient=False`` makes the
+    chosen experts' weights constants of the loss: no gradient reaches the
+    router's weights or the layer's input through them (what a strict share
+    trained without the experts' exchange can say of that gradient is a part
+    of a sum over all the chips, and applied alone the part pulls every
+    token towards the experts held). Returns ``(out, load, lost, ran)``; the
+    three counts carry no gradient."""
     n_routed = int(n_routed or router_weight.shape[0])
     held = tuple(int(v) for v in (experts_held or (0, n_routed)))
     lead = data.shape[:-1]
@@ -296,6 +320,7 @@ def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             data.reshape(-1, data.shape[-1]), router_weight, router_bias,
             gate_weight, up_weight, down_weight, shared_gate_weight,
             shared_up_weight, shared_down_weight, top_k=int(top_k),
-            n_routed=n_routed, experts_held=held, scaling=float(scaling))
+            n_routed=n_routed, experts_held=held, scaling=float(scaling),
+            scoring=str(scoring), router_gradient=bool(router_gradient))
     return (y.reshape(lead + (y.shape[-1],)),
             *(jax.lax.stop_gradient(c) for c in counts))

@@ -72,6 +72,7 @@ __all__ = [
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
     "record_moe_counts", "moe_counts",
+    "record_selection_counts", "selection_counts",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
     "record_rpc", "rpc_spans", "clear_rpc_spans",
     "record_trace_span", "trace_spans", "clear_trace_spans",
@@ -843,7 +844,10 @@ def record_moe_counts(expert_load, slots_lost, blocks_run):
     (``mxt_moe_expert_slots{layer,expert}``), slots held but not computed
     (``mxt_moe_slots_lost``, which must read 0) and blocks of rows run past
     each layer's first (``mxt_moe_blocks_run``: how often a routing overflowed
-    the rows its layer is laid out for). Cumulative, so gauges."""
+    the rows its layer is laid out for). Cumulative, so gauges. A publication
+    replaces the one before it whole: a model of fewer layers or experts
+    leaves no child of an earlier one behind."""
+    _REGISTRY.unregister("mxt_moe_expert_slots")
     g = gauge("mxt_moe_expert_slots",
               "Cumulative token-slots each held expert got (on-device "
               "accounting, read once a window).", ("layer", "expert"))
@@ -873,6 +877,38 @@ def moe_counts():
     return {"expert_load": [[row[e] for e in sorted(row)]
                             for _, row in sorted(rows.items())],
             "slots_lost": int(lost.value), "blocks_run": int(ran.value)}
+
+
+def record_selection_counts(selected_pairs, rows_searched):
+    """The counts a model with a lightning indexer keeps on the device, as
+    read once a window (``model_zoo.keye.publish_selection_counts``): (query,
+    key) pairs each layer's indexer selected, which are the pairs the
+    attention kernels consumed (``mxt_selected_pairs{layer}``), and query rows
+    that had more candidates than ``topk`` and were searched
+    (``mxt_rows_searched{layer}``). Cumulative, so gauges."""
+    for name, text, values in (
+            ("mxt_selected_pairs", "Cumulative (query, key) pairs the indexer "
+             "selected for the attention kernels.", selected_pairs),
+            ("mxt_rows_searched", "Cumulative query rows whose selection needed "
+             "a search.", rows_searched)):
+        _REGISTRY.unregister(name)  # a publication replaces the last one whole
+        g = gauge(name, text + " (on-device accounting, read once a window)",
+                  ("layer",))
+        for layer, v in enumerate(values):
+            g.labels(str(layer)).set(float(v))  # sync-ok: host value
+
+
+def selection_counts():
+    """What :func:`record_selection_counts` last published:
+    ``{"selected_pairs": [per layer], "rows_searched": [per layer]}``, or {}."""
+    out = {}
+    for key in ("selected_pairs", "rows_searched"):
+        fam = _REGISTRY.get("mxt_" + key)
+        if fam is None:
+            return {}
+        rows = {int(layer): int(ch.value) for (layer,), ch in fam.children().items()}
+        out[key] = [rows[i] for i in sorted(rows)]
+    return out
 
 
 # --------------------------------------------------------------------------

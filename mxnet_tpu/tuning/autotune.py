@@ -134,7 +134,7 @@ def heuristic_attention(q_shape, kv_len, dtype, causal):
 
 
 def measure_attention(q, k, v, bias, causal, sm_scale, interpret=False,
-                      iters=None, candidates=None):
+                      iters=None, candidates=None, mask=None):
     """Time each candidate (and the XLA reference) on the live arrays;
     returns the winning entry dict. Runs OUTSIDE the training hot path
     (first call per shape bucket, or an explicit sweep). A candidate
@@ -151,12 +151,12 @@ def measure_attention(q, k, v, bias, causal, sm_scale, interpret=False,
         def run(bq=bq, bk=bk):
             out, _ = A._flash_forward_pallas(
                 q, k, v, bias, causal, sm_scale, bq, bk,
-                interpret=interpret)
+                interpret=interpret, mask=mask)
             return out
         timings[("pallas", bq, bk)] = _time(run, iters)
 
     def ref():
-        return A._attention_reference(q, k, v, bias, causal, sm_scale)
+        return A._attention_reference(q, k, v, bias, causal, sm_scale, mask)
     timings[("xla", 0, 0)] = _time(ref, iters)
 
     (backend, bq, bk), score = min(timings.items(), key=lambda kv: kv[1])
@@ -399,18 +399,21 @@ def _may_measure(arrays):
                    for a in arrays if a is not None)
 
 
-def resolve_attention(q_shape, kv_len, dtype, causal, arrays=None):
+def resolve_attention(q_shape, kv_len, dtype, causal, arrays=None,
+                      kv_heads=None, mask=None):
     """The per-call decision the flash kernel consumes: table hit, else
-    measure (when allowed) or cost model, recorded either way."""
+    measure (when allowed) or cost model, recorded either way. The key
+    carries the K/V head count and whether a selection mask is there."""
     tab = _table_mod.table()
-    key = _table_mod.attn_key(q_shape, kv_len, dtype, causal)
+    key = _table_mod.attn_key(q_shape, kv_len, dtype, causal,
+                              kv_heads=kv_heads, masked=mask is not None)
     ent = tab.lookup(key)
     if ent is not None:
         return ent
     if arrays is not None and _may_measure(arrays):
         q, k, v, bias, sm_scale = arrays
         ent = measure_attention(q, k, v, bias, causal, sm_scale,
-                                interpret=not on_tpu())
+                                interpret=not on_tpu(), mask=mask)
     else:
         ent = heuristic_attention(q_shape, kv_len, dtype, causal)
     return tab.record(key, ent)

@@ -76,11 +76,21 @@ def bucket_rows(m):
     return p
 
 
-def attn_key(q_shape, kv_len, dtype, causal, kind=None):
+def attn_key(q_shape, kv_len, dtype, causal, kind=None, kv_heads=None,
+             masked=False):
+    """``kv_heads`` (where they are fewer than the query's) and ``masked``
+    (a selection mask) get fields of their own, so an entry tuned for a
+    dense call is never taken for a grouped or a masked one; a dense call's
+    key is what it always was."""
     b, h, tq, d = q_shape
-    return "flash|bh%d|q%d|k%d|d%d|%s|c%d|%s" % (
+    key = "flash|bh%d|q%d|k%d|d%d|%s|c%d" % (
         bucket_rows(b * h), bucket_seq(tq), bucket_seq(kv_len), d,
-        str(dtype), 1 if causal else 0, kind or device_kind())
+        str(dtype), 1 if causal else 0)
+    if kv_heads not in (None, h):
+        key += "|g%d" % (h // kv_heads)
+    if masked:
+        key += "|m1"
+    return "%s|%s" % (key, kind or device_kind())
 
 
 def bn_key(m, c, dtype, kind=None):

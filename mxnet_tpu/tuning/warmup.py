@@ -71,24 +71,20 @@ def _warm_flash(spec):
     causal = bool(spec["causal"])
     sm_scale = float(spec["sm_scale"])  # sync-ok: host float from JSON
     q = _sds(spec["q_shape"], spec["dtype"])
-    k = _sds(spec["k_shape"], spec["dtype"])
+    k = _sds(spec["k_shape"], spec["dtype"])  # its own (fewer) heads
     v = _sds(spec["v_shape"], spec["dtype"])
+    b = m = None
     if spec.get("bias_shape"):
         b = _sds(spec["bias_shape"], spec.get("bias_dtype", spec["dtype"]))
+    if spec.get("mask_dtype"):  # a selection mask: (B, Tq, Tk)
+        m = _sds([q.shape[0], q.shape[2], k.shape[2]], spec["mask_dtype"])
 
-        def fwd(q_, k_, v_, b_):
-            return A._flash_core(q_, k_, v_, b_, causal, sm_scale)
+    def fwd(q_, k_, v_, b_, m_):
+        return A._flash_core(q_, k_, v_, b_, m_, causal, sm_scale)
 
-        jax.jit(fwd).lower(q, k, v, b).compile()
-        jax.jit(jax.grad(lambda q_, k_, v_, b_: fwd(q_, k_, v_, b_).sum(),
-                         argnums=(0, 1, 2))).lower(q, k, v, b).compile()
-    else:
-        def fwd(q_, k_, v_):
-            return A._flash_core(q_, k_, v_, None, causal, sm_scale)
-
-        jax.jit(fwd).lower(q, k, v).compile()
-        jax.jit(jax.grad(lambda q_, k_, v_: fwd(q_, k_, v_).sum(),
-                         argnums=(0, 1, 2))).lower(q, k, v).compile()
+    jax.jit(fwd).lower(q, k, v, b, m).compile()
+    jax.jit(jax.grad(lambda *a: fwd(*a).sum(),
+                     argnums=(0, 1, 2))).lower(q, k, v, b, m).compile()
     return "flash_attention"
 
 

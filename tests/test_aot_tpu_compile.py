@@ -21,6 +21,7 @@ import pytest
 from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import bn_pallas
+from mxnet_tpu.ops import indexer as X
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +55,37 @@ def _compile(chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     with jax.default_matmul_precision("default"):
         return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4):
+    """Either kernel as the Keye cell runs it: grouped heads under a
+    selection mask, causal, at the blocks the dispatch picks."""
+    def case(chip):
+        B, H, T, D = shape
+        dt, sm = jnp.dtype("bfloat16"), D ** -0.5
+        q, kv = (shape, dt), ((B, kv_heads, T, D), dt)
+        mask = ((B, T, T), jnp.int8)
+        if not backward:
+            cfg = tuning.heuristic_attention(shape, T, "bfloat16", True)
+            return _compile(
+                chip, lambda q, k, v, m: A._flash_forward_pallas(
+                    q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"], False,
+                    mask=m), q, kv, kv, mask)
+        bq, bk = A._bwd_blocks(T, T)
+        return _compile(
+            chip, lambda q, k, v, out, lse, do, m: A._flash_backward_pallas(
+                q, k, v, None, out, lse, do, True, sm, bq, bk, False, mask=m),
+            q, kv, kv, q, ((B, H, T), jnp.float32), q, mask)
+    return case
+
+
+def _indexer(batch, t, heads=16, dim=64, topk=2048):
+    def case(chip):
+        dt = jnp.dtype("bfloat16")
+        return _compile(
+            chip, lambda q, k, w: X._select_pallas(q, k, w, topk, False),
+            ((batch, heads, t, dim), dt), ((batch, t, dim), dt), ((batch, heads, t), dt))
+    return case
 
 
 def _flash(shape, dtype, causal, bias_shape=None, blocks=None, dv=None):
@@ -172,11 +204,21 @@ _CASES = {
                                        dv=128),
     "flash_bwd_mla_4096_k192_v128": _flash_bwd((2, 32, 4096, 192), "bfloat16",
                                                True, dv=128),
-    # and the corner of the gate since it admits 3 MB of Q + dO
-    "flash_bwd_maxq_12288_maxkv_16384": _flash_bwd(
-        (1, 2, 12288, 64), "bfloat16", True, tk=16384),
-    "flash_bwd_f32_maxq_6144_maxkv_8192": _flash_bwd(
-        (1, 2, 6144, 64), "float32", True, tk=8192),
+    # and the corner of the gate since it admits 4 MB of Q + dO
+    "flash_bwd_maxq_16384_maxkv_16384": _flash_bwd(
+        (1, 2, 16384, 64), "bfloat16", True, tk=16384),
+    "flash_bwd_f32_maxq_8192_maxkv_8192": _flash_bwd(
+        (1, 2, 8192, 64), "float32", True, tk=8192),
+    # the Keye cell: one sequence of 8192, 32 query heads on 4 K/V heads of
+    # 128, under the indexer's selection mask (both kernels ask for their
+    # own scoped VMEM: 30.5 and 41.9 MB), the same heads dense, and the
+    # indexer's kernel (16 heads of 64, top-2048) there and at a ragged
+    # length and a batch
+    "flash_selected_8192_gqa": _flash_selected(False),
+    "flash_bwd_selected_8192_gqa": _flash_selected(True),
+    "flash_bwd_selected_2x3000_gqa": _flash_selected(True, (2, 8, 3000, 128), 2),
+    "indexer_select_8192": _indexer(1, 8192),
+    "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
     # repaired generator picks it
     "paged_8x12x64": _paged(8, 12, 64),
@@ -212,12 +254,12 @@ def test_flash_bwd_corner_is_what_the_dispatch_admits():
     def fits(t, dt, gate):
         return gate(jax.ShapeDtypeStruct((1, 2, t, 64), dt))
 
-    assert fits(12288, bf16, A._qdo_fits_vmem)
-    assert not fits(12288 + 128, bf16, A._qdo_fits_vmem)
+    assert fits(16384, bf16, A._qdo_fits_vmem)
+    assert not fits(16384 + 128, bf16, A._qdo_fits_vmem)
     assert fits(16384, bf16, A._kv_fits_vmem)
     assert not fits(16384 + 128, bf16, A._kv_fits_vmem)
-    assert fits(6144, f32, A._qdo_fits_vmem)
-    assert not fits(6144 + 128, f32, A._qdo_fits_vmem)
+    assert fits(8192, f32, A._qdo_fits_vmem)
+    assert not fits(8192 + 128, f32, A._qdo_fits_vmem)
     assert fits(8192, f32, A._kv_fits_vmem)
     assert not fits(8192 + 128, f32, A._kv_fits_vmem)
 

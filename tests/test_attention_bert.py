@@ -74,7 +74,7 @@ def test_flash_kernel_vs_reference(causal, with_bias, case):
                         np.asarray(jax.nn.logsumexp(s, axis=-1)),
                         rtol=1e-5, atol=1e-5)
     # lse-based backward must match autodiff-of-reference
-    got = A._flash_bwd(causal, sm, (q, k, v, bias, out, lse), do)
+    got = A._flash_bwd(causal, sm, (q, k, v, bias, None, out, lse), do)
     _assert_grads_close(got[:3], _ref_grads(q, k, v, do, bias, causal, sm)[:3],
                         max(tol, 1e-4))
 
@@ -354,11 +354,11 @@ def test_flash_bwd_branch_counter_and_no_kernel_on_cpu(monkeypatch):
     ref = _ref_grads(q, k, v, do, None, False, 0.25)
 
     before = telemetry.flash_bwd_branches()
-    got = A._flash_bwd(False, 0.25, (q, k, v, None, out, lse), do)
+    got = A._flash_bwd(False, 0.25, (q, k, v, None, None, out, lse), do)
     _assert_grads_close(got, ref, 1e-4)
     # a score matrix over the budget: the chunked branch, in whole tiles
     monkeypatch.setattr(A, "_BWD_SCORE_BYTES", 1024)
-    got = A._flash_bwd(False, 0.25, (q, k, v, None, out, lse), do)
+    got = A._flash_bwd(False, 0.25, (q, k, v, None, None, out, lse), do)
     _assert_grads_close(got, ref, 1e-4)
     # through the op, differentiated under jit: one count a trace
     g = jax.jit(jax.grad(lambda q_: jnp.sum(
@@ -402,7 +402,7 @@ def test_flash_fwd_branch_counter(monkeypatch):
     fwd = A._flash_forward_pallas
     monkeypatch.setattr(A, "on_tpu", lambda: True)
     monkeypatch.setattr(A, "_flash_forward_pallas",
-                        lambda *a, interpret=False: fwd(*a, interpret=True))
+                        lambda *a, interpret=False, **kw: fwd(*a, interpret=True, **kw))
     assert delta(lambda: op(q)) == {"kernel": 1}
 
 
@@ -485,3 +485,156 @@ def test_bert_export_symbol_block(tmp_path):
     s1, p1 = blk(tokens, types)
     assert_almost_equal(s0.asnumpy(), s1.asnumpy(), rtol=1e-4, atol=1e-5)
     assert_almost_equal(p0.asnumpy(), p1.asnumpy(), rtol=1e-4, atol=1e-5)
+
+
+# -- a selection mask, and grouped heads ------------------------------------------
+def _grouped_case(group, masked, dtype="float32", B=2, Hkv=2, T=96, D=16):
+    """q of ``group`` query heads a K/V head, and a mask that is data: half
+    the pairs at random, every row keeping its own position."""
+    rng = np.random.RandomState(11)
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype("f4")).astype(dtype)
+
+    q, do = arr(B, Hkv * group, T, D), arr(B, Hkv * group, T, D)
+    k, v = arr(B, Hkv, T, D), arr(B, Hkv, T, D)
+    mask = None
+    if masked:
+        mask = jnp.asarray(rng.rand(B, T, T) < 0.5) | jnp.eye(T, dtype=bool)[None]
+        mask = mask.astype(jnp.int8)
+    return q, k, v, do, mask
+
+
+def _masked_ref_grads(q, k, v, do, mask, causal, sm):
+    f32 = jnp.float32
+    out, vjp = jax.vjp(lambda q_, k_, v_: A._attention_reference(
+        q_, k_, v_, None, causal, sm, mask), *(a.astype(f32) for a in (q, k, v)))
+    return out, vjp(do.astype(f32))
+
+
+@pytest.mark.parametrize("branch", ["kernels", "scan_chunked", "materialised", "op"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("group,masked", [(1, True), (8, False), (8, True)])
+def test_selection_mask_and_grouped_heads_in_every_branch(group, masked, causal,
+                                                          branch, monkeypatch):
+    """A mask for (query, key) pairs, shared by a sequence's heads, and 8
+    query heads a K/V head: forward and all three gradients of the kernels
+    (interpret mode) and of every XLA branch against the reference, dk / dv
+    summed over the group."""
+    q, k, v, do, mask = _grouped_case(group, masked)
+    sm = 0.25
+    want, grads = _masked_ref_grads(q, k, v, do, mask, causal, sm)
+    if branch == "kernels":
+        out, lse = A._flash_forward_pallas(q, k, v, None, causal, sm, 32, 32,
+                                           interpret=True, mask=mask)
+        got = A._flash_backward_pallas(q, k, v, None, out, lse, do, causal, sm,
+                                       32, 32, interpret=True, mask=mask)[:3]
+    elif branch == "scan_chunked":  # a chunk that does not divide Tk
+        out, lse = A._attention_scan_fwd(q, k, v, None, causal, sm, chunk=40,
+                                         mask=mask)
+        got = A._bwd_chunked(q, k, v, None, out, lse, do, causal, sm, chunk=40,
+                             mask=mask)[:3]
+    elif branch == "materialised":
+        out = A._attention_reference(q, k, v, None, causal, sm, mask)
+        got = A._flash_bwd(causal, sm, (q, k, v, None, mask, out, None), do)
+        assert got[3] is None and got[4] is None  # no bias, and the mask has none
+        got = got[:3]
+    else:  # the registered op, differentiated as a model differentiates it
+        out, vjp = jax.vjp(lambda q_, k_, v_: A.flash_attention(
+            q_, k_, v_, None, mask, causal=causal, sm_scale=sm), q, k, v)
+        got = vjp(do)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    assert_almost_equal(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-5)
+    _assert_grads_close(got, grads, 1e-4)
+
+
+def test_grouped_heads_in_bfloat16_sum_the_group_in_float32():
+    q, k, v, do, mask = _grouped_case(8, True, "bfloat16")
+    want, grads = _masked_ref_grads(q, k, v, do, mask, True, 0.25)
+    out, lse = A._flash_forward_pallas(q, k, v, None, True, 0.25, 32, 32,
+                                       interpret=True, mask=mask)
+    got = A._flash_backward_pallas(q, k, v, None, out, lse, do, True, 0.25, 32, 32,
+                                   interpret=True, mask=mask)
+    assert [g.dtype for g in got[:3]] == [q.dtype, k.dtype, v.dtype]
+    _assert_grads_close(got[:3], grads, 2e-2)
+
+
+def test_mask_and_heads_are_checked_and_a_sequence_scope_takes_neither():
+    q, k, v, _, mask = _grouped_case(8, True)
+    with pytest.raises(mx.base.MXNetError):
+        A.flash_attention(q, k, v, None, mask[:, :, :-1])
+    with pytest.raises(mx.base.MXNetError):
+        A.flash_attention(q[:, :15], k, v)
+
+
+def test_dense_equal_head_calls_trace_to_the_kernels_they_traced_to():
+    """A call without a mask and with as many K/V heads as query heads lowers
+    to the jaxpr it lowered to before either was built (``tests/data``: the
+    text the parent commit gave, addresses and paths taken out): both
+    kernels with a bias and with latent widths under ``causal``, and the op's
+    gradient on the CPU."""
+    import json
+    import os
+    import re
+
+    def norm(txt):
+        txt = re.sub(r"0x[0-9a-f]+", "0x", str(txt))
+        txt = re.sub(r" at [^\s:]+\.py:\d+", " at FILE", txt)
+        return re.sub(r"/[^\s'\"]+\.py(:\d+)?", "FILE", txt)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    S = jax.ShapeDtypeStruct
+    q, bias, lse = S((2, 3, 256, 64), bf), S((2, 1, 1, 256), f32), S((2, 3, 256), f32)
+    ql, vl, lsel = S((1, 4, 1024, 192), bf), S((1, 4, 1024, 128), bf), S((1, 4, 1024), f32)
+    qs = S((2, 2, 48, 16), f32)
+    with jax.default_matmul_precision("default"):  # as the fixture was traced
+        now = _dense_jaxprs(q, bias, lse, ql, vl, lsel, qs)
+    path = os.path.join(os.path.dirname(__file__), "data", "flash_dense_jaxprs_pr31.json")
+    with open(path) as f:
+        before = json.load(f)
+    assert set(now) == set(before)
+    for name, jaxpr in now.items():
+        assert norm(jaxpr) == before[name], name
+    # and a mask or a group does change the kernels' programs
+    m = S((2, 256, 256), jnp.int8)
+    with jax.default_matmul_precision("default"):
+        masked = jax.make_jaxpr(lambda q, k, v, b, m: A._flash_forward_pallas(
+            q, k, v, b, False, 0.125, 128, 128, False, mask=m))(q, q, q, bias, m)
+    assert norm(masked) != before["fwd_kernel_bias"]
+
+
+def _dense_jaxprs(q, bias, lse, ql, vl, lsel, qs):
+    return {
+        "fwd_kernel_bias": jax.make_jaxpr(lambda q, k, v, b: A._flash_forward_pallas(
+            q, k, v, b, False, 0.125, 128, 128, False))(q, q, q, bias),
+        "fwd_kernel_causal_latent": jax.make_jaxpr(
+            lambda q, k, v: A._flash_forward_pallas(
+                q, k, v, None, True, 192 ** -0.5, 512, 512, False))(ql, ql, vl),
+        "bwd_kernel_causal_latent": jax.make_jaxpr(
+            lambda q, k, v, o, l, do: A._flash_backward_pallas(
+                q, k, v, None, o, l, do, True, 192 ** -0.5, 512, 512, False))(
+                    ql, ql, vl, vl, lsel, vl),
+        "bwd_kernel_bias": jax.make_jaxpr(
+            lambda q, k, v, b, o, l, do: A._flash_backward_pallas(
+                q, k, v, b, o, l, do, False, 0.125, 256, 256, False))(
+                    q, q, q, bias, q, lse, q),
+        "op_grad_cpu": jax.make_jaxpr(jax.grad(
+            lambda q, k, v: A.flash_attention(q, k, v, causal=True).sum(),
+            argnums=(0, 1, 2)))(qs, qs, qs),
+    }
+
+
+def test_vmem_asked_for_follows_the_mask_and_the_group():
+    # the cell's head: 8192 rows at 128 + 128; Q + dO are 4 MB, K + V 4 MB
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+    q, kv = shape(1, 32, 8192, 128), shape(1, 4, 8192, 128)
+    assert A._kv_fits_vmem(kv, kv) and A._qdo_fits_vmem(q, q)
+    assert not A._qdo_fits_vmem(shape(1, 32, 8192 + 512, 128))
+    dense = A._bwd_vmem_limit(8192, 128, 128, 512, 512, 2)
+    masked = A._bwd_vmem_limit(8192, 128, 128, 512, 512, 2, mask=True, out_itemsize=4)
+    assert 30e6 < dense < 32e6 and 41e6 < masked < 43e6
+    assert masked - dense > 1.25 * 2 * 8192 * 512  # the mask's tiles, twice
+    assert 29e6 < A._fwd_vmem_limit(8192, 128, 128, 512, 512, 2) < 31e6
+    # what stood before is what it was
+    assert A._bwd_vmem_limit(4096, 192, 128, 512, 512, 2) == 28508160
+    assert A._bwd_vmem_limit(512, 64, 64, 512, 512, 2) is None

@@ -140,9 +140,9 @@ def interpreted_kernels(monkeypatch):
     fwd, bwd = A._flash_forward_pallas, A._flash_backward_pallas
     monkeypatch.setattr(A, "on_tpu", lambda: True)
     monkeypatch.setattr(A, "_flash_forward_pallas",
-                        lambda *a, interpret=False: fwd(*a, interpret=True))
+                        lambda *a, interpret=False, **kw: fwd(*a, interpret=True, **kw))
     monkeypatch.setattr(A, "_flash_backward_pallas",
-                        lambda *a, interpret=False: bwd(*a, interpret=True))
+                        lambda *a, interpret=False, **kw: bwd(*a, interpret=True, **kw))
 
 
 def _qkv(dk=24, dv=16, t=160, seed=10):

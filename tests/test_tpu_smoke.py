@@ -157,8 +157,8 @@ def test_flash_bwd_kernel_hardware(shape, causal, with_bias):
     out, lse = A._flash_forward_pallas(q, k, v, bias, causal, sm,
                                        128, 128, interpret=False)
     before = telemetry.flash_bwd_branches().get("kernel", 0)
-    got = jax.jit(lambda *a: A._flash_bwd(causal, sm, a[:6], a[6]))(
-        q, k, v, bias, out, lse, do)
+    got = jax.jit(lambda *a: A._flash_bwd(causal, sm, a[:4] + (None,) + a[4:6],
+                                          a[6]))(q, k, v, bias, out, lse, do)
     assert telemetry.flash_bwd_branches().get("kernel", 0) == before + 1
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(

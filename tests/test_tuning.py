@@ -449,3 +449,49 @@ def test_jax_cache_dir_variable_outranks_everything(tmp_path, monkeypatch):
     assert compile_cache.setup(str(tmp_path / "argument")) == env_dir
     assert compile_cache.cache_dir() == env_dir
     assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_attention_key_names_kv_heads_and_a_mask_and_leaves_dense_keys_alone():
+    """An entry tuned for a dense call is never taken for a grouped or a
+    masked one: both get fields of their own, and a dense call's key is what
+    it always was."""
+    shape = (1, 32, 8192, 128)
+    dense = tuning.attn_key(shape, 8192, "bfloat16", True, kind="k")
+    assert dense == "flash|bh32|q8192|k8192|d128|bfloat16|c1|k"
+    assert tuning.attn_key(shape, 8192, "bfloat16", True, kind="k", kv_heads=32) == dense
+    grouped = tuning.attn_key(shape, 8192, "bfloat16", True, kind="k", kv_heads=4)
+    masked = tuning.attn_key(shape, 8192, "bfloat16", True, kind="k", kv_heads=4,
+                             masked=True)
+    assert grouped == "flash|bh32|q8192|k8192|d128|bfloat16|c1|g8|k"
+    assert masked == "flash|bh32|q8192|k8192|d128|bfloat16|c1|g8|m1|k"
+    assert len({dense, grouped, masked}) == 3
+
+
+def test_flash_signature_and_warmup_carry_kv_heads_and_the_mask(tmp_path, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+    import importlib
+
+    W = importlib.import_module("mxnet_tpu.tuning.warmup")
+
+    seen = []
+    monkeypatch.setattr(tuning, "record_signature", lambda kind, spec: seen.append((kind, spec)))
+    q = jnp.zeros((1, 8, 32, 16), jnp.float32)
+    kv = jnp.zeros((1, 2, 32, 16), jnp.float32)
+    mask = jnp.ones((1, 32, 32), jnp.int8)
+    A.flash_attention(q, kv, kv, None, mask, causal=True)
+    kind, spec = seen[-1]
+    assert kind == "flash_attention" and spec["k_shape"] == [1, 2, 32, 16]
+    assert spec["mask_dtype"] == "int8" and spec["bias_shape"] is None
+    A.flash_attention(q, q, q)
+    assert seen[-1][1]["mask_dtype"] is None
+    # the replay compiles forward and gradient of what was recorded
+    calls = []
+    core = A._flash_core
+    monkeypatch.setattr(A, "_flash_core", lambda *a: calls.append(a) or core(*a))
+    assert W._warm_flash(spec) == "flash_attention"
+    q_, k_, v_, b_, m_ = calls[0][:5]
+    assert k_.shape == (1, 2, 32, 16) and b_ is None
+    assert m_.shape == (1, 32, 32) and str(m_.dtype) == "int8"

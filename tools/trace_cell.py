@@ -13,7 +13,9 @@ took (``flash_fwd_branches``, ``flash_bwd_branches``), the tuning table's
 entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
-run past a layer's first), the set-up
+run past a layer's first), what a model with a lightning indexer counted there
+(``selection_counts``: the pairs selected and the rows searched, by layer),
+the set-up
 phases and the compile counters. The
 benchmark's cells cannot name a new per-layer metric without an edit to
 their files (PERF.md section 7), so this is how those numbers are taken
@@ -30,9 +32,11 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import run as bench  # noqa: E402 — benchmark/run.py
 
-# further single scopes quoted in PERF.md: latent attention and the expert layer
+# further single scopes quoted in PERF.md: latent and grouped-query attention,
+# the indexer and the expert layer
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
-         "moe", "router", "dispatch", "experts", "combine", "shared")
+         "moe", "router", "dispatch", "experts", "combine", "shared",
+         "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select")
 
 
 class Context(bench.Context):
@@ -44,6 +48,7 @@ class Context(bench.Context):
     flash_bwd = None
     tuned = None
     moe = None
+    selection = None
 
     def say(self, **row):
         if row.get("phase") == "traced":
@@ -72,6 +77,7 @@ class Context(bench.Context):
         Context.flash_bwd = telemetry.flash_bwd_branches()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
+        Context.selection = telemetry.selection_counts()
         super().cleanup()
 
 
@@ -117,6 +123,8 @@ def main(argv):
             row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
             row["moe_counts"] = Context.moe
+        if Context.selection:  # likewise: pairs selected, rows searched, by layer
+            row["selection_counts"] = Context.selection
         if agg is None:
             print("no device operations in the trace")
         else:
