@@ -40,6 +40,15 @@ hold (``_fwd_vmem_limit``, ``_bwd_vmem_limit``).
 
 A call without a mask and with as many K/V heads as query heads traces to
 the kernels it traced to before either was built.
+
+Several heads a grid step: where a head is one tile (BERT's 128 x 128 and
+512 x 512) both kernels take ``_heads_per_step``'s heads a step, blocks
+``(G, T, D)`` on the flat batch x head axis, walked by a loop inside the
+kernel whose body does not grow with ``G`` (``_each_head``). ``G`` is a
+fixed function of the shape, counted a traced call
+(``telemetry.flash_heads_per_step()``); every call that does not qualify
+(a mask, ``causal``, grouped heads, several blocks a head, a padded length,
+a grid of under 256 steps) lowers to the one-head program, letter for letter.
 """
 from __future__ import annotations
 
@@ -162,12 +171,38 @@ def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None):
 _KV_INLINE = 4  # K/V blocks of a static loop the kernel writes out in line
 
 
+def _each_head(heads, unroll, head):
+    """``head(g, u)`` for the ``heads`` heads a grid step holds, by a loop
+    INSIDE the kernel of ``heads / unroll`` trips. A trip holds ``unroll``
+    heads in flight (so that one head's softmax can sit beside another's
+    matmuls): an inner loop that the lowering writes out whole, so ``head``
+    is traced ONCE whatever ``heads`` and ``unroll`` (a start pays that
+    trace at every call site). ``u`` is which of a trip's heads it is. One
+    head is the call it always was, index 0 and no loop."""
+    if heads == 1:
+        return head(0, 0)
+
+    def trip(j, _):
+        def one(u, _):
+            head(j * unroll + u, u)
+            return u + 1, None
+
+        # a scan and not a fori_loop: its index is the i32 it is given
+        # (fori_loop's static form counts in i64 under jax_enable_x64)
+        jax.lax.scan(one, np.int32(0), None, length=unroll, unroll=True)
+
+    # i32 bounds: under jax_enable_x64 a Python int traces as i64
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(heads // unroll), trip, None)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
-                      *, block_k, causal, sm_scale, kv_len, q_len):
+                      *, block_k, causal, sm_scale, kv_len, q_len, unroll=1):
     """One (batch x head, Q block) grid step of the forward: the head's K/V
     sit in VMEM whole, the Q block streams over them block_k keys at a time
     with the online softmax. Operands of both matmuls are in the input
-    dtype, the accumulators float32.
+    dtype, the accumulators float32. Where the blocks hold several heads
+    (``_heads_per_step``: each head one tile) ``_each_head`` walks them,
+    every head's arithmetic what it is alone.
 
     What is static here decides the vector work a score element pays:
     ``sm_scale`` goes onto the Q block once where that is exact (a power of
@@ -192,126 +227,203 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
     # f64 and Mosaic cannot lower the resulting f64 constants/casts
     neg_inf = f32(_NEG_INF)
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
-
-    q = q_ref[0]  # (BQ, D)
     # a power of two scales every product exactly, so scaling the Q block
     # gives bit for bit the scores the backward recomputes (k @ q.T * scale)
     fold = math.frexp(sm_scale)[0] == 0.5
-    if fold:
-        q = (q.astype(f32) * f32(sm_scale)).astype(q.dtype)
 
-    def step(ik, carry, masked):
-        """Online-softmax update with K/V block ``ik``; ``carry`` None is
-        the first block, which has nothing to rescale."""
-        if num_kv == 1:
-            ik = 0  # a lone block is any length: its offset has to be static
-        k_off = ik * block_k
-        if not isinstance(ik, int):
-            k_off = pl.multiple_of(k_off, block_k)
-        k_blk = k_ref[0, pl.ds(k_off, block_k), :]  # (BK, D)
-        v_blk = v_ref[0, pl.ds(k_off, block_k), :]  # (BK, Dv)
-        s = jax.lax.dot_general(q, k_blk, nt,
-                                preferred_element_type=f32)  # (BQ, BK)
-        if not fold:
-            s = s * f32(sm_scale)
-        if bias_ref is not None:
-            s = s + bias_ref[0, ik].astype(f32)  # (1, BK), over the rows
-        masks = []
-        if masked:
-            col = k_off + jax.lax.broadcasted_iota(
-                i32, (block_q, block_k), 1)
-            if kv_pad != kv_len:  # tail-block padding
-                masks.append(col < kv_len)
-            if causal:
-                # query row i attends keys up to i + (Tk - Tq)
-                row = q_off + jax.lax.broadcasted_iota(
-                    i32, (block_q, block_k), 0)
-                masks.append(col <= row + shift)
-        if mask_ref is not None:  # the selection: data, on every block
-            masks.append(mask_ref[0, 0, ik].astype(i32) != 0)
-        if masks:
-            s = jnp.where(functools.reduce(jnp.logical_and, masks), s,
-                          neg_inf)
-        m_new = jnp.max(s, axis=1, keepdims=True)  # (BQ, 1)
-        if carry is not None:
-            m_i, l_i, acc_i = carry
-            m_new = jnp.maximum(m_i, m_new)
-        p = jnp.exp(s - m_new)
-        l_new = jnp.sum(p, axis=1, keepdims=True)
-        acc_new = jnp.dot(p.astype(v_blk.dtype), v_blk,
-                          preferred_element_type=f32)  # (BQ, Dv)
-        if carry is not None:
-            alpha = jnp.exp(m_i - m_new)
-            l_new = l_i * alpha + l_new
-            acc_new = acc_i * alpha + acc_new
-        return m_new, l_new, acc_new
+    def head(g, _):
+        q = q_ref[g]  # (BQ, D)
+        if fold:
+            q = (q.astype(f32) * f32(sm_scale)).astype(q.dtype)
 
-    def loop(lo, hi, carry, masked):
-        """Blocks lo .. hi - 1, two an iteration and then the odd one. i32
-        bounds: with jax_enable_x64 on (MXNet dtype parity) a plain
-        Python-int loop index traces as i64, which Mosaic cannot lower;
-        lax.div on non-negative i32: jnp's floor_divide does not lower."""
-        lo, hi = i32(lo), i32(hi)
-        pairs = jax.lax.div(hi - lo, i32(2))
+        def step(ik, carry, masked):
+            """Online-softmax update with K/V block ``ik``; ``carry`` None is
+            the first block, which has nothing to rescale."""
+            if num_kv == 1:
+                ik = 0  # a lone block is any length: its offset has to be static
+            k_off = ik * block_k
+            if not isinstance(ik, int):
+                k_off = pl.multiple_of(k_off, block_k)
+            k_blk = k_ref[g, pl.ds(k_off, block_k), :]  # (BK, D)
+            v_blk = v_ref[g, pl.ds(k_off, block_k), :]  # (BK, Dv)
+            s = jax.lax.dot_general(q, k_blk, nt,
+                                    preferred_element_type=f32)  # (BQ, BK)
+            if not fold:
+                s = s * f32(sm_scale)
+            if bias_ref is not None:
+                s = s + bias_ref[g, ik].astype(f32)  # (1, BK), over the rows
+            masks = []
+            if masked:
+                col = k_off + jax.lax.broadcasted_iota(
+                    i32, (block_q, block_k), 1)
+                if kv_pad != kv_len:  # tail-block padding
+                    masks.append(col < kv_len)
+                if causal:
+                    # query row i attends keys up to i + (Tk - Tq)
+                    row = q_off + jax.lax.broadcasted_iota(
+                        i32, (block_q, block_k), 0)
+                    masks.append(col <= row + shift)
+            if mask_ref is not None:  # the selection: data, on every block
+                masks.append(mask_ref[0, 0, ik].astype(i32) != 0)
+            if masks:
+                s = jnp.where(functools.reduce(jnp.logical_and, masks), s,
+                              neg_inf)
+            m_new = jnp.max(s, axis=1, keepdims=True)  # (BQ, 1)
+            if carry is not None:
+                m_i, l_i, acc_i = carry
+                m_new = jnp.maximum(m_i, m_new)
+            p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=1, keepdims=True)
+            acc_new = jnp.dot(p.astype(v_blk.dtype), v_blk,
+                              preferred_element_type=f32)  # (BQ, Dv)
+            if carry is not None:
+                alpha = jnp.exp(m_i - m_new)
+                l_new = l_i * alpha + l_new
+                acc_new = acc_i * alpha + acc_new
+            return m_new, l_new, acc_new
 
-        def pair(j, c):
-            return step(lo + 2 * j + 1, step(lo + 2 * j, c, masked), masked)
+        def loop(lo, hi, carry, masked):
+            """Blocks lo .. hi - 1, two an iteration and then the odd one.
+            i32 bounds: with jax_enable_x64 on (MXNet dtype parity) a plain
+            Python-int loop index traces as i64, which Mosaic cannot lower;
+            lax.div on non-negative i32: jnp's floor_divide does not lower."""
+            lo, hi = i32(lo), i32(hi)
+            pairs = jax.lax.div(hi - lo, i32(2))
 
-        carry = jax.lax.fori_loop(i32(0), pairs, pair, carry)
-        return jax.lax.fori_loop(lo + 2 * pairs, hi,
-                                 lambda ik, c: step(ik, c, masked), carry)
+            def pair(j, c):
+                return step(lo + 2 * j + 1, step(lo + 2 * j, c, masked),
+                            masked)
 
-    if causal:
-        n_all = i32(num_kv)
-        if shift >= 0:
-            # K/V blocks wholly right of the diagonal add exactly zero
-            # (every row has seen a key by then, so exp(-1e30 - m) is 0):
-            # stop at the block that holds this Q block's last visible key
-            n_all = jnp.minimum(n_all, jax.lax.div(
-                q_off + i32(block_q + shift + block_k - 1), i32(block_k)))
-        carry = (jnp.full((block_q, 1), neg_inf, f32),
-                 jnp.zeros((block_q, 1), f32),
-                 jnp.zeros((block_q, v_ref.shape[2]), f32))
-        m, l, acc = loop(0, n_all, carry, True)
-    else:
-        n_clear = num_kv - (kv_pad != kv_len)  # only the tail block is masked
-        carry = step(0, None, n_clear == 0)
-        if num_kv <= _KV_INLINE:
-            for ik in range(1, num_kv):
-                carry = step(ik, carry, ik >= n_clear)
+            carry = jax.lax.fori_loop(i32(0), pairs, pair, carry)
+            return jax.lax.fori_loop(lo + 2 * pairs, hi,
+                                     lambda ik, c: step(ik, c, masked), carry)
+
+        if causal:
+            n_all = i32(num_kv)
+            if shift >= 0:
+                # K/V blocks wholly right of the diagonal add exactly zero
+                # (every row has seen a key by then, so exp(-1e30 - m) is
+                # 0): stop at the block that holds this Q block's last
+                # visible key
+                n_all = jnp.minimum(n_all, jax.lax.div(
+                    q_off + i32(block_q + shift + block_k - 1), i32(block_k)))
+            carry = (jnp.full((block_q, 1), neg_inf, f32),
+                     jnp.zeros((block_q, 1), f32),
+                     jnp.zeros((block_q, v_ref.shape[2]), f32))
+            m, l, acc = loop(0, n_all, carry, True)
         else:
-            carry = loop(1, n_clear, carry, False)
-            if n_clear < num_kv:
-                carry = step(n_clear, carry, True)
-        m, l, acc = carry
-    l = jnp.maximum(l, f32(1e-30))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse = m + jnp.log(l)  # (BQ, 1)
-    # lse leaves as a lane-oriented row, the backward kernel's own idiom for
-    # its bias gradient: the (BQ, 1) column is broadcast over the lanes and
-    # turned, and the first row of that is the (1, BQ) block
-    lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LSE_LANES)).T[:1, :]
+            n_clear = num_kv - (kv_pad != kv_len)  # only the tail block is masked
+            carry = step(0, None, n_clear == 0)
+            if num_kv <= _KV_INLINE:
+                for ik in range(1, num_kv):
+                    carry = step(ik, carry, ik >= n_clear)
+            else:
+                carry = loop(1, n_clear, carry, False)
+                if n_clear < num_kv:
+                    carry = step(n_clear, carry, True)
+            m, l, acc = carry
+        l = jnp.maximum(l, f32(1e-30))
+        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        lse = m + jnp.log(l)  # (BQ, 1)
+        # lse leaves as a lane-oriented row, the backward kernel's own idiom
+        # for its bias gradient: the (BQ, 1) column is broadcast over the
+        # lanes and turned, and the first row of that is the (1, BQ) block
+        lse_ref[g] = jnp.broadcast_to(lse, (block_q, _LSE_LANES)).T[:1, :]
+
+    _each_head(q_ref.shape[0], unroll, head)
 
 
 def _lanes(d):
     return -(-d // 128) * 128
 
 
+_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call unasked
+
+
+def _fwd_vmem_held(tk, d, dv, block_q, block_k, itemsize, heads=1,
+                   in_flight=1):
+    """What a grid step of the forward kernel holds without a mask, each
+    block twice for the pipeline's two buffers and minor dimensions rounded
+    up to the 128 lanes: ``heads`` times the Q and output blocks and K and V
+    whole, the float32 accumulator once a head ``in_flight``, and six
+    float32 (block_q, block_k) tiles (once: see ``_bwd_vmem_limit``)."""
+    return (heads * 2 * (block_q + tk) * (_lanes(d) + _lanes(dv)) * itemsize
+            + in_flight * block_q * _lanes(dv) * 4 + 6 * block_q * block_k * 4)
+
+
 def _fwd_vmem_limit(tk, d, dv, block_q, block_k, itemsize):
     """Scoped VMEM the forward kernel asks for when it carries a selection
     mask (without one it lives in what the compiler gives unasked, as every
-    call did before the mask was built): what it holds and a quarter more.
-    Held, each block twice for the pipeline's two buffers and minor
-    dimensions rounded up to the 128 lanes: the Q and output blocks, K and V
-    whole, the Q block's mask tiles (block_q x Tk bytes), the float32
-    accumulator and six float32 (block_q, block_k) tiles. One sequence of
-    8192 at 128 + 128 in bfloat16 with 512 x 512 blocks: 8.4 MB of K/V, 8.4
-    of mask, 23.9 MB held, 29.8 MB asked for."""
-    held = (2 * block_q * (_lanes(d) + _lanes(dv)) * itemsize
-            + 2 * tk * (_lanes(d) + _lanes(dv)) * itemsize
-            + 2 * block_q * tk + block_q * _lanes(dv) * 4
-            + 6 * block_q * block_k * 4)
+    call did before the mask was built): what it holds and a quarter more,
+    ``_fwd_vmem_held`` and the Q block's mask tiles (block_q x Tk bytes),
+    twice. One sequence of 8192 at 128 + 128 in bfloat16 with 512 x 512
+    blocks: 8.4 MB of K/V, 8.4 of mask, 23.9 MB held, 29.8 MB asked for."""
+    held = _fwd_vmem_held(tk, d, dv, block_q, block_k, itemsize) \
+        + 2 * block_q * tk
     return held + held // 4
+
+
+# Several heads a grid step, where a head is ONE tile (BERT: 128 x 128 or
+# 512 x 512, 1536 or 384 heads a call). Alone in a step, a head is a chain of
+# latencies (matmul, row maximum, exp, row sum, matmul) behind the step's own
+# 0.35-0.5 us. Device ms a call, forward / backward, bf16, TPU v5e (my chip
+# run, PR 36, profiler trace of 20 calls), by heads a step G and heads written
+# out an iteration U:
+#   (128, 12, 128, 64): one head 0.684 / 0.943; G 16 at U 1 0.632 / 0.718, U 2
+#   0.343 / 0.535, U 4 0.293 / 0.441; at U 4, G 4 0.304 / 0.498, G 8 0.294 /
+#   0.451; G 32 at U 2 as G 16. The heads in flight buy the time, the grid
+#   steps saved little; 2048 rows a step is where more stops paying.
+#   (32, 12, 512, 64): one head 0.3465 / 0.7724; U 1 at any G 0.345 / 0.767;
+#   U 2 at G 2 0.3358 / 0.7420, G 4 0.3358 / 0.7387, G 8 0.3369 / 0.7428; U 4 at
+#   G 4 0.3399 / 0.7356 for twice the backward's compile (1.0 s). Tiles of 1 MB
+#   leave little to interleave: two heads in flight, no more.
+# Those runs wrote the U heads out in Python: tracing that body cost a start
+# 0.31 s a backward call site of BERT's step at U 4 (0.03 at one head), 2.67 s
+# of warm set-up at 128 tokens. ``_each_head`` leaves the writing out to the
+# lowering: the same Mosaic modules, ``head`` traced once whatever G and U.
+_HEAD_ROWS = 2048  # rows (heads x Tq) a grid step carries at most
+_HEAD_MIN_GRID = 256  # grid steps under which a step's fixed cost is noise
+# heads in flight an iteration of the in-kernel loop, by Tq: the two shapes
+# measured; a length between them takes the longer's, one past them 2. At
+# 128 rows four are faster (above) and cost BERT's warm start 2.25 s, over
+# the 2 s it may cost: lowering writes the body out once a head in flight
+_HEADS_IN_FLIGHT = {128: 2, 512: 2}
+
+
+def _heads_per_step(kernel, forced, bh, tq, tk, one_plain_tile, fits):
+    """(heads, unroll) of a grid step of a flash kernel, a fixed function of
+    the call's shape (as ``_bwd_blocks``), counted once a traced call
+    (``telemetry.flash_heads_per_step()``). One head, the kernel as it was,
+    unless ``one_plain_tile``: a head is one unpadded block on both axes,
+    of whole 128-lane lengths, in a call with no selection mask, no
+    ``causal`` and as many K/V heads as query heads; and unless the grid is
+    shorter than ``_HEAD_MIN_GRID`` steps (the deferred-shape forward at
+    batch 1: 12 steps, 5 us: it keeps the one-head program and its
+    compile). Then the largest power of two that divides ``bh``, carries
+    ``_HEAD_ROWS`` rows or fewer and ``fits(heads, unroll)``: what a step
+    then holds stays inside three quarters of the scoped VMEM the compiler
+    gives unasked, by the parent's own measure (``_bwd_vmem_limit``), so
+    nothing is asked for. ``unroll`` of them are in flight together
+    (``_HEADS_IN_FLIGHT``). ``forced`` heads override the sizes, never
+    ``one_plain_tile``: no caller outside the tests and stand-alone sweeps
+    passes them."""
+    one_plain_tile = (one_plain_tile and tq % _LSE_LANES == 0
+                      and tk % _LSE_LANES == 0)
+    in_flight = next((u for t, u in sorted(_HEADS_IN_FLIGHT.items())
+                      if tq <= t), 2)
+    heads = 1
+    if forced is not None:
+        heads = int(forced)
+        if heads > 1 and not (one_plain_tile and bh % heads == 0):
+            raise MXNetError("flash_attention: %d heads a grid step want one "
+                             "plain tile a head and a divisor of %d"
+                             % (heads, bh))
+    elif one_plain_tile and bh >= _HEAD_MIN_GRID:
+        heads = next((g for g in (32, 16, 8, 4, 2) if bh % g == 0
+                      and g * tq <= _HEAD_ROWS
+                      and fits(g, math.gcd(g, in_flight))), 1)
+    _telemetry.record_flash_heads(kernel, heads)
+    return heads, math.gcd(heads, in_flight)
 
 
 def _mask_tiles(mask, block_q, block_k, pad_q, pad_k, key_major=False):
@@ -337,11 +449,14 @@ def _kv_head_of(group):
 
 
 def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                          interpret, mask=None):
+                          interpret, mask=None, heads_per_step=None):
     """(out, lse) of the forward kernel: ``out`` (B, H, Tq, Dv) in the input
     dtype, ``lse`` (B, H, Tq) float32. The kernel writes ``lse`` as a
     (B*H, 1, Tq) array in (1, 1, block_q) row blocks. ``k`` / ``v`` hold
-    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None."""
+    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None. A grid step takes
+    ``_heads_per_step``'s heads (``heads_per_step`` overrides the rule, for
+    tests and stand-alone sweeps), counted by
+    ``telemetry.flash_heads_per_step()``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -370,16 +485,25 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     kf = k.reshape(B * Hkv, Tkp, D)
     vf = v.reshape(B * Hkv, Tkp, Dv)
     kv_head = _kv_head_of(H // Hkv)
+    # G heads a grid step: the blocks' leading dimension, on the flat
+    # batch x head axis (1: the program as it was)
+    G, unroll = _heads_per_step(
+        "fwd", heads_per_step, B * H, Tq, Tk,
+        mask is None and not causal and H == Hkv
+        and (block_q, block_k) == (Tq, Tk),
+        lambda g, u: _fwd_vmem_held(
+            Tkp, D, Dv, block_q, block_k, q.dtype.itemsize, g, u)
+        <= 3 * _VMEM_SCOPED_DEFAULT // 4)
 
     # index maps return np.int32 zeros: under jax_enable_x64 a literal 0
     # traces as i64, which Mosaic rejects in the index-map signature
     z = np.int32(0)
     in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, iq: (bh, iq, z),
+        pl.BlockSpec((G, block_q, D), lambda bh, iq: (bh, iq, z),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tkp, D), lambda bh, iq: (kv_head(bh), z, z),
+        pl.BlockSpec((G, Tkp, D), lambda bh, iq: (kv_head(bh), z, z),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Tkp, Dv), lambda bh, iq: (kv_head(bh), z, z),
+        pl.BlockSpec((G, Tkp, Dv), lambda bh, iq: (kv_head(bh), z, z),
                      memory_space=pltpu.VMEM),
     ]
     args = [qf, kf, vf]
@@ -390,7 +514,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         # picks a block's row by a leading index, never by a lane offset
         bflat = jnp.broadcast_to(bias, (B, H, 1, Tkp)).reshape(
             B * H, nkv, 1, block_k)
-        in_specs.append(pl.BlockSpec((1, nkv, 1, block_k),
+        in_specs.append(pl.BlockSpec((G, nkv, 1, block_k),
                                      lambda bh, iq: (bh, z, z, z),
                                      memory_space=pltpu.VMEM))
         args.append(bflat)
@@ -414,18 +538,18 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         if mask is None:
             refs.insert(4, None)
         _flash_fwd_kernel(*refs, block_k=block_k, causal=causal,
-                          sm_scale=sm_scale, kv_len=Tk, q_len=Tq)
+                          sm_scale=sm_scale, kv_len=Tk, q_len=Tq, unroll=unroll)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, Tqp // block_q),
+        grid=(B * H // G, Tqp // block_q),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, Dv), lambda bh, iq: (bh, iq, z),
+            pl.BlockSpec((G, block_q, Dv), lambda bh, iq: (bh, iq, z),
                          memory_space=pltpu.VMEM),
             # the same 3-D trick as the bias: a row block of a (B*H, 1, Tqp)
             # array, 4 bytes a query
-            pl.BlockSpec((1, 1, block_q), lambda bh, iq: (bh, z, iq),
+            pl.BlockSpec((G, 1, block_q), lambda bh, iq: (bh, z, iq),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
@@ -446,12 +570,17 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                       dq_ref, dk_ref, dv_ref, db_ref, dq_acc, *,
-                      block_q, causal, sm_scale, kv_len, q_len, kv_pad):
+                      block_q, causal, sm_scale, kv_len, q_len, kv_pad,
+                      unroll=1):
     """One (batch x head, K/V block) grid step of the backward: Q, dO and
     the row statistics of the whole head sit in VMEM, the K/V block's
     scores are recomputed from ``lse`` one Q block at a time, and five
     matmuls a tile give dv, dp, dk and dq. Operands of every matmul are in
-    the input dtype, the accumulators float32.
+    the input dtype, the accumulators float32. Where the blocks hold several
+    heads (``_heads_per_step``: each head one tile, so the K/V axis of the
+    grid is one step) ``_each_head`` walks them, every head's arithmetic
+    what it is alone; ``dq_acc`` then has a plane for each of the ``unroll``
+    heads in flight.
 
     The tile is held key-major, ``s_t[k, q]``: dv and dk are then plain
     matmuls, ``lse`` and ``delta`` are lane-oriented rows (no lane-padded
@@ -465,6 +594,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
 
     f32 = jnp.float32
     ik = pl.program_id(1)
+    heads = q_ref.shape[0]
     block_k = k_ref.shape[1]
     tq_pad = q_ref.shape[1]
     k_off = ik * block_k
@@ -473,81 +603,94 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
     neg_inf = f32(_NEG_INF)
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
 
-    @pl.when(ik == 0)
-    def _zero():
-        dq_acc[...] = jnp.zeros(dq_acc.shape, f32)
+    def at_step(which):
+        """``pl.when`` on the K/V axis; with several heads a step that axis
+        is one step long, and a head's first step is its last."""
+        return pl.when(ik == which) if heads == 1 else (lambda f: f())
 
-    k = k_ref[0]  # (BK, Dk)
-    v = v_ref[0]  # (BK, Dv)
-    bias_col = None
-    if bias_ref is not None:
-        # the key bias is a lane-oriented row; a key-major tile wants it as
-        # a column: turn a sublane-broadcast (128, BK) tile
-        bias_col = jnp.broadcast_to(
-            bias_ref[0].astype(f32), (_LSE_LANES, block_k)).T[:, :1]
+    def head(g, u):
+        # one accumulator a head in flight: plane u of dq_acc, picked by a
+        # leading index; one head has the one plane, indexed as it always was
+        plane = () if heads == 1 else (u,)
+        whole = plane + (...,) if plane else ...
 
-    def body(iq, carry):
-        dk_i, dv_i, db_i = carry
-        q_off = pl.multiple_of(iq * block_q, block_q)
-        q = q_ref[0, pl.ds(q_off, block_q), :]  # (BQ, Dk)
-        do = do_ref[0, pl.ds(q_off, block_q), :]  # (BQ, Dv)
-        lse = st_ref[0, 0:1, pl.ds(q_off, block_q)]  # (1, BQ)
-        delta = st_ref[0, 1:2, pl.ds(q_off, block_q)]
-        s_t = jax.lax.dot_general(
-            k, q, nt, preferred_element_type=f32) * sm_scale  # (BK, BQ)
-        if bias_col is not None:
-            s_t = s_t + bias_col
-        masks = []
-        if kv_pad != kv_len or tq_pad != q_len or causal:
-            kcol = k_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            qrow = q_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            if kv_pad != kv_len:  # tail-block padding
-                masks.append(kcol < kv_len)
-            if tq_pad != q_len:
-                masks.append(qrow < q_len)
-            if causal:
-                masks.append(kcol <= qrow + shift)
-        if mask_ref is not None:  # the selection: data, on every tile
-            masks.append(mask_ref[0, 0, iq].astype(jnp.int32) != 0)
-        if masks:
-            s_t = jnp.where(functools.reduce(jnp.logical_and, masks), s_t,
-                            neg_inf)
-        p_t = jnp.exp(s_t - lse)
-        dv_i = dv_i + jnp.dot(p_t.astype(do.dtype), do,
-                              preferred_element_type=f32)  # (BK, D)
-        dp_t = jax.lax.dot_general(v, do, nt, preferred_element_type=f32)
-        ds_t = p_t * (dp_t - delta)
-        if db_i is not None:
-            db_i = db_i + jnp.sum(ds_t, axis=1, keepdims=True)
-        dk_i = dk_i + jnp.dot(ds_t.astype(q.dtype), q,
-                              preferred_element_type=f32)
-        dq_acc[pl.ds(q_off, block_q), :] += jnp.dot(
-            ds_t.T.astype(k.dtype), k, preferred_element_type=f32)
-        return dk_i, dv_i, db_i
+        @at_step(0)
+        def _zero():
+            dq_acc[whole] = jnp.zeros(dq_acc.shape[-2:], f32)
 
-    nq = tq_pad // block_q
-    first = jnp.int32(0)
-    if causal:
-        # Q blocks wholly above the diagonal see none of this K/V block:
-        # their tiles are exactly zero
-        # (lax.div on a non-negative i32: jnp's floor_divide does not lower)
-        first = jnp.minimum(jax.lax.div(
-            jnp.maximum(k_off - shift, 0), jnp.int32(block_q)), nq)
-    db0 = None if bias_ref is None else jnp.zeros((block_k, 1), f32)
-    # i32 bounds: under jax_enable_x64 a Python int traces as i64
-    dk, dv, db = jax.lax.fori_loop(
-        first, jnp.int32(nq), body,
-        (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32), db0))
-    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    if db_ref is not None:
-        db_ref[0] = jnp.broadcast_to(db, (block_k, _LSE_LANES)).T[:1, :]
+        k = k_ref[g]  # (BK, Dk)
+        v = v_ref[g]  # (BK, Dv)
+        bias_col = None
+        if bias_ref is not None:
+            # the key bias is a lane-oriented row; a key-major tile wants it
+            # as a column: turn a sublane-broadcast (128, BK) tile
+            bias_col = jnp.broadcast_to(
+                bias_ref[g].astype(f32), (_LSE_LANES, block_k)).T[:, :1]
 
-    @pl.when(ik == pl.num_programs(1) - 1)
-    def _finish():
-        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+        def body(iq, carry):
+            dk_i, dv_i, db_i = carry
+            q_off = pl.multiple_of(iq * block_q, block_q)
+            q = q_ref[g, pl.ds(q_off, block_q), :]  # (BQ, Dk)
+            do = do_ref[g, pl.ds(q_off, block_q), :]  # (BQ, Dv)
+            lse = st_ref[g, 0:1, pl.ds(q_off, block_q)]  # (1, BQ)
+            delta = st_ref[g, 1:2, pl.ds(q_off, block_q)]
+            s_t = jax.lax.dot_general(
+                k, q, nt, preferred_element_type=f32) * sm_scale  # (BK, BQ)
+            if bias_col is not None:
+                s_t = s_t + bias_col
+            masks = []
+            if kv_pad != kv_len or tq_pad != q_len or causal:
+                kcol = k_off + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                qrow = q_off + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                if kv_pad != kv_len:  # tail-block padding
+                    masks.append(kcol < kv_len)
+                if tq_pad != q_len:
+                    masks.append(qrow < q_len)
+                if causal:
+                    masks.append(kcol <= qrow + shift)
+            if mask_ref is not None:  # the selection: data, on every tile
+                masks.append(mask_ref[0, 0, iq].astype(jnp.int32) != 0)
+            if masks:
+                s_t = jnp.where(functools.reduce(jnp.logical_and, masks), s_t,
+                                neg_inf)
+            p_t = jnp.exp(s_t - lse)
+            dv_i = dv_i + jnp.dot(p_t.astype(do.dtype), do,
+                                  preferred_element_type=f32)  # (BK, D)
+            dp_t = jax.lax.dot_general(v, do, nt, preferred_element_type=f32)
+            ds_t = p_t * (dp_t - delta)
+            if db_i is not None:
+                db_i = db_i + jnp.sum(ds_t, axis=1, keepdims=True)
+            dk_i = dk_i + jnp.dot(ds_t.astype(q.dtype), q,
+                                  preferred_element_type=f32)
+            dq_acc[plane + (pl.ds(q_off, block_q), slice(None))] += jnp.dot(
+                ds_t.T.astype(k.dtype), k, preferred_element_type=f32)
+            return dk_i, dv_i, db_i
+
+        nq = tq_pad // block_q
+        first = jnp.int32(0)
+        if causal:
+            # Q blocks wholly above the diagonal see none of this K/V block:
+            # their tiles are exactly zero (lax.div on a non-negative i32:
+            # jnp's floor_divide does not lower)
+            first = jnp.minimum(jax.lax.div(
+                jnp.maximum(k_off - shift, 0), jnp.int32(block_q)), nq)
+        db0 = None if bias_ref is None else jnp.zeros((block_k, 1), f32)
+        # i32 bounds: under jax_enable_x64 a Python int traces as i64
+        dk, dv, db = jax.lax.fori_loop(
+            first, jnp.int32(nq), body,
+            (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32), db0))
+        dk_ref[g] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[g] = dv.astype(dv_ref.dtype)
+        if db_ref is not None:
+            db_ref[g] = jnp.broadcast_to(db, (block_k, _LSE_LANES)).T[:1, :]
+
+        @at_step(pl.num_programs(1) - 1)
+        def _finish():
+            dq_ref[g] = (dq_acc[whole] * sm_scale).astype(dq_ref.dtype)
+
+    _each_head(heads, unroll, head)
 
 
 def _bwd_blocks(Tq, Tk):
@@ -560,11 +703,8 @@ def _bwd_blocks(Tq, Tk):
     return pick(Tq), pick(Tk)
 
 
-_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call unasked
-
-
 def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
-                    out_itemsize=None):
+                    out_itemsize=None, heads=1, in_flight=1):
     """Scoped VMEM the backward kernel asks for: nothing (the compiler's
     default) while what it holds fits that with room to spare, as every
     call of 1 MB of Q + dO does; else what it holds and a quarter
@@ -576,12 +716,21 @@ def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
     A head of 4096 rows at 192 + 128 asks for 28.5 MB; one of 8192 rows at
     128 + 128 holds 24.6 MB and asks for 30.8, and with the mask and
     float32 dk / dv blocks holds 33.5 MB and asks for 41.9 (of the chip's
-    128 MB)."""
+    128 MB). With ``heads`` a grid step the blocks are held that many
+    times and the dq accumulator once a head ``in_flight`` (the scratch is
+    ``(in_flight, tq, dk)``); ``_heads_per_step`` takes only what this
+    leaves at nothing asked for. The six tiles stay counted once: six is the
+    allowance for ONE head's chain with every value live at once, which the
+    compiler never needs (at 512 rows, 2 heads a step and 2 in flight it
+    fits both heads' tiles and 4.2 MB of blocks and planes into the default
+    16 MB, where twice six tiles alone would be 12.6); whether a step's
+    heads fit is decided by the compiler, and ``tests/test_aot_tpu_compile.py``
+    holds the rule's choices at BERT's two shapes to it."""
     lanes, out_itemsize = _lanes, out_itemsize or itemsize
-    held = (2 * tq * (2 * lanes(dk) + lanes(dv)) * itemsize
-            + tq * lanes(dk) * 4 + 2 * 8 * tq * 4
-            + 2 * block_k * (lanes(dk) + lanes(dv)) * (itemsize + out_itemsize)
-            + 6 * block_q * block_k * 4)
+    held = (heads * (2 * tq * (2 * lanes(dk) + lanes(dv)) * itemsize
+                     + 2 * 8 * tq * 4 + 2 * block_k * (lanes(dk) + lanes(dv))
+                     * (itemsize + out_itemsize))
+            + in_flight * tq * lanes(dk) * 4 + 6 * block_q * block_k * 4)
     if mask:
         held += 2 * tq * block_k
     if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
@@ -590,10 +739,12 @@ def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
 
 
 def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
-                           block_q, block_k, interpret, mask=None):
+                           block_q, block_k, interpret, mask=None,
+                           heads_per_step=None):
     """dq, dk, dv (and dbias) of flash attention from the forward's
     residuals, never building a score tensor in HBM. ``k`` / ``v`` hold
-    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None."""
+    ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None. A grid step takes
+    ``_heads_per_step``'s heads, as the forward's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -620,25 +771,40 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
     # carries dq, so a group's heads cannot share an output block)
     part = f32 if group > 1 else None
 
+    def vmem_limit(heads, in_flight):
+        return _bwd_vmem_limit(
+            Tqp, D, Dv, block_q, block_k, q.dtype.itemsize,
+            mask=mask is not None, out_itemsize=4 if part else None,
+            heads=heads, in_flight=in_flight)
+
+    # G heads a grid step: the blocks' leading dimension, on the flat
+    # batch x head axis (1: the program as it was); what fits is what asks
+    # for no scoped VMEM
+    G, unroll = _heads_per_step(
+        "bwd", heads_per_step, BH, Tq, Tk,
+        mask is None and not causal and group == 1
+        and (block_q, block_k) == (Tq, Tk),
+        lambda g, u: vmem_limit(g, u) is None)
+
     # np.int32 zeros in the index maps, as the forward (x64 is on)
     z = np.int32(0)
     def whole(d):
-        return pl.BlockSpec((1, Tqp, d), lambda bh, ik: (bh, z, z),
+        return pl.BlockSpec((G, Tqp, d), lambda bh, ik: (bh, z, z),
                             memory_space=pltpu.VMEM)
 
     def kv_blk(d):
-        return pl.BlockSpec((1, block_k, d),
+        return pl.BlockSpec((G, block_k, d),
                             lambda bh, ik: (kv_head(bh), ik, z),
                             memory_space=pltpu.VMEM)
 
     def dkv_blk(d):
-        return pl.BlockSpec((1, block_k, d), lambda bh, ik: (bh, ik, z),
+        return pl.BlockSpec((G, block_k, d), lambda bh, ik: (bh, ik, z),
                             memory_space=pltpu.VMEM)
 
-    key_row = pl.BlockSpec((1, 1, block_k), lambda bh, ik: (bh, z, ik),
+    key_row = pl.BlockSpec((G, 1, block_k), lambda bh, ik: (bh, z, ik),
                            memory_space=pltpu.VMEM)
     in_specs = [whole(D), kv_blk(D), kv_blk(Dv), whole(Dv),
-                pl.BlockSpec((1, 2, Tqp), lambda bh, ik: (bh, z, z),
+                pl.BlockSpec((G, 2, Tqp), lambda bh, ik: (bh, z, z),
                              memory_space=pltpu.VMEM)]
     args = [q.reshape(BH, Tqp, D), k.reshape(B * Hkv, Tkp, D),
             v.reshape(B * Hkv, Tkp, Dv), do.reshape(BH, Tqp, Dv),
@@ -648,7 +814,7 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
                  jax.ShapeDtypeStruct((BH, Tkp, D), part or k.dtype),
                  jax.ShapeDtypeStruct((BH, Tkp, Dv), part or v.dtype)]
     static = dict(block_q=block_q, causal=causal, sm_scale=sm_scale,
-                  kv_len=Tk, q_len=Tq, kv_pad=Tkp)
+                  kv_len=Tk, q_len=Tq, kv_pad=Tkp, unroll=unroll)
     if bias is not None:
         bflat = jnp.broadcast_to(bias.astype(f32), (B, H, 1, Tk))
         if pad_k:
@@ -679,17 +845,16 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
 
     outs = pl.pallas_call(
         kernel,
-        grid=(BH, Tkp // block_k),
+        grid=(BH // G, Tkp // block_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((Tqp, D), f32)],
+        scratch_shapes=[pltpu.VMEM(
+            (Tqp, D) if G == 1 else (unroll, Tqp, D), f32)],
         # the K/V axis carries the dq accumulator: sequential
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_limit(
-                Tqp, D, Dv, block_q, block_k, q.dtype.itemsize,
-                mask=mask is not None, out_itemsize=4 if part else None)),
+            vmem_limit_bytes=vmem_limit(G, unroll)),
         interpret=interpret,
         name="flash_attention_bwd",
     )(*args)

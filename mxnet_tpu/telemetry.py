@@ -71,6 +71,7 @@ __all__ = [
     "record_compile", "record_compile_cache", "record_tune_lookup",
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
+    "record_flash_heads", "flash_heads_per_step",
     "record_moe_counts", "moe_counts",
     "record_selection_counts", "selection_counts",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
@@ -835,6 +836,27 @@ def record_flash_bwd(branch):
 def flash_bwd_branches():
     """{branch: traces} of :func:`record_flash_bwd` so far."""
     return _flash_branches("bwd")
+
+
+def record_flash_heads(kernel, heads):
+    """One traced flash-attention kernel (``kernel``: ``fwd`` or ``bwd``) by
+    the heads its grid step takes
+    (``mxt_flash_heads_per_step{kernel,heads}``): how often several heads a
+    step engage, and at how many. Counted at trace time, as the branches:
+    nothing enters the compiled step."""
+    counter("mxt_flash_heads_per_step",
+            "Traced flash-attention kernels by heads a grid step.",
+            ("kernel", "heads")).labels(kernel, str(int(heads))).inc()
+
+
+def flash_heads_per_step():
+    """{kernel: {heads: traces}} of :func:`record_flash_heads` so far."""
+    fam = _REGISTRY.get("mxt_flash_heads_per_step")
+    out = {}
+    if fam is not None:
+        for (kernel, heads), ch in sorted(fam.children().items()):
+            out.setdefault(kernel, {})[heads] = int(ch.value)
+    return out
 
 
 def record_moe_counts(expert_load, slots_lost, blocks_run):

@@ -180,6 +180,10 @@ _CASES = {
     "flash_300_ragged_bias": _flash((2, 4, 300, 64), "bfloat16", True,
                                     bias_shape=(2, 1, 1, 300),
                                     blocks=(128, 128)),
+    # the deferred-shape forward of the BERT cells, batch 1: 12 grid steps,
+    # one head a step, and the 128-token cell's backward (16 heads a step)
+    "flash_bert_eager_s128": _flash((1, 12, 128, 64), "bfloat16", False),
+    "flash_bwd_bert_cell_s128": _flash_bwd((128, 12, 128, 64), "bfloat16", False),
     # flash backward: the benchmark's BERT cell (32 x 512), causal, a
     # padding bias (dbias comes out of the kernel), a longer causal
     # sequence, a ragged one, and the corner of what _flash_bwd sends it:
@@ -237,13 +241,33 @@ _LSE_ROWS = {"flash_bert_cell_s512": (384, 512),
              "flash_300_ragged_bias": (8, 384)}
 
 
+# heads a grid step that A._heads_per_step gives the benchmark's BERT cells
+# (both kernels at 128 tokens, forward and backward at 512): these cases
+# compile the grouped kernels, inside the scoped VMEM the compiler gives
+# unasked (they pass no limit: tests/test_attention_bert.py reads that from
+# their jaxprs; one that held more would be refused here)
+_HEADS = {"flash_bert_cell_s128": ("fwd", "16"), "flash_bwd_bert_cell_s128": ("bwd", "16"),
+          "flash_bert_cell_s512": ("fwd", "4"), "flash_bwd_bert_s512": ("bwd", "2"),
+          "flash_bert_bias": ("fwd", "16"), "flash_bwd_bert_bias": ("bwd", "16"),
+          # the deferred-shape forward at batch 1 and the latent cell: one head
+          "flash_bert_eager_s128": ("fwd", "1"), "flash_mla_4096_k192_v128": ("fwd", "1"),
+          "flash_bwd_mla_4096_k192_v128": ("bwd", "1")}
+
+
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_compiles_for_v5e(chip, name):
+    from mxnet_tpu import telemetry
+
+    before = telemetry.flash_heads_per_step()
     text = _CASES[name](chip)
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     if name in _LSE_ROWS:
         assert "f32[%d,1,%d]" % _LSE_ROWS[name] in text
         assert "f32[%d,%d,128]" % _LSE_ROWS[name] not in text
+    if name in _HEADS:
+        kernel, heads = _HEADS[name]
+        after = telemetry.flash_heads_per_step()[kernel]
+        assert after[heads] == before.get(kernel, {}).get(heads, 0) + 1
 
 
 def test_flash_bwd_corner_is_what_the_dispatch_admits():

@@ -407,7 +407,10 @@ def test_flash_fwd_branch_counter(monkeypatch):
 
 
 def _pallas_eqn(jaxpr):
-    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    """The one kernel of a traced builder, where its call sits: in the
+    jaxpr, or (several heads a grid step) in the jitted function the call
+    sites of a shape share."""
+    (eqn,) = [e for e in _inner_eqns(jaxpr) if e.primitive.name == "pallas_call"]
     return eqn
 
 
@@ -638,3 +641,177 @@ def test_vmem_asked_for_follows_the_mask_and_the_group():
     # what stood before is what it was
     assert A._bwd_vmem_limit(4096, 192, 128, 512, 512, 2) == 28508160
     assert A._bwd_vmem_limit(512, 64, 64, 512, 512, 2) is None
+
+
+# -- several heads a grid step ----------------------------------------------------
+_HEADS_SHAPES = {"s128": (4, 12, 128, 64), "s512": (2, 12, 512, 64)}
+_one_head_cache = {}
+
+
+def _heads_case(shape, dtype, with_bias):
+    """Seeded inputs at a BERT cell's head shape (fewer sequences), the
+    one-head kernels' outputs and the reference's, made once a case."""
+    key = (shape, dtype, with_bias)
+    if key not in _one_head_cache:
+        B, H, T, D = _HEADS_SHAPES[shape]
+        q, k, v, do, bias = _bwd_case(np.random.RandomState(12), B, H, T, T, D,
+                                      dtype, with_bias)
+        f32 = jnp.float32
+        ref = A._attention_reference(q.astype(f32), k.astype(f32), v.astype(f32),
+                                     bias, False, 0.125)
+        out, lse = A._flash_forward_pallas(q, k, v, bias, False, 0.125, T, T,
+                                           interpret=True, heads_per_step=1)
+        grads = A._flash_backward_pallas(q, k, v, bias, out, lse, do, False, 0.125,
+                                         T, T, interpret=True, heads_per_step=1)
+        _one_head_cache[key] = (q, k, v, do, bias, ref,
+                                _ref_grads(q, k, v, do, bias, False, 0.125),
+                                out, lse, grads)
+    return _one_head_cache[key]
+
+
+def _cell_heads(shape):
+    """Heads a step the rule gives the benchmark's cell of this head shape
+    (forward, backward), read from the programs it traces to."""
+    B = {"s128": 128, "s512": 32}[shape]
+    return _traced_heads((B,) + _HEADS_SHAPES[shape][1:])
+
+
+def _traced_heads(shape, causal=False, kv_heads=None, masked=False, bias=False,
+                  dv=None, blocks=None):
+    """(forward, backward) heads a grid step of the kernels a call of this
+    shape traces to under the rule, with the blocks the dispatch gives it:
+    the leading dimension of the Q block, checked against the grid."""
+    B, H, T, D = shape
+    bf = jnp.bfloat16
+    S = jax.ShapeDtypeStruct
+    q, kv = S(shape, bf), S((B, kv_heads or H, T, D), bf)
+    v = S((B, kv_heads or H, T, dv or D), bf)
+    o, lse = S((B, H, T, dv or D), bf), S((B, H, T), jnp.float32)
+    b = S((B, 1, 1, T), jnp.float32) if bias else None
+    m = S((B, T, T), jnp.int8) if masked else None
+    from mxnet_tpu import tuning
+    cfg = tuning.heuristic_attention(shape, T, "bfloat16", causal)
+    bq, bk = blocks or (cfg["block_q"], cfg["block_k"])
+    fwd = jax.make_jaxpr(lambda q, k, v, b, m: A._flash_forward_pallas(
+        q, k, v, b, causal, D ** -0.5, bq, bk, False, mask=m))(q, kv, v, b, m)
+    bq, bk = A._bwd_blocks(T, T)
+    bwd = jax.make_jaxpr(lambda q, k, v, b, o, l, do, m: A._flash_backward_pallas(
+        q, k, v, b, o, l, do, causal, D ** -0.5, bq, bk, False, mask=m))(
+            q, kv, v, b, o, lse, o, m)
+    heads = []
+    for jaxpr in (fwd, bwd):
+        call = _pallas_eqn(jaxpr.jaxpr)
+        gm = call.params["grid_mapping"]
+        g = gm.block_mappings[0].block_shape[0]
+        g = getattr(g, "block_size", g)
+        assert gm.grid[0] * g == B * H
+        if g > 1:  # several heads live in what the compiler gives unasked
+            params = dict(call.params["compiler_params"] or {})
+            assert all(p.vmem_limit_bytes is None for p in params.values())
+        heads.append(int(g))
+    return tuple(heads)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("heads", [2, 4, "cell"])
+@pytest.mark.parametrize("shape", sorted(_HEADS_SHAPES))
+def test_several_heads_a_grid_step_vs_reference(shape, heads, with_bias, dtype, tol):
+    """Both kernels (interpret mode) with 2, 4 and the cell's own count of
+    heads a grid step at BERT's two head shapes, with and without a key
+    bias: against the reference and its gradient, and every output equal to
+    the one-head kernel's to the last bit (a head's arithmetic is what it
+    is alone), lse among them."""
+    B, H, T, D = _HEADS_SHAPES[shape]
+    q, k, v, do, bias, ref, ref_grads, out1, lse1, grads1 = _heads_case(
+        shape, dtype, with_bias)
+    gf, gb = _cell_heads(shape) if heads == "cell" else (heads, heads)
+    assert gf > 1 and gb > 1 and B * H % gf == 0 and B * H % gb == 0
+    out, lse = A._flash_forward_pallas(q, k, v, bias, False, 0.125, T, T,
+                                       interpret=True, heads_per_step=gf)
+    assert_almost_equal(np.asarray(out.astype(jnp.float32)), np.asarray(ref),
+                        rtol=tol, atol=tol)
+    assert np.array_equal(np.asarray(lse), np.asarray(lse1))
+    assert np.array_equal(np.asarray(out.astype(jnp.float32)),
+                          np.asarray(out1.astype(jnp.float32)))
+    got = A._flash_backward_pallas(q, k, v, bias, out, lse, do, False, 0.125,
+                                   T, T, interpret=True, heads_per_step=gb)
+    _assert_grads_close(got, ref_grads, tol)
+    for g, g1 in zip(got, grads1):
+        assert (g is None) == (g1 is None)
+        if g is not None:
+            assert np.array_equal(np.asarray(g.astype(jnp.float32)),
+                                  np.asarray(g1.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("case,call,expect", [
+    # the two BERT cells, without and with a padding bias
+    ("bert_s128", dict(shape=(128, 12, 128, 64)), (16, 16)),
+    ("bert_s128_bias", dict(shape=(128, 12, 128, 64), bias=True), (16, 16)),
+    ("bert_s512", dict(shape=(32, 12, 512, 64)), (4, 2)),
+    # the deferred-shape forward of either: 12 grid steps
+    ("batch1_s128", dict(shape=(1, 12, 128, 64)), (1, 1)),
+    ("batch1_s512", dict(shape=(1, 12, 512, 64)), (1, 1)),
+    # just under the grid's threshold, and at it
+    ("grid_252", dict(shape=(21, 12, 128, 64)), (1, 1)),
+    ("grid_256", dict(shape=(64, 4, 128, 64)), (16, 16)),
+    # a head count the larger powers of two do not divide
+    ("grid_12x25", dict(shape=(25, 12, 128, 64)), (4, 4)),
+    # Kanana's latent attention, Keye's selected grouped-query attention
+    ("kanana", dict(shape=(2, 32, 4096, 192), causal=True, dv=128), (1, 1)),
+    ("keye", dict(shape=(1, 32, 8192, 128), causal=True, kv_heads=4,
+                  masked=True), (1, 1)),
+    # each of the conditions alone, at a shape that else qualifies
+    ("causal", dict(shape=(128, 12, 128, 64), causal=True), (1, 1)),
+    ("masked", dict(shape=(128, 12, 128, 64), masked=True), (1, 1)),
+    ("grouped", dict(shape=(128, 12, 128, 64), kv_heads=4), (1, 1)),
+    ("padded_100", dict(shape=(128, 12, 100, 64)), (1, 1)),
+    ("two_q_blocks", dict(shape=(128, 12, 256, 64), blocks=(128, 256)), (1, 8)),
+    ("two_kv_blocks", dict(shape=(128, 12, 256, 64), blocks=(256, 128)), (1, 8)),
+])
+def test_heads_per_step_rule_as_a_table(case, call, expect):
+    """``_heads_per_step`` by the programs it gives: (forward, backward)
+    heads a grid step at the shapes the benchmark's cells send and at each
+    condition that keeps the one-head program."""
+    assert _traced_heads(**call) == expect
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_kernel_body_does_not_grow_with_the_heads(kernel):
+    """The heads of a step are walked by a loop inside the kernel: its jaxpr
+    holds as many equations at 16 and at 32 heads a step as at 2, and a
+    head's matmuls ONCE, however many heads are in flight (the lowering
+    writes those out; a Python loop over the heads wrote the body out once
+    a head, and every start paid the tracing of it at every call site)."""
+    B, H, T, D = 8, 12, 128, 64
+    S = jax.ShapeDtypeStruct
+    q, lse = S((B, H, T, D), jnp.bfloat16), S((B, H, T), jnp.float32)
+
+    def body(heads):
+        if kernel == "fwd":
+            jaxpr = jax.make_jaxpr(lambda q, k, v: A._flash_forward_pallas(
+                q, k, v, None, False, 0.125, T, T, False,
+                heads_per_step=heads))(q, q, q)
+        else:
+            jaxpr = jax.make_jaxpr(lambda q, k, v, o, l, do: A._flash_backward_pallas(
+                q, k, v, None, o, l, do, False, 0.125, T, T, False,
+                heads_per_step=heads))(q, q, q, q, lse, q)
+        eqns = list(_inner_eqns(_pallas_eqn(jaxpr.jaxpr).params["jaxpr"]))
+        return len(eqns), sum(e.primitive.name == "dot_general" for e in eqns)
+
+    counts = {g: body(g) for g in (1, 2, 4, 16, 32)}
+    assert counts[2][0] == counts[4][0] == counts[16][0] == counts[32][0]
+    assert A._HEADS_IN_FLIGHT[128] == 2  # at these 128 rows
+    # the matmuls of a head: 2 forward, 5 backward
+    assert {c[1] for c in counts.values()} == {{"fwd": 2, "bwd": 5}[kernel]}
+    assert counts[1][0] < counts[2][0]  # the two loops themselves
+
+
+def test_forced_heads_want_a_plain_tile():
+    q = jnp.zeros((2, 4, 128, 16), jnp.float32)
+    with pytest.raises(mx.base.MXNetError):  # causal: heads differ in their work
+        A._flash_forward_pallas(q, q, q, None, True, 0.25, 128, 128, True,
+                                heads_per_step=2)
+    with pytest.raises(mx.base.MXNetError):  # 3 does not divide 8
+        A._flash_forward_pallas(q, q, q, None, False, 0.25, 128, 128, True,
+                                heads_per_step=3)

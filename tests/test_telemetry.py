@@ -487,3 +487,64 @@ def test_profiler_shims_ride_registry():
     assert profiler.gauge_value(gname) == 7
     text = telemetry.render_prometheus()
     assert name in text and gname in text
+
+
+def test_flash_heads_per_step_counts_traces_and_trace_cell_prints_it(
+        monkeypatch, capsys, tmp_path):
+    """``mxt_flash_heads_per_step{kernel, heads}``: one count a traced flash
+    kernel under the heads its grid step takes, none a call of the compiled
+    program; ``tools/trace_cell.py`` reads it with the branches and prints it
+    on its ``scoped`` line."""
+    import importlib.util
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+
+    q = jnp.ones((4, 4, 128, 16), jnp.float32)
+    lse = jnp.zeros((4, 4, 128), jnp.float32)
+
+    def delta(fn):
+        before = telemetry.flash_heads_per_step()
+        fn()
+        after = telemetry.flash_heads_per_step()
+        return {(k, h): n - before.get(k, {}).get(h, 0)
+                for k, by in after.items() for h, n in by.items()
+                if n != before.get(k, {}).get(h, 0)}
+
+    fwd = jax.jit(lambda q_, g: A._flash_forward_pallas(
+        q_, q_, q_, None, False, 0.25, 128, 128, True, heads_per_step=g)[0],
+        static_argnums=1)
+    assert delta(lambda: (fwd(q, 4), fwd(q, 4), fwd(q, 4))) == {("fwd", "4"): 1}
+    bwd = jax.jit(lambda q_: A._flash_backward_pallas(
+        q_, q_, q_, None, q_, lse, q_, False, 0.25, 128, 128, True,
+        heads_per_step=2)[0])
+    assert delta(lambda: (bwd(q), bwd(q))) == {("bwd", "2"): 1}
+    # the rule, not a forced count: 16 grid steps are too few for it
+    assert delta(lambda: A._flash_forward_pallas(
+        q, q, q, None, False, 0.25, 128, 128, True)) == {("fwd", "1"): 1}
+    assert 'mxt_flash_heads_per_step{kernel="fwd",heads="4"}' in \
+        telemetry.render_prometheus()
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_cell_under_test", os.path.join(root, "tools", "trace_cell.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    args = types.SimpleNamespace(seed=0, seconds=1.0, trace=1, rehearse=True)
+
+    def fake_run(argv):  # the run itself: an empty trace, and its clean-up
+        ctx = tool.Context(args, {}, {}, {}, [])
+        ctx._trace_dirs.append(str(tmp_path))
+        ctx.cleanup()
+        return 0
+
+    monkeypatch.setattr(tool.bench, "main", fake_run)
+    capsys.readouterr()
+    assert tool.main([]) == 0
+    line = [l for l in capsys.readouterr().out.splitlines()
+            if l.startswith('{"scoped"')][-1]
+    heads = json.loads(line)["scoped"]["flash_heads_per_step"]
+    assert heads["fwd"]["4"] >= 1 and heads["bwd"]["2"] >= 1

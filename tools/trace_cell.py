@@ -9,8 +9,9 @@ run's own lines and result, then ``dumps()``'s device table, then one JSON
 line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
 kernels' calls a step, the branch each traced attention forward and backward
-took (``flash_fwd_branches``, ``flash_bwd_branches``), the tuning table's
-entries (``tuning_entries``: the tiles each kernel shape ran with), what an
+took (``flash_fwd_branches``, ``flash_bwd_branches``) and the heads a grid
+step of each traced flash kernel takes (``flash_heads_per_step``), the tuning
+table's entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
 run past a layer's first), what a model with a lightning indexer counted there
@@ -46,6 +47,7 @@ class Context(bench.Context):
     steps = None
     flash_fwd = None
     flash_bwd = None
+    flash_heads = None
     tuned = None
     moe = None
     selection = None
@@ -75,6 +77,7 @@ class Context(bench.Context):
         Context.compile_stats = tuning.compile_stats()
         Context.flash_fwd = telemetry.flash_fwd_branches()
         Context.flash_bwd = telemetry.flash_bwd_branches()
+        Context.flash_heads = telemetry.flash_heads_per_step()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
         Context.selection = telemetry.selection_counts()
@@ -119,6 +122,8 @@ def main(argv):
             row["flash_fwd_branches"] = Context.flash_fwd
         if Context.flash_bwd:  # and which backward
             row["flash_bwd_branches"] = Context.flash_bwd
+        if Context.flash_heads:  # and how many heads a grid step each kernel
+            row["flash_heads_per_step"] = Context.flash_heads
         if Context.tuned:  # the tiles each kernel shape ran with
             row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
