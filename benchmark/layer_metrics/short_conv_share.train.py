@@ -1,0 +1,21 @@
+"""Share of the device's busy time, in the traced part of the window, that
+went to the gated short convolution mixers: operations under the scope
+``short_conv`` (the input projection, the op ``gated_short_conv`` under
+``gated_conv`` / ``gated_conv_bwd``, the output projection; both passes), by
+the program's own names in the trace (``mxnet_tpu.profiler.aggregate``'s
+``named_s``). In percent. Nothing where the run was not traced, where the
+program has no such reader, or where no operation ran under that scope."""
+NAME = "short_conv_share.train"
+UNIT = "%"
+LAYER = "model step"
+MOVES = "train_samples_per_s"
+SOURCE = "device_trace"
+
+
+def read(run):
+    from harness import program_trace
+
+    agg = program_trace.aggregate(run)
+    if not agg or not agg.get("busy_s") or not agg.get("named_s", {}).get("short_conv"):
+        return None
+    return 100.0 * agg["named_s"]["short_conv"] / agg["busy_s"]

@@ -102,13 +102,14 @@ class DeepseekMoE(HybridBlock):
     router's (``"sigmoid"``, or ``"softmax"`` over all the experts);
     ``selection_bias=False`` is a router without the bias buffer;
     ``router_gradient=False`` lets no gradient through the chosen experts'
-    weights (op ``moe_ffn``)."""
+    weights (op ``moe_ffn``); ``sum_epsilon`` is what the model adds to the
+    chosen scores' sum before dividing by it."""
 
     def __init__(self, units, moe_intermediate_size, n_routed_experts,
                  num_experts_per_tok, n_shared_experts=0,
                  routed_scaling_factor=1.0, experts_held=None,
                  scoring="sigmoid", selection_bias=True, router_gradient=True,
-                 prefix=None, params=None):
+                 sum_epsilon=1e-20, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = experts_held or (0, n_routed_experts)
         if first < 0 or count < 1 or first + count > n_routed_experts:
@@ -117,7 +118,8 @@ class DeepseekMoE(HybridBlock):
         self._static = dict(top_k=num_experts_per_tok, n_routed=n_routed_experts,
                             experts_held=(first, count),
                             scaling=routed_scaling_factor, scoring=scoring,
-                            router_gradient=router_gradient)
+                            router_gradient=router_gradient,
+                            sum_epsilon=sum_epsilon)
         self._bias = bool(selection_bias)
         width, shared = moe_intermediate_size, n_shared_experts * moe_intermediate_size
         with self.name_scope():

@@ -12,6 +12,7 @@ from . import optimizer_ops
 from . import loss_output
 from . import attention
 from . import indexer
+from . import gated_conv
 from . import moe
 from . import linalg
 from . import contrib_ops

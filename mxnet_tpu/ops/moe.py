@@ -103,10 +103,13 @@ _take_rows.defvjp(_take_fwd, _take_bwd)
 _sum_rows.defvjp(_sum_fwd, _sum_bwd)
 
 
-def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid"):
+def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid",
+          sum_epsilon=1e-20):
     """(N, H) tokens -> chosen experts (N, k) int32 and their weights (N, k)
     float32. ``scoring`` is ``"sigmoid"`` (each expert alone) or
-    ``"softmax"`` (over all the experts); ``router_bias`` None is no bias.
+    ``"softmax"`` (over all the experts); ``router_bias`` None is no bias;
+    ``sum_epsilon`` is what the model's publisher adds to the chosen scores'
+    sum before dividing by it (DeepSeek-V3 1e-20, LFM2 1e-6).
     The gradient reaches ``router_w`` and ``x`` through the weights; the
     choice and the bias carry none."""
     logits = jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
@@ -121,7 +124,7 @@ def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid"):
         choice = scores + jax.lax.stop_gradient(router_bias.astype(F32))
     _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + sum_epsilon)
     return idx.astype(jnp.int32), weights
 
 
@@ -246,7 +249,7 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                 shared_gate, shared_up, shared_down, *, top_k, n_routed,
                 experts_held, scaling=1.0, slots_bound=None,
-                scoring="sigmoid", router_gradient=True):
+                scoring="sigmoid", router_gradient=True, sum_epsilon=1e-20):
     """The expert layer on (N, H) tokens. ``w_gate`` / ``w_up`` are
     (count, H, I) and ``w_down`` (count, I, H): the experts held, expert
     ``first + i`` at row i. ``shared_*`` are the shared expert's weights as
@@ -268,7 +271,8 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
     bound = min(bound, slots)
     blocks = -(-slots // bound)
     with jax.named_scope("router"):
-        idx, weights = route(x, router_w, router_bias, top_k, scaling, scoring)
+        idx, weights = route(x, router_w, router_bias, top_k, scaling, scoring,
+                             sum_epsilon)
         if not router_gradient:
             weights = jax.lax.stop_gradient(weights)
     with jax.named_scope("dispatch"):
@@ -299,13 +303,14 @@ def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             down_weight, shared_gate_weight=None, shared_up_weight=None,
             shared_down_weight=None, top_k=1, n_routed=None,
             experts_held=None, scaling=1.0, scoring="sigmoid",
-            router_gradient=True):
+            router_gradient=True, sum_epsilon=1e-20):
     """The sparse expert layer of a chip that holds ``experts_held=(first,
     count)`` of ``n_routed`` experts, on ``data`` (..., H): see the module's
     docstring. ``scoring`` is the router's: ``"sigmoid"`` or ``"softmax"``
     over all ``n_routed`` logits; ``router_bias=None`` is a router without a
     selection bias; the weights of the chosen are ``scaling`` times their
-    scores over the chosen scores' sum. ``router_gradient=False`` makes the
+    scores over the chosen scores' sum plus ``sum_epsilon`` (the model's own:
+    DeepSeek-V3 adds 1e-20, LFM2 1e-6). ``router_gradient=False`` makes the
     chosen experts' weights constants of the loss: no gradient reaches the
     router's weights or the layer's input through them (what a strict share
     trained without the experts' exchange can say of that gradient is a part
@@ -321,6 +326,7 @@ def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             gate_weight, up_weight, down_weight, shared_gate_weight,
             shared_up_weight, shared_down_weight, top_k=int(top_k),
             n_routed=n_routed, experts_held=held, scaling=float(scaling),
-            scoring=str(scoring), router_gradient=bool(router_gradient))
+            scoring=str(scoring), router_gradient=bool(router_gradient),
+            sum_epsilon=float(sum_epsilon))
     return (y.reshape(lead + (y.shape[-1],)),
             *(jax.lax.stop_gradient(c) for c in counts))

@@ -71,6 +71,7 @@ __all__ = [
     "record_compile", "record_compile_cache", "record_tune_lookup",
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
+    "record_gated_conv", "gated_conv_branches",
     "record_flash_heads", "flash_heads_per_step",
     "record_moe_counts", "moe_counts",
     "record_selection_counts", "selection_counts",
@@ -804,12 +805,17 @@ def _record_flash(direction, branch):
             ("branch",)).labels(branch).inc()
 
 
-def _flash_branches(direction):
-    fam = _REGISTRY.get("mxt_flash_%s_total" % direction)
+def _branches(family):
+    """{branch: count} of a counter family with the one label ``branch``."""
+    fam = _REGISTRY.get(family)
     if fam is None:
         return {}
     return {values[0]: int(ch.value)
             for values, ch in sorted(fam.children().items())}
+
+
+def _flash_branches(direction):
+    return _branches("mxt_flash_%s_total" % direction)
 
 
 def record_flash_fwd(branch):
@@ -857,6 +863,19 @@ def flash_heads_per_step():
         for (kernel, heads), ch in sorted(fam.children().items()):
             out.setdefault(kernel, {})[heads] = int(ch.value)
     return out
+
+
+def record_gated_conv(branch):
+    """One traced ``gated_short_conv`` forward, by the branch it took
+    (``mxt_gated_conv_total{branch=xla|kernel}``). Counted at trace time, as
+    the flash branches: nothing enters the compiled step."""
+    counter("mxt_gated_conv_total", "Traced gated short convolutions by branch.",
+            ("branch",)).labels(branch).inc()
+
+
+def gated_conv_branches():
+    """{branch: traces} of :func:`record_gated_conv` so far."""
+    return _branches("mxt_gated_conv_total")
 
 
 def record_moe_counts(expert_load, slots_lost, blocks_run):

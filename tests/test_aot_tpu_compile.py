@@ -57,25 +57,27 @@ def _compile(chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4):
+def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4, masked=True):
     """Either kernel as the Keye cell runs it: grouped heads under a
-    selection mask, causal, at the blocks the dispatch picks."""
+    selection mask, causal, at the blocks the dispatch picks; ``masked=False``
+    is the same call without the mask operand (the LFM2 cell's)."""
     def case(chip):
         B, H, T, D = shape
         dt, sm = jnp.dtype("bfloat16"), D ** -0.5
         q, kv = (shape, dt), ((B, kv_heads, T, D), dt)
-        mask = ((B, T, T), jnp.int8)
+        mask = (((B, T, T), jnp.int8),) if masked else ()
         if not backward:
             cfg = tuning.heuristic_attention(shape, T, "bfloat16", True)
             return _compile(
-                chip, lambda q, k, v, m: A._flash_forward_pallas(
+                chip, lambda q, k, v, *m: A._flash_forward_pallas(
                     q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"], False,
-                    mask=m), q, kv, kv, mask)
+                    mask=m[0] if m else None), q, kv, kv, *mask)
         bq, bk = A._bwd_blocks(T, T)
         return _compile(
-            chip, lambda q, k, v, out, lse, do, m: A._flash_backward_pallas(
-                q, k, v, None, out, lse, do, True, sm, bq, bk, False, mask=m),
-            q, kv, kv, q, ((B, H, T), jnp.float32), q, mask)
+            chip, lambda q, k, v, out, lse, do, *m: A._flash_backward_pallas(
+                q, k, v, None, out, lse, do, True, sm, bq, bk, False,
+                mask=m[0] if m else None),
+            q, kv, kv, q, ((B, H, T), jnp.float32), q, *mask)
     return case
 
 
@@ -221,6 +223,10 @@ _CASES = {
     "flash_selected_8192_gqa": _flash_selected(False),
     "flash_bwd_selected_8192_gqa": _flash_selected(True),
     "flash_bwd_selected_2x3000_gqa": _flash_selected(True, (2, 8, 3000, 128), 2),
+    # the LFM2 cell: 32 query heads on 8 K/V heads of 64 at 8192 rows, no mask
+    # operand (a 64-wide head fills half the lanes: VMEM holds it at 128)
+    "flash_lfm2_8192_gqa_d64": _flash_selected(False, (1, 32, 8192, 64), 8, False),
+    "flash_bwd_lfm2_8192_gqa_d64": _flash_selected(True, (1, 32, 8192, 64), 8, False),
     "indexer_select_8192": _indexer(1, 8192),
     "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
@@ -251,7 +257,9 @@ _HEADS = {"flash_bert_cell_s128": ("fwd", "16"), "flash_bwd_bert_cell_s128": ("b
           "flash_bert_bias": ("fwd", "16"), "flash_bwd_bert_bias": ("bwd", "16"),
           # the deferred-shape forward at batch 1 and the latent cell: one head
           "flash_bert_eager_s128": ("fwd", "1"), "flash_mla_4096_k192_v128": ("fwd", "1"),
-          "flash_bwd_mla_4096_k192_v128": ("bwd", "1")}
+          "flash_bwd_mla_4096_k192_v128": ("bwd", "1"),
+          "flash_lfm2_8192_gqa_d64": ("fwd", "1"),
+          "flash_bwd_lfm2_8192_gqa_d64": ("bwd", "1")}
 
 
 @pytest.mark.parametrize("name", sorted(_CASES))
@@ -268,6 +276,21 @@ def test_kernel_compiles_for_v5e(chip, name):
         kernel, heads = _HEADS[name]
         after = telemetry.flash_heads_per_step()[kernel]
         assert after[heads] == before.get(kernel, {}).get(heads, 0) + 1
+
+
+def test_gated_short_conv_compiles_to_fusions_without_a_convolution(chip):
+    """``gated_short_conv`` with its hand-written backward at a conv layer of
+    the LFM2 cell (1 x 8192 tokens, 2048 channels, 3 taps): shifted
+    multiply-adds that XLA fuses, no convolution program and no kernel."""
+    from mxnet_tpu.ops.gated_conv import gated_short_conv
+
+    dt = jnp.dtype("bfloat16")
+    text = _compile(
+        chip, jax.grad(lambda bcx, w, g: jnp.sum(
+            gated_short_conv(bcx, w).astype(jnp.float32) * g), argnums=(0, 1)),
+        ((1, 8192, 6144), dt), ((2048, 3), dt), ((1, 8192, 2048), jnp.float32))
+    assert " convolution(" not in text and "tpu_custom_call" not in text
+    assert "bf16[1,8192,6144]" in text  # the gradient of bcx, written once
 
 
 def test_flash_bwd_corner_is_what_the_dispatch_admits():

@@ -761,6 +761,8 @@ def test_several_heads_a_grid_step_vs_reference(shape, heads, with_bias, dtype, 
     ("kanana", dict(shape=(2, 32, 4096, 192), causal=True, dv=128), (1, 1)),
     ("keye", dict(shape=(1, 32, 8192, 128), causal=True, kv_heads=4,
                   masked=True), (1, 1)),
+    # LFM2's grouped-query attention: 64-wide heads at 8192 rows, no mask
+    ("lfm2", dict(shape=(1, 32, 8192, 64), causal=True, kv_heads=8), (1, 1)),
     # each of the conditions alone, at a shape that else qualifies
     ("causal", dict(shape=(128, 12, 128, 64), causal=True), (1, 1)),
     ("masked", dict(shape=(128, 12, 128, 64), masked=True), (1, 1)),

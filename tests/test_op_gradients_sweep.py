@@ -212,6 +212,7 @@ SPEC = {
     "RMSNorm": ([_any(3, 6), _pos(6)], {}, None),
     "rotary_embedding": ([_any(2, 5, 8)], {"interleaved": True}, None),
     "swiglu": ([_any(3, 4), _any(3, 4)], {}, None),
+    "gated_short_conv": ([_any(2, 5, 6), _any(2, 3)], {}, None),
     "GroupNorm": ([_any(2, 4, 3), _pos(4), _any(4)],
                   {"num_groups": 2}, None),
     "InstanceNorm": ([_any(2, 3, 4), _pos(3), _any(3)], {}, None),
@@ -346,6 +347,7 @@ F32_INTERNAL_TOL = {
     "RMSNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "rotary_embedding": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "swiglu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "gated_short_conv": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
 }
 
 # differentiable in the registry but excluded from the numeric sweep,

@@ -10,7 +10,8 @@ line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
 kernels' calls a step, the branch each traced attention forward and backward
 took (``flash_fwd_branches``, ``flash_bwd_branches``) and the heads a grid
-step of each traced flash kernel takes (``flash_heads_per_step``), the tuning
+step of each traced flash kernel takes (``flash_heads_per_step``), the branch
+each traced gated short convolution took (``gated_conv_branches``), the tuning
 table's entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
@@ -34,10 +35,11 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 import run as bench  # noqa: E402 — benchmark/run.py
 
 # further single scopes quoted in PERF.md: latent and grouped-query attention,
-# the indexer and the expert layer
+# the indexer, the expert layer and the gated short convolution
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
          "moe", "router", "dispatch", "experts", "combine", "shared",
-         "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select")
+         "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select",
+         "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj")
 
 
 class Context(bench.Context):
@@ -48,6 +50,7 @@ class Context(bench.Context):
     flash_fwd = None
     flash_bwd = None
     flash_heads = None
+    gated_conv = None
     tuned = None
     moe = None
     selection = None
@@ -78,6 +81,7 @@ class Context(bench.Context):
         Context.flash_fwd = telemetry.flash_fwd_branches()
         Context.flash_bwd = telemetry.flash_bwd_branches()
         Context.flash_heads = telemetry.flash_heads_per_step()
+        Context.gated_conv = telemetry.gated_conv_branches()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
         Context.selection = telemetry.selection_counts()
@@ -124,6 +128,8 @@ def main(argv):
             row["flash_bwd_branches"] = Context.flash_bwd
         if Context.flash_heads:  # and how many heads a grid step each kernel
             row["flash_heads_per_step"] = Context.flash_heads
+        if Context.gated_conv:  # and which path each gated short convolution
+            row["gated_conv_branches"] = Context.gated_conv
         if Context.tuned:  # the tiles each kernel shape ran with
             row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
