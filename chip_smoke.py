@@ -253,6 +253,43 @@ def phase_kernels(args, dev):
         check(max(errs) < 2e-2, "flash backward %s: rel errs %s vs the reference's "
               "gradient" % (name, errs))
 
+    # -- the fused projection in place (flash_attention_qkv's kernels): output
+    #    and the one dqkv cotangent against the reference on turned operands,
+    #    at the BERT cells' shapes and the rule's cut of a grid step
+    out["flash_in_place"] = {}
+    in_place_cases = [("s128_bias", (2, 128, 2, 64), True), ("s256", (1, 256, 2, 64), False)] \
+        if args.rehearse else [("bert_s128_bias", (128, 128, 12, 64), True),
+                               ("bert_s512", (32, 512, 12, 64), False)]
+    for name, (B, T, H, D), with_bias in in_place_cases:
+        ks = jax.random.split(jax.random.fold_in(key, 100 + len(name)), 2)
+        qkv = jax.random.normal(ks[0], (B, T, 3 * H * D), jnp.bfloat16)
+        do = jax.random.normal(ks[1], (B, T, H * D), jnp.bfloat16)
+        bias = None
+        if with_bias:
+            lens = np.linspace(T // 4, T, B).astype(np.int32)
+            bias = A.make_padding_bias(jnp.asarray(lens), max_len=T)
+        sm = 1.0 / math.sqrt(D)
+        fwd = jax.jit(lambda x, b: A._qkv_forward_pallas(x, b, H, sm, interpret=interp))
+        bwd = jax.jit(lambda x, b, o, l, d: A._qkv_backward_pallas(
+            x, b, o, l, d, H, sm, interpret=interp)[0])
+
+        def turned(x, b):
+            q, k, v = A._heads_major(x.astype(jnp.float32), H, D)
+            o = A._attention_reference(q, k, v, b, False, sm)
+            return jnp.reshape(jnp.transpose(o, (0, 2, 1, 3)), (B, T, -1))
+
+        with jax.default_matmul_precision("highest"):
+            want, vjp = jax.vjp(lambda x: turned(x, bias), qkv)
+            want_g = vjp(do.astype(jnp.float32))[0]
+        got, lse = fwd(qkv, bias)
+        errs = [rel_err(got, want), rel_err(bwd(qkv, bias, got, lse, do), want_g)]
+        out["flash_in_place"][name] = dict(
+            shape=(B, T, H, D), rows=A._in_place_rows(B, T), rel_err=errs,
+            kernel_ms=median_ms(lambda x, b: fwd(x, b)[0], qkv, bias),
+            bwd_kernel_ms=median_ms(bwd, qkv, bias, got, lse, do))
+        check(max(errs) < 2e-2, "in-place flash %s: rel errs %s vs the reference"
+              % (name, errs))
+
     # -- the learned selection: the indexer's kernel against lax.top_k's set
     #    (the XLA path: the same float32 scores, a full sort), then grouped
     #    heads under that mask through both flash kernels against the

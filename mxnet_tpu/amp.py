@@ -28,7 +28,7 @@ __all__ = ["init", "init_trainer", "scale_loss", "unscale", "LossScaler",
 # TARGET_DTYPE_OPS — conv/FC/dot family)
 TARGET_DTYPE_OPS = [
     "Convolution", "Deconvolution", "FullyConnected", "dot", "batch_dot",
-    "flash_attention", "RNN",
+    "flash_attention", "flash_attention_qkv", "RNN",
 ]
 
 # numerically sensitive: force float32 compute (ref: FP32_FUNCS)

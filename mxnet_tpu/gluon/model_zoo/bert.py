@@ -3,7 +3,8 @@ BERT in the separate gluon-nlp repo built on these same mxnet primitives —
 bert_12_768_12 config. SURVEY §7 P8).
 
 TPU-native choices: multi-head attention runs through the fused Pallas
-flash-attention op (ops/attention.py) instead of batch_dot+softmax, the
+flash-attention op (ops/attention.py; fed from the fused QKV projection in
+place, ``flash_attention_qkv``) instead of batch_dot+softmax, the
 whole encoder hybridizes into one XLA program, and shapes are static —
 padding is handled by an additive attention bias from valid_length.
 """
@@ -21,7 +22,7 @@ __all__ = ["tensor_parallel_rules",
 
 
 class BERTSelfAttention(HybridBlock):
-    """Fused-QKV multi-head self-attention over flash_attention.
+    """Fused-QKV multi-head self-attention over flash_attention_qkv.
     ``causal=True`` turns it into decoder-style masked attention (used
     by the GPT zoo model)."""
 
@@ -42,19 +43,14 @@ class BERTSelfAttention(HybridBlock):
             self.dropout = nn.Dropout(dropout) if dropout else None
 
     def hybrid_forward(self, F, x, bias=None):
-        H = self._num_heads
-        D = self._units // H
-        qkv = self.qkv(x)  # (B, T, 3C)
-        # shape-free (0 copies the input dim): stays traceable as a Symbol
-        qkv = F.reshape(qkv, shape=(0, 0, 3, H, D))
-        q, k, v = F.split(qkv, num_outputs=3, axis=2, squeeze_axis=True)
-        q = F.transpose(q, axes=(0, 2, 1, 3))  # (B, H, T, D)
-        k = F.transpose(k, axes=(0, 2, 1, 3))
-        v = F.transpose(v, axes=(0, 2, 1, 3))
-        out = F.flash_attention(q, k, v, bias, causal=self._causal,
-                                sm_scale=1.0 / math.sqrt(D))
-        out = F.transpose(out, axes=(0, 2, 1, 3))  # (B, T, H, D)
-        out = F.reshape(out, shape=(0, 0, -1))
+        D = self._units // self._num_heads
+        # the fused projection as it lies, (B, T, 3C), into the kernels and
+        # (B, T, C) out of them: no (B, T, H, D) <-> (B, H, T, D) copy where
+        # a head is one plain tile (ops/attention.py:flash_attention_qkv)
+        out = F.flash_attention_qkv(self.qkv(x), bias,
+                                    num_heads=self._num_heads,
+                                    causal=self._causal,
+                                    sm_scale=1.0 / math.sqrt(D))
         out = self.proj(out)
         if self.dropout is not None:
             out = self.dropout(out)

@@ -49,6 +49,15 @@ fixed function of the shape, counted a traced call
 (``telemetry.flash_heads_per_step()``); every call that does not qualify
 (a mask, ``causal``, grouped heads, several blocks a head, a padded length,
 a grid of under 256 steps) lowers to the one-head program, letter for letter.
+
+The fused projection in place: ``flash_attention_qkv`` takes (B, T, 3 x H x
+D) as one matmul wrote it and returns (B, T, H x D). Where a head is one
+plain tile (the rule above, and whole 128-lane blocks of whole heads:
+``_in_place``) two further kernels under the same two names read that array
+where it lies and write what the next matmul reads, the backward ONE
+cotangent for it: no (B, T, H, D) <-> (B, H, T, D) copy. Every other call
+turns the operands and is ``flash_attention``'s. Which a traced call takes
+is counted (``telemetry.flash_layouts()``).
 """
 from __future__ import annotations
 
@@ -113,6 +122,13 @@ def _tuned_config(q, k, v, bias, causal, sm_scale, mask=None):
     never taken for a masked one). The returned dict carries the
     XLA-vs-Pallas choice per shape; the device gate (context.on_tpu) still
     applies on top."""
+    return _tuned_config_at(q.shape, k.shape[2], k.shape[1], str(q.dtype),
+                            causal, (q, k, v, bias, sm_scale), mask)
+
+
+def _tuned_config_at(q_shape, kv_len, kv_heads, dtype, causal, arrays, mask):
+    """``_tuned_config`` by the call's (B, H, Tq, D) shape; ``arrays`` (q, k,
+    v, bias, sm_scale) only where the table may measure on them."""
     if str(_config.get("MXT_TUNE_MODE")).lower() == "off" \
             or blocks_pinned():
         bq, bk = default_blocks()
@@ -121,8 +137,10 @@ def _tuned_config(q, k, v, bias, causal, sm_scale, mask=None):
     from .. import tuning
 
     return tuning.resolve_attention(
-        q.shape, k.shape[2], str(q.dtype), causal,
-        arrays=(q, k, v, bias, sm_scale), kv_heads=k.shape[1], mask=mask)
+        q_shape, kv_len, dtype, causal, arrays=arrays, kv_heads=kv_heads,
+        mask=mask)
+
+
 _NEG_INF = -1e30
 # lanes of a vector register: a column is broadcast over them to turn it
 _LSE_LANES = 128
@@ -871,6 +889,349 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
 
 
 # ---------------------------------------------------------------------------
+# The fused projection in place: qkv (B, T, 3 x H x D) in, (B, T, H x D) out
+# ---------------------------------------------------------------------------
+# BERT's block computes q, k and v by ONE matmul, (B, T, 3 x H x D): all of q,
+# then k, then v, head h of each at lanes [h x D, (h + 1) x D). The kernels
+# above want (B, H, T, D), and XLA pays for the difference: eight passes a
+# layer over the step's tokens (q, k, v and dO turned, the output and dq, dk,
+# dv turned back), as much time as the kernels they surround. The two kernels
+# below read that array as it lies and write what the next matmul reads: a
+# grid step holds whole rows of a few batch elements, a head's operands are
+# 128-lane column blocks of them, and at D = 64 a block holds a PAIR of heads
+# that the kernel never slices: a head's scores are (q_pair with the other
+# head's lanes zeroed) @ k_pair^T (the zeroed lanes add exact zeros, and a
+# 64-deep contraction costs the 128-deep MXU a whole pass already), p @ v_pair
+# gives 128 lanes of which the head's own 64 are stored. Engaged only where a
+# head is one plain tile (``_in_place``); everything else turns the
+# operands and calls ``flash_attention``, inside ``flash_attention_qkv``.
+def _own_lanes(u, rows, head_dim):
+    """(rows, 128) booleans, true at the lanes of the ``u % (128 // head_dim)``-th
+    head of a 128-lane block; None where a head is the whole block. ``u`` is a
+    head's place among those in flight, which the lowering knows, so the
+    mask is a constant of the compiled kernel."""
+    per = _LSE_LANES // head_dim
+    if per == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LSE_LANES), 1)
+    lo = jax.lax.rem(u, np.int32(per)) * np.int32(head_dim)
+    return jnp.logical_and(lane >= lo, lane < lo + np.int32(head_dim))
+
+
+def _owned(x, own, factor=None):
+    """An operand with the other head's lanes zeroed (``own`` None: none to
+    zero) and ``factor`` applied in float32, back in its own dtype."""
+    if own is None and factor is None:
+        return x
+    xf = x.astype(jnp.float32)
+    if factor is not None:
+        xf = xf * factor
+    if own is not None:  # the zeroed lanes add exact zeros to a product
+        xf = jnp.where(own, xf, jnp.float32(0))
+    return xf.astype(x.dtype)
+
+
+def _head_blocks(g, heads, head_dim):
+    """Head ``g`` of a grid step's ``rows x heads`` -> (b, h, lanes): its batch
+    element in the step, its head, and ``lanes(third)``, the 128-lane block
+    that holds it in the ``third``-th of q, k, v (0 .. 2) of a ``(.., 3 x heads
+    x head_dim)`` row, or in a ``(.., heads x head_dim)`` row at ``third`` 0."""
+    from jax.experimental import pallas as pl
+
+    i32 = np.int32
+    per = _LSE_LANES // head_dim
+    # lax.div / rem on non-negative i32: jnp's floor_divide does not lower
+    b = jax.lax.div(g, i32(heads))
+    h = jax.lax.rem(g, i32(heads))
+    blk = h if per == 1 else jax.lax.div(h, i32(per))
+
+    def lanes(third):
+        off = (i32(third * (heads // per)) + blk) * i32(_LSE_LANES)
+        return pl.ds(pl.multiple_of(off, _LSE_LANES), _LSE_LANES)
+
+    return b, h, lanes
+
+
+def _store_own(ref, b, lanes, value, own):
+    """``value`` (T, 128) float32 into the 128-lane block ``lanes`` of row
+    block ``b``: whole, or the head's own lanes of a pair's block, the other
+    head's kept as they stand (the chip's compiler takes no masked store of a
+    16-bit type; the pair's first head reads lanes nobody has written and
+    hands them back unread)."""
+    if own is not None:
+        value = jnp.where(own, value, ref[b, :, lanes].astype(value.dtype))
+    ref[b, :, lanes] = value.astype(ref.dtype)
+
+
+def _row_of(col):
+    """(T, 1) column -> (1, T) lane-oriented row: broadcast over the lanes
+    and turned, the idiom of ``lse`` and the bias gradient above."""
+    return jnp.broadcast_to(col, (col.shape[0], _LSE_LANES)).T[:1, :]
+
+
+def _qkv_fwd_kernel(qkv_ref, bias_ref, o_ref, lse_ref, *, heads, head_dim,
+                    sm_scale, unroll):
+    """One grid step of the in-place forward: ``qkv_ref`` holds whole rows
+    ``(rows, T, 3 x heads x head_dim)`` of a few batch elements, and
+    ``_each_head`` walks their ``rows x heads`` heads, each ONE tile: the
+    arithmetic of ``_flash_fwd_kernel``'s lone unmasked block (operands in
+    the input dtype, float32 accumulator and softmax, ``sm_scale`` on the Q
+    block where that is exact), on 128-lane blocks read where the projection
+    wrote them. ``o_ref`` is ``(rows, T, heads x head_dim)``; ``lse_ref``
+    ``(rows x heads, 1, T)``, a lane-oriented row a head; ``bias_ref``
+    ``(rows, heads | 1, 1, T)`` or None."""
+    f32 = jnp.float32
+    T = qkv_ref.shape[1]
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+    fold = math.frexp(sm_scale)[0] == 0.5  # as _flash_fwd_kernel
+
+    def head(g, u):
+        b, h, lanes = _head_blocks(g, heads, head_dim)
+        own = _own_lanes(u, T, head_dim)
+        # (T, 128) each: the head, or its pair
+        q = _owned(qkv_ref[b, :, lanes(0)], own, f32(sm_scale) if fold else None)
+        k = qkv_ref[b, :, lanes(1)]
+        v = qkv_ref[b, :, lanes(2)]
+        s = jax.lax.dot_general(q, k, nt, preferred_element_type=f32)  # (T, T)
+        if not fold:
+            s = s * f32(sm_scale)
+        if bias_ref is not None:  # (1, T), over the rows
+            s = s + bias_ref[b, h if bias_ref.shape[1] > 1 else 0].astype(f32)
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), f32(1e-30))
+        acc = jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
+        _store_own(o_ref, b, lanes(0), acc / l, own)
+        lse_ref[g] = _row_of(m + jnp.log(l))
+
+    _each_head(qkv_ref.shape[0] * heads, unroll, head)
+
+
+def _qkv_bwd_kernel(qkv_ref, o_ref, do_ref, lse_ref, bias_ref, dqkv_ref,
+                    db_ref, *, heads, head_dim, sm_scale, unroll):
+    """One grid step of the in-place backward, over the blocks of
+    ``_qkv_fwd_kernel`` and ``do_ref`` / ``o_ref`` ``(rows, T, heads x
+    head_dim)``: a head's key-major tile as ``_flash_bwd_kernel`` holds it
+    (``s_t[k, q]`` recomputed from ``lse``, five matmuls, float32
+    accumulators), ``delta`` summed here from the head's own lanes of dO x
+    out, and dq, dk, dv stored into the head's lanes of the three thirds of
+    ONE ``(rows, T, 3 x heads x head_dim)`` cotangent. At a pair of heads a
+    block, K and V take the zeroed lanes (and ``sm_scale`` where that is
+    exact: the same bits as scaling the scores); the products that leave
+    through Q, K and dO carry the other head's lanes, which are not
+    stored. ``db_ref`` ``(rows x heads, 1, T)`` float32 or None."""
+    f32 = jnp.float32
+    T = qkv_ref.shape[1]
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+    fold = math.frexp(sm_scale)[0] == 0.5
+    scale = f32(sm_scale)
+
+    def head(g, u):
+        b, h, lanes = _head_blocks(g, heads, head_dim)
+        own = _own_lanes(u, T, head_dim)
+        q = qkv_ref[b, :, lanes(0)]
+        k = qkv_ref[b, :, lanes(1)]
+        v = qkv_ref[b, :, lanes(2)]
+        do = do_ref[b, :, lanes(0)]
+        prod = do.astype(f32) * o_ref[b, :, lanes(0)].astype(f32)
+        if own is not None:
+            prod = jnp.where(own, prod, f32(0))
+        delta = _row_of(jnp.sum(prod, axis=1, keepdims=True))  # (1, T)
+        lse = lse_ref[g]  # (1, T)
+        s_t = jax.lax.dot_general(_owned(k, own, scale if fold else None), q,
+                                  nt, preferred_element_type=f32)  # (Tk, Tq)
+        if not fold:
+            s_t = s_t * scale
+        if bias_ref is not None:
+            # the key bias is a lane-oriented row; a key-major tile wants it
+            # as a column: turn a sublane-broadcast (128, T) tile
+            row = bias_ref[b, h if bias_ref.shape[1] > 1 else 0].astype(f32)
+            s_t = s_t + jnp.broadcast_to(row, (_LSE_LANES, T)).T[:, :1]
+        p_t = jnp.exp(s_t - lse)
+        dv = jnp.dot(p_t.astype(do.dtype), do, preferred_element_type=f32)
+        dp_t = jax.lax.dot_general(_owned(v, own), do, nt,
+                                   preferred_element_type=f32)
+        ds_t = p_t * (dp_t - delta)
+        dk = jnp.dot(ds_t.astype(q.dtype), q, preferred_element_type=f32)
+        dq = jnp.dot(ds_t.T.astype(k.dtype), k, preferred_element_type=f32)
+        _store_own(dqkv_ref, b, lanes(0), dq * scale, own)
+        _store_own(dqkv_ref, b, lanes(1), dk * scale, own)
+        _store_own(dqkv_ref, b, lanes(2), dv, own)
+        if db_ref is not None:
+            db_ref[g] = _row_of(jnp.sum(ds_t, axis=1, keepdims=True))
+
+    _each_head(qkv_ref.shape[0] * heads, unroll, head)
+
+
+def _in_place_vmem_limit(kernel, rows, tokens, width, heads, itemsize, bias):
+    """Scoped VMEM an in-place kernel asks for, by ``_bwd_vmem_limit``'s
+    measure: nothing while what a grid step holds fits three quarters of
+    what the compiler gives unasked, else that and a quarter more. Held: the
+    blocks twice for the pipeline's two buffers (forward: qkv and out, 4 x
+    ``width`` lanes a token; backward: qkv, out, dO and dqkv, 8 x), the
+    ``lse`` rows (a (1, T) float32 block pads to 8 sublanes), the bias rows
+    and their gradient's, and six float32 (T, T) tiles. 128 tokens x 768:
+    0.39 MB a batch element forward, 0.79 backward, twice; 512 tokens: 12.9
+    MB forward (16.1 asked for), 19.2 backward (24.0), of the chip's 128."""
+    thirds = 4 if kernel == "fwd" else 8
+    held = 2 * rows * tokens * thirds * _lanes(width) * itemsize \
+        + 2 * rows * heads * 8 * tokens * 4 + 6 * tokens * tokens * 4
+    if bias is not None:
+        held += 2 * rows * bias.shape[1] * 8 * tokens * bias.dtype.itemsize
+        if kernel == "bwd":
+            held += 2 * rows * heads * 8 * tokens * 4
+    if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
+        return None
+    return held + held // 4
+
+
+# Tokens (batch elements x T) a grid step of an in-place kernel holds, whole
+# rows of them: a step's blocks are contiguous in HBM however it is cut, and
+# the cut hardly shows. Device ms a call by the host's clock over 200 calls,
+# forward / backward, bf16, 12 heads of 64, TPU v5e (my chip run, PR 38, call
+# 2), by batch elements a step and heads in flight U:
+#   128 tokens x 128 sequences: 1 at U 2 0.377 / 0.565; 2 0.370 / 0.562; 4
+#   0.365 / 0.564; 8 0.367 / 0.572; at U 4, 2 a step 0.365 / 0.491 (PR 36's
+#   finding again: four in flight are faster and cost a start its 2 s).
+#   512 tokens x 32: 1 at U 2 0.360 / 0.744; 2 0.391 / 0.752; U 4 0.379 / 0.733.
+# The heads-major kernels with the copies XLA makes around them, same clock:
+# 0.865 forward, 2.234 forward + backward at 128 tokens (in place 0.918);
+# 0.845 / 2.422 at 512 (in place 1.089). The forward's output is theirs to the
+# last bit, the gradient within one place of bf16.
+_IN_PLACE_TOKENS = 256
+
+
+def _in_place_rows(batch, tokens):
+    """Batch elements a grid step of either in-place kernel holds: the
+    largest power of two that divides the batch and carries
+    ``_IN_PLACE_TOKENS`` tokens or fewer, at least one: 2 at 128 tokens, 1 at
+    256 and 512."""
+    rows = max(1, _IN_PLACE_TOKENS // tokens)
+    rows = 1 << (rows.bit_length() - 1)
+    while batch % rows:
+        rows //= 2
+    return rows
+
+
+def _in_place_call(kernel, qkv, heads, bias, rows):
+    """What both in-place ``pallas_call``s share: the grid, the heads in
+    flight, the block specs of whole rows, the compiler's parameters."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, width3 = qkv.shape
+    width = width3 // 3
+    rows = rows or _in_place_rows(B, T)
+    per = _LSE_LANES // (width // heads)
+    in_flight = next((u for t, u in sorted(_HEADS_IN_FLIGHT.items())
+                      if T <= t), 2)
+    # a trip of the loop holds whole 128-lane blocks, so that a head's place
+    # in the trip says which lanes of its block are its own
+    unroll = math.gcd(rows * heads, max(in_flight, per))
+    _telemetry.record_flash_heads(kernel, rows * heads)
+    # np.int32 zeros in the index maps, as the kernels above (x64 is on)
+    z = np.int32(0)
+
+    def whole(*minor):
+        return pl.BlockSpec(
+            (rows,) + minor, lambda i: (i,) + (z,) * len(minor),
+            memory_space=pltpu.VMEM)
+
+    # a (1, T) row a head: lse, the bias gradient
+    head_rows = pl.BlockSpec((rows * heads, 1, T), lambda i: (i, z, z),
+                             memory_space=pltpu.VMEM)
+
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=_in_place_vmem_limit(
+            kernel, rows, T, width, heads, qkv.dtype.itemsize, bias))
+    return B // rows, unroll, whole, head_rows, params
+
+
+def _qkv_forward_pallas(qkv, bias, heads, sm_scale, interpret, rows=None):
+    """(out, lse) of the in-place forward kernel: ``qkv`` (B, T, 3 x H x D)
+    as the fused projection wrote it, ``out`` (B, T, H x D) as the output
+    projection reads it, ``lse`` (B x H, 1, T) float32, the layout the
+    backward kernel takes. ``bias`` (B, H | 1, 1, T) or None. ``rows``
+    overrides the grid step's cut, for tests and stand-alone sweeps."""
+    from jax.experimental import pallas as pl
+
+    B, T, width3 = qkv.shape
+    width = width3 // 3
+    grid, unroll, whole, head_rows, params = _in_place_call(
+        "fwd", qkv, heads, bias, rows)
+    in_specs, args = [whole(T, width3)], [qkv]
+    if bias is not None:
+        in_specs.append(whole(bias.shape[1], 1, T))
+        args.append(bias)
+
+    def kernel(*refs):
+        refs = list(refs)
+        if bias is None:
+            refs.insert(1, None)
+        _qkv_fwd_kernel(*refs, heads=heads, head_dim=width // heads,
+                        sm_scale=sm_scale, unroll=unroll)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(grid,),
+        in_specs=in_specs,
+        out_specs=[whole(T, width), head_rows],
+        out_shape=[jax.ShapeDtypeStruct((B, T, width), qkv.dtype),
+                   jax.ShapeDtypeStruct((B * heads, 1, T), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_attention_fwd",
+    )(*args)
+
+
+def _qkv_backward_pallas(qkv, bias, out, lse, do, heads, sm_scale, interpret,
+                         rows=None):
+    """(dqkv, dbias) of the in-place backward kernel from the forward's
+    residuals: ONE (B, T, 3 x H x D) cotangent, written by the kernel where
+    the projection's backward reads it."""
+    from jax.experimental import pallas as pl
+
+    B, T, width3 = qkv.shape
+    width = width3 // 3
+    grid, unroll, whole, head_rows, params = _in_place_call(
+        "bwd", qkv, heads, bias, rows)
+    in_specs = [whole(T, width3), whole(T, width), whole(T, width),
+                head_rows]
+    args = [qkv, out, do, lse]
+    out_specs = [whole(T, width3)]
+    out_shape = [jax.ShapeDtypeStruct(qkv.shape, qkv.dtype)]
+    if bias is not None:
+        in_specs.append(whole(bias.shape[1], 1, T))
+        args.append(bias)
+        out_specs.append(head_rows)
+        out_shape.append(jax.ShapeDtypeStruct((B * heads, 1, T), jnp.float32))
+
+    def kernel(*refs):
+        refs = list(refs)
+        if bias is None:  # no bias in, no dbias out
+            refs.insert(4, None)
+            refs.append(None)
+        _qkv_bwd_kernel(*refs, heads=heads, head_dim=width // heads,
+                        sm_scale=sm_scale, unroll=unroll)
+
+    outs = pl.pallas_call(
+        kernel,
+        grid=(grid,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )(*args)
+    dbias = None
+    if bias is not None:
+        dbias = _reduce_dbias(outs[1].reshape(B, heads, 1, T), bias)
+    return outs[0], dbias
+
+
+# ---------------------------------------------------------------------------
 # chunked-XLA path for long sequences (K/V too big for whole-sequence VMEM
 # residency; lax.scan streams KV chunks with the same online softmax —
 # O(Tq * chunk) memory, fused by XLA)
@@ -1051,6 +1412,7 @@ def _flash_fwd(q, k, v, bias, mask, causal, sm_scale):
     """Forward of ``_flash_core``. The branch it takes is counted
     (``telemetry.flash_fwd_branches()``), once a trace as the backward's."""
     _record_flash_signature(q, k, v, bias, mask, causal, sm_scale)
+    _telemetry.record_flash_layout("fwd", "heads_major")
     if not _kv_fits_vmem(k, v):
         _telemetry.record_flash_fwd("scan")
         out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
@@ -1127,6 +1489,7 @@ def _flash_bwd(causal, sm_scale, res, do):
     q, k, v, bias, mask, out, lse = res
     B, H, Tq, _ = q.shape
     kv_heads, Tk = k.shape[1], k.shape[2]
+    _telemetry.record_flash_layout("bwd", "heads_major")
     if (lse is not None and on_tpu() and _kv_fits_vmem(k, v)
             and _qdo_fits_vmem(q, v)):
         # the forward kernel ran (its lse is here, its K/V fit VMEM) and a
@@ -1254,6 +1617,120 @@ def flash_attention(query, key, value, bias=None, mask=None, causal=False,
         return out
     return _flash_core(query, key, value, bias, mask, bool(causal),
                        float(sm_scale))
+
+
+# ---------------------------------------------------------------------------
+# flash_attention_qkv: attention fed from the fused projection
+# ---------------------------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _qkv_core(qkv, bias, heads, sm_scale):
+    return _qkv_fwd(qkv, bias, heads, sm_scale)[0]
+
+
+@jax.named_scope("attention")
+def _qkv_fwd(qkv, bias, heads, sm_scale):
+    """Forward of ``_qkv_core``: the in-place kernel, counted as the kernel
+    branch it is. The residuals are ``qkv`` itself (in place of its three
+    turned copies), ``out`` and ``lse``."""
+    _telemetry.record_flash_fwd("kernel")
+    _telemetry.record_flash_layout("fwd", "in_place")
+    out, lse = _qkv_forward_pallas(qkv, bias, heads, sm_scale, interpret=False)
+    return out, (qkv, bias, out, lse)
+
+
+@jax.named_scope("attention_bwd")
+def _qkv_bwd(heads, sm_scale, res, do):
+    """Backward of ``_qkv_core``: one cotangent for ``qkv``, written by the
+    kernel."""
+    qkv, bias, out, lse = res
+    _telemetry.record_flash_bwd("kernel")
+    _telemetry.record_flash_layout("bwd", "in_place")
+    return _qkv_backward_pallas(qkv, bias, out, lse, do, heads, sm_scale,
+                                interpret=False)
+
+
+_qkv_core.defvjp(_qkv_fwd, _qkv_bwd)
+
+
+def _in_place(qkv, heads, head_dim, bias, causal, sm_scale):
+    """Whether a ``flash_attention_qkv`` call runs the in-place kernels: on
+    a TPU, outside a sequence scope, where a head is one plain tile by the
+    rule ``_heads_per_step`` states (no ``causal``; ``T`` whole 128-lane
+    lengths and one block on both axes, in the forward by the tuning table's
+    choice for the shape and in the backward by ``_bwd_blocks``; a grid of
+    ``_HEAD_MIN_GRID`` steps or more at one head a step) and whole 128-lane
+    blocks hold whole heads: ``head_dim`` 128, or 64 with an even head
+    count. The table is asked as ``_flash_fwd`` asks it, and has to say
+    ``pallas``."""
+    from ..parallel.sequence import current_sequence_scope
+
+    B, T, _ = qkv.shape
+    if (causal or not on_tpu() or current_sequence_scope() is not None
+            or not (head_dim == _LSE_LANES
+                    or (2 * head_dim == _LSE_LANES and heads % 2 == 0))
+            or T % _LSE_LANES or B * heads < _HEAD_MIN_GRID
+            or _bwd_blocks(T, T) != (T, T)
+            or (bias is not None and (
+                bias.ndim != 4 or bias.shape[0] != B or bias.shape[1] not in
+                (1, heads) or bias.shape[2:] != (1, T)))):
+        return False
+    # live arrays only where the table may measure on them (an eager call)
+    arrays = None
+    if not isinstance(qkv, jax.core.Tracer):
+        arrays = _heads_major(qkv, heads, head_dim) + (bias, sm_scale)
+    cfg = _tuned_config_at((B, heads, T, head_dim), T, heads, str(qkv.dtype),
+                           False, arrays, None)
+    return (cfg.get("backend") == "pallas"
+            and min(int(cfg["block_q"]), int(cfg["block_k"])) >= T)
+
+
+def _heads_major(qkv, heads, head_dim):
+    """(B, T, 3 x H x D) -> q, k, v, each (B, H, T, D): the reshape, split
+    and transposes ``BERTSelfAttention`` made before this operator was."""
+    B, T, _ = qkv.shape
+    parts = jnp.split(jnp.reshape(qkv, (B, T, 3, heads, head_dim)), 3, axis=2)
+    parts = [jnp.squeeze(x, axis=2) for x in parts]
+    return tuple(jnp.transpose(x, (0, 2, 1, 3)) for x in parts)
+
+
+@register("flash_attention_qkv", aliases=("_contrib_flash_attention_qkv",))
+def flash_attention_qkv(qkv, bias=None, num_heads=None, causal=False,
+                        sm_scale=None):
+    """Self-attention fed from a fused QKV projection. qkv: (B, T, 3 x H x
+    D) with ``H = num_heads``: all of q, then k, then v, head ``h`` of each
+    at ``[h x D, (h + 1) x D)`` (what ``reshape(qkv, (0, 0, 3, H, D))``
+    splits); bias: optional additive (B, H|1, 1, T) key bias. Returns (B, T,
+    H x D), heads side by side: what an output projection reads. The TPU
+    form of the reference's ``_contrib_interleaved_matmul_selfatt_qk`` /
+    ``_valatt`` pair, as one fused kernel each way.
+
+    Array inputs come first and ``num_heads`` is a keyword, as for every
+    registered op (a Symbol's positional inputs are Symbols):
+    ``F.flash_attention_qkv(qkv, bias, num_heads=H)``.
+
+    Where a head is one plain tile (``_in_place``: BERT's training shapes)
+    both flash kernels read ``qkv`` where the projection wrote it and write
+    the result, and in the backward ONE cotangent for ``qkv``, where the next
+    matmul reads it: no copy of q, k, v, the output or their gradients is
+    made. Every other call (``causal``, an odd head count at 64, a length
+    that is not whole lanes, a short grid, any CPU run, a sequence scope)
+    turns the operands to (B, H, T, D) and is :func:`flash_attention`'s call
+    on them. Which of the two a traced call takes is counted
+    (``telemetry.flash_layouts()``)."""
+    if not num_heads or qkv.ndim != 3 or qkv.shape[2] % (3 * num_heads):
+        raise MXNetError("flash_attention_qkv: qkv %s is not (B, T, 3 x "
+                         "num_heads x D) at num_heads=%r"
+                         % (tuple(qkv.shape), num_heads))
+    heads = int(num_heads)
+    head_dim = qkv.shape[2] // (3 * heads)
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(head_dim))
+    if _in_place(qkv, heads, head_dim, bias, causal, float(sm_scale)):
+        return _qkv_core(qkv, bias, heads, float(sm_scale))
+    q, k, v = _heads_major(qkv, heads, head_dim)
+    out = flash_attention(q, k, v, bias, causal=causal, sm_scale=sm_scale)
+    B, T = qkv.shape[:2]
+    return jnp.reshape(jnp.transpose(out, (0, 2, 1, 3)), (B, T, -1))
 
 
 @register("attention_padding_bias", differentiable=False)

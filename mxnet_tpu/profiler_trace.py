@@ -337,7 +337,8 @@ def aggregate(trace, depth=2, window=None, top=20):
     ``window`` names a host span (a ``TraceAnnotation``): operations are
     clipped to its extent on the uncorrected clocks, as the benchmark's
     reduction clips them to ``bench.trace_window``. ``depth`` is how many
-    scopes deep ``scope_s`` goes, ``top`` how many operations ``ops`` lists.
+    scopes deep ``scope_s`` goes, ``top`` how many operations ``ops`` lists
+    (None: all of them).
 
     Returns None where the trace holds no device operations, else a dict:
     ``devices``, ``busy_s`` (union of the operations' intervals), ``window_s``,
@@ -348,7 +349,7 @@ def aggregate(trace, depth=2, window=None, top=20):
     number taken off: ``conv2d``, ``batchnorm_bwd``, ``dense``), ``kernel_s`` /
     ``kernel_calls`` (by ``pallas_call`` name), ``category_s`` (by
     ``hlo_category``), ``ops`` (the operations that took most, each with its
-    scope path, category, phase, seconds and calls), ``flops`` and
+    scope path, category, phase, result shape, seconds and calls), ``flops`` and
     ``bytes_accessed`` (as XLA's cost model wrote them, summed over the
     operations run), ``clock_offset_us``, ``launch_pairs`` and ``idle_gaps``
     (see ``_clock``)."""
@@ -418,6 +419,7 @@ def aggregate(trace, depth=2, window=None, top=20):
            "kernel_calls": {k: v / devices for k, v in tables["calls"].items()},
            "category_s": sec("category"),
            "ops": [{"name": k[0], "scope": k[1], "category": k[2], "phase": k[3],
+                    "shape": k[4],
                     "seconds": v[0] / devices / 1e12, "calls": v[1] / devices}
                    for k, v in ops],
            "flops": flops / devices, "bytes_accessed": nbytes / devices}
@@ -426,8 +428,9 @@ def aggregate(trace, depth=2, window=None, top=20):
 
 
 def _describe(meta, depth):
-    """An operation's row in ``ops`` (name, scope path, category, phase) and
-    the (table, key) pairs its time is added under; a kernel's pair is last."""
+    """An operation's row in ``ops`` (name, scope path, category, phase, the
+    result's shape with its layout) and the (table, key) pairs its time is
+    added under; a kernel's pair is last."""
     tf_op = meta.get("tf_op") or ""
     own = (meta.get("display_name")
            or meta.get("name", "").partition(" = ")[0]).lstrip("%")
@@ -441,7 +444,8 @@ def _describe(meta, depth):
     kernel = kernel_name(meta)
     if kernel:
         keys.append(("kernel", kernel))
-    return (own, "/".join(scopes), category, phase), keys
+    return (own, "/".join(scopes), category, phase,
+            meta.get("shape_with_layout") or ""), keys
 
 
 def _number(value):

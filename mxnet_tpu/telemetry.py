@@ -73,6 +73,7 @@ __all__ = [
     "record_flash_bwd", "flash_bwd_branches",
     "record_gated_conv", "gated_conv_branches",
     "record_flash_heads", "flash_heads_per_step",
+    "record_flash_layout", "flash_layouts",
     "record_moe_counts", "moe_counts",
     "record_selection_counts", "selection_counts",
     "trace_scope", "current_trace_id", "new_trace_id", "new_span_id",
@@ -855,14 +856,38 @@ def record_flash_heads(kernel, heads):
             ("kernel", "heads")).labels(kernel, str(int(heads))).inc()
 
 
-def flash_heads_per_step():
-    """{kernel: {heads: traces}} of :func:`record_flash_heads` so far."""
-    fam = _REGISTRY.get("mxt_flash_heads_per_step")
+def _by_kernel(family):
+    """{kernel: {second label: count}} of a counter family whose labels are
+    ``kernel`` and one more."""
+    fam = _REGISTRY.get(family)
     out = {}
     if fam is not None:
-        for (kernel, heads), ch in sorted(fam.children().items()):
-            out.setdefault(kernel, {})[heads] = int(ch.value)
+        for (kernel, label), ch in sorted(fam.children().items()):
+            out.setdefault(kernel, {})[label] = int(ch.value)
     return out
+
+
+def flash_heads_per_step():
+    """{kernel: {heads: traces}} of :func:`record_flash_heads` so far."""
+    return _by_kernel("mxt_flash_heads_per_step")
+
+
+def record_flash_layout(kernel, layout):
+    """One traced flash-attention pass (``kernel``: ``fwd`` or ``bwd``) by
+    the layout its operands lie in (``mxt_flash_layout_total{kernel,
+    layout=in_place|heads_major}``): ``in_place`` where ``flash_attention_qkv``
+    reads the fused projection as it lies, ``heads_major`` for every
+    ``flash_attention`` call on (B, H, T, D) operands, a fallen-back
+    ``flash_attention_qkv`` among them. Counted at trace time, as the
+    branches: nothing enters the compiled step."""
+    counter("mxt_flash_layout_total",
+            "Traced flash-attention passes by operand layout.",
+            ("kernel", "layout")).labels(kernel, layout).inc()
+
+
+def flash_layouts():
+    """{kernel: {layout: traces}} of :func:`record_flash_layout` so far."""
+    return _by_kernel("mxt_flash_layout_total")
 
 
 def record_gated_conv(branch):

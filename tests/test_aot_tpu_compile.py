@@ -134,6 +134,23 @@ def _flash_bwd(shape, dtype, causal, bias_shape=None, tk=None, blocks=None,
     return case
 
 
+def _in_place(batch, tokens, heads, dim, backward, bias=False):
+    """An in-place flash kernel (``flash_attention_qkv``'s) at the cut of a
+    grid step the rule gives the shape."""
+    def case(chip):
+        dt, width = jnp.dtype("bfloat16"), heads * dim
+        qkv, out = ((batch, tokens, 3 * width), dt), ((batch, tokens, width), dt)
+        b = (((batch, 1, 1, tokens), jnp.float32),) if bias else ()
+        if not backward:
+            return _compile(chip, lambda x, *b: A._qkv_forward_pallas(
+                x, b[0] if b else None, heads, dim ** -0.5, False), qkv, *b)
+        return _compile(
+            chip, lambda x, o, l, do, *b: A._qkv_backward_pallas(
+                x, b[0] if b else None, o, l, do, heads, dim ** -0.5, False),
+            qkv, out, ((batch * heads, 1, tokens), jnp.float32), out, *b)
+    return case
+
+
 def _paged(batch, heads, dim, block_h=None, dtype="bfloat16", page=16,
            max_pages=64):
     def case(chip):
@@ -227,6 +244,18 @@ _CASES = {
     # operand (a 64-wide head fills half the lanes: VMEM holds it at 128)
     "flash_lfm2_8192_gqa_d64": _flash_selected(False, (1, 32, 8192, 64), 8, False),
     "flash_bwd_lfm2_8192_gqa_d64": _flash_selected(True, (1, 32, 8192, 64), 8, False),
+    # the BERT cells through flash_attention_qkv: the fused projection read
+    # in place, whole rows of (128, 128, 2304) two batch elements a grid step
+    # and of (32, 512, 2304) one (the backward asks for 24 MB of scoped VMEM),
+    # a padding bias, a length between them, a 128-wide head
+    "flash_in_place_s128": _in_place(128, 128, 12, 64, False),
+    "flash_bwd_in_place_s128": _in_place(128, 128, 12, 64, True),
+    "flash_in_place_s512": _in_place(32, 512, 12, 64, False),
+    "flash_bwd_in_place_s512": _in_place(32, 512, 12, 64, True),
+    "flash_in_place_s128_bias": _in_place(128, 128, 12, 64, False, bias=True),
+    "flash_bwd_in_place_s512_bias": _in_place(32, 512, 12, 64, True, bias=True),
+    "flash_bwd_in_place_s256": _in_place(64, 256, 12, 64, True),
+    "flash_bwd_in_place_d128": _in_place(64, 128, 6, 128, True, bias=True),
     "indexer_select_8192": _indexer(1, 8192),
     "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
@@ -259,7 +288,10 @@ _HEADS = {"flash_bert_cell_s128": ("fwd", "16"), "flash_bwd_bert_cell_s128": ("b
           "flash_bert_eager_s128": ("fwd", "1"), "flash_mla_4096_k192_v128": ("fwd", "1"),
           "flash_bwd_mla_4096_k192_v128": ("bwd", "1"),
           "flash_lfm2_8192_gqa_d64": ("fwd", "1"),
-          "flash_bwd_lfm2_8192_gqa_d64": ("bwd", "1")}
+          "flash_bwd_lfm2_8192_gqa_d64": ("bwd", "1"),
+          # in place a step holds whole batch elements: 2 x 12 heads, 1 x 12
+          "flash_in_place_s128": ("fwd", "24"), "flash_bwd_in_place_s128": ("bwd", "24"),
+          "flash_in_place_s512": ("fwd", "12"), "flash_bwd_in_place_s512": ("bwd", "12")}
 
 
 @pytest.mark.parametrize("name", sorted(_CASES))

@@ -817,3 +817,386 @@ def test_forced_heads_want_a_plain_tile():
     with pytest.raises(mx.base.MXNetError):  # 3 does not divide 8
         A._flash_forward_pallas(q, q, q, None, False, 0.25, 128, 128, True,
                                 heads_per_step=3)
+
+
+# -- the fused projection in place ------------------------------------------------
+def _cell_rows(shape):
+    """Batch elements a grid step of the in-place kernels holds in the
+    benchmark's cell of this length (``_HEADS_SHAPES``'s key)."""
+    return A._in_place_rows({"s128": 128, "s512": 32}[shape],
+                            _HEADS_SHAPES[shape][2])
+
+
+_IN_PLACE_CASES = {
+    # (B, T, H, D, rows a grid step forward / backward)
+    "d64_h2_t128": (2, 128, 2, 64, (1, 1)),
+    "d64_h4_t128": (4, 128, 4, 64, (2, 4)),
+    "d64_h12_t128_cell": (4, 128, 12, 64, "s128"),
+    "d64_h4_t256": (2, 256, 4, 64, (2, 1)),
+    "d64_h12_t512_cell": (2, 512, 12, 64, "s512"),
+    "d128_h3_t128": (2, 128, 3, 128, (1, 2)),
+}
+
+
+def _qkv_reference(qkv, do, bias, heads, sm):
+    """out, lse and the cotangents of ``qkv`` and the bias by the plain
+    formula on the turned operands, in float32."""
+    f32 = jnp.float32
+    B, T, width3 = qkv.shape
+    D = width3 // 3 // heads
+
+    def fn(x, b):
+        q, k, v = A._heads_major(x, heads, D)
+        out = A._attention_reference(q, k, v, b, False, sm)
+        return jnp.reshape(jnp.transpose(out, (0, 2, 1, 3)), (B, T, -1))
+
+    q, k, _ = A._heads_major(qkv.astype(f32), heads, D)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * sm
+    if bias is not None:
+        s = s + bias
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    out, vjp = jax.vjp(fn, qkv.astype(f32), bias)
+    return (out, lse) + tuple(vjp(do.astype(f32)))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("case", sorted(_IN_PLACE_CASES))
+def test_in_place_kernels_vs_reference(case, with_bias, dtype, tol):
+    """Both in-place kernels (interpret mode) against the reference on the
+    turned operands: the output where the output projection reads it, lse,
+    and the ONE cotangent of the fused projection, at a pair of 64-wide
+    heads a 128-lane block (2, 4 and 12 heads) and at a 128-wide head a
+    block, with and without a key bias, at the cells' own cut of a grid
+    step."""
+    B, T, H, D, rows = _IN_PLACE_CASES[case]
+    if isinstance(rows, str):
+        rows = (_cell_rows(rows), _cell_rows(rows))
+        assert B % rows[0] == 0 and B % rows[1] == 0
+    rng = np.random.RandomState(13)
+    qkv = jnp.asarray(rng.normal(size=(B, T, 3 * H * D)).astype("f4")).astype(dtype)
+    do = jnp.asarray(rng.normal(size=(B, T, H * D)).astype("f4")).astype(dtype)
+    bias = A.make_padding_bias(jnp.asarray([T - 43] + [T] * (B - 1)), T) \
+        if with_bias else None
+    sm = D ** -0.5
+    ref_out, ref_lse, ref_dqkv, ref_db = _qkv_reference(qkv, do, bias, H, sm)
+    out, lse = A._qkv_forward_pallas(qkv, bias, H, sm, interpret=True,
+                                     rows=rows[0])
+    assert out.shape == (B, T, H * D) and out.dtype == qkv.dtype
+    assert lse.shape == (B * H, 1, T) and lse.dtype == jnp.float32
+    assert_almost_equal(np.asarray(out.astype(jnp.float32)), np.asarray(ref_out),
+                        rtol=tol, atol=tol)
+    assert_almost_equal(np.asarray(lse).reshape(B, H, T), np.asarray(ref_lse),
+                        rtol=tol, atol=tol)
+    dqkv, db = A._qkv_backward_pallas(qkv, bias, out, lse, do, H, sm,
+                                      interpret=True, rows=rows[1])
+    assert dqkv.shape == qkv.shape and dqkv.dtype == qkv.dtype
+    scale = float(jnp.max(jnp.abs(ref_dqkv))) or 1.0
+    assert_almost_equal(np.asarray(dqkv.astype(jnp.float32)) / scale,
+                        np.asarray(ref_dqkv) / scale, rtol=tol, atol=tol)
+    assert (db is None) == (bias is None)
+    if bias is not None:
+        assert db.shape == bias.shape and db.dtype == bias.dtype
+        scale = float(jnp.max(jnp.abs(ref_db))) or 1.0
+        assert_almost_equal(np.asarray(db) / scale, np.asarray(ref_db) / scale,
+                            rtol=tol, atol=tol)
+
+
+def _norm_jaxpr(jaxpr):
+    import re
+
+    txt = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    txt = re.sub(r" at [^\s:]+\.py:\d+", " at FILE", txt)
+    return re.sub(r"/[^\s'\"]+\.py(:\d+)?", "FILE", txt)
+
+
+def _parent_formula(qkv, bias, heads, causal):
+    """``BERTSelfAttention.hybrid_forward`` between its two projections as
+    it stood before ``flash_attention_qkv``: the operators it called."""
+    from mxnet_tpu.ops import matrix as M
+
+    D = qkv.shape[2] // 3 // heads
+    x = M.reshape(qkv, shape=(0, 0, 3, heads, D))
+    q, k, v = M.split(x, num_outputs=3, axis=2, squeeze_axis=True)
+    q, k, v = (M.transpose(t, axes=(0, 2, 1, 3)) for t in (q, k, v))
+    out = A.flash_attention(q, k, v, bias, causal=causal, sm_scale=D ** -0.5)
+    return M.reshape(M.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
+
+
+@pytest.mark.parametrize("case,call,tpu,in_place", [
+    # the two BERT cells, without and with a padding bias
+    ("bert_s128", dict(shape=(128, 128, 12, 64)), True, True),
+    ("bert_s128_bias", dict(shape=(128, 128, 12, 64), bias=True), True, True),
+    ("bert_s512", dict(shape=(32, 512, 12, 64)), True, True),
+    ("s256", dict(shape=(64, 256, 12, 64)), True, True),
+    # a 128-wide head is a block of its own: any head count
+    ("d128_h3", dict(shape=(128, 128, 3, 128)), True, True),
+    # each of the conditions alone, at a shape that else qualifies
+    ("causal", dict(shape=(128, 128, 12, 64), causal=True), True, False),
+    ("odd_heads_at_64", dict(shape=(128, 128, 3, 64)), True, False),
+    ("t_100", dict(shape=(128, 100, 12, 64)), True, False),
+    ("t_384_three_backward_blocks", dict(shape=(64, 384, 12, 64)), True, False),
+    ("t_1024_two_blocks", dict(shape=(32, 1024, 12, 64)), True, False),
+    ("batch_1", dict(shape=(1, 128, 12, 64)), True, False),
+    ("grid_252", dict(shape=(21, 128, 12, 64)), True, False),
+    ("d_32", dict(shape=(128, 128, 12, 32)), True, False),
+    ("sequence_scope", dict(shape=(128, 128, 12, 64), scope=True), True, False),
+    ("cpu", dict(shape=(128, 128, 12, 64)), False, False),
+])
+def test_in_place_rule_as_a_table(case, call, tpu, in_place, monkeypatch):
+    """Which ``flash_attention_qkv`` calls run the in-place kernels, by the
+    programs they trace to: the cells' shapes do (both ``pallas_call``s
+    under the names the trace reads, one (B, T, 3 x H x D) cotangent out
+    of the backward's, no transpose anywhere); each condition that does not
+    hold falls back to the jaxpr that ``flash_attention`` on turned operands
+    traces to, letter for letter, forward and gradient."""
+    import contextlib
+
+    from mxnet_tpu import parallel, telemetry
+
+    B, T, H, D = call["shape"]
+    causal = call.get("causal", False)
+    S = jax.ShapeDtypeStruct
+    qkv = S((B, T, 3 * H * D), jnp.bfloat16)
+    bias = S((B, 1, 1, T), jnp.float32) if call.get("bias") else None
+    if tpu:
+        monkeypatch.setattr(A, "on_tpu", lambda: True)
+    scope = contextlib.nullcontext()
+    if call.get("scope"):
+        mesh = parallel.make_mesh((1,), ("sp",), devices=jax.devices()[:1])
+        scope = parallel.sequence_scope(mesh, "sp")
+
+    def new(x, b):
+        return A.flash_attention_qkv(x, b, num_heads=H, causal=causal)
+
+    def grad_of(fn):
+        return jax.grad(lambda x, b: fn(x, b).astype(jnp.float32).sum())
+
+    before = telemetry.flash_layouts()
+    with scope:
+        said = []
+        jax.eval_shape(lambda x, b: said.append(
+            A._in_place(x, H, D, b, causal, D ** -0.5)), qkv, bias)
+        assert said == [in_place]
+        if call.get("scope"):
+            return  # the dispatch under a scope: tests/test_sequence_scope.py
+        fwd = jax.make_jaxpr(new)(qkv, bias)
+        bwd = jax.make_jaxpr(grad_of(new))(qkv, bias)
+    after = telemetry.flash_layouts()
+    layout = "in_place" if in_place else "heads_major"
+    delta = {(k, l): n - before.get(k, {}).get(l, 0)
+             for k, d in after.items() for l, n in d.items()
+             if n != before.get(k, {}).get(l, 0)}
+    # the forward is traced by both programs, the backward by the gradient's
+    assert delta == {("fwd", layout): 2, ("bwd", layout): 1}
+    if not in_place:
+        old = lambda x, b: _parent_formula(x, b, H, causal)  # noqa: E731
+        assert _norm_jaxpr(fwd) == _norm_jaxpr(jax.make_jaxpr(old)(qkv, bias))
+        assert _norm_jaxpr(bwd) == _norm_jaxpr(
+            jax.make_jaxpr(grad_of(old))(qkv, bias))
+        return
+    for jaxpr, name, outs in ((fwd, "flash_attention_fwd", [(B, T, H * D), (B * H, 1, T)]),
+                              (bwd, "flash_attention_bwd", [(B, T, 3 * H * D)])):
+        calls = [e for e in _inner_eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls][-1] == name
+        assert [tuple(x.aval.shape) for x in calls[-1].outvars][:len(outs)] == outs
+        # no copy stands between the projection and the kernels
+        assert not [e for e in _inner_eqns(jaxpr.jaxpr)
+                    if e.primitive.name in ("transpose", "concatenate", "split")
+                    and e not in list(_inner_eqns(calls[-1].params["jaxpr"]))
+                    and all(e not in list(_inner_eqns(c.params["jaxpr"])) for c in calls)]
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_in_place_body_does_not_grow_with_the_heads(kernel):
+    """``test_kernel_body_does_not_grow_with_the_heads``'s measure on the
+    in-place kernels: a head's body is traced ONCE whatever the heads and
+    batch elements a grid step holds (2 matmuls forward, 5 backward; the two
+    heads of a 128-lane pair are two trips of the loop the lowering writes
+    out, not two copies of the body)."""
+    T, D = 128, 64
+    S = jax.ShapeDtypeStruct
+
+    def body(B, H, rows):
+        qkv, o = S((B, T, 3 * H * D), jnp.bfloat16), S((B, T, H * D), jnp.bfloat16)
+        lse = S((B * H, 1, T), jnp.float32)
+        if kernel == "fwd":
+            jaxpr = jax.make_jaxpr(lambda x: A._qkv_forward_pallas(
+                x, None, H, 0.125, False, rows=rows))(qkv)
+        else:
+            jaxpr = jax.make_jaxpr(lambda x, o, l, do: A._qkv_backward_pallas(
+                x, None, o, l, do, H, 0.125, False, rows=rows))(qkv, o, lse, o)
+        eqns = list(_inner_eqns(_pallas_eqn(jaxpr.jaxpr).params["jaxpr"]))
+        return len(eqns), sum(e.primitive.name == "dot_general" for e in eqns)
+
+    counts = {body(8, 2, 1), body(8, 4, 2), body(8, 12, 1), body(8, 12, 8)}
+    assert len(counts) == 1
+    assert counts.pop()[1] == {"fwd": 2, "bwd": 5}[kernel]
+
+
+def test_in_place_vmem_asked_for():
+    """What the in-place kernels ask of scoped VMEM at the cells' shapes:
+    nothing at 128 tokens, what a step of whole rows holds and a quarter
+    more at 512."""
+    assert (_cell_rows("s128"), _cell_rows("s512")) == (2, 1)
+    for kernel in ("fwd", "bwd"):
+        assert A._in_place_vmem_limit(kernel, 2, 128, 768, 12, 2, None) is None
+    fwd = A._in_place_vmem_limit("fwd", 1, 512, 768, 12, 2, None)
+    bwd = A._in_place_vmem_limit("bwd", 1, 512, 768, 12, 2, None)
+    assert 16e6 < fwd < 16.5e6 and 24e6 < bwd < 24.5e6
+
+
+class _ParentSelfAttention(model_zoo.bert.BERTSelfAttention):
+    """``BERTSelfAttention`` with the ``hybrid_forward`` it had before
+    ``flash_attention_qkv``: the plain formula the block is held to."""
+
+    def hybrid_forward(self, F, x, bias=None):
+        import math
+
+        H = self._num_heads
+        D = self._units // H
+        qkv = self.qkv(x)  # (B, T, 3C)
+        qkv = F.reshape(qkv, shape=(0, 0, 3, H, D))
+        q, k, v = F.split(qkv, num_outputs=3, axis=2, squeeze_axis=True)
+        q = F.transpose(q, axes=(0, 2, 1, 3))  # (B, H, T, D)
+        k = F.transpose(k, axes=(0, 2, 1, 3))
+        v = F.transpose(v, axes=(0, 2, 1, 3))
+        out = F.flash_attention(q, k, v, bias, causal=self._causal,
+                                sm_scale=1.0 / math.sqrt(D))
+        out = F.transpose(out, axes=(0, 2, 1, 3))  # (B, T, H, D)
+        out = F.reshape(out, shape=(0, 0, -1))
+        out = self.proj(out)
+        if self.dropout is not None:
+            out = self.dropout(out)
+        return out
+
+
+def _block_pair(causal, units=32, heads=4):
+    """The block and the parent's formula over the SAME parameters."""
+    blk = model_zoo.bert.BERTSelfAttention(units, heads, causal=causal,
+                                           prefix="attn_")
+    blk.initialize(mx.init.Normal(0.5))
+    old = _ParentSelfAttention(units, heads, causal=causal, prefix="attn_",
+                               params=blk.collect_params())
+    return blk, old
+
+
+def _out_and_grads(blk, x, bias):
+    params = [p for _, p in sorted(blk.collect_params().items())]
+    with ag.record():
+        out = blk(x, bias) if bias is not None else blk(x)
+        loss = (out * out).sum()
+    loss.backward()
+    return out.asnumpy(), [p.grad().asnumpy().copy() for p in params]
+
+
+@with_seed()
+@pytest.mark.parametrize("mode", ["eager", "hybridized"])
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_self_attention_block_equals_the_parent_formula(causal, with_bias, mode):
+    """``BERTSelfAttention`` over ``flash_attention_qkv`` against the block
+    as it stood (same parameters): the output and the gradient of every
+    parameter to the last bit on the CPU, eager and hybridized, with and
+    without a padding bias; ``causal`` is ``model_zoo/gpt.py``'s block."""
+    blk, old = _block_pair(causal)
+    rng = np.random.RandomState(3)
+    x = nd.array(rng.normal(size=(2, 16, 32)).astype("f4"))
+    bias = nd.array(np.asarray(A.make_padding_bias(
+        jnp.asarray([11, 16]), 16))) if with_bias else None
+    if mode == "hybridized":
+        blk.hybridize()
+        old.hybridize()
+    want, want_grads = _out_and_grads(old, x, bias)
+    got, got_grads = _out_and_grads(blk, x, bias)
+    assert np.array_equal(got, want)
+    assert len(got_grads) == len(want_grads) == 4
+    for g, w in zip(got_grads, want_grads):
+        assert np.abs(w).max() > 0 and np.array_equal(g, w)
+
+
+@with_seed()
+def test_self_attention_block_through_export_and_imports(tmp_path):
+    """The block traces as a Symbol (``flash_attention_qkv`` is shape-free:
+    the head count is its attribute) and comes back through ``export`` /
+    ``SymbolBlock.imports`` with the output it had."""
+    blk, _ = _block_pair(False)
+    x = nd.array(np.random.RandomState(4).normal(size=(2, 16, 32)).astype("f4"))
+    blk.hybridize()
+    want = blk(x).asnumpy()
+    sym_file, param_file = blk.export(str(tmp_path / "attn"))
+    with open(sym_file) as f:
+        assert '"flash_attention_qkv"' in f.read()
+    loaded = gluon.SymbolBlock.imports(sym_file, ["data"], param_file)
+    assert_almost_equal(loaded(x).asnumpy(), want, rtol=1e-5, atol=1e-5)
+
+
+@with_seed()
+def test_gpt_block_is_the_parent_formula_to_the_last_bit():
+    """``model_zoo/gpt.py`` builds on ``BERTSelfAttention(causal=True)``:
+    the operator falls back inside, and a GPT forward and its parameters'
+    gradients equal those of the same net with the parent's formula in
+    every block."""
+    from mxnet_tpu.gluon.model_zoo import gpt
+
+    net = gpt.gpt_mini(dropout=0.0)
+    net.initialize(mx.init.Normal(0.05))
+    ids = nd.array(np.random.RandomState(5).randint(0, 100, (2, 16)))
+
+    def run():
+        params = [p for _, p in sorted(net.collect_params().items())
+                  if p.grad_req != "null"]
+        with ag.record():
+            out = net(ids)
+            loss = (out * out).mean()
+        loss.backward()
+        return out.asnumpy(), [p.grad().asnumpy().copy() for p in params]
+
+    got, got_grads = run()
+    for blk in net.blocks:  # the parent's formula over the same parameters
+        blk.attn.__class__ = _ParentSelfAttention
+    want, want_grads = run()
+    assert np.array_equal(got, want)
+    for g, w in zip(got_grads, want_grads):
+        assert np.array_equal(g, w)
+
+
+def _interpreted(kernel_call):
+    """An in-place ``pallas_call`` builder with ``interpret`` forced on."""
+    def call(*args, interpret=False, **kwargs):
+        return kernel_call(*args, interpret=True, **kwargs)
+    return call
+
+
+@with_seed()
+@pytest.mark.parametrize("with_bias", [False, True])
+def test_self_attention_block_in_place_equals_the_parent_formula(with_bias, monkeypatch):
+    """The block with the in-place kernels ENGAGED (a TPU's dispatch, the
+    kernels in interpret mode here; heads of 64 at 128 tokens) against the
+    parent's formula over the same parameters, hybridized: the output and
+    every parameter's gradient, and the layout counter says which ran."""
+    from mxnet_tpu import telemetry
+
+    blk, old = _block_pair(False, units=128, heads=2)
+    rng = np.random.RandomState(6)
+    x = nd.array(rng.normal(size=(2, 128, 128)).astype("f4"))
+    bias = nd.array(np.asarray(A.make_padding_bias(
+        jnp.asarray([90, 128]), 128))) if with_bias else None
+    old.hybridize()
+    want, want_grads = _out_and_grads(old, x, bias)
+    monkeypatch.setattr(A, "on_tpu", lambda: True)
+    monkeypatch.setattr(A, "_HEAD_MIN_GRID", 4)
+    for name in ("_qkv_forward_pallas", "_qkv_backward_pallas"):
+        monkeypatch.setattr(A, name, _interpreted(getattr(A, name)))
+    before = telemetry.flash_layouts()
+    blk.hybridize()
+    got, got_grads = _out_and_grads(blk, x, bias)
+    after = telemetry.flash_layouts()
+    assert after["fwd"]["in_place"] > before.get("fwd", {}).get("in_place", 0)
+    assert after["bwd"]["in_place"] > before.get("bwd", {}).get("in_place", 0)
+    assert_almost_equal(got, want, rtol=1e-4, atol=1e-4)
+    for g, w in zip(got_grads, want_grads):
+        scale = np.abs(w).max()
+        assert scale > 0
+        assert_almost_equal(g / scale, w / scale, rtol=1e-4, atol=1e-4)

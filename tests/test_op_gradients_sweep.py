@@ -237,6 +237,8 @@ SPEC = {
     # attention (the north-star hot kernel, CPU/interpret path here)
     "flash_attention": ([_unit(1, 2, 4, 8), _unit(1, 2, 4, 8),
                          _unit(1, 2, 4, 8)], {}, None),
+    # the same attention fed from a fused (B, T, 3 x H x D) projection
+    "flash_attention_qkv": ([_unit(1, 4, 3 * 2 * 8)], {"num_heads": 2}, None),
 }
 
 
@@ -344,6 +346,7 @@ F32_INTERNAL_TOL = {
     "BatchNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "LayerNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "flash_attention": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "flash_attention_qkv": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "RMSNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "rotary_embedding": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "swiglu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),

@@ -548,3 +548,80 @@ def test_flash_heads_per_step_counts_traces_and_trace_cell_prints_it(
             if l.startswith('{"scoped"')][-1]
     heads = json.loads(line)["scoped"]["flash_heads_per_step"]
     assert heads["fwd"]["4"] >= 1 and heads["bwd"]["2"] >= 1
+
+
+def test_flash_layout_counts_traces_and_trace_cell_prints_it(
+        monkeypatch, capsys, tmp_path):
+    """``mxt_flash_layout_total{kernel, layout}``: one count a traced flash
+    pass under the layout its operands lie in (``in_place`` where
+    ``flash_attention_qkv`` reads the fused projection as it lies,
+    ``heads_major`` for ``flash_attention``'s (B, H, T, D) operands, a
+    fallen-back ``flash_attention_qkv`` among them), none a call of the
+    compiled program; ``tools/trace_cell.py`` prints it on its ``scoped``
+    line beside ``flash_heads_per_step``."""
+    import importlib.util
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+
+    def delta(fn):
+        before = telemetry.flash_layouts()
+        fn()
+        after = telemetry.flash_layouts()
+        return {(k, l): n - before.get(k, {}).get(l, 0)
+                for k, by in after.items() for l, n in by.items()
+                if n != before.get(k, {}).get(l, 0)}
+
+    qkv = jnp.ones((2, 128, 3 * 2 * 64), jnp.float32)
+    loss = lambda x: A.flash_attention_qkv(x, num_heads=2).sum()  # noqa: E731
+    # the CPU: the operator falls back, and says so, once a trace
+    fwd = jax.jit(loss)
+    assert delta(lambda: (fwd(qkv), fwd(qkv), fwd(qkv))) == {
+        ("fwd", "heads_major"): 1}
+    grad = jax.jit(jax.grad(loss))
+    assert delta(lambda: (grad(qkv), grad(qkv))) == {
+        ("fwd", "heads_major"): 1, ("bwd", "heads_major"): 1}
+    q = jnp.ones((2, 2, 128, 64), jnp.float32)
+    assert delta(lambda: A.flash_attention(q, q, q)) == {("fwd", "heads_major"): 1}
+    # a TPU (the kernels in interpret mode here) at a shape the rule takes
+    with monkeypatch.context() as m:
+        m.setattr(A, "on_tpu", lambda: True)
+        m.setattr(A, "_HEAD_MIN_GRID", 4)
+        for name in ("_qkv_forward_pallas", "_qkv_backward_pallas"):
+            m.setattr(A, name, _interpreted(getattr(A, name)))
+        grad = jax.jit(jax.grad(loss))
+        assert delta(lambda: (grad(qkv), grad(qkv))) == {
+            ("fwd", "in_place"): 1, ("bwd", "in_place"): 1}
+    assert 'mxt_flash_layout_total{kernel="bwd",layout="in_place"}' in \
+        telemetry.render_prometheus()
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_cell_under_test", os.path.join(root, "tools", "trace_cell.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    args = types.SimpleNamespace(seed=0, seconds=1.0, trace=1, rehearse=True)
+
+    def fake_run(argv):  # the run itself: an empty trace, and its clean-up
+        ctx = tool.Context(args, {}, {}, {}, [])
+        ctx._trace_dirs.append(str(tmp_path))
+        ctx.cleanup()
+        return 0
+
+    monkeypatch.setattr(tool.bench, "main", fake_run)
+    capsys.readouterr()
+    assert tool.main([]) == 0
+    line = [l for l in capsys.readouterr().out.splitlines()
+            if l.startswith('{"scoped"')][-1]
+    layouts = json.loads(line)["scoped"]["flash_layouts"]
+    assert layouts["fwd"]["in_place"] >= 1 and layouts["bwd"]["heads_major"] >= 1
+
+
+def _interpreted(kernel_call):
+    """An in-place ``pallas_call`` builder with ``interpret`` forced on."""
+    def call(*args, interpret=False, **kwargs):
+        return kernel_call(*args, interpret=True, **kwargs)
+    return call

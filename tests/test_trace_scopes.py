@@ -178,7 +178,10 @@ def test_kernels_have_names():
                                     "paged_decode")),
                        (bn_pallas, ("bn_bwd_reduce", "bn_bwd_dx"))):
         src = inspect.getsource(mod)
-        assert src.count("pl.pallas_call(") == len(names)
+        # the in-place flash kernels carry the names of the heads-major ones:
+        # the trace's readers find either under flash_attention_fwd / _bwd
+        assert src.count("pl.pallas_call(") == sum(
+            src.count('name="%s"' % n) for n in names)
         for n in names:
             assert 'name="%s"' % n in src
 

@@ -10,7 +10,9 @@ line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
 kernels' calls a step, the branch each traced attention forward and backward
 took (``flash_fwd_branches``, ``flash_bwd_branches``) and the heads a grid
-step of each traced flash kernel takes (``flash_heads_per_step``), the branch
+step of each traced flash kernel takes (``flash_heads_per_step``), the layout
+each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
+fused projection, ``heads_major`` turned), the branch
 each traced gated short convolution took (``gated_conv_branches``), the tuning
 table's entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
@@ -18,7 +20,10 @@ expert-parallel model counted on the device
 run past a layer's first), what a model with a lightning indexer counted there
 (``selection_counts``: the pairs selected and the rows searched, by layer),
 the set-up
-phases and the compile counters. The
+phases and the compile counters. ``--category NAME`` (an HLO category of the
+device table, e.g. ``"data formatting"``) adds one JSON line
+``{"category_ops": ...}`` with every operation of that category by scope and
+result shape: what a category is made of. The
 benchmark's cells cannot name a new per-layer metric without an edit to
 their files (PERF.md section 7), so this is how those numbers are taken
 meanwhile.
@@ -46,10 +51,12 @@ class Context(bench.Context):
     """The benchmark's context, reading each trace back before deleting it."""
 
     tables = []
+    category = None  # --category: the HLO category whose operations are listed
     steps = None
     flash_fwd = None
     flash_bwd = None
     flash_heads = None
+    flash_layouts = None
     gated_conv = None
     tuned = None
     moe = None
@@ -74,13 +81,14 @@ class Context(bench.Context):
         for path in self._trace_dirs:
             t0 = time.perf_counter()
             agg = profiler.aggregate(path, depth=5, window="bench.trace_window",
-                                     top=40)
+                                     top=None if Context.category else 40)
             Context.tables.append((agg, time.perf_counter() - t0))
         Context.setup = profiler.setup_seconds()
         Context.compile_stats = tuning.compile_stats()
         Context.flash_fwd = telemetry.flash_fwd_branches()
         Context.flash_bwd = telemetry.flash_bwd_branches()
         Context.flash_heads = telemetry.flash_heads_per_step()
+        Context.flash_layouts = telemetry.flash_layouts()
         Context.gated_conv = telemetry.gated_conv_branches()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
@@ -108,13 +116,34 @@ def scoped(agg, steps):
            "kind_ms": {k: v * per for k, v in list(agg["kind_s"].items())[:24]},
            "scope_ms": {k: v * per for k, v in list(agg["scope_s"].items())[:40]},
            "ops_ms": [[o["name"], o["phase"], o["scope"], o["category"],
-                       o["seconds"] * per, o["calls"] / steps] for o in agg["ops"]],
+                       o["seconds"] * per, o["calls"] / steps] for o in agg["ops"][:40]],
            "clock_offset_us": agg["clock_offset_us"], "launch_pairs": agg["launch_pairs"],
            "idle_gaps": agg["idle_gaps"]}
     return out
 
 
+def category_ops(agg, steps, category):
+    """Every operation of one HLO category, summed by (scope, result shape):
+    milliseconds and calls a step, largest first."""
+    groups = {}
+    for o in agg["ops"]:
+        if o["category"] == category:
+            ent = groups.setdefault((o["scope"], o["phase"], o["shape"]), [0.0, 0.0, []])
+            ent[0] += o["seconds"] * 1e3 / steps
+            ent[1] += o["calls"] / steps
+            ent[2].append(o["name"])
+    rows = sorted(groups.items(), key=lambda kv: -kv[1][0])
+    return {"category": category, "ms": sum(v[0] for _, v in rows),
+            "rows": [{"scope": k[0], "phase": k[1], "shape": k[2], "ms": v[0],
+                      "calls_per_step": v[1], "ops": sorted(v[2])[:4]} for k, v in rows]}
+
+
 def main(argv):
+    argv = list(argv)
+    if "--category" in argv:
+        at = argv.index("--category")
+        Context.category = argv[at + 1]
+        del argv[at:at + 2]
     bench.Context = Context
     rc = bench.main(list(argv) + ["--trace", "1"])
     from mxnet_tpu import profiler_trace  # the run has imported the program
@@ -128,6 +157,8 @@ def main(argv):
             row["flash_bwd_branches"] = Context.flash_bwd
         if Context.flash_heads:  # and how many heads a grid step each kernel
             row["flash_heads_per_step"] = Context.flash_heads
+        if Context.flash_layouts:  # and whether it read the projection in place
+            row["flash_layouts"] = Context.flash_layouts
         if Context.gated_conv:  # and which path each gated short convolution
             row["gated_conv_branches"] = Context.gated_conv
         if Context.tuned:  # the tiles each kernel shape ran with
@@ -142,6 +173,9 @@ def main(argv):
             print(profiler_trace.format_table(agg, top=40))
             row.update(scoped(agg, Context.steps))
         print(json.dumps({"scoped": row}), flush=True)
+        if agg is not None and Context.category:
+            print(json.dumps({"category_ops": category_ops(
+                agg, Context.steps, Context.category)}), flush=True)
     return rc
 
 
