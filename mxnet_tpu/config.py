@@ -432,8 +432,8 @@ _declare("MXT_DATA_BUFFER_BATCHES", int, 8,
          "Bounded decoded-batch buffer per host (the data plane's "
          "backpressure boundary): decode workers block when the "
          "consumer falls this many batches behind instead of growing "
-         "host memory; resident bytes are accounted in the HBM "
-         "ledger's 'prefetch' pool.")
+         "host memory; resident host bytes are the "
+         "mxt_data_buffer_bytes gauge.")
 _declare("MXT_DATA_CHUNK_RECORDS", int, 256,
          "Records per data-plane chunk — the unit of lease, steal, and "
          "batch formation (batches never cross a chunk, so keep this a "

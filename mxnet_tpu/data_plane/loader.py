@@ -13,9 +13,17 @@ One loader per host. Per epoch it:
    of PR 8's step cursor, riding ``CheckpointManager.save(extra=...)``);
 3. starts the decode-worker fleet and yields :class:`StreamBatch`es,
    stamping the time it spends WAITING on the fleet's buffer as the
-   ``data_wait`` phase span (telemetry + goodput pick it up through the
-   existing tap) plus a host-labeled seconds counter so ``mxt_top`` and
-   the fleet collector attribute input-boundness per host.
+   ``mxt.data.wait`` span of the profiler's trace (``n``, ``epoch``,
+   ``depth``; the zero-length ``mxt.data.got`` after it carries ``n``
+   and the batch's ``batch`` tag, which a span cannot know when it
+   opens) and, from the same clock read, the ``data_wait`` phase
+   (telemetry + goodput pick it up through the existing tap) plus a
+   host-labeled seconds counter so ``mxt_top`` and the fleet collector
+   attribute input-boundness per host. The device put is
+   ``mxt.data.h2d`` (``batch``, ``bytes``: the host's cost of the put,
+   the copy itself is asynchronous), and an epoch's turn is two spans:
+   ``mxt.data.epoch_begin`` (ledger install, fleet construction and
+   start) and ``mxt.data.epoch_end`` (the fleet's close).
 
 The feed path into the device stays sync-free: batches convert to
 NDArrays with one device put each and optionally ride the existing
@@ -25,6 +33,8 @@ N+1's H2D transfer overlaps the step running on batch N.
 from __future__ import annotations
 
 import time
+
+from jax.profiler import TraceAnnotation as _span
 
 from ..base import MXNetError
 from .ledger import ChunkLedger
@@ -173,29 +183,32 @@ class StreamingDataLoader:
     def _device_batches(self, fleet):
         from ..ndarray import ndarray as _nd
 
-        for data, labels, ids, cid in fleet.batches():
+        for data, labels, ids, cid, tag in fleet.batches():
             if self._to_device:
-                yield (_nd.array(data, dtype=data.dtype),
-                       _nd.array(labels, dtype=labels.dtype), ids, cid)
-            else:
-                yield (data, labels, ids, cid)
+                with _span("mxt.data.h2d", batch=tag,
+                           bytes=data.nbytes + labels.nbytes):
+                    data = _nd.array(data, dtype=data.dtype)
+                    labels = _nd.array(labels, dtype=labels.dtype)
+            yield data, labels, ids, cid, tag
 
     def _epoch_iter(self):
         from .. import telemetry
 
-        self._begin_epoch()
-        fleet = DecodeWorkerFleet(
-            self.manifest, self.ledger, self.host, self.decoder,
-            self.batch_size, epoch=self.epoch, seed=self.seed,
-            num_workers=self._num_workers,
-            buffer_batches=self._buffer_batches, steal=self._steal)
-        self.fleet = fleet
-        wait_counter = telemetry.counter(
-            "mxt_data_wait_seconds_total",
-            "Seconds the consumer spent blocked on the data plane "
-            "(per-host data_wait attribution).",
-            ("host",)).labels(str(self.host))
-        base = self._device_batches(fleet.start())
+        epoch = self.epoch
+        with _span("mxt.data.epoch_begin", epoch=epoch):
+            self._begin_epoch()
+            fleet = DecodeWorkerFleet(
+                self.manifest, self.ledger, self.host, self.decoder,
+                self.batch_size, epoch=epoch, seed=self.seed,
+                num_workers=self._num_workers,
+                buffer_batches=self._buffer_batches, steal=self._steal)
+            self.fleet = fleet
+            wait_counter = telemetry.counter(
+                "mxt_data_wait_seconds_total",
+                "Seconds the consumer spent blocked on the data plane "
+                "(per-host data_wait attribution).",
+                ("host",)).labels(str(self.host))
+            base = self._device_batches(fleet.start())
         if self._prefetch_to_device and self._to_device:
             from ..gluon.data.dataloader import _DevicePrefetcher
 
@@ -204,17 +217,23 @@ class StreamingDataLoader:
         n = 0
         try:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    data, labels, ids, cid = next(it)
-                except StopIteration:
+                # one interval, measured once: the span and ``dt`` open
+                # and close together, and ``dt`` feeds both older readers
+                with _span("mxt.data.wait", n=n + 1, epoch=epoch,
+                           depth=fleet._q.qsize()):
+                    t0 = time.perf_counter()
+                    batch = next(it, None)
+                    dt = time.perf_counter() - t0
+                if batch is None:
                     return
+                data, labels, ids, cid, tag = batch
                 skip = self._skip.get(cid, 0)
                 if skip > 0:
                     # resume replay: this chunk's head was consumed
                     # before the checkpoint — drop the re-decoded copy
                     # (decode is deterministic, so what follows is the
-                    # sample-exact continuation)
+                    # sample-exact continuation); its wait stays out of
+                    # the counts, and its span has no ``got``
                     self._skip[cid] = skip - 1
                     continue
                 got = self._consumed.get(cid, 0) + 1
@@ -222,12 +241,14 @@ class StreamingDataLoader:
                 if got >= self._chunk_batches(cid):
                     self._complete.add(cid)
                 n += 1
-                dt = time.perf_counter() - t0
+                with _span("mxt.data.got", n=n, batch=tag):
+                    pass
                 telemetry.record_phase("data_wait", dt,
                                        stream="data_plane", step=n)
                 wait_counter.inc(dt)
                 yield StreamBatch(data, labels, ids, cid)
         finally:
-            fleet.close()
+            with _span("mxt.data.epoch_end", epoch=epoch):
+                fleet.close()
             if not fleet.killed and not fleet.fenced:
                 self.epoch += 1

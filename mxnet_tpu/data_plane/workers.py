@@ -9,19 +9,33 @@ rebuilt around chunk leases instead of a per-process cursor).
       → (dry) steal from the reclaim pool / the slowest live peer
       → decode the chunk's records into batches (host-side numpy —
         the one layer of this system that is SUPPOSED to touch host
-        memory; JPEG decode releases the GIL, so threads scale)
+        memory; JPEG decode releases the GIL, the per-record Python
+        around it does not: on a TPU v5e's host two threads gave 1.63
+        times one and more gave nothing, PERF.md Findings, PR 39)
       → COMMIT the chunk (exactly-once point — a stale lease is
         refused typed and the batches are dropped, never fed)
       → enqueue the batches into the host's bounded buffer
 
 The buffer is the backpressure boundary: ``MXT_DATA_BUFFER_BATCHES``
-bounds how far decode may run ahead of the consumer, its resident bytes
-are accounted in the diagnostics HBM ledger's ``prefetch`` pool (shape
-metadata only, never a device read), and a full buffer blocks the
-workers instead of OOMing the host. The consumer side
+bounds how far decode may run ahead of the consumer, its resident HOST
+bytes are the ``mxt_data_buffer_bytes`` gauge (numpy buffers: they are
+not in the HBM ledger, which counts device memory), and a full buffer
+blocks the workers instead of OOMing the host. The consumer side
 (:class:`~.loader.StreamingDataLoader`) stamps the time it spends
-waiting on this queue as the ``data_wait`` phase span — goodput
-accounting and ``mxt_top`` attribute input-boundness per host from it.
+waiting on this queue as the ``mxt.data.wait`` span and, from the same
+clock read, the ``data_wait`` phase — goodput accounting and ``mxt_top``
+attribute input-boundness per host from it.
+
+What a worker is doing is in the profiler's trace, one
+``jax.profiler.TraceAnnotation`` a state (about a microsecond each with
+no trace running): ``mxt.data.lease`` (the ledger's grant or steal and
+the 5 ms poll after an empty one; ``wid``, ``granted``, ``stolen``),
+``mxt.data.decode`` (ONE batch's records read and decoded; ``wid``,
+``batch``, ``records``, ``bytes_in``), ``mxt.data.commit`` and
+``mxt.data.put`` (the enqueue, whose length is the backpressure;
+``wid``, ``batch``, ``depth``). ``batch`` is ``"<epoch>:<chunk>:<k>"``,
+the k-th batch of the chunk: it rides with the batch through the buffer,
+and the consumer's ``mxt.data.h2d`` and ``mxt.data.got`` carry it too.
 
 Decoding is deterministic by construction: a chunk's record order and
 augmentation draws derive from (manifest, seed, epoch, chunk) — never
@@ -44,6 +58,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from ..base import MXNetError
 from ..membership import StaleWorkerError
@@ -183,9 +198,6 @@ def _host_metrics(host):
             "mxt_data_bytes_total",
             "Decoded batch bytes produced by the data-plane fleet.",
             ("host",)).labels(lbl),
-        "chunks": telemetry.counter(
-            "mxt_data_chunks_total",
-            "Chunks committed by this host.", ("host",)).labels(lbl),
         "steals": telemetry.counter(
             "mxt_data_steals_total",
             "Chunks this host stole from peers (dry lease queue).",
@@ -202,6 +214,24 @@ def _host_metrics(host):
             "mxt_data_records_per_second",
             "Decode throughput of this host's worker fleet (epoch "
             "running average).", ("host",)).labels(lbl),
+        "buffered": telemetry.gauge(
+            "mxt_data_buffer_bytes",
+            "Host bytes of decoded batches buffered ahead of the "
+            "consumer (numpy, not device memory).",
+            ("host",)).labels(lbl),
+        "read_s": telemetry.counter(
+            "mxt_data_read_seconds_total",
+            "Worker seconds inside the record reader (read_idx), "
+            "summed over this host's workers.", ("host",)).labels(lbl),
+        "decode_s": telemetry.counter(
+            "mxt_data_decode_seconds_total",
+            "Worker seconds inside decoder.decode, summed over this "
+            "host's workers.", ("host",)).labels(lbl),
+        "put_wait_s": telemetry.counter(
+            "mxt_data_put_wait_seconds_total",
+            "Worker seconds enqueueing into the bounded buffer "
+            "(backpressure), summed over this host's workers.",
+            ("host",)).labels(lbl),
     }
 
 
@@ -245,7 +275,6 @@ class DecodeWorkerFleet:
         self.killed = False     # data_host_kill fired
         self.fenced = False     # a commit came back stale — we are dead
         self._errors = []       # worker exceptions, re-raised to consumer
-        self._hbm_key = "data-plane-h%d-%x" % (self.host, id(self))
         self._m = _host_metrics(self.host)
 
     # -- lifecycle ---------------------------------------------------------
@@ -317,9 +346,7 @@ class DecodeWorkerFleet:
         self._stop.set()
         for t in self._threads:
             t.join(timeout=5.0)
-        from .. import diagnostics
-
-        diagnostics.hbm_release("prefetch", self._hbm_key)
+        self._m["buffered"].set(0)
         self._m["depth"].set(0)
 
     # -- chaos hooks -------------------------------------------------------
@@ -353,27 +380,30 @@ class DecodeWorkerFleet:
                     # chunk boundary, never mid-decode
                 if self._chaos():
                     return
-                try:
-                    grants = self.ledger.lease(self.host, 1)
-                    stolen = False
-                    if not grants and self.steal_enabled:
-                        grants = self.ledger.steal(self.host, 1)
-                        stolen = bool(grants)
-                except StaleWorkerError:
-                    self.fenced = True
-                    self._m["stale"].inc()
-                    return
-                if not grants:
-                    if self.ledger.finished():
+                with _span("mxt.data.lease", wid=wid) as span:
+                    try:
+                        grants = self.ledger.lease(self.host, 1)
+                        stolen = False
+                        if not grants and self.steal_enabled:
+                            grants = self.ledger.steal(self.host, 1)
+                            stolen = bool(grants)
+                    except StaleWorkerError:
+                        self.fenced = True
+                        self._m["stale"].inc()
                         return
-                    # everything left is leased to live peers: poll —
-                    # a late death can still reclaim work for us
-                    self._stop.wait(0.005)
-                    continue
+                    span.set_metadata(granted=len(grants),
+                                      stolen=int(stolen))
+                    if not grants:
+                        if self.ledger.finished():
+                            return
+                        # everything left is leased to live peers: poll —
+                        # a late death can still reclaim work for us
+                        self._stop.wait(0.005)
+                        continue
                 if stolen:
                     self._m["steals"].inc(len(grants))
                 for grant in grants:
-                    self._process(grant[0], grant[1], readers)
+                    self._process(wid, grant[0], grant[1], readers)
                     if self._stop.is_set():
                         return
         except BaseException as e:  # noqa: BLE001 — re-raised in batches()
@@ -399,7 +429,7 @@ class DecodeWorkerFleet:
                 except _queue.Full:
                     pass
 
-    def _process(self, chunk_id, token, readers):
+    def _process(self, wid, chunk_id, token, readers):
         chunk = self.manifest.epoch_chunk(chunk_id, self.epoch, self.seed)
         reader = readers.get(chunk.shard_id)
         if reader is None:
@@ -414,32 +444,47 @@ class DecodeWorkerFleet:
         bs = self.batch_size
         batches = []
         keys = chunk.keys
-        for lo in range(0, len(keys), bs):
+        clock = time.perf_counter
+        for k, lo in enumerate(range(0, len(keys), bs)):
             part = keys[lo:lo + bs]
-            data = np.empty((len(part),) + tuple(self.decoder.sample_shape),
-                            self.decoder.sample_dtype)
-            labels = np.empty((len(part),), np.float32)
-            ids = []
-            for j, key in enumerate(part):
-                raw = reader.read_idx(key)
-                labels[j] = self.decoder.decode(raw, data[j], rng)
-                ids.append((chunk.shard_id, key))
-            batches.append((data, labels, ids, chunk.chunk_id))
+            tag = "%d:%d:%d" % (self.epoch, chunk.chunk_id, k)
+            with _span("mxt.data.decode", wid=wid, batch=tag,
+                       records=len(part)) as span:
+                data = np.empty(
+                    (len(part),) + tuple(self.decoder.sample_shape),
+                    self.decoder.sample_dtype)
+                labels = np.empty((len(part),), np.float32)
+                ids = []
+                read_s = decode_s = 0.0
+                bytes_in = 0
+                for j, key in enumerate(part):
+                    t0 = clock()
+                    raw = reader.read_idx(key)
+                    t1 = clock()
+                    labels[j] = self.decoder.decode(raw, data[j], rng)
+                    decode_s += clock() - t1
+                    read_s += t1 - t0
+                    bytes_in += len(raw)
+                    ids.append((chunk.shard_id, key))
+                span.set_metadata(bytes_in=bytes_in)
+            self._m["read_s"].inc(read_s)
+            self._m["decode_s"].inc(decode_s)
+            batches.append((data, labels, ids, chunk.chunk_id, tag))
         # commit BEFORE enqueue: the exactly-once point. If the commit
         # comes back stale this host was fenced (or the chunk re-leased
         # to a thief) — feeding the batches anyway would duplicate the
         # new leaseholder's work, so they are dropped on the floor.
         try:
-            self.ledger.commit(self.host, chunk.chunk_id, token)
+            with _span("mxt.data.commit", wid=wid, chunk=chunk.chunk_id):
+                self.ledger.commit(self.host, chunk.chunk_id, token)
         except StaleWorkerError:
             self.fenced = True
             self._m["stale"].inc()
             self._stop.set()
             return
         self._commits += 1
-        self._m["chunks"].inc()
         nrec = len(keys)
-        nbytes = sum(d.nbytes + lab.nbytes for d, lab, _, _ in batches)
+        nbytes = sum(b[0].nbytes + b[1].nbytes for b in batches)
         self._m["records"].inc(nrec)
         self._m["bytes"].inc(nbytes)
         with self._lock:
@@ -448,35 +493,38 @@ class DecodeWorkerFleet:
         if dt > 0:
             self._m["rate"].set(self._records / dt)
         for b in batches:
-            self._put(b)
+            self._put(wid, b)
             if self._stop.is_set():
                 return
 
     # -- bounded buffer (the backpressure boundary) ------------------------
-    def _publish_bytes(self):
-        from .. import diagnostics
-
-        diagnostics.hbm_set("prefetch", self._hbm_key,
-                            self._buffered_bytes)
+    def _publish_buffer(self):
+        """The buffer's depth and its HOST bytes (numpy batches waiting
+        for the consumer), as gauges."""
+        self._m["buffered"].set(self._buffered_bytes)
         self._m["depth"].set(self._q.qsize())
 
-    def _put(self, batch):
-        data, labels, _, _ = batch
-        while not self._stop.is_set():
-            try:
-                self._q.put(batch, timeout=0.05)
-                break
-            except _queue.Full:
-                continue  # backpressure: decode blocks, never OOMs
-        else:
-            return
+    def _put(self, wid, batch):
+        t0 = time.perf_counter()
+        with _span("mxt.data.put", wid=wid, batch=batch[4],
+                   depth=self._q.qsize()):
+            while not self._stop.is_set():
+                try:
+                    self._q.put(batch, timeout=0.05)
+                    break
+                except _queue.Full:
+                    continue  # backpressure: decode blocks, never OOMs
+            else:
+                return
+        self._m["put_wait_s"].inc(time.perf_counter() - t0)
         with self._lock:
-            self._buffered_bytes += data.nbytes + labels.nbytes
-        self._publish_bytes()
+            self._buffered_bytes += batch[0].nbytes + batch[1].nbytes
+        self._publish_buffer()
 
     def batches(self):
-        """Consumer side: yield (data, labels, ids, chunk_id) until the
-        epoch is globally finished and this host's buffer drained."""
+        """Consumer side: yield (data, labels, ids, chunk_id, batch tag)
+        until the epoch is globally finished and this host's buffer
+        drained."""
         while True:
             try:
                 batch = self._q.get(timeout=0.02)
@@ -493,8 +541,7 @@ class DecodeWorkerFleet:
                         "data-plane decode worker died: %r"
                         % (self._errors[0],)) from self._errors[0]
                 return
-            data, labels, _, _ = batch
             with self._lock:
-                self._buffered_bytes -= data.nbytes + labels.nbytes
-            self._publish_bytes()
+                self._buffered_bytes -= batch[0].nbytes + batch[1].nbytes
+            self._publish_buffer()
             yield batch

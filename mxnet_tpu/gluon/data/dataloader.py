@@ -256,20 +256,25 @@ class DataLoader:
         the step budget. Host wall-clock only, no device reads."""
         import time
 
+        from jax.profiler import TraceAnnotation
+
         from ... import telemetry
 
         it = iter(base)
+        done = object()
         n = 0
         while True:
-            t0 = time.perf_counter()
-            try:
-                batch = next(it)
-            except StopIteration:
+            # the same interval as the streaming loader's span of this
+            # name, so either loader names a device gap in the trace
+            with TraceAnnotation("mxt.data.wait", n=n + 1):
+                t0 = time.perf_counter()
+                batch = next(it, done)
+                dt = time.perf_counter() - t0
+            if batch is done:
                 return
             n += 1
-            telemetry.record_phase("data_wait",
-                                   time.perf_counter() - t0,
-                                   stream="dataloader", step=n)
+            telemetry.record_phase("data_wait", dt, stream="dataloader",
+                                   step=n)
             yield batch
 
     def _iter_threads(self):

@@ -44,6 +44,7 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute
                "all-to-all")
 GAP_US = 50.0  # idle gaps longer than this are named
 LAUNCH_EVENT = "DoEnqueueProgram"  # the host event that carries a launch's run_id
+DISPATCH_SPAN = "mxt.step.dispatch"  # its threads are the ones whose spans name a gap
 
 
 # -- wire format ----------------------------------------------------------
@@ -307,26 +308,59 @@ def _self_times(events):
 
 
 def _host_events(planes, prefixes):
-    """One pass over the host planes: [(name, start_ps, end_ps)] of the events
-    whose name starts with one of ``prefixes``, and {(device ordinal, run_id):
+    """One pass over the host planes: [(name, start_ps, end_ps, arguments,
+    thread)] of the events of every thread whose name starts with one of
+    ``prefixes`` (the arguments are what the ``TraceAnnotation`` was given; a
+    thread is a line of a plane, by number), and {(device ordinal, run_id):
     picosecond at which the host enqueued that execution} from the runtime's
     own ``DoEnqueueProgram`` events."""
     spans, launches = [], {}
-    for plane in planes:
+    for p, plane in enumerate(planes):
         if plane.name.startswith(DEVICE_PREFIX):
             continue
         named = plane.ids_named(lambda n: n.startswith(prefixes))
         enqueue = plane.ids_named(lambda n: n == LAUNCH_EVENT)
         if not named and not enqueue:
             continue
-        for _, events in plane.lines(only=named | enqueue, stats_for=enqueue):
+        both = named | enqueue
+        for t, (_, events) in enumerate(plane.lines(only=both, stats_for=both)):
             for mid, s, e, st in events:
                 if mid in named:
-                    spans.append((plane.metadata[mid]["name"], s, e))
-                if st and st.get("run_id") is not None:
+                    spans.append((plane.metadata[mid]["name"], s, e, st, (p, t)))
+                elif st.get("run_id") is not None:
                     key = (st.get("device_ordinal", 0), st["run_id"])
                     launches[key] = min(s, launches.get(key, s))
     return spans, launches
+
+
+def _by_start(span):
+    return span[1:3]
+
+
+def host_spans(trace, prefixes=("mxt.", "bench.")):
+    """[(name, start_ps, end_ps, arguments, thread)] of the program's spans in
+    a trace, from every host thread, by start: what a check of one batch's spans, or a
+    percentile of one span's lengths, is computed from. A trace taken on the
+    CPU has them too, where ``aggregate`` finds no device and returns None."""
+    path = find_xplane(trace)
+    if path is None:
+        return []
+    return sorted(_host_events(read_planes(path), tuple(prefixes))[0], key=_by_start)
+
+
+def span_totals(spans, lo=None, hi=None):
+    """({name: seconds}, {name: calls}) of ``spans`` over all threads, each
+    clipped to ``lo``..``hi`` picoseconds (a span outside counts nowhere; one
+    of no length counts as a call where it lies inside)."""
+    seconds, calls = {}, {}
+    for name, s, e, _, _ in spans:
+        if lo is not None:
+            if e < lo or s >= hi:
+                continue
+            s, e = max(s, lo), min(e, hi)
+        seconds[name] = seconds.get(name, 0) + (e - s)
+        calls[name] = calls.get(name, 0) + 1
+    return {k: v / 1e12 for k, v in sorted(seconds.items())}, calls
 
 
 def aggregate(trace, depth=2, window=None, top=20):
@@ -351,8 +385,11 @@ def aggregate(trace, depth=2, window=None, top=20):
     ``hlo_category``), ``ops`` (the operations that took most, each with its
     scope path, category, phase, result shape, seconds and calls), ``flops`` and
     ``bytes_accessed`` (as XLA's cost model wrote them, summed over the
-    operations run), ``clock_offset_us``, ``launch_pairs`` and ``idle_gaps``
-    (see ``_clock``)."""
+    operations run), ``clock_offset_us``, ``launch_pairs``, ``idle_gaps``,
+    ``idle_by_span_s``, ``host_span_s`` and ``host_span_calls`` (see
+    ``_clock``), and ``spans`` (the ``mxt.*`` / ``bench.*`` spans themselves, as
+    ``host_spans`` lists them: a trace of a host-fed loop is hundreds of
+    megabytes, read once)."""
     path = find_xplane(trace)
     if path is None:
         return None
@@ -361,7 +398,7 @@ def aggregate(trace, depth=2, window=None, top=20):
     spans, launches = _host_events(
         planes, ("mxt.", "bench.") + ((window,) if window else ()))
     if window:
-        ws = [(s, e) for n, s, e in spans if n == window]
+        ws = [(s, e) for n, s, e, _, _ in spans if n == window]
         if ws:
             lo, hi = min(s for s, _ in ws), max(e for _, e in ws)
     tables = {k: {} for k in ("phase", "scope", "named", "kind", "kernel", "calls",
@@ -424,6 +461,7 @@ def aggregate(trace, depth=2, window=None, top=20):
                    for k, v in ops],
            "flops": flops / devices, "bytes_accessed": nbytes / devices}
     out.update(_clock(first, spans, launches, lo, hi))
+    out["spans"] = sorted(spans, key=_by_start)
     return out
 
 
@@ -479,8 +517,19 @@ def _clock(first, spans, launches, lo, hi):
     is taken for it: ``clock_offset_us`` is what to subtract from a device
     time to put it on the host's clock. Gaps longer than ``GAP_US`` on the
     first device are named by the shortest ``mxt.*`` / ``bench.*`` span that
-    covers their start."""
-    out = {"clock_offset_us": None, "launch_pairs": 0, "idle_gaps": []}
+    covers their start, among the spans of the threads that dispatch steps
+    (those with a ``mxt.step.dispatch`` span; every thread where the trace has
+    none): a pool of decode threads is always inside some span, and what holds
+    the device back is what the thread that launches was doing.
+    ``idle_gaps`` lists the ten longest, and
+    ``idle_by_span_s`` sums all of them by that name, those that no span
+    covers under ``(no span)`` and the shorter ones, which the rule does not
+    name, under ``(short)``. Its values add up to the window less the first
+    device's busy time (``window_s - busy_s`` on one device).
+    ``host_span_s`` / ``host_span_calls`` are the ``mxt.*`` spans of every
+    thread over the same window on the host's clock."""
+    out = {"clock_offset_us": None, "launch_pairs": 0, "idle_gaps": [],
+           "idle_by_span_s": {}, "host_span_s": {}, "host_span_calls": {}}
     if first is None:
         return out
     plane, merged = first
@@ -496,15 +545,26 @@ def _clock(first, spans, launches, lo, hi):
     if diffs:
         out["clock_offset_us"] = offset / 1e6
         out["launch_pairs"] = len(diffs)
-    gaps, edge = [], lo
+    dispatchers = {sp[4] for sp in spans if sp[0] == DISPATCH_SPAN}
+    naming = [sp for sp in spans if sp[4] in dispatchers] if dispatchers else spans
+    gaps, by_span, edge = [], {}, lo
     for s, e in merged + [[hi, hi]]:
         if s - edge > GAP_US * 1e6:
             at = edge - offset
-            cover = [(e2 - s2, n) for n, s2, e2 in spans if s2 <= at < e2]
-            gaps.append((s - edge, min(cover)[1] if cover else "(no span)"))
+            cover = [(e2 - s2, n) for n, s2, e2, _, _ in naming if s2 <= at < e2]
+            name = min(cover)[1] if cover else "(no span)"
+            gaps.append((s - edge, name))
+        else:
+            name = "(short)"
+        if s > edge:
+            by_span[name] = by_span.get(name, 0) + s - edge
         edge = max(edge, e)
     gaps.sort(reverse=True)
     out["idle_gaps"] = [[n, d / 1e12] for d, n in gaps[:10]]
+    out["idle_by_span_s"] = {n: d / 1e12 for n, d in
+                             sorted(by_span.items(), key=lambda kv: -kv[1])}
+    out["host_span_s"], out["host_span_calls"] = span_totals(
+        [sp for sp in spans if sp[0].startswith("mxt.")], lo - offset, hi - offset)
     return out
 
 
@@ -539,4 +599,9 @@ def format_table(agg, top=12):
                      % (agg["clock_offset_us"], agg["launch_pairs"]))
     for name, secs in agg["idle_gaps"]:
         lines.append("  idle gap %10.3f ms under %s" % (1e3 * secs, name))
+    for name, secs in agg["idle_by_span_s"].items():
+        lines.append("  idle in all %7.3f ms under %s" % (1e3 * secs, name))
+    for name, secs in agg["host_span_s"].items():
+        lines.append("  host span %9.3f ms in %6d of %s"
+                     % (1e3 * secs, agg["host_span_calls"][name], name))
     return "\n".join(lines)

@@ -592,7 +592,7 @@ def test_worker_fleet_resize_typed_floor_and_cooperative_shrink(tmp_path):
     fleet.resize(2)  # grow spawns the missing worker immediately
     assert fleet.num_workers == 2
     got = []
-    for data, labels, ids, cid in fleet.batches():
+    for data, labels, ids, cid, _ in fleet.batches():
         got.append(ids)
         if len(got) == 2:
             fleet.resize(1)  # shrink mid-stream: cooperative, no loss

@@ -262,7 +262,7 @@ def test_two_host_acceptance_exactly_once_bit_identical(tmp_path):
         any(b.ids != r.ids for b, r in zip(b2, single))
 
 
-def test_backpressure_bounded_buffer_and_hbm_ledger(tmp_path):
+def test_backpressure_bounded_buffer_and_its_host_bytes(tmp_path):
     from mxnet_tpu import diagnostics
 
     man = ShardManifest(make_shards(tmp_path, per_shard=24),
@@ -274,21 +274,21 @@ def test_backpressure_bounded_buffer_and_hbm_ledger(tmp_path):
     ldr.fleet._stop.wait(0.25)
     depth = ldr.fleet._q.qsize()
     assert depth <= 2, "buffer exceeded its bound (no backpressure)"
-    snap = diagnostics.ledger().snapshot()
-    pool = snap.get("prefetch")
-    assert pool and pool["peak_bytes"] > 0, \
-        "buffered batch bytes not accounted in the HBM ledger"
-    assert any("data-plane" in k for k in pool["entries"]), \
-        "the fleet's buffer is not a named prefetch-pool entry"
+    from mxnet_tpu import telemetry
+
+    def buffered():
+        return telemetry.gauge("mxt_data_buffer_bytes", "",
+                               ("host",)).labels("0").value
+
+    # numpy batches on the host: a gauge of their own, and nothing in the
+    # HBM ledger, which counts device memory
+    assert buffered() == depth * (4 * 4 * 4 + 4 * 4)
+    pool = diagnostics.ledger().snapshot().get("prefetch", {})
+    assert not any("data-plane" in k for k in pool.get("entries", {}))
     rest = list(it)
     ids = sorted(i for b in [first] + rest for i in b.ids)
     assert ids == sorted(man.record_ids())
-    # buffer bytes released at epoch end (the fleet's entry is gone)
-    after = diagnostics.ledger().snapshot().get("prefetch", {})
-    assert not any("data-plane-h0" in k and v
-                   for k, v in after.get("entries", {}).items())
-    from mxnet_tpu import telemetry
-
+    assert buffered() == 0  # released at the epoch's end
     page = telemetry.render_prometheus()
     assert 'mxt_data_queue_depth{host="0"} 0' in page
 
@@ -541,6 +541,219 @@ def test_streaming_jpeg_two_hosts_one_epoch(tmp_path):
             assert waited(h) > before[h], "host %d: no data_wait" % h
 
 
+# --------------------------------------------------------------------------
+# the pipeline's spans in the profiler's trace (ISSUE 39)
+# --------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def traced_epochs(tmp_path_factory):
+    """Two epochs of a tiny seeded set under ``jax.profiler.trace`` on the
+    CPU, through the device put and the prefetcher, with what the older
+    readers of the consumer's wait received meanwhile."""
+    import jax
+
+    from mxnet_tpu import profiler_trace, telemetry
+
+    root = tmp_path_factory.mktemp("traced")
+    man = ShardManifest(make_shards(root, per_shard=24), chunk_records=8)
+    ldr = StreamingDataLoader(
+        man, 4, ArrayDecoder((4,), "float32"), host_id=0, num_hosts=1, seed=5,
+        num_workers=2, buffer_batches=2, prefetch_to_device=True)
+
+    def total(name):
+        return telemetry.counter(name, "", ("host",)).labels("0").value
+
+    names = ("mxt_data_wait_seconds_total", "mxt_data_read_seconds_total",
+             "mxt_data_decode_seconds_total", "mxt_data_put_wait_seconds_total")
+    before = {n: total(n) for n in names}
+    phases, record_phase = [], telemetry.record_phase
+
+    def tap(phase, seconds, **kw):
+        if phase == "data_wait" and kw.get("stream") == "data_plane":
+            phases.append((kw["step"], seconds))
+        record_phase(phase, seconds, **kw)
+
+    telemetry.record_phase = tap
+    try:
+        with jax.profiler.trace(str(root / "trace")):
+            yielded = [list(iter(ldr)), list(iter(ldr))]
+    finally:
+        telemetry.record_phase = record_phase
+    return {"spans": profiler_trace.host_spans(str(root / "trace")),
+            "yielded": yielded, "phases": phases, "manifest": man,
+            "counters": {n: total(n) - before[n] for n in names}}
+
+
+def _named(spans, name):
+    return [(s, e, a) for n, s, e, a, _ in spans if n == "mxt.data." + name]
+
+
+def test_every_batch_has_its_spans_under_one_batch_value(traced_epochs):
+    from mxnet_tpu import profiler_trace
+
+    spans, yielded = traced_epochs["spans"], traced_epochs["yielded"]
+    assert [len(e) for e in yielded] == [12, 12]
+    n_batches = 24
+    tags = [a["batch"] for _, _, a in _named(spans, "got")]
+    assert len(set(tags)) == n_batches
+    # "<epoch>:<chunk>:<k>": both epochs, every chunk, both batches of a chunk
+    assert {t.split(":")[0] for t in tags} == {"0", "1"}
+    assert {int(t.split(":")[2]) for t in tags} == {0, 1}
+    for kind in ("decode", "put", "h2d", "got"):
+        assert sorted(a["batch"] for _, _, a in _named(spans, kind)) == sorted(tags), kind
+    for tag in tags:  # in the order a batch passes them
+        starts = [[s for s, _, a in _named(spans, kind) if a["batch"] == tag]
+                  for kind in ("decode", "put", "h2d", "got")]
+        assert all(len(s) == 1 for s in starts) and starts == sorted(starts), tag
+    # the chunk in the tag is the chunk the loader reports, in the order yielded
+    chunk_of = {(int(a["batch"].split(":")[0]), a["n"]): int(a["batch"].split(":")[1])
+                for _, _, a in _named(spans, "got")}
+    for ep, epoch in enumerate(yielded):
+        assert [b.chunk_id for b in epoch] == \
+            [chunk_of[ep, i + 1] for i in range(len(epoch))]
+    # one wait a got, under its n (and one more an epoch, for the stream's end)
+    waits = [(a["n"], a["epoch"]) for _, _, a in _named(spans, "wait")]
+    assert sorted(waits) == sorted([(n, ep) for ep in (0, 1) for n in range(1, 14)])
+    assert all(set(a) == {"n", "epoch", "depth"} for _, _, a in _named(spans, "wait"))
+    _, calls = profiler_trace.span_totals(spans)
+    assert calls["mxt.data.decode"] == n_batches
+    assert calls["mxt.data.commit"] == 2 * traced_epochs["manifest"].num_chunks
+    # the arguments the issue names
+    assert all(set(a) == {"wid", "batch", "records", "bytes_in"} and a["records"] == 4
+               and a["bytes_in"] > 0 for _, _, a in _named(spans, "decode"))
+    assert all(set(a) == {"wid", "batch", "depth"} for _, _, a in _named(spans, "put"))
+    assert all(a["bytes"] == 4 * 4 * 4 + 4 * 4 for _, _, a in _named(spans, "h2d"))
+    # the consumer's spans on one thread, the workers' on others
+    by_thread = {}
+    for name, _, _, _, thread in spans:
+        by_thread.setdefault(name.rpartition(".")[2], set()).add(thread)
+    (consumer,) = by_thread["wait"]
+    assert all(by_thread[k] == {consumer} for k in ("got", "h2d", "epoch_begin", "epoch_end"))
+    assert all(consumer not in by_thread[k] for k in ("lease", "decode", "commit", "put"))
+    leases = [a for _, _, a in _named(spans, "lease")]
+    assert sum(a.get("granted", 0) for a in leases) == calls["mxt.data.commit"]
+    assert all(a.get("stolen", 0) == 0 for a in leases)  # one host: nothing to steal
+
+
+def test_an_epochs_turn_is_two_spans(traced_epochs):
+    spans = traced_epochs["spans"]
+    begins, ends = _named(spans, "epoch_begin"), _named(spans, "epoch_end")
+    assert [a["epoch"] for _, _, a in begins] == [0, 1]
+    assert [a["epoch"] for _, _, a in ends] == [0, 1]
+    # between the two epochs: the first one's end, then the second one's begin
+    assert begins[0][1] <= ends[0][0] and ends[0][1] <= begins[1][0]
+    gots = sorted(s for s, _, _ in _named(spans, "got"))
+    assert sum(ends[0][1] <= s for s in gots) == 12 == sum(s <= ends[0][0] for s in gots)
+    # every worker span of an epoch lies between its begin's start and its end's end
+    for kind in ("lease", "decode", "commit", "put"):
+        for s, e, a in _named(spans, kind):
+            ep = int(a["batch"].split(":")[0]) if "batch" in a else int(s >= begins[1][0])
+            assert begins[ep][0] <= s and e <= ends[ep][1], (kind, a)
+
+
+def test_one_clock_read_feeds_the_counter_the_phase_and_the_span(traced_epochs):
+    """``dt`` is taken once: the phase's observations add up to the counter's
+    rise to the last bit, and each lies inside its span (the two open and
+    close together, so the span is the longer by the annotation's own cost)."""
+    phases = traced_epochs["phases"]
+    assert [n for n, _ in phases] == list(range(1, 13)) * 2
+    total = 0.0
+    for _, dt in phases:
+        total += dt
+    assert traced_epochs["counters"]["mxt_data_wait_seconds_total"] == \
+        pytest.approx(total, rel=1e-12)
+    spans = sorted((a["epoch"], a["n"], (e - s) / 1e12)
+                   for s, e, a in _named(traced_epochs["spans"], "wait") if a["n"] <= 12)
+    assert len(spans) == len(phases)
+    for (_, n, span_s), (step, dt) in zip(spans, phases):
+        assert n == step and span_s >= dt - 1e-6
+    assert sum(s for _, _, s in spans) - total < 0.05
+
+
+def test_read_and_decode_seconds_lie_inside_the_decode_spans(traced_epochs):
+    c = traced_epochs["counters"]
+    decode_spans = sum(e - s for s, e, _ in _named(traced_epochs["spans"], "decode")) / 1e12
+    inside = c["mxt_data_read_seconds_total"] + c["mxt_data_decode_seconds_total"]
+    assert 0 < c["mxt_data_read_seconds_total"] and 0 < c["mxt_data_decode_seconds_total"]
+    assert inside <= decode_spans
+    put_spans = sum(e - s for s, e, _ in _named(traced_epochs["spans"], "put")) / 1e12
+    assert 0 < put_spans <= c["mxt_data_put_wait_seconds_total"] + 1e-6
+
+
+def test_resume_replay_stays_out_of_the_waits_count(tmp_path):
+    """A resumed epoch pulls the consumed head of a partial chunk again and
+    drops it: no ``data_wait`` observation, no ``n``, as before the spans."""
+    from mxnet_tpu import telemetry
+
+    man = ShardManifest(make_shards(tmp_path, n_shards=1, per_shard=16), chunk_records=8)
+    first = _loader(man)
+    it = iter(first)
+    head = [next(it)]
+    cursor = first.cursor()
+    it.close()
+    assert cursor["partial"]
+    seen, record_phase = [], telemetry.record_phase
+
+    def tap(phase, seconds, **kw):
+        if phase == "data_wait":
+            seen.append(kw["step"])
+        record_phase(phase, seconds, **kw)
+
+    telemetry.record_phase = tap
+    try:
+        rest = list(iter(_loader(man).restore_cursor(cursor)))
+    finally:
+        telemetry.record_phase = record_phase
+    assert sorted(i for b in head + rest for i in b.ids) == sorted(man.record_ids())
+    assert seen == [1, 2, 3]  # four batches pulled, the replayed one uncounted
+
+
+def test_per_process_dataloader_names_the_same_wait(tmp_path):
+    import jax
+
+    from mxnet_tpu import gluon, profiler_trace
+
+    data = gluon.data.ArrayDataset(np.arange(12, dtype=np.float32).reshape(6, 2))
+    with jax.profiler.trace(str(tmp_path)):
+        got = list(gluon.data.DataLoader(data, batch_size=2))
+    assert len(got) == 3
+    waits = [sp[3] for sp in profiler_trace.host_spans(str(tmp_path))
+             if sp[0] == "mxt.data.wait"]
+    assert [a["n"] for a in waits] == [1, 2, 3, 4]  # the fourth finds the end
+
+
+def test_trace_input_rehearses_on_the_cpu():
+    """``tools/trace_input.py --rehearse``: a 64-record set and a two-layer
+    net through the tool's own path, its JSON line with every key, and its two
+    checks deciding the exit code (3: passed, on the CPU, never a result)."""
+    import json
+    import subprocess
+    import sys
+
+    tool = os.path.join(os.path.dirname(__file__), "..", "tools", "trace_input.py")
+    run = subprocess.run(
+        [sys.executable, tool, "--rehearse", "--seed", "3000000019", "--seconds", "0.6",
+         "--trace-seconds", "0.3", "--workers", "2", "--buffer-batches", "2"],
+        capture_output=True, text=True, timeout=120)
+    assert run.returncode == 3, run.stderr[-2000:]
+    row = json.loads(run.stdout.strip().splitlines()[-1])
+    assert row["rehearse"] and row["device"]["platform"] == "cpu" and row["wrong"] == []
+    assert row["images_per_s"] is None  # no speed from a CPU
+    assert row["launches_per_step"] == 1.0  # the cast and the normalisation inside it
+    for key in ("steps", "workers", "buffer_batches", "records", "stored_bytes_per_record",
+                "read_us_per_record", "decode_us_per_record", "data_wait_share"):
+        assert key in row, key
+    traced = row["traced"]
+    for key in ("images_per_s", "steps", "busy_s", "window_s", "idle_share",
+                "idle_by_span_s", "worker_share_by_state", "consumer_share_by_span",
+                "host_span_calls",
+                "h2d_ms_per_batch", "h2d_MB_per_s", "data_wait_ms", "epoch_turns",
+                "epoch_turn_ms_longest", "clock_offset_us"):
+        assert key in traced, key
+    calls = traced["host_span_calls"]
+    assert calls["mxt.data.got"] == calls["mxt.step.dispatch"] == traced["steps"]
+    assert traced["epoch_turns"] >= 1
+
+
 def test_check_host_syncs_covers_data_plane():
     """Lint regression: the data-plane modules are SCANNED (a removal
     would silently drop coverage) and currently clean — worker-boundary
@@ -574,6 +787,7 @@ def test_mxt_top_data_section():
         ("mxt_data_records_per_second", frozenset({("host", "1")})): 400.0,
         ("mxt_data_queue_depth", frozenset({("host", "0")})): 3,
         ("mxt_data_queue_depth", frozenset({("host", "1")})): 0,
+        ("mxt_data_buffer_bytes", frozenset({("host", "0")})): 3 * 1024 * 1024,
         ("mxt_data_steals_total", frozenset({("host", "0")})): 4,
         ("mxt_data_stale_leases_total", frozenset({("host", "1")})): 1,
         ("mxt_data_wait_seconds_total", frozenset({("host", "1")})): 2.5,
@@ -581,6 +795,7 @@ def test_mxt_top_data_section():
     frame = mod.render(samples, None, 0)
     assert "data rec/s" in frame and "h0 900" in frame
     assert "steals 4" in frame and "stale refused 1" in frame
+    assert "h0 3 (3.0MB on the host)" in frame  # numpy batches, not HBM
     assert "data_wait share" in frame
     # a process without a data plane renders no data noise
     assert "data rec/s" not in mod.render({}, None, 0)

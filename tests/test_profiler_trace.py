@@ -166,6 +166,88 @@ def test_clock_offset_and_named_gaps(built):
     assert "flash_attention_fwd" in table and "forward/net" in table
 
 
+def test_idle_time_summed_by_span_and_host_seconds_by_name(tmp_path):
+    """One device, busy 0-100, 400-500, 530-600, 900-1000 and 1300-1400 us on
+    a clock 200 us behind the host's: two gaps start under the dispatching
+    thread's ``mxt.data.wait`` (300 and 300 us), one under no span of that
+    thread (300 us: a worker's ``mxt.data.put`` covers its start and a
+    worker's ``mxt.data.decode`` the first gap's, and neither names a gap
+    while the trace says which thread dispatches), and one of 30 us is
+    shorter than ``GAP_US``."""
+    meta = {1: ("%fusion.1 = f32[8] fusion(...)", "fusion.1",
+                {"tf_op": "jit(step)/jvp(forward)/net/dot_general:",
+                 "hlo_category": "convolution fusion"}),
+            8: ("jit_step(123)", "", {})}
+    ops = [(1, 0, 100 * US, {}), (1, 400 * US, 100 * US, {}), (1, 530 * US, 70 * US, {}),
+           (1, 900 * US, 100 * US, {}), (1, 1300 * US, 100 * US, {})]
+    modules = [(8, 0, 100 * US, {"run_id": 7})]
+    names = ("tf_op", "hlo_category", "run_id", "device_ordinal", "n", "batch")
+    host_meta = {1: ("DoEnqueueProgram", "", {}), 2: ("mxt.data.wait", "", {}),
+                 3: ("mxt.data.decode", "", {}), 4: ("mxt.data.got", "", {}),
+                 5: ("mxt.data.put", "", {}), 6: ("mxt.step.dispatch", "", {})}
+    main = [(1, 200 * US, 2 * US, {"run_id": 7, "device_ordinal": 0}),
+            (2, 250 * US, 340 * US, {"n": 1}),        # device 50-390
+            (4, 590 * US, 0, {"n": 1, "batch": "0:3:0"}),
+            (2, 790 * US, 100 * US, {"n": 2})]        # device 590-690
+    worker = [(3, 100 * US, 1050 * US, {"batch": "0:3:0"}),   # device -100-950
+              (5, 1190 * US, 20 * US, {"batch": "0:3:0"}),    # device 990-1010
+              (3, 1700 * US, 100 * US, {"batch": "0:4:0"})]   # past the window
+
+    def aggregate(dispatch):
+        host = {"main": main + [(6, 195 * US, 10 * US, {})] * dispatch, "worker": worker}
+        path = tmp_path / ("idle%d.xplane.pb" % dispatch)
+        path.write_bytes(
+            _plane("/device:TPU:0", {"XLA Modules": modules, "XLA Ops": ops}, meta, names)
+            + _plane("/host:CPU", host, host_meta, names))
+        return pt.aggregate(str(path)), str(path)
+
+    agg, path = aggregate(1)
+    assert agg["clock_offset_us"] == pytest.approx(-200.0)
+    us = {k: round(v * 1e6, 6) for k, v in agg["idle_by_span_s"].items()}
+    assert us == {"mxt.data.wait": 600.0, "(no span)": 300.0, "(short)": 30.0}
+    assert list(us) == ["mxt.data.wait", "(no span)", "(short)"]  # largest first
+    assert sum(agg["idle_by_span_s"].values()) == \
+        pytest.approx(agg["window_s"] - agg["busy_s"], abs=1e-12)
+    assert agg["idle_gaps"] == [[n, pytest.approx(300e-6)] for n in
+                                ("mxt.data.wait", "mxt.data.wait", "(no span)")]
+    # the window is device 0-1400 us, host 200-1600: the worker's first span is
+    # clipped to it, its last lies outside, and one of no length is a call
+    assert agg["host_span_s"] == {
+        "mxt.data.decode": pytest.approx(950e-6), "mxt.data.got": 0.0,
+        "mxt.data.put": pytest.approx(20e-6), "mxt.data.wait": pytest.approx(440e-6),
+        "mxt.step.dispatch": pytest.approx(5e-6)}
+    assert agg["host_span_calls"] == {"mxt.data.decode": 1, "mxt.data.got": 1,
+                                      "mxt.data.put": 1, "mxt.data.wait": 2,
+                                      "mxt.step.dispatch": 1}
+    spans = pt.host_spans(path)
+    assert agg["spans"] == spans  # read once: the aggregate carries them
+    assert [(sp[0], sp[3]) for sp in spans if sp[0] == "mxt.data.got"] == \
+        [("mxt.data.got", {"n": 1, "batch": "0:3:0"})]
+    threads = {sp[0]: sp[4] for sp in spans}
+    assert threads["mxt.data.wait"] == threads["mxt.step.dispatch"] != threads["mxt.data.put"]
+    assert pt.span_totals(spans)[1]["mxt.data.decode"] == 2  # no window: all of them
+    table = pt.format_table(agg)
+    assert "idle in all   0.600 ms under mxt.data.wait" in table
+    assert "host span     0.950 ms in      1 of mxt.data.decode" in table
+    # a trace that does not say which thread dispatches: every thread's spans name
+    us = {k: round(v * 1e6, 6) for k, v in aggregate(0)[0]["idle_by_span_s"].items()}
+    assert us == {"mxt.data.wait": 600.0, "mxt.data.put": 300.0, "(short)": 30.0}
+
+
+@pytest.mark.parametrize("fixture, window", [
+    (OLD_FIXTURE, "bench.trace_window"), (OLD_FIXTURE, None),
+    (NEW_FIXTURE, "bench.trace_window"), (NEW_FIXTURE, None)])
+def test_idle_by_span_adds_up_on_the_chip_traces(fixture, window):
+    agg = pt.aggregate(fixture, window=window)
+    idle = agg["idle_by_span_s"]
+    assert sum(idle.values()) == pytest.approx(agg["window_s"] - agg["busy_s"], abs=1e-9)
+    # the ten named gaps are part of the sum under their names
+    for name in {n for n, _ in agg["idle_gaps"]}:
+        assert idle[name] >= sum(d for n, d in agg["idle_gaps"] if n == name) - 1e-12
+    assert set(agg["host_span_s"]) == set(agg["host_span_calls"])
+    assert all(n.startswith("mxt.") for n in agg["host_span_s"])
+
+
 def test_window_clips_like_the_benchmark(built):
     agg = pt.aggregate(built, window="mxt.window.retire")  # host 1010-1390, unshifted
     assert agg["busy_s"] == pytest.approx(40e-6)  # the second step alone

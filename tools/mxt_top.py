@@ -441,6 +441,8 @@ def render(samples, prev, dt):
                               host=h) for h in data_hosts}
     data_q = {h: metric_sum(samples, "mxt_data_queue_depth", host=h)
               for h in data_hosts}
+    data_buf = {h: metric_sum(samples, "mxt_data_buffer_bytes", host=h)
+                for h in data_hosts}
     data_wait = {h: rate("mxt_data_wait_seconds_total", host=h)[0]
                  for h in data_hosts}
 
@@ -617,7 +619,8 @@ def render(samples, prev, dt):
                          for h in data_hosts),
                _fmt_b(data_bytes_rate)),
             "  data queue       %s   steals %s   stale refused %s"
-            % ("  ".join("h%s %s" % (h, _fmt(data_q[h], "%.0f"))
+            % ("  ".join("h%s %s (%s on the host)"
+                         % (h, _fmt(data_q[h], "%.0f"), _fmt_b(data_buf[h]))
                          for h in data_hosts),
                _fmt(data_steals, "%.0f"), _fmt(data_stale, "%.0f")),
             "  data_wait share  %s"
