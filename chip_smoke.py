@@ -8,7 +8,9 @@ widths with random weights made from ``--seed``:
                  time to ``wait_to_read`` against time to a host read.
 2. ``kernels`` — every Pallas kernel in mxnet_tpu/ops, compiled (never
                  interpreted) against its plain reference: flash forward,
-                 paged decode, BN backward; plus one on-device autotune pass.
+                 the expert layer's grouped matmuls (with ms a call beside
+                 ``ragged_dot``'s), paged decode, BN backward; plus one
+                 on-device autotune pass.
 3. ``resnet50``— ResNet-50 v1, bf16, NHWC, batch 64, 224x224, through the
                  Gluon loop fused into one launch (``Trainer.fuse_step``).
 4. ``bert``    — BERT-base MLM, vocabulary 30522, batch 32 x 128, bf16,
@@ -78,6 +80,23 @@ def median_ms(fn, *args):
     from mxnet_tpu.tuning import autotune
 
     return autotune._time(functools.partial(fn, *args), 5) * 1e3
+
+
+def inflight_ms(fn, *args, calls=20, repeats=3):
+    """Wall milliseconds a call of ``calls`` calls dispatched back to back and
+    waited for once, the least of ``repeats``: for a kernel of a fraction of a
+    millisecond, which one blocking call's dispatch would drown."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best * 1e3
 
 
 def mem(dev):
@@ -353,6 +372,8 @@ def phase_kernels(args, dev):
         check(bool(jnp.all(jnp.isfinite(o.astype(jnp.float32)))), "selected flash: not finite")
         out["flash"]["selected_%d" % T] = row
 
+    out["grouped_matmul"] = grouped_matmul_table(args, key, interp)
+
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks
     if args.rehearse:
@@ -427,6 +448,62 @@ def phase_kernels(args, dev):
     ent = tuning.measure_bn(x, dy, 0.0 * chan, chan, chan, interpret=interp, iters=3)
     out["measure_bn"] = dict(shape=(m, c), entry=ent, seconds=time.perf_counter() - t0)
     return out
+
+
+def grouped_matmul_table(args, key, interp):
+    """The expert layer's three grouped products (forward, input gradient,
+    weight gradient; gate / up and down) at the three expert cells' shapes,
+    16 groups holding about half the rows laid out: each against
+    ``ragged_dot`` in float32, and ms a call for the kernel, for ``ragged_dot``
+    on the rows laid out and on the rows cut to those held (PERF.md,
+    Findings, PR 40: the stand-alone table)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import grouped_matmul as GM
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    groups = 16
+    cells = {"tiny": (1024, 256, 128, 520, 0.25)} if args.rehearse else {
+        "kanana2_a3b_train_s4096": (12288, 2048, 768, 7400, 0.25),
+        "keye_vl2_a3b_train_s8192": (16384, 2048, 768, 8000, 0.15),
+        "lfm2_a2b_train_s8192": (16384, 2048, 1536, 8200, 0.10)}
+    table = {}
+    for cell, (rows, hidden, width, held, sigma) in cells.items():
+        rs = np.random.RandomState(args.seed % (2 ** 31))
+        share = np.exp(sigma * rs.normal(size=groups))
+        sizes = jnp.asarray(rs.multinomial(held, share / share.sum()), jnp.int32)
+        cut = -(-held // GM.ROW_TILE) * GM.ROW_TILE
+        ks = jax.random.split(jax.random.fold_in(key, rows + width), 4)
+        valid = (jnp.arange(rows) < held)[:, None]
+        wide = jnp.where(valid, jax.random.normal(ks[0], (rows, hidden), f32), 0).astype(bf16)
+        thin = jnp.where(valid, jax.random.normal(ks[1], (rows, width), f32), 0).astype(bf16)
+        gate = (0.02 * jax.random.normal(ks[2], (groups, hidden, width), f32)).astype(bf16)
+        down = (0.02 * jax.random.normal(ks[3], (groups, width, hidden), f32)).astype(bf16)
+
+        for name, x, w, dy in (("gate", wide, gate, thin), ("down", thin, down, wide)):
+            for product in ("fwd", "dx", "dw"):
+                with jax.default_matmul_precision("highest"):
+                    want = jax.jit(lambda x, w, dy, s, p=product: GM._ragged(
+                        p, x.astype(f32), w.astype(f32), dy.astype(f32), s))(x, w, dy, sizes)
+                if product != "dw":  # on the chip ragged_dot leaves those rows as they lay
+                    want = jnp.where(valid, want, 0.0)
+                ours = jax.jit(functools.partial(GM._kernel, product, interpret=interp))
+                theirs = jax.jit(functools.partial(GM._ragged, product))
+                got = ours(x, w, dy, sizes)
+                row = dict(rel_err=rel_err(got, want),
+                           kernel_ms=inflight_ms(ours, x, w, dy, sizes),
+                           ragged_dot_ms=inflight_ms(theirs, x, w, dy, sizes),
+                           ragged_dot_rows_held_ms=inflight_ms(
+                               theirs, x[:cut], w, dy[:cut], sizes))
+                table["%s.%s.%s" % (cell, name, product)] = row
+                check(row["rel_err"] < 2e-2, "grouped matmul %s %s %s: rel err %.4f vs "
+                      "float32" % (cell, name, product, row["rel_err"]))
+                if product != "dw":
+                    check(not bool(jnp.any(got[held:])), "grouped matmul %s %s %s: rows "
+                          "past the groups are not zero" % (cell, name, product))
+    return table
 
 
 # ---------------------------------------------------------------------------

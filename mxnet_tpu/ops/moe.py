@@ -18,8 +18,9 @@ One pure function, :func:`moe_ffn`:
   gathered, at most a static bound of rows at a time
   (``default_slots_bound`` of the shapes);
 * **experts** — SwiGLU of each held expert over its own rows: three grouped
-  matmuls (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
-  grouped-matmul kernel and everything else to masked dots);
+  matmuls (``ops/grouped_matmul.py``: on a TPU, at whole 128-lane widths, the
+  program's own kernels, which visit only the row tiles a group holds;
+  ``jax.lax.ragged_dot`` everywhere else);
 * **combine** — every token sums its held slots, weighted; what the experts
   that are NOT held would have added is left out (it is computed where they
   live);
@@ -51,11 +52,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .grouped_matmul import ROW_TILE, grouped_matmul
 from .registry import register
 
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
-_BOUND_TILE = 512  # the bound on the rows is rounded up to this many
+_BOUND_TILE = ROW_TILE  # the bound on the rows is rounded up to this many
 
 
 def _rows(x, idx):
@@ -141,10 +143,10 @@ def default_slots_bound(tokens, top_k, n_routed, count):
 
 def _swiglu_rows(xs, sizes, w_gate, w_up, w_down):
     """SwiGLU of every row by its group's expert: (C, H) -> (C, H)."""
-    h = jax.lax.ragged_dot(xs, w_gate, sizes)
-    u = jax.lax.ragged_dot(xs, w_up, sizes)
+    h = grouped_matmul(xs, w_gate, sizes)
+    u = grouped_matmul(xs, w_up, sizes)
     a = (jax.nn.silu(h.astype(F32)) * u.astype(F32)).astype(xs.dtype)
-    return jax.lax.ragged_dot(a, w_down, sizes)
+    return grouped_matmul(a, w_down, sizes)
 
 
 def _block(c, x, wflat, experts, *, bound, order, inv, is_held, starts):

@@ -72,6 +72,7 @@ __all__ = [
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
     "record_gated_conv", "gated_conv_branches",
+    "record_grouped_matmul", "grouped_matmul_branches",
     "record_flash_heads", "flash_heads_per_step",
     "record_flash_layout", "flash_layouts",
     "record_moe_counts", "moe_counts",
@@ -856,9 +857,9 @@ def record_flash_heads(kernel, heads):
             ("kernel", "heads")).labels(kernel, str(int(heads))).inc()
 
 
-def _by_kernel(family):
-    """{kernel: {second label: count}} of a counter family whose labels are
-    ``kernel`` and one more."""
+def _by_first_label(family):
+    """{first label: {second label: count}} of a counter family with two
+    labels."""
     fam = _REGISTRY.get(family)
     out = {}
     if fam is not None:
@@ -869,7 +870,7 @@ def _by_kernel(family):
 
 def flash_heads_per_step():
     """{kernel: {heads: traces}} of :func:`record_flash_heads` so far."""
-    return _by_kernel("mxt_flash_heads_per_step")
+    return _by_first_label("mxt_flash_heads_per_step")
 
 
 def record_flash_layout(kernel, layout):
@@ -887,7 +888,7 @@ def record_flash_layout(kernel, layout):
 
 def flash_layouts():
     """{kernel: {layout: traces}} of :func:`record_flash_layout` so far."""
-    return _by_kernel("mxt_flash_layout_total")
+    return _by_first_label("mxt_flash_layout_total")
 
 
 def record_gated_conv(branch):
@@ -901,6 +902,22 @@ def record_gated_conv(branch):
 def gated_conv_branches():
     """{branch: traces} of :func:`record_gated_conv` so far."""
     return _branches("mxt_gated_conv_total")
+
+
+def record_grouped_matmul(product, branch):
+    """One traced grouped matmul of the expert layer (``product``: ``fwd``,
+    ``dx`` or ``dw``) by the branch it took
+    (``mxt_grouped_matmul_total{product, branch=kernel|ragged_dot}``). Counted
+    at trace time, as the flash branches: nothing enters the compiled step. A
+    call that is ``jax.lax.ragged_dot`` whole counts its forward alone: its
+    derivative is JAX's own."""
+    counter("mxt_grouped_matmul_total", "Traced grouped matmuls by product and branch.",
+            ("product", "branch")).labels(product, branch).inc()
+
+
+def grouped_matmul_branches():
+    """{product: {branch: traces}} of :func:`record_grouped_matmul` so far."""
+    return _by_first_label("mxt_grouped_matmul_total")
 
 
 def record_moe_counts(expert_load, slots_lost, blocks_run):

@@ -68,3 +68,21 @@ def pytest_collection_modifyitems(config, items):
         if item.get_closest_marker("nightly") is not None \
                 and not nightly_on:
             item.add_marker(skip_nightly)
+
+
+@pytest.fixture
+def grouped_matmul_kernels(monkeypatch):
+    """``engage()``: from then on ``ops/grouped_matmul.py`` dispatches as on a
+    TPU, its two kernels in interpret mode (a test computes what
+    ``ragged_dot`` gives first, then engages)."""
+    from mxnet_tpu.ops import grouped_matmul as GM
+
+    def engage():
+        gmm, tgmm = GM._gmm_pallas, GM._tgmm_pallas
+        monkeypatch.setattr(GM, "on_tpu", lambda: True)
+        monkeypatch.setattr(GM, "_gmm_pallas", lambda *a, interpret=False, **kw:
+                            gmm(*a, interpret=True, **kw))
+        monkeypatch.setattr(GM, "_tgmm_pallas", lambda *a, interpret=False, **kw:
+                            tgmm(*a, interpret=True, **kw))
+
+    return engage

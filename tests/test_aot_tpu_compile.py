@@ -10,6 +10,7 @@ references on the device.
 
 The file's name sorts early on purpose: the tier-1 window reaches it.
 """
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
@@ -21,6 +22,7 @@ import pytest
 from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import bn_pallas
+from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
 
 
@@ -177,6 +179,18 @@ def _bn(m, c, block_rows=None):
     return case
 
 
+def _grouped(product, rows, k, n, dtype="bfloat16"):
+    """One of the expert layer's three grouped products, 16 groups, at the
+    tiles the module gives every shape: the forward (rows, k) x (16, k, n),
+    its input gradient, its weight gradient."""
+    def case(chip):
+        dt = jnp.dtype(dtype)
+        return _compile(chip, functools.partial(GM._kernel, product),
+                        ((rows, k), dt), ((16, k, n), dt), ((rows, n), dt),
+                        ((16,), jnp.int32))
+    return case
+
+
 _CASES = {
     # flash forward: BERT-base (batch 32 x 128) without and with a
     # padding bias, longer and ragged sequences, explicit big blocks, and
@@ -256,6 +270,16 @@ _CASES = {
     "flash_bwd_in_place_s512_bias": _in_place(32, 512, 12, 64, True, bias=True),
     "flash_bwd_in_place_s256": _in_place(64, 256, 12, 64, True),
     "flash_bwd_in_place_d128": _in_place(64, 128, 6, 128, True, bias=True),
+    # the expert layer's grouped matmuls at the three expert cells' shapes:
+    # 12288 rows laid out at width 768 (Kanana), 16384 at 768 (Keye) and at
+    # 1536 (LFM2), gate / up and down, each product; a weight block whole in
+    # VMEM, the weight gradient's float32 accumulator beside its blocks
+    **{"grouped_%s_%dx%dx%d" % (p, r, k, n): _grouped(p, r, k, n)
+       for p in ("fwd", "dx", "dw")
+       for r, k, n in ((12288, 2048, 768), (12288, 768, 2048),
+                       (16384, 2048, 1536), (16384, 1536, 2048))},
+    "grouped_fwd_16384x2048x768": _grouped("fwd", 16384, 2048, 768),
+    "grouped_dw_f32_1024x2048x1536": _grouped("dw", 1024, 2048, 1536, "float32"),
     "indexer_select_8192": _indexer(1, 8192),
     "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the

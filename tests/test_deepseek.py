@@ -336,6 +336,50 @@ def test_expert_layer_against_the_reference(routing, bound):
         assert total > 4 * bound  # the further blocks did the rest, exactly
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bound", [None, 512], ids=["one_block", "further_blocks"])
+def test_expert_layer_through_the_kernels_equals_ragged_dot(bound, dtype,
+                                                            grouped_matmul_kernels):
+    """The layer at whole 128-lane widths and whole row tiles, where the rule
+    of ``ops/grouped_matmul.py`` engages: value and every gradient through the
+    kernels (interpret mode) against the same layer through ``ragged_dot``,
+    within the operands' rounding. With 512 rows a block and every slot held,
+    the first block's kept residuals AND the recomputed further blocks take
+    the kernels."""
+    from mxnet_tpu import telemetry
+
+    held = (4, 6)
+    a = ref.arch(_config(held, hidden_size=128, moe_intermediate_size=128))
+    p = _moe_params(a, held)
+    p["router.bias"] = ROUTINGS["every_slot_held"](p["router.bias"])
+    x = _normal(52, (512, a["hidden_size"]))  # 1536 slots, all held
+    ct = _normal(53, x.shape)
+    p, x = jax.tree_util.tree_map(lambda v: v.astype(dtype), (p, x))
+
+    def run():
+        (y, load, lost, ran), vjp = jax.vjp(
+            lambda pp, xx: _run_moe(pp, xx, a, held, bound), p, x)
+        return y, vjp((ct.astype(dtype),) + tuple(
+            jnp.zeros(c.shape, jax.dtypes.float0) for c in (load, lost, ran))), lost, ran
+
+    want, want_grads, _, _ = run()
+    grouped_matmul_kernels()
+    before = telemetry.grouped_matmul_branches()
+    got, got_grads, lost, ran = run()
+    after = telemetry.grouped_matmul_branches()
+    # gate, up and down, once for block 0 and once inside each loop's body
+    calls = 3 if bound is None else 6
+    for product in ("fwd", "dx", "dw"):
+        assert after[product]["kernel"] - before.get(product, {}).get("kernel", 0) >= calls
+    assert int(lost) == 0 and int(ran) == (0 if bound is None else 2)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    _close(got, want, tol)
+    for g, w in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        if np.any(np.asarray(w, np.float32)):
+            _close(g, w, tol)
+
+
 def test_slots_lost_counts_the_rows_of_a_block_that_did_not_run(monkeypatch):
     """The count is taken from the work done: the slots held less the rows
     handed to the grouped matmuls of the blocks that ran. With the trip count

@@ -189,6 +189,34 @@ def test_the_router_adds_the_publishers_epsilon_and_the_default_stays():
     _close(np.asarray(default).sum(-1), np.ones(40), 2e-7)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_expert_layer_through_the_kernels_equals_ragged_dot(dtype, grouped_matmul_kernels):
+    """``moe_ffn`` as the model calls it (its own bound on the rows, no shared
+    expert, the publisher's epsilon) at whole 128-lane widths: value and
+    gradients through the grouped-matmul kernels (interpret mode) against
+    ``ragged_dot``'s, within the operands' rounding."""
+    from mxnet_tpu import telemetry
+
+    a = ref.arch(_arch(hidden_size=128, moe_intermediate_size=256))
+    p = {n: v.astype(dtype) for n, v in _moe_params(a, (0, 8)).items()}
+    x = jax.random.normal(jax.random.PRNGKey(43), (512, 128), F32).astype(dtype)
+    ct = jax.random.normal(jax.random.PRNGKey(44), (512, 128), F32)
+
+    def run():
+        return jax.value_and_grad(lambda pp, xx: jnp.sum(
+            _run_moe(pp, xx, a, (0, 8))[0].astype(F32) * ct), (0, 1))(p, x)
+
+    want = run()
+    grouped_matmul_kernels()
+    got = run()
+    assert telemetry.grouped_matmul_branches()["dw"]["kernel"] >= 3
+    assert int(_run_moe(p, x, a, (0, 8))[2]) == 0  # no slot lost
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        if np.any(np.asarray(w, np.float32)):
+            _close(g, w, tol)
+
+
 @pytest.mark.parametrize("chips", [4, 2])
 def test_shares_of_the_experts_add_up_to_the_uncut_layer(chips):
     """``chips`` chips hold 8 / chips of eight experts each, as the cell's four

@@ -13,7 +13,9 @@ took (``flash_fwd_branches``, ``flash_bwd_branches``) and the heads a grid
 step of each traced flash kernel takes (``flash_heads_per_step``), the layout
 each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
-each traced gated short convolution took (``gated_conv_branches``), the tuning
+each traced gated short convolution took (``gated_conv_branches``) and each
+traced grouped matmul of an expert layer by product (``grouped_matmul_branches``),
+the tuning
 table's entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
@@ -44,7 +46,8 @@ import run as bench  # noqa: E402 — benchmark/run.py
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
          "moe", "router", "dispatch", "experts", "combine", "shared",
          "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select",
-         "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj")
+         "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj",
+         "grouped_matmul_bwd")
 
 
 class Context(bench.Context):
@@ -58,6 +61,7 @@ class Context(bench.Context):
     flash_heads = None
     flash_layouts = None
     gated_conv = None
+    grouped_matmul = None
     tuned = None
     moe = None
     selection = None
@@ -90,6 +94,7 @@ class Context(bench.Context):
         Context.flash_heads = telemetry.flash_heads_per_step()
         Context.flash_layouts = telemetry.flash_layouts()
         Context.gated_conv = telemetry.gated_conv_branches()
+        Context.grouped_matmul = telemetry.grouped_matmul_branches()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
         Context.selection = telemetry.selection_counts()
@@ -161,6 +166,8 @@ def main(argv):
             row["flash_layouts"] = Context.flash_layouts
         if Context.gated_conv:  # and which path each gated short convolution
             row["gated_conv_branches"] = Context.gated_conv
+        if Context.grouped_matmul:  # and which each grouped matmul, by product
+            row["grouped_matmul_branches"] = Context.grouped_matmul
         if Context.tuned:  # the tiles each kernel shape ran with
             row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
