@@ -103,13 +103,17 @@ class DeepseekMoE(HybridBlock):
     ``selection_bias=False`` is a router without the bias buffer;
     ``router_gradient=False`` lets no gradient through the chosen experts'
     weights (op ``moe_ffn``); ``sum_epsilon`` is what the model adds to the
-    chosen scores' sum before dividing by it."""
+    chosen scores' sum before dividing by it; ``activation`` is the gate of
+    the experts' units (``"silu"``: SwiGLU; ``"relu"``: ReGLU). Called with a
+    second input, ``router_rows``, the router reads those rows and not the
+    experts' (a model whose router sits before its attention)."""
 
     def __init__(self, units, moe_intermediate_size, n_routed_experts,
                  num_experts_per_tok, n_shared_experts=0,
                  routed_scaling_factor=1.0, experts_held=None,
                  scoring="sigmoid", selection_bias=True, router_gradient=True,
-                 sum_epsilon=1e-20, prefix=None, params=None):
+                 sum_epsilon=1e-20, activation="silu", prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = experts_held or (0, n_routed_experts)
         if first < 0 or count < 1 or first + count > n_routed_experts:
@@ -119,7 +123,7 @@ class DeepseekMoE(HybridBlock):
                             experts_held=(first, count),
                             scaling=routed_scaling_factor, scoring=scoring,
                             router_gradient=router_gradient,
-                            sum_epsilon=sum_epsilon)
+                            sum_epsilon=sum_epsilon, activation=activation)
         self._bias = bool(selection_bias)
         width, shared = moe_intermediate_size, n_shared_experts * moe_intermediate_size
         with self.name_scope():
@@ -156,14 +160,17 @@ class DeepseekMoE(HybridBlock):
         self.slots_lost.cast("int32")
         self.blocks_run.cast("int32")
 
-    def hybrid_forward(self, F, x, router_weight=None, router_bias=None,
-                       gate_weight=None, up_weight=None, down_weight=None,
-                       shared_gate_weight=None, shared_up_weight=None,
-                       shared_down_weight=None, expert_load=None, slots_lost=None,
-                       blocks_run=None):
+    def hybrid_forward(self, F, x, router_rows=None, router_weight=None,
+                       router_bias=None, gate_weight=None, up_weight=None,
+                       down_weight=None, shared_gate_weight=None,
+                       shared_up_weight=None, shared_down_weight=None,
+                       expert_load=None, slots_lost=None, blocks_run=None):
+        logits = None
+        if router_rows is not None:
+            logits = F.moe_router_logits(router_rows, router_weight)
         ret = F.moe_ffn(x, router_weight, router_bias, gate_weight, up_weight,
                         down_weight, shared_gate_weight, shared_up_weight,
-                        shared_down_weight, **self._static)
+                        shared_down_weight, logits, **self._static)
         if not isinstance(ret, tuple):
             return ret  # symbolic trace: the counts are hidden outputs
         out, load, lost, ran = ret

@@ -114,16 +114,21 @@ class _HeadNorm(HybridBlock):
 
 class GroupedQueryAttention(HybridBlock):
     """Causal attention of ``num_heads`` query heads on ``num_kv_heads`` K/V
-    heads, over the keys ``mask`` selects (all the earlier ones without)."""
+    heads, over the keys ``mask`` selects (all the earlier ones without).
+    ``rope_theta=None`` is attention without positions, ``head_norm=False``
+    without the RMSNorm over each head of q and k, ``window`` a static
+    window: a query sees itself and the ``window - 1`` keys before it."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 rope_theta=10000.0, rms_norm_eps=1e-6, prefix=None, params=None):
+                 rope_theta=10000.0, rms_norm_eps=1e-6, head_norm=True,
+                 window=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if num_heads % num_kv_heads:
             raise MXNetError("GroupedQueryAttention: %d query heads on %d K/V "
                              "heads" % (num_heads, num_kv_heads))
         self._heads, self._kv, self._dim = num_heads, num_kv_heads, head_dim
-        self._theta = float(rope_theta)
+        self._theta = None if rope_theta is None else float(rope_theta)
+        self._window = window
         dense = dict(flatten=False, use_bias=False)
         with self.name_scope():
             self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
@@ -131,23 +136,27 @@ class GroupedQueryAttention(HybridBlock):
             # keys then values, one matmul
             self.kv_proj = nn.Dense(2 * num_kv_heads * head_dim, in_units=units,
                                     prefix="kv_proj_", **dense)
-            self.qk_norm = _HeadNorm(head_dim, rms_norm_eps, prefix="qk_norm_")
+            self.qk_norm = None
+            if head_norm:
+                self.qk_norm = _HeadNorm(head_dim, rms_norm_eps, prefix="qk_norm_")
             self.o_proj = nn.Dense(units, in_units=num_heads * head_dim,
                                    prefix="o_proj_", **dense)
 
     def hybrid_forward(self, F, x, mask=None):
         H, G, D = self._heads, self._kv, self._dim
-        turn = dict(theta=self._theta, seq_axis=1, interleaved=False)
         q = F.reshape(self.q_proj(x), shape=(0, 0, H, D))
         kv = F.reshape(self.kv_proj(x), shape=(0, 0, 2 * G, D))
         k = F.slice_axis(kv, axis=2, begin=0, end=G)
         v = F.slice_axis(kv, axis=2, begin=G, end=None)
-        q, k = self.qk_norm(q, k)
-        q, k = F.rotary_embedding(q, **turn), F.rotary_embedding(k, **turn)
+        if self.qk_norm is not None:
+            q, k = self.qk_norm(q, k)
+        if self._theta is not None:
+            turn = dict(theta=self._theta, seq_axis=1, interleaved=False)
+            q, k = F.rotary_embedding(q, **turn), F.rotary_embedding(k, **turn)
         out = F.flash_attention(
             F.transpose(q, axes=(0, 2, 1, 3)), F.transpose(k, axes=(0, 2, 1, 3)),
             F.transpose(v, axes=(0, 2, 1, 3)), None, mask, causal=True,
-            sm_scale=1.0 / math.sqrt(D))
+            sm_scale=1.0 / math.sqrt(D), window=self._window)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
         return self.o_proj(out)
 

@@ -41,6 +41,18 @@ hold (``_fwd_vmem_limit``, ``_bwd_vmem_limit``).
 A call without a mask and with as many K/V heads as query heads traces to
 the kernels it traced to before either was built.
 
+Window: ``window=W`` (a static integer, under ``causal``) lets a query see
+itself and the ``W - 1`` keys before it. Nothing is read for it: the forward's
+loop over K/V blocks starts at the first block that holds a key inside the
+Q block's window, the backward's loop over Q blocks ends at the last block
+that can see the K/V block, and every tile visited carries one more
+comparison. Those calls are ``pallas_call``s
+under names of their own (``window_attention_fwd`` / ``window_attention_bwd``),
+counted as branches of their own, with the blocks they visit beside the
+causal call's (``telemetry.flash_window_blocks()``). A call without
+``window``, or with one that reaches every key, is the causal call, letter
+for letter.
+
 Several heads a grid step: where a head is one tile (BERT's 128 x 128 and
 512 x 512) both kernels take ``_heads_per_step``'s heads a step, blocks
 ``(G, T, D)`` on the flat batch x head axis, walked by a loop inside the
@@ -165,7 +177,36 @@ def _sum_kv_group(dx, kv_heads):
                    .astype(jnp.float32), axis=2).astype(dx.dtype)
 
 
-def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None):
+def _in_window(row, col, shift, window):
+    """Whether key ``col`` lies inside query ``row``'s window: the query's
+    own position (``row + shift``, bottom-right aligned as ``causal``) and
+    the ``window - 1`` keys before it. Index arrays or scalars."""
+    return row + (shift - window) < col
+
+
+def _window_band(tq, tk, window):
+    """(tq, tk) booleans of ``_in_window`` (the causal side is the caller's)."""
+    return _in_window(jnp.arange(tq)[:, None], jnp.arange(tk)[None, :],
+                      tk - tq, window)
+
+
+def window_blocks(tq, tk, block_q, block_k, window):
+    """(visited, causal): the (Q block, K/V block) tiles a window call of
+    these lengths and blocks visits, and those the causal call visits.
+    From the shapes alone; the same for both kernels (the forward walks a
+    Q block's row of tiles, the backward a K/V block's column)."""
+    shift = tk - tq
+    visited = causal = 0
+    for q_off in range(0, tq, block_q):
+        hi = min(-(-tk // block_k), (q_off + block_q + shift + block_k - 1) // block_k)
+        lo = max(q_off + shift - (window - 1), 0) // block_k
+        causal += hi
+        visited += hi - min(lo, hi)
+    return visited, causal
+
+
+def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None,
+                         window=None):
     """Plain-XLA reference (also the CPU path). O(T^2) memory."""
     k, v = _kv_per_query_head(q, k, v)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -176,6 +217,8 @@ def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None):
     if causal:
         tq, tk = scores.shape[-2], scores.shape[-1]
         tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            tril = jnp.logical_and(tril, _window_band(tq, tk, window))
         scores = jnp.where(tril, scores, _NEG_INF)
     if mask is not None:
         scores = jnp.where(mask[:, None] != 0, scores, _NEG_INF)
@@ -214,7 +257,8 @@ def _each_head(heads, unroll, head):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
-                      *, block_k, causal, sm_scale, kv_len, q_len, unroll=1):
+                      *, block_k, causal, sm_scale, kv_len, q_len, unroll=1,
+                      window=None):
     """One (batch x head, Q block) grid step of the forward: the head's K/V
     sit in VMEM whole, the Q block streams over them block_k keys at a time
     with the online softmax. Operands of both matmuls are in the input
@@ -231,8 +275,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
     block, two blocks an iteration so that the second block's scores can be
     issued beside the first's softmax. A selection mask (``mask_ref``: this
     Q block's int8 tiles, one a K/V block, picked by a leading index) joins
-    the other masks on every block. ``lse`` leaves as a lane-oriented row,
-    4 bytes a query."""
+    the other masks on every block. Under a ``window`` the same loop starts
+    at the first block that holds a key inside the Q block's window, with
+    the window's comparison beside the diagonal's on every block (three
+    loops, the blocks between the edges unmasked, gave back on the chip all
+    that the skipped blocks save: 4.03 ms a call at (1, 28 on 4, 8192, 128)
+    under 4096 against 3.36 so, the causal call 4.02). ``lse`` leaves as a
+    lane-oriented row, 4 bytes a query."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -281,6 +330,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
                     row = q_off + jax.lax.broadcasted_iota(
                         i32, (block_q, block_k), 0)
                     masks.append(col <= row + shift)
+                    if window is not None:
+                        masks.append(_in_window(row, col, shift, window))
             if mask_ref is not None:  # the selection: data, on every block
                 masks.append(mask_ref[0, 0, ik].astype(i32) != 0)
             if masks:
@@ -328,7 +379,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
             carry = (jnp.full((block_q, 1), neg_inf, f32),
                      jnp.zeros((block_q, 1), f32),
                      jnp.zeros((block_q, v_ref.shape[2]), f32))
-            m, l, acc = loop(0, n_all, carry, True)
+            lo = 0
+            if window is not None:
+                # (shift >= 0 here.) The first block that holds a key the
+                # Q block's top row sees. A row that sees nothing of that
+                # block carries exp(0) sums of it until its first real
+                # score rescales them by exp(-1e30)
+                lo = jax.lax.div(jnp.maximum(
+                    q_off + i32(shift - window + 1), 0), i32(block_k))
+            m, l, acc = loop(lo, n_all, carry, True)
         else:
             n_clear = num_kv - (kv_pad != kv_len)  # only the tail block is masked
             carry = step(0, None, n_clear == 0)
@@ -467,14 +526,17 @@ def _kv_head_of(group):
 
 
 def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                          interpret, mask=None, heads_per_step=None):
+                          interpret, mask=None, heads_per_step=None,
+                          window=None):
     """(out, lse) of the forward kernel: ``out`` (B, H, Tq, Dv) in the input
     dtype, ``lse`` (B, H, Tq) float32. The kernel writes ``lse`` as a
     (B*H, 1, Tq) array in (1, 1, block_q) row blocks. ``k`` / ``v`` hold
     ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None. A grid step takes
     ``_heads_per_step``'s heads (``heads_per_step`` overrides the rule, for
     tests and stand-alone sweeps), counted by
-    ``telemetry.flash_heads_per_step()``."""
+    ``telemetry.flash_heads_per_step()``. With ``window`` (causal, Tk >= Tq)
+    the call goes by the name ``window_attention_fwd`` and the tiles it
+    visits are counted (``telemetry.flash_window_blocks()``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -549,6 +611,10 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             vmem_limit_bytes=_fwd_vmem_limit(Tkp, D, Dv, block_q, block_k,
                                              q.dtype.itemsize))
 
+    if window is not None:
+        _telemetry.record_flash_window_blocks(
+            "fwd", *window_blocks(Tq, Tk, block_q, block_k, window))
+
     def kernel(*refs):
         refs = list(refs)
         if bias is None:
@@ -556,7 +622,8 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         if mask is None:
             refs.insert(4, None)
         _flash_fwd_kernel(*refs, block_k=block_k, causal=causal,
-                          sm_scale=sm_scale, kv_len=Tk, q_len=Tq, unroll=unroll)
+                          sm_scale=sm_scale, kv_len=Tk, q_len=Tq, unroll=unroll,
+                          window=window)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -575,7 +642,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, 1, Tqp), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" if window is None else "window_attention_fwd",
         **extra,
     )(*args)
     out = out.reshape(B, H, Tqp, Dv)[:, :, :Tq]
@@ -589,7 +656,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                       dq_ref, dk_ref, dv_ref, db_ref, dq_acc, *,
                       block_q, causal, sm_scale, kv_len, q_len, kv_pad,
-                      unroll=1):
+                      unroll=1, window=None):
     """One (batch x head, K/V block) grid step of the backward: Q, dO and
     the row statistics of the whole head sit in VMEM, the K/V block's
     scores are recomputed from ``lse`` one Q block at a time, and five
@@ -607,7 +674,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
     (``mask_ref``: this K/V block's int8 tiles, key-major, one a Q block)
     joins the other masks on every tile. ``dk`` / ``dv`` leave in the type
     of their blocks: the input's, or float32 a query head where a group of
-    query heads shares the K/V head and XLA sums the group."""
+    query heads shares the K/V head and XLA sums the group. Under a
+    ``window`` the loop over Q blocks ends at the last block that can see
+    this K/V block, the window's comparison on every tile beside the
+    diagonal's."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -668,6 +738,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                     masks.append(qrow < q_len)
                 if causal:
                     masks.append(kcol <= qrow + shift)
+                    if window is not None:
+                        masks.append(_in_window(qrow, kcol, shift, window))
             if mask_ref is not None:  # the selection: data, on every tile
                 masks.append(mask_ref[0, 0, iq].astype(jnp.int32) != 0)
             if masks:
@@ -696,8 +768,15 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                 jnp.maximum(k_off - shift, 0), jnp.int32(block_q)), nq)
         db0 = None if bias_ref is None else jnp.zeros((block_k, 1), f32)
         # i32 bounds: under jax_enable_x64 a Python int traces as i64
+        last = jnp.int32(nq)
+        if window is not None:
+            # (shift >= 0 here.) One past the last Q block with a row that
+            # sees this K/V block's last key
+            last = jnp.minimum(jax.lax.div(jnp.maximum(
+                k_off + jnp.int32(block_k + window - 2 - shift), 0),
+                jnp.int32(block_q)) + 1, nq)
         dk, dv, db = jax.lax.fori_loop(
-            first, jnp.int32(nq), body,
+            first, last, body,
             (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32), db0))
         dk_ref[g] = (dk * sm_scale).astype(dk_ref.dtype)
         dv_ref[g] = dv.astype(dv_ref.dtype)
@@ -758,11 +837,12 @@ def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
 
 def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
                            block_q, block_k, interpret, mask=None,
-                           heads_per_step=None):
+                           heads_per_step=None, window=None):
     """dq, dk, dv (and dbias) of flash attention from the forward's
     residuals, never building a score tensor in HBM. ``k`` / ``v`` hold
     ``H // group`` heads; ``mask`` is (B, Tq, Tk) or None. A grid step takes
-    ``_heads_per_step``'s heads, as the forward's."""
+    ``_heads_per_step``'s heads, as the forward's. With ``window`` the call
+    goes by the name ``window_attention_bwd``, as the forward's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -832,7 +912,10 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
                  jax.ShapeDtypeStruct((BH, Tkp, D), part or k.dtype),
                  jax.ShapeDtypeStruct((BH, Tkp, Dv), part or v.dtype)]
     static = dict(block_q=block_q, causal=causal, sm_scale=sm_scale,
-                  kv_len=Tk, q_len=Tq, kv_pad=Tkp, unroll=unroll)
+                  kv_len=Tk, q_len=Tq, kv_pad=Tkp, unroll=unroll, window=window)
+    if window is not None:
+        _telemetry.record_flash_window_blocks(
+            "bwd", *window_blocks(Tq, Tk, block_q, block_k, window))
     if bias is not None:
         bflat = jnp.broadcast_to(bias.astype(f32), (B, H, 1, Tk))
         if pad_k:
@@ -874,7 +957,7 @@ def _flash_backward_pallas(q, k, v, bias, out, lse, do, causal, sm_scale,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(G, unroll)),
         interpret=interpret,
-        name="flash_attention_bwd",
+        name="flash_attention_bwd" if window is None else "window_attention_bwd",
     )(*args)
     dq = outs[0].reshape(B, H, Tqp, D)[:, :, :Tq]
     dk = outs[1].reshape(B, H, Tkp, D)[:, :, :Tk]
@@ -1263,7 +1346,7 @@ def _chunk_mask(mask, chunk, pad):
 
 
 def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK,
-                        mask=None):
+                        mask=None, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     k, v = _kv_per_query_head(q, k, v)
@@ -1291,6 +1374,9 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK,
             row = jnp.arange(Tq)
             valid = jnp.logical_and(
                 valid, col[None, :] <= row[:, None] + (Tk - Tq))
+            if window is not None:
+                valid = jnp.logical_and(valid, _in_window(
+                    row[:, None], col[None, :], Tk - Tq, window))
         valid = valid[None, None]
         if mask is not None:
             valid = jnp.logical_and(valid, xs[-2])
@@ -1317,7 +1403,7 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK,
 
 
 def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
-                 chunk=LONG_CHUNK, mask=None):
+                 chunk=LONG_CHUNK, mask=None, window=None):
     """Backward over K/V chunks in XLA. Matmul operands stay in the input
     dtype (``p`` and ``ds`` are cast to it just before their matmuls) and
     accumulate in float32; ``exp``, ``delta`` and the ``dq`` carry are
@@ -1349,6 +1435,9 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
             row = jnp.arange(Tq)
             valid = jnp.logical_and(
                 valid, col[None, :] <= row[:, None] + (Tk - Tq))
+            if window is not None:
+                valid = jnp.logical_and(valid, _in_window(
+                    row[:, None], col[None, :], Tk - Tq, window))
         valid = valid[None, None]
         if mask is not None:
             valid = jnp.logical_and(valid, xs[-2])
@@ -1401,45 +1490,61 @@ def _reduce_dbias(db, bias):
 # the Pallas kernel wherever the forward kernel ran, else XLA (chunked over
 # K/V when the scores are over _BWD_SCORE_BYTES, else materialised)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash_core(q, k, v, bias, mask, causal, sm_scale):
-    out, _ = _flash_fwd(q, k, v, bias, mask, causal, sm_scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_core(q, k, v, bias, mask, causal, sm_scale, window=None):
+    out, _ = _flash_fwd(q, k, v, bias, mask, causal, sm_scale, window)
     return out
 
 
+def _count_branch(kernel, name, window, xla_tiles=None):
+    """Count the branch a traced ``kernel`` (``fwd`` or ``bwd``) pass takes;
+    a window call's branches are its own (``window_<name>``). An XLA branch
+    bounds no loop by the window: it counts the ``xla_tiles`` it walks as
+    visited and as the causal call's alike
+    (``telemetry.flash_window_blocks()``; the kernels count their own)."""
+    if window is not None:
+        name = "window_" + name
+        if xla_tiles is not None:
+            _telemetry.record_flash_window_blocks(kernel, xla_tiles, xla_tiles)
+    (_telemetry.record_flash_fwd if kernel == "fwd"
+     else _telemetry.record_flash_bwd)(name)
+
+
 @jax.named_scope("attention")
-def _flash_fwd(q, k, v, bias, mask, causal, sm_scale):
+def _flash_fwd(q, k, v, bias, mask, causal, sm_scale, window=None):
     """Forward of ``_flash_core``. The branch it takes is counted
     (``telemetry.flash_fwd_branches()``), once a trace as the backward's."""
-    _record_flash_signature(q, k, v, bias, mask, causal, sm_scale)
+    _record_flash_signature(q, k, v, bias, mask, causal, sm_scale, window)
     _telemetry.record_flash_layout("fwd", "heads_major")
     if not _kv_fits_vmem(k, v):
-        _telemetry.record_flash_fwd("scan")
+        _count_branch("fwd", "scan", window, -(-k.shape[2] // LONG_CHUNK))
         out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
-                                       mask=mask)
+                                       mask=mask, window=window)
     else:
         cfg = _tuned_config(q, k, v, bias, causal, sm_scale, mask)
         if cfg.get("backend") == "pallas" and on_tpu():
-            _telemetry.record_flash_fwd("kernel")
+            _count_branch("fwd", "kernel", window)
             out, lse = _flash_forward_pallas(
                 q, k, v, bias, causal, sm_scale,
                 int(cfg["block_q"]), int(cfg["block_k"]), interpret=False,
-                mask=mask)
+                mask=mask, window=window)
         else:
             # per-shape XLA choice (small shapes, or a tuned decision
             # that XLA's fused reference wins here), and every non-TPU
             # backend
-            _telemetry.record_flash_fwd("reference")
-            out = _attention_reference(q, k, v, bias, causal, sm_scale, mask)
+            _count_branch("fwd", "reference", window, 1)
+            out = _attention_reference(q, k, v, bias, causal, sm_scale, mask,
+                                       window)
             lse = None
     return out, (q, k, v, bias, mask, out, lse)
 
 
-def _record_flash_signature(q, k, v, bias, mask, causal, sm_scale):
+def _record_flash_signature(q, k, v, bias, mask, causal, sm_scale, window=None):
     """Remember this dispatch's shape signature for tuning.warmup()'s
     AOT replay (deduplicated in the table; a fresh serving replica
     compiles these ahead of traffic). ``k_shape`` carries the K/V head
-    count; ``mask_dtype`` says whether a selection mask is there."""
+    count; ``mask_dtype`` says whether a selection mask is there;
+    ``window`` the static window, on a call that has one."""
     try:
         from .. import tuning
 
@@ -1450,7 +1555,8 @@ def _record_flash_signature(q, k, v, bias, mask, causal, sm_scale):
             "bias_dtype": None if bias is None else str(bias.dtype),
             "mask_dtype": None if mask is None else str(mask.dtype),
             "dtype": str(q.dtype), "causal": bool(causal),
-            "sm_scale": float(sm_scale)})
+            "sm_scale": float(sm_scale),
+            **({} if window is None else {"window": int(window)})})
     except Exception:  # noqa: BLE001 — bookkeeping must not fail the op
         pass
 
@@ -1482,7 +1588,7 @@ def _bwd_chunk(B, H, Tq, Tk):
 
 
 @jax.named_scope("attention_bwd")
-def _flash_bwd(causal, sm_scale, res, do):
+def _flash_bwd(causal, sm_scale, res, do, window=None):
     """Backward of ``_flash_core``. The branch it takes is counted
     (``telemetry.flash_bwd_branches()``): once a trace, so once a compiled
     program that differentiates the op."""
@@ -1494,23 +1600,23 @@ def _flash_bwd(causal, sm_scale, res, do):
             and _qdo_fits_vmem(q, v)):
         # the forward kernel ran (its lse is here, its K/V fit VMEM) and a
         # head's Q and dO fit beside them: same recipe, tiled in VMEM
-        _telemetry.record_flash_bwd("kernel")
+        _count_branch("bwd", "kernel", window)
         block_q, block_k = _bwd_blocks(Tq, Tk)
         return _flash_backward_pallas(
             q, k, v, bias, out, lse, do, causal, sm_scale, block_q, block_k,
-            interpret=False, mask=mask) + (None,)
+            interpret=False, mask=mask, window=window) + (None,)
     score_bytes = B * H * Tq * Tk * 4
     if not _kv_fits_vmem(k, v) or score_bytes > _BWD_SCORE_BYTES:
         # keep backward O(Tq * chunk): a forward that fit VMEM can still
         # have a score matrix far too big to materialize (e.g. T=8k)
-        _telemetry.record_flash_bwd("chunked")
+        chunk = _bwd_chunk(B, H, Tq, Tk)
+        _count_branch("bwd", "chunked", window, -(-Tk // chunk))
         if lse is None:
             _, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
-                                         mask=mask)
+                                         mask=mask, window=window)
         return _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
-                            chunk=_bwd_chunk(B, H, Tq, Tk),
-                            mask=mask) + (None,)
-    _telemetry.record_flash_bwd("materialised")
+                            chunk=chunk, mask=mask, window=window) + (None,)
+    _count_branch("bwd", "materialised", window, 1)
     k, v = _kv_per_query_head(q, k, v)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
@@ -1523,6 +1629,8 @@ def _flash_bwd(causal, sm_scale, res, do):
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            tril = jnp.logical_and(tril, _window_band(tq, tk, window))
         s = jnp.where(tril, s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
@@ -1544,12 +1652,14 @@ def _flash_bwd(causal, sm_scale, res, do):
             _sum_kv_group(dv.astype(v.dtype), kv_heads), dbias, None)
 
 
-_flash_core.defvjp(_flash_fwd, _flash_bwd)
+_flash_core.defvjp(
+    _flash_fwd, lambda causal, sm_scale, window, res, do: _flash_bwd(
+        causal, sm_scale, res, do, window))
 
 
 @register("flash_attention", aliases=("_contrib_flash_attention",))
 def flash_attention(query, key, value, bias=None, mask=None, causal=False,
-                    sm_scale=None):
+                    sm_scale=None, window=None):
     """Fused scaled-dot-product attention. query: (B, H, T, D); key:
     (B, Hkv, Tk, D) and value: (B, Hkv, Tk, Dv) with ``H % Hkv == 0``: query
     head ``h`` reads K/V head ``h // (H // Hkv)`` (grouped heads; ``Hkv ==
@@ -1559,16 +1669,27 @@ def flash_attention(query, key, value, bias=None, mask=None, causal=False,
     mask); mask: optional (B, Tq, Tk) selection, nonzero where a (query,
     key) pair may be attended, shared by the heads of a sequence, with no
     gradient, combined with ``causal`` and ``bias`` (a row that selects
-    nothing is the caller's fault). Returns (B, H, Tq, Dv).
+    nothing is the caller's fault); window: optional static integer, under
+    ``causal`` with ``Tk >= Tq``: a query sees itself and the ``window - 1``
+    keys before it (a window that reaches every key is the plain causal
+    call). Returns (B, H, Tq, Dv).
 
     Inside ``parallel.sequence_scope(mesh, axis, schedule)`` this
     dispatches to a sequence-parallel schedule (ring KV rotation, or
     Ulysses head all-to-all when heads divide and there is no bias) —
     the hook that makes every attention user sequence-parallel without
     model changes. Neither schedule takes a selection mask or grouped
-    heads."""
+    heads, nor a window."""
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(query.shape[-1]))
+    if window is not None:
+        window = int(window)
+        if window < 1 or not causal or key.shape[2] < query.shape[2]:
+            raise MXNetError("flash_attention: window=%d wants causal=True, "
+                             "Tk >= Tq and at least the query's own key"
+                             % window)
+        if window >= key.shape[2]:
+            window = None  # every key of the causal past is inside it
     if query.shape[1] % key.shape[1] or key.shape[1] != value.shape[1]:
         raise MXNetError("flash_attention: %d query heads on %d key and %d "
                          "value heads" % (query.shape[1], key.shape[1],
@@ -1591,10 +1712,11 @@ def flash_attention(query, key, value, bias=None, mask=None, causal=False,
                 "sequence_scope's eager dispatch is single-process; on "
                 "multi-host meshes call parallel.ring_attention inside "
                 "your pjit/shard_map program instead")
-        if mask is not None or query.shape[1] != key.shape[1]:
+        if (mask is not None or window is not None
+                or query.shape[1] != key.shape[1]):
             raise MXNetError(
-                "sequence_scope: neither schedule takes a selection mask "
-                "or grouped heads")
+                "sequence_scope: neither schedule takes a selection mask, "
+                "a window or grouped heads")
         from ..parallel.sequence import ulysses_attention
 
         if (schedule == "ulysses" and bias is None
@@ -1616,7 +1738,7 @@ def flash_attention(query, key, value, bias=None, mask=None, causal=False,
                 mesh.devices.flat[0]))
         return out
     return _flash_core(query, key, value, bias, mask, bool(causal),
-                       float(sm_scale))
+                       float(sm_scale), window)
 
 
 # ---------------------------------------------------------------------------

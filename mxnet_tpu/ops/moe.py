@@ -11,20 +11,26 @@ One pure function, :func:`moe_ffn`:
   (the bias is a buffer with no gradient: the family's load balancing
   without an auxiliary loss; ``None`` where the model has none), and
   weighted by ``scaling * score / sum of the chosen scores`` (from the
-  scores, not from ``score + bias``: ``norm_topk_prob``);
+  scores, not from ``score + bias``: ``norm_topk_prob``). A model whose
+  router reads other rows than the experts do (the attention's input, one
+  residual add earlier) hands the logits in ready-made
+  (``router_logits``; op ``moe_router_logits`` is the same float32
+  product);
 * **dispatch** — the layer is told which experts it holds,
   ``experts_held=(first, count)``. Token-slots (token x chosen expert) are
   sorted by expert, held experts first; the rows of the slots held are
   gathered, at most a static bound of rows at a time
   (``default_slots_bound`` of the shapes);
-* **experts** — SwiGLU of each held expert over its own rows: three grouped
+* **experts** — ``W_down (act(W_gate x) * W_up x)`` of each held expert over
+  its own rows, ``act`` SiLU (SwiGLU, the default) or ReLU (ReGLU,
+  ``activation="relu"``): three grouped
   matmuls (``ops/grouped_matmul.py``: on a TPU, at whole 128-lane widths, the
   program's own kernels, which visit only the row tiles a group holds;
   ``jax.lax.ragged_dot`` everywhere else);
 * **combine** — every token sums its held slots, weighted; what the experts
   that are NOT held would have added is left out (it is computed where they
   live);
-* **shared** — the shared expert's SwiGLU of every token is added.
+* **shared** — the shared expert's gated unit of every token is added.
 
 Nothing is dropped: where more slots are held than that bound, the
 rest are taken in further blocks of that many rows, so the result is exact
@@ -105,16 +111,26 @@ _take_rows.defvjp(_take_fwd, _take_bwd)
 _sum_rows.defvjp(_sum_fwd, _sum_bwd)
 
 
+def router_product(x, router_w):
+    """(..., H) rows -> (..., n_routed) float32 logits, the product in
+    float32 at the highest precision."""
+    return jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
+
+
 def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid",
-          sum_epsilon=1e-20):
+          sum_epsilon=1e-20, logits=None):
     """(N, H) tokens -> chosen experts (N, k) int32 and their weights (N, k)
     float32. ``scoring`` is ``"sigmoid"`` (each expert alone) or
     ``"softmax"`` (over all the experts); ``router_bias`` None is no bias;
     ``sum_epsilon`` is what the model's publisher adds to the chosen scores'
-    sum before dividing by it (DeepSeek-V3 1e-20, LFM2 1e-6).
-    The gradient reaches ``router_w`` and ``x`` through the weights; the
-    choice and the bias carry none."""
-    logits = jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
+    sum before dividing by it (DeepSeek-V3 1e-20, LFM2 1e-6). ``logits``
+    (N, n_routed) are the router's product where the caller made it, from
+    whatever rows its router reads; ``x`` and ``router_w`` are then unread.
+    The gradient reaches ``router_w`` and ``x`` (or ``logits``) through the
+    weights; the choice and the bias carry none."""
+    if logits is None:
+        logits = router_product(x, router_w)
+    logits = logits.astype(F32)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     elif scoring == "softmax":
@@ -141,15 +157,25 @@ def default_slots_bound(tokens, top_k, n_routed, count):
     return min(slots, -(-2 * even // _BOUND_TILE) * _BOUND_TILE)
 
 
-def _swiglu_rows(xs, sizes, w_gate, w_up, w_down):
-    """SwiGLU of every row by its group's expert: (C, H) -> (C, H)."""
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gate(activation):
+    """The gate's function of a gated unit: SiLU (SwiGLU) or ReLU (ReGLU)."""
+    if activation not in _ACTIVATIONS:
+        raise ValueError("moe_ffn: activation=%r (silu or relu)" % (activation,))
+    return _ACTIVATIONS[activation]
+
+
+def _glu_rows(xs, sizes, w_gate, w_up, w_down, act):
+    """The gated unit of every row by its group's expert: (C, H) -> (C, H)."""
     h = grouped_matmul(xs, w_gate, sizes)
     u = grouped_matmul(xs, w_up, sizes)
-    a = (jax.nn.silu(h.astype(F32)) * u.astype(F32)).astype(xs.dtype)
+    a = (act(h.astype(F32)) * u.astype(F32)).astype(xs.dtype)
     return grouped_matmul(a, w_down, sizes)
 
 
-def _block(c, x, wflat, experts, *, bound, order, inv, is_held, starts):
+def _block(c, x, wflat, experts, *, bound, act, order, inv, is_held, starts):
     """Rows ``c * bound ...`` of the sorted slots: gathered, through their
     experts, weighted and summed back to their tokens. Returns (N, H) and
     the rows its grouped matmuls were handed, int32 ()."""
@@ -165,7 +191,7 @@ def _block(c, x, wflat, experts, *, bound, order, inv, is_held, starts):
         sizes = jnp.clip(starts[1:], lo, lo + bound) - jnp.clip(starts[:-1], lo, lo + bound)
         xs = _take_rows(x, slot // k, valid, back.reshape(n, k))
     with jax.named_scope("experts"):
-        o = _swiglu_rows(xs, sizes, *experts)
+        o = _glu_rows(xs, sizes, *experts, act)
     with jax.named_scope("combine"):
         ws = _take_rows(wflat, slot, valid, back.reshape(n * k, 1))
         o = jnp.where(valid[:, None], o.astype(F32) * ws, 0.0).astype(x.dtype)
@@ -179,12 +205,13 @@ def _further_blocks(held, bound):
     return jnp.maximum((held + bound - 1) // bound - 1, 0)
 
 
-def _routed_parts(bound, order, inv, is_held, starts):
+def _routed_parts(bound, activation, order, inv, is_held, starts):
     """``_block`` of this routing, and how many further blocks it needs
     (None where the first block's rows are all the slots there are: such a
     layer never builds a loop)."""
-    block = functools.partial(_block, bound=bound, order=order, inv=inv,
-                              is_held=is_held, starts=starts)
+    block = functools.partial(_block, bound=bound, act=_gate(activation),
+                              order=order, inv=inv, is_held=is_held,
+                              starts=starts)
     if order.shape[0] <= bound:
         return block, None
     return block, _further_blocks(starts[-1], bound)
@@ -204,8 +231,8 @@ def _further_forward(block, trips, x, wflat, experts, y, done):
     return y, done, trips
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(bound, x, wflat, experts, order, inv, is_held, starts):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(bound, activation, x, wflat, experts, order, inv, is_held, starts):
     """The routed experts' part of the layer, one differentiable unit: block
     0, then as many further blocks as hold a row (a loop whose trip count is
     read on the device). Returns (N, H), the rows handed to the grouped
@@ -213,23 +240,24 @@ def _routed(bound, x, wflat, experts, order, inv, is_held, starts):
     int32 (). The backward keeps block 0's residuals, recomputes each further
     block that ran and adds its cotangents into block 0's: a block that does
     not run costs nothing in either pass."""
-    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+    block, trips = _routed_parts(bound, activation, order, inv, is_held, starts)
     y, done = block(0, x, wflat, experts)
     return _further_forward(block, trips, x, wflat, experts, y, done)
 
 
-def _routed_fwd(bound, x, wflat, experts, order, inv, is_held, starts):
-    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+def _routed_fwd(bound, activation, x, wflat, experts, order, inv, is_held,
+                starts):
+    block, trips = _routed_parts(bound, activation, order, inv, is_held, starts)
     y, vjp0, done = jax.vjp(functools.partial(block, 0), x, wflat, experts,
                             has_aux=True)
     out = _further_forward(block, trips, x, wflat, experts, y, done)
     return out, (vjp0, x, wflat, experts, order, inv, is_held, starts)
 
 
-def _routed_bwd(bound, res, cts):
+def _routed_bwd(bound, activation, res, cts):
     vjp0, x, wflat, experts, order, inv, is_held, starts = res
     ct = cts[0]  # the counts carry no gradient
-    block, trips = _routed_parts(bound, order, inv, is_held, starts)
+    block, trips = _routed_parts(bound, activation, order, inv, is_held, starts)
     # a hand-written rule names its own operations: device time is read by
     # scope (``moe``; ``dispatch`` / ``experts`` / ``combine`` come with the
     # blocks), in this pass as in the forward
@@ -251,11 +279,15 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                 shared_gate, shared_up, shared_down, *, top_k, n_routed,
                 experts_held, scaling=1.0, slots_bound=None,
-                scoring="sigmoid", router_gradient=True, sum_epsilon=1e-20):
+                scoring="sigmoid", router_gradient=True, sum_epsilon=1e-20,
+                router_logits=None, activation="silu"):
     """The expert layer on (N, H) tokens. ``w_gate`` / ``w_up`` are
     (count, H, I) and ``w_down`` (count, I, H): the experts held, expert
     ``first + i`` at row i. ``shared_*`` are the shared expert's weights as
     ``FullyConnected`` keeps them, (I_s, H), (I_s, H), (H, I_s), or None.
+    ``router_logits`` (N, n_routed) stand in for ``x @ router_w.T`` where the
+    model's router reads other rows; ``activation`` is the gated units' gate,
+    ``"silu"`` or ``"relu"``.
 
     Returns ``(y, load, lost, ran)``: (N, H); int32 (count,) slots each held
     expert got; int32 () slots held and not computed: the slots held less
@@ -274,7 +306,7 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
     blocks = -(-slots // bound)
     with jax.named_scope("router"):
         idx, weights = route(x, router_w, router_bias, top_k, scaling, scoring,
-                             sum_epsilon)
+                             sum_epsilon, router_logits)
         if not router_gradient:
             weights = jax.lax.stop_gradient(weights)
     with jax.named_scope("dispatch"):
@@ -288,24 +320,33 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
                                   side="left").astype(jnp.int32)
         load = starts[1:] - starts[:-1]
         order = jnp.pad(order, (0, blocks * bound - slots))
-    y, done, ran = _routed(bound, x, weights.reshape(slots, 1), (w_gate, w_up, w_down),
-                           order, inv, is_held, starts)
+    y, done, ran = _routed(bound, activation, x, weights.reshape(slots, 1),
+                           (w_gate, w_up, w_down), order, inv, is_held, starts)
     lost = starts[-1] - done
     if shared_gate is not None:
         with jax.named_scope("shared"):
             dot = functools.partial(jnp.einsum, "nc,oc->no")
-            a = (jax.nn.silu(dot(x, shared_gate).astype(F32))
+            a = (_gate(activation)(dot(x, shared_gate).astype(F32))
                  * dot(x, shared_up).astype(F32)).astype(x.dtype)
             y = y + dot(a, shared_down)
     return y, load, lost, ran
 
 
-@register("moe_ffn", num_outputs=4, wrt=(0, 1, 3, 4, 5, 6, 7, 8))
+@register("moe_router_logits")
+def moe_router_logits(data, router_weight):
+    """A router's float32 logits of ``data`` (..., H) under ``router_weight``
+    (n_routed, H): what ``moe_ffn`` computes from its own rows, for a model
+    whose router reads others (``moe_ffn(router_logits=...)``)."""
+    with jax.named_scope("router"):
+        return router_product(data, router_weight)
+
+
+@register("moe_ffn", num_outputs=4, wrt=(0, 1, 3, 4, 5, 6, 7, 8, 9))
 def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             down_weight, shared_gate_weight=None, shared_up_weight=None,
-            shared_down_weight=None, top_k=1, n_routed=None,
-            experts_held=None, scaling=1.0, scoring="sigmoid",
-            router_gradient=True, sum_epsilon=1e-20):
+            shared_down_weight=None, router_logits=None, top_k=1,
+            n_routed=None, experts_held=None, scaling=1.0, scoring="sigmoid",
+            router_gradient=True, sum_epsilon=1e-20, activation="silu"):
     """The sparse expert layer of a chip that holds ``experts_held=(first,
     count)`` of ``n_routed`` experts, on ``data`` (..., H): see the module's
     docstring. ``scoring`` is the router's: ``"sigmoid"`` or ``"softmax"``
@@ -317,11 +358,17 @@ def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
     router's weights or the layer's input through them (what a strict share
     trained without the experts' exchange can say of that gradient is a part
     of a sum over all the chips, and applied alone the part pulls every
-    token towards the experts held). Returns ``(out, load, lost, ran)``; the
-    three counts carry no gradient."""
+    token towards the experts held). ``router_logits`` (..., n_routed) are
+    the router's product ready-made, where the model's router reads other
+    rows than ``data`` (``router_weight`` is then unread here); ``activation``
+    is the gate of the experts' units, ``"silu"`` (SwiGLU) or ``"relu"``
+    (ReGLU). Returns ``(out, load, lost, ran)``; the three counts carry no
+    gradient."""
     n_routed = int(n_routed or router_weight.shape[0])
     held = tuple(int(v) for v in (experts_held or (0, n_routed)))
     lead = data.shape[:-1]
+    if router_logits is not None:
+        router_logits = router_logits.reshape(-1, router_logits.shape[-1])
     with jax.named_scope("moe"):
         y, *counts = moe_ffn_raw(
             data.reshape(-1, data.shape[-1]), router_weight, router_bias,
@@ -329,6 +376,7 @@ def moe_ffn(data, router_weight, router_bias, gate_weight, up_weight,
             shared_up_weight, shared_down_weight, top_k=int(top_k),
             n_routed=n_routed, experts_held=held, scaling=float(scaling),
             scoring=str(scoring), router_gradient=bool(router_gradient),
-            sum_epsilon=float(sum_epsilon))
+            sum_epsilon=float(sum_epsilon), router_logits=router_logits,
+            activation=str(activation))
     return (y.reshape(lead + (y.shape[-1],)),
             *(jax.lax.stop_gradient(c) for c in counts))

@@ -74,6 +74,7 @@ __all__ = [
     "record_gated_conv", "gated_conv_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
     "record_flash_heads", "flash_heads_per_step",
+    "record_flash_window_blocks", "flash_window_blocks",
     "record_flash_layout", "flash_layouts",
     "record_moe_counts", "moe_counts",
     "record_selection_counts", "selection_counts",
@@ -822,7 +823,9 @@ def _flash_branches(direction):
 
 def record_flash_fwd(branch):
     """One traced flash-attention forward, by the branch its dispatch took
-    (``mxt_flash_fwd_total{branch=kernel|scan|reference}``). Counted at
+    (``mxt_flash_fwd_total{branch=kernel|scan|reference}``; a call with a
+    window counts under ``window_kernel`` / ``window_scan`` /
+    ``window_reference``). Counted at
     trace time, as :func:`record_flash_bwd`: nothing enters the compiled
     step."""
     _record_flash("fwd", branch)
@@ -835,7 +838,8 @@ def flash_fwd_branches():
 
 def record_flash_bwd(branch):
     """One traced flash-attention backward, by the branch its dispatch took
-    (``mxt_flash_bwd_total{branch=kernel|chunked|materialised}``). Counted
+    (``mxt_flash_bwd_total{branch=kernel|chunked|materialised}``, and
+    ``window_`` before each for a call with a window). Counted
     at trace time: once per compiled program that differentiates the op,
     not once a step."""
     _record_flash("bwd", branch)
@@ -844,6 +848,28 @@ def record_flash_bwd(branch):
 def flash_bwd_branches():
     """{branch: traces} of :func:`record_flash_bwd` so far."""
     return _flash_branches("bwd")
+
+
+def record_flash_window_blocks(kernel, visited, causal):
+    """One traced window call of a flash kernel (``kernel``: ``fwd`` or
+    ``bwd``): the (Q block, K/V block) tiles it visits and those the causal
+    call of the same shapes and blocks visits
+    (``mxt_flash_window_blocks_total{kernel, tiles=visited|causal}``), both
+    from the shapes alone (``ops.attention.window_blocks``); a window call
+    on an XLA branch, which bounds no loop, counts the chunks it walks on
+    both sides. Counted at trace time, as the branches: nothing enters the
+    compiled step."""
+    fam = counter("mxt_flash_window_blocks_total",
+                  "Tiles of traced window-attention kernels: visited, and "
+                  "what the causal call visits.", ("kernel", "tiles"))
+    fam.labels(kernel, "visited").inc(int(visited))
+    fam.labels(kernel, "causal").inc(int(causal))
+
+
+def flash_window_blocks():
+    """{kernel: {"visited": tiles, "causal": tiles}} of
+    :func:`record_flash_window_blocks` so far."""
+    return _by_first_label("mxt_flash_window_blocks_total")
 
 
 def record_flash_heads(kernel, heads):

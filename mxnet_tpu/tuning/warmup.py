@@ -80,7 +80,8 @@ def _warm_flash(spec):
         m = _sds([q.shape[0], q.shape[2], k.shape[2]], spec["mask_dtype"])
 
     def fwd(q_, k_, v_, b_, m_):
-        return A._flash_core(q_, k_, v_, b_, m_, causal, sm_scale)
+        return A._flash_core(q_, k_, v_, b_, m_, causal, sm_scale,
+                             spec.get("window"))
 
     jax.jit(fwd).lower(q, k, v, b, m).compile()
     jax.jit(jax.grad(lambda *a: fwd(*a).sum(),

@@ -11,7 +11,9 @@ references on the device.
 The file's name sorts early on purpose: the tier-1 window reaches it.
 """
 import functools
+import hashlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -59,10 +61,14 @@ def _compile(chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4, masked=True):
+def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4, masked=True,
+                    window=None):
     """Either kernel as the Keye cell runs it: grouped heads under a
     selection mask, causal, at the blocks the dispatch picks; ``masked=False``
-    is the same call without the mask operand (the LFM2 cell's)."""
+    is the same call without the mask operand (the LFM2 cell's), ``window``
+    the same with a static window (the SmallThinker cell's window layers)."""
+    windowed = {} if window is None else {"window": window}
+
     def case(chip):
         B, H, T, D = shape
         dt, sm = jnp.dtype("bfloat16"), D ** -0.5
@@ -73,12 +79,12 @@ def _flash_selected(backward, shape=(1, 32, 8192, 128), kv_heads=4, masked=True)
             return _compile(
                 chip, lambda q, k, v, *m: A._flash_forward_pallas(
                     q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"], False,
-                    mask=m[0] if m else None), q, kv, kv, *mask)
+                    mask=m[0] if m else None, **windowed), q, kv, kv, *mask)
         bq, bk = A._bwd_blocks(T, T)
         return _compile(
             chip, lambda q, k, v, out, lse, do, *m: A._flash_backward_pallas(
                 q, k, v, None, out, lse, do, True, sm, bq, bk, False,
-                mask=m[0] if m else None),
+                mask=m[0] if m else None, **windowed),
             q, kv, kv, q, ((B, H, T), jnp.float32), q, *mask)
     return case
 
@@ -258,6 +264,21 @@ _CASES = {
     # operand (a 64-wide head fills half the lanes: VMEM holds it at 128)
     "flash_lfm2_8192_gqa_d64": _flash_selected(False, (1, 32, 8192, 64), 8, False),
     "flash_bwd_lfm2_8192_gqa_d64": _flash_selected(True, (1, 32, 8192, 64), 8, False),
+    # the SmallThinker cell: 28 query heads on 4 K/V heads of 128 at 8192 rows
+    # (7 a group: no power of two), the full layer's kernels and the window
+    # layers' under their own names, a 4096-token window as a static argument
+    "flash_smallthinker_8192_gqa7": _flash_selected(False, (1, 28, 8192, 128), 4, False),
+    "flash_bwd_smallthinker_8192_gqa7": _flash_selected(True, (1, 28, 8192, 128), 4,
+                                                        False),
+    "window_smallthinker_8192_gqa7_w4096": _flash_selected(
+        False, (1, 28, 8192, 128), 4, False, window=4096),
+    "window_bwd_smallthinker_8192_gqa7_w4096": _flash_selected(
+        True, (1, 28, 8192, 128), 4, False, window=4096),
+    # a window no multiple of the blocks, on a ragged length and a batch
+    "window_bwd_2x3000_gqa_w1000": _flash_selected(True, (2, 8, 3000, 128), 2, False,
+                                                   window=1000),
+    "window_2x3000_gqa_w1000": _flash_selected(False, (2, 8, 3000, 128), 2, False,
+                                               window=1000),
     # the BERT cells through flash_attention_qkv: the fused projection read
     # in place, whole rows of (128, 128, 2304) two batch elements a grid step
     # and of (32, 512, 2304) one (the backward asks for 24 MB of scoped VMEM),
@@ -318,6 +339,17 @@ _HEADS = {"flash_bert_cell_s128": ("fwd", "16"), "flash_bwd_bert_cell_s128": ("b
           "flash_in_place_s512": ("fwd", "12"), "flash_bwd_in_place_s512": ("bwd", "12")}
 
 
+# the name each window case's kernel goes by in the compiled program (the
+# roofline readers divide a name's device time by its calls), and the full
+# layer's beside them
+_KERNEL_NAMES = {"window_smallthinker_8192_gqa7_w4096": "window_attention_fwd",
+                 "window_bwd_smallthinker_8192_gqa7_w4096": "window_attention_bwd",
+                 "window_2x3000_gqa_w1000": "window_attention_fwd",
+                 "window_bwd_2x3000_gqa_w1000": "window_attention_bwd",
+                 "flash_smallthinker_8192_gqa7": "flash_attention_fwd",
+                 "flash_bwd_smallthinker_8192_gqa7": "flash_attention_bwd"}
+
+
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_compiles_for_v5e(chip, name):
     from mxnet_tpu import telemetry
@@ -325,6 +357,9 @@ def test_kernel_compiles_for_v5e(chip, name):
     before = telemetry.flash_heads_per_step()
     text = _CASES[name](chip)
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    if name in _KERNEL_NAMES:
+        names = set(re.findall(r"(?:window|flash)_attention_(?:fwd|bwd)", text))
+        assert names == {_KERNEL_NAMES[name]}
     if name in _LSE_ROWS:
         assert "f32[%d,1,%d]" % _LSE_ROWS[name] in text
         assert "f32[%d,%d,128]" % _LSE_ROWS[name] not in text
@@ -408,3 +443,81 @@ def test_extreme_attention_candidates_compile(chip):
                    (max(cands)[0], min(cands)[1])):
         assert blocks in cands
         _flash(shape, "bfloat16", True, blocks=blocks)(chip)
+
+
+# -- a call without ``window`` is the call it was ----------------------------------
+def _heads_major_calls(shape, kv_heads, dv=None, masked=False):
+    """(forward, backward) jaxprs of ``flash_attention``'s two kernels at a
+    decoder cell's shapes and the blocks the dispatch picks, causal."""
+    B, H, T, D = shape
+    dt, sm = jnp.dtype("bfloat16"), D ** -0.5
+    S = jax.ShapeDtypeStruct
+    q, k, v = S(shape, dt), S((B, kv_heads, T, D), dt), S((B, kv_heads, T, dv or D), dt)
+    o = S((B, H, T, dv or D), dt)
+    mask = (S((B, T, T), jnp.int8),) if masked else ()
+    cfg = tuning.heuristic_attention(shape, T, "bfloat16", True)
+    fwd = jax.make_jaxpr(lambda q, k, v, *m: A._flash_forward_pallas(
+        q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"], False,
+        mask=m[0] if m else None))(q, k, v, *mask)
+    bq, bk = A._bwd_blocks(T, T)
+    bwd = jax.make_jaxpr(lambda q, k, v, out, lse, do, *m: A._flash_backward_pallas(
+        q, k, v, None, out, lse, do, True, sm, bq, bk, False,
+        mask=m[0] if m else None))(q, k, v, o, S((B, H, T), jnp.float32), o, *mask)
+    return fwd, bwd
+
+
+def _in_place_calls(batch, tokens, heads, dim):
+    """(forward, backward) jaxprs of ``flash_attention_qkv``'s two kernels at
+    a BERT cell's shapes."""
+    dt, width, S = jnp.dtype("bfloat16"), heads * dim, jax.ShapeDtypeStruct
+    qkv, out = S((batch, tokens, 3 * width), dt), S((batch, tokens, width), dt)
+    fwd = jax.make_jaxpr(lambda x: A._qkv_forward_pallas(
+        x, None, heads, dim ** -0.5, False))(qkv)
+    bwd = jax.make_jaxpr(lambda x, o, l, do: A._qkv_backward_pallas(
+        x, None, o, l, do, heads, dim ** -0.5, False))(
+            qkv, out, S((batch * heads, 1, tokens), jnp.float32), out)
+    return fwd, bwd
+
+
+_OLDER_CELLS = {
+    "bert_base_train_s512": lambda: _in_place_calls(32, 512, 12, 64),
+    "bert_base_train_s128": lambda: _in_place_calls(128, 128, 12, 64),
+    "kanana2_a3b_train_s4096": lambda: _heads_major_calls((2, 32, 4096, 192), 32, dv=128),
+    "keye_vl2_a3b_train_s8192": lambda: _heads_major_calls((1, 32, 8192, 128), 4,
+                                                           masked=True),
+    "lfm2_a2b_train_s8192": lambda: _heads_major_calls((1, 32, 8192, 64), 8),
+}
+
+# sha256 (16 hex digits) of the traced call, kernel body, grid, blocks, names
+# and compiler parameters, as the tree BEFORE the window was built (PR 40's,
+# e2462b4) traces it. A PR that means to change one of these kernels changes
+# its line here, and says so; one that does not, cannot.
+_OLDER_CELLS_DIGESTS = {
+    ("bert_base_train_s128", "fwd"): "c6312a91c046b45d",
+    ("bert_base_train_s128", "bwd"): "0800496a05a8d2f5",
+    ("bert_base_train_s512", "fwd"): "bd12583bbea5aea3",
+    ("bert_base_train_s512", "bwd"): "9d6747e86d107358",
+    ("kanana2_a3b_train_s4096", "fwd"): "1b7cbbe3b89410df",
+    ("kanana2_a3b_train_s4096", "bwd"): "684e127c486a639f",
+    ("keye_vl2_a3b_train_s8192", "fwd"): "6fac6b2ecfdb5b58",
+    ("keye_vl2_a3b_train_s8192", "bwd"): "134eae970437245c",
+    ("lfm2_a2b_train_s8192", "fwd"): "83ad77ac89dac598",
+    ("lfm2_a2b_train_s8192", "bwd"): "4c8e31996160df79",
+}
+
+
+def _digest(jaxpr):
+    return hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(jaxpr)).encode()
+                          ).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("half", ["fwd", "bwd"])
+@pytest.mark.parametrize("cell", sorted(_OLDER_CELLS))
+def test_a_call_without_a_window_lowers_to_the_kernels_it_was(cell, half):
+    """The attention calls of the five older cells (both BERT cells in place,
+    Kanana's latent heads, Keye's under a selection mask, LFM2's 64-wide
+    grouped heads) trace to the programs they traced to before ``window``
+    was an argument, letter for letter."""
+    jaxpr = _OLDER_CELLS[cell]()[half == "bwd"]
+    assert "window_attention" not in str(jaxpr)
+    assert _digest(jaxpr) == _OLDER_CELLS_DIGESTS[(cell, half)]

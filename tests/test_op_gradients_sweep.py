@@ -239,6 +239,18 @@ SPEC = {
                          _unit(1, 2, 4, 8)], {}, None),
     # the same attention fed from a fused (B, T, 3 x H x D) projection
     "flash_attention_qkv": ([_unit(1, 4, 3 * 2 * 8)], {"num_heads": 2}, None),
+    # a router's float32 product, for a model whose router reads other rows
+    # than its experts (moe_ffn(router_logits=...))
+    "moe_router_logits": ([_any(3, 4), _any(5, 4)], {}, None),
+}
+
+# further argument sets of an op that is swept above: "<op>:<what>"
+VARIANTS = {
+    # a static window under causal, on grouped heads: a query sees itself and
+    # the two keys before it
+    "flash_attention:window": ([_unit(1, 4, 6, 8), _unit(1, 2, 6, 8),
+                                _unit(1, 2, 6, 8)],
+                               {"causal": True, "window": 3}, None),
 }
 
 
@@ -351,6 +363,7 @@ F32_INTERNAL_TOL = {
     "rotary_embedding": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "swiglu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "gated_short_conv": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "moe_router_logits": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
 }
 
 # differentiable in the registry but excluded from the numeric sweep,
@@ -386,6 +399,7 @@ def test_sweep_is_complete():
         missing)
     assert not stale, "sweep entries for unregistered ops: %s" % sorted(
         stale)
+    assert {v.partition(":")[0] for v in VARIANTS} <= set(SPEC)
 
 
 def _op_fn(name):
@@ -397,9 +411,10 @@ def _op_fn(name):
     return lambda *xs, **kw: apply_op(op, *xs, **kw)
 
 
-@pytest.mark.parametrize("name", sorted(SPEC))
+@pytest.mark.parametrize("name", sorted(SPEC) + sorted(VARIANTS))
 def test_numeric_gradient(name):
-    inputs, kwargs, grad_nodes = SPEC[name]
+    inputs, kwargs, grad_nodes = SPEC[name] if name in SPEC else VARIANTS[name]
+    name = name.partition(":")[0]
     fn = _sum_outputs(_op_fn(name), **kwargs)
     tol = F32_INTERNAL_TOL.get(name,
                                dict(eps=1e-4, rtol=1e-4, atol=1e-5))

@@ -10,7 +10,8 @@ line ``{"scoped": ...}`` with the milliseconds a step by phase and by the
 scopes PERF.md section 5 quotes (``batchnorm``, ``attention``, ...), the
 kernels' calls a step, the branch each traced attention forward and backward
 took (``flash_fwd_branches``, ``flash_bwd_branches``) and the heads a grid
-step of each traced flash kernel takes (``flash_heads_per_step``), the layout
+step of each traced flash kernel takes (``flash_heads_per_step``), the tiles the
+traced window calls visit beside the causal call's (``flash_window_blocks``), the layout
 each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
 each traced gated short convolution took (``gated_conv_branches``) and each
@@ -42,12 +43,13 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 import run as bench  # noqa: E402 — benchmark/run.py
 
 # further single scopes quoted in PERF.md: latent and grouped-query attention,
-# the indexer, the expert layer and the gated short convolution
+# the indexer, the expert layer, the gated short convolution, and attention
+# over a window beside attention over the whole past
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
          "moe", "router", "dispatch", "experts", "combine", "shared",
          "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select",
          "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj",
-         "grouped_matmul_bwd")
+         "grouped_matmul_bwd", "attn_full", "attn_window")
 
 
 class Context(bench.Context):
@@ -60,6 +62,7 @@ class Context(bench.Context):
     flash_bwd = None
     flash_heads = None
     flash_layouts = None
+    flash_window = None
     gated_conv = None
     grouped_matmul = None
     tuned = None
@@ -93,6 +96,7 @@ class Context(bench.Context):
         Context.flash_bwd = telemetry.flash_bwd_branches()
         Context.flash_heads = telemetry.flash_heads_per_step()
         Context.flash_layouts = telemetry.flash_layouts()
+        Context.flash_window = telemetry.flash_window_blocks()
         Context.gated_conv = telemetry.gated_conv_branches()
         Context.grouped_matmul = telemetry.grouped_matmul_branches()
         Context.tuned = tuning.table().entries()
@@ -164,6 +168,8 @@ def main(argv):
             row["flash_heads_per_step"] = Context.flash_heads
         if Context.flash_layouts:  # and whether it read the projection in place
             row["flash_layouts"] = Context.flash_layouts
+        if Context.flash_window:  # and the tiles its window calls visit
+            row["flash_window_blocks"] = Context.flash_window
         if Context.gated_conv:  # and which path each gated short convolution
             row["gated_conv_branches"] = Context.gated_conv
         if Context.grouped_matmul:  # and which each grouped matmul, by product
