@@ -99,6 +99,44 @@ def inflight_ms(fn, *args, calls=20, repeats=3):
     return best * 1e3
 
 
+def device_ms(variants, calls=5):
+    """Device milliseconds a call of each of ``variants`` ({name: (fn, args)}),
+    read from one profiler trace of ``calls`` calls of each under a scope of
+    its name, and the Pallas kernels' by kernel name: ({name: ms}, {kernel: ms
+    a call of its variant}). Under the 0.2 ms a dispatch costs the host, where
+    ``inflight_ms`` reads that cost and not the device's. {} where the trace
+    holds no device line (the CPU)."""
+    import shutil
+    import tempfile
+
+    import jax
+
+    from mxnet_tpu import profiler_trace
+
+    scoped = {}
+    for name, (fn, args) in variants.items():
+        def under(*a, fn=fn, name=name):
+            with jax.named_scope(name):
+                return fn(*a)
+        scoped[name] = jax.jit(under)
+        jax.block_until_ready(scoped[name](*args))
+    path = tempfile.mkdtemp(prefix="mxt_device_ms_")
+    try:
+        jax.profiler.start_trace(path)
+        for name, (_, args) in variants.items():
+            for _ in range(calls):
+                jax.block_until_ready(scoped[name](*args))
+        jax.profiler.stop_trace()
+        agg = profiler_trace.aggregate(path, top=0)
+    finally:
+        shutil.rmtree(path, ignore_errors=True)
+    if agg is None:
+        return {}, {}
+    per = 1e3 / calls
+    return ({name: agg["named_s"].get(name, 0.0) * per for name in variants},
+            {name: s * per for name, s in agg["kernel_s"].items()})
+
+
 def mem(dev):
     """(bytes in use now, peak bytes so far) as the backend reports them."""
     st = dev.memory_stats() or {}
@@ -373,6 +411,7 @@ def phase_kernels(args, dev):
         out["flash"]["selected_%d" % T] = row
 
     out["grouped_matmul"] = grouped_matmul_table(args, key, interp)
+    out["row_movement"] = row_movement_table(args, interp)
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks
@@ -503,6 +542,65 @@ def grouped_matmul_table(args, key, interp):
                 if product != "dw":
                     check(not bool(jnp.any(got[held:])), "grouped matmul %s %s %s: rows "
                           "past the groups are not zero" % (cell, name, product))
+    return table
+
+
+def row_movement_table(args, interp):
+    """The expert layer's two movements of rows at the four expert cells'
+    shapes, a seeded routing of 8192 tokens laid out as ``ops/moe.py`` lays
+    block 0 out: ``take`` (XLA's gather of the rows laid out) and ``sum`` by
+    XLA's gathers of every entry against ``ops/row_gather.py``'s kernels,
+    which move the rows held; equality checked, device ms a call (one trace a
+    cell: ``device_ms``), and of the kernels' sum the two kernels' own (what is
+    left is the list of rows by token) (PERF.md, Findings, PR 42: the
+    stand-alone table)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import moe as M
+    from mxnet_tpu.ops import row_gather as RG
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    cells = {"tiny": (512, 256, 4, 8, 2)} if args.rehearse else {
+        "smallthinker_a3b_train_s8192": (8192, 2560, 6, 64, 16),
+        "keye_vl2_a3b_train_s8192": (8192, 2048, 8, 128, 16),
+        "kanana2_a3b_train_s4096": (8192, 2048, 6, 128, 16),
+        "lfm2_a2b_train_s8192": (8192, 2048, 4, 64, 16)}
+    table = {}
+    for cell, (tokens, hidden, k, n_routed, count) in cells.items():
+        rs = np.random.RandomState(args.seed % (2 ** 31))
+        chosen = np.argsort(rs.rand(tokens, n_routed), axis=1)[:, :k].reshape(-1)
+        bound = M.default_slots_bound(tokens, k, n_routed, count)
+        key_ = np.where(chosen < count, chosen, count)
+        order = np.argsort(key_, kind="stable")
+        held = int((chosen < count).sum())
+        check(held <= bound, "row movements %s: the seeded routing overflows block 0" % cell)
+        back = np.full(tokens * k, bound)
+        back[order[:held]] = np.arange(held)
+        slot = jnp.asarray(order[:bound], i32)
+        valid = jnp.arange(bound) < held
+        back = jnp.asarray(back.reshape(tokens, k), i32)
+        ks = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 2)
+        x = jax.random.normal(ks[0], (tokens, hidden), f32).astype(bf16)
+        o = jnp.where(valid[:, None], jax.random.normal(ks[1], (bound, hidden), f32), 0).astype(bf16)
+
+        take, gathers = jax.jit(M._take_rows), jax.jit(M._gathered_sum)  # what runs where the rule refuses
+
+        kernels = functools.partial(RG._sum_pallas, tokens=tokens, k=k, interpret=interp)
+        n_held = jnp.asarray(held, i32)
+        got, want = kernels(o, slot, n_held), gathers(o, back)
+        variants = {"take_xla": (take, (x, slot, valid, back)), "sum_xla": (gathers, (o, back)),
+                    "sum_kernels": (kernels, (o, slot, n_held))}
+        ms, parts = device_ms(variants)
+        if not ms:  # no device line to read: the host's clock
+            ms = {name: inflight_ms(fn, *a) for name, (fn, a) in variants.items()}
+        row = dict(rows_laid_out=bound, rows_held=held, entries=tokens * k,
+                   equal=bool(jnp.all(got == want)), clock="device" if parts else "host",
+                   take_xla_ms=ms["take_xla"], sum_xla_ms=ms["sum_xla"],
+                   sum_kernels_ms=ms["sum_kernels"], **{"%s_ms" % n: v for n, v in parts.items()})
+        table[cell] = row
+        check(row["equal"], "row movements %s: the kernels' sum is not XLA's" % cell)
     return table
 
 

@@ -19,8 +19,9 @@ part of the router's gradient, and whoever runs it so decides what to do
 about that (the benchmark's cell freezes the router by ``grad_req``).
 Every expert layer counts, in aux state carried through the step like
 BatchNorm's running statistics, the slots each held expert got, the slots
-it did not compute and the blocks of rows it ran past the first
-(``moe_counts``): read them once a window, never a step.
+it did not compute and the blocks of rows it ran past the first, and beside
+those sums its last call's rows moved and rows laid out (``moe_counts``):
+read them once a window, never a step.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import math
 import numpy as np
 
 from ...base import MXNetError
+from ...ops.moe import rows_moved as _moe_rows_moved
 from ..block import HybridBlock
 from .. import nn
 
@@ -148,6 +150,9 @@ class DeepseekMoE(HybridBlock):
                                 init="zeros", grad_req="null")
             self.blocks_run = g("blocks_run", shape=(1,), dtype="int32",
                                 init="zeros", grad_req="null")
+            # the last call's, not a sum: rows moved, rows laid out
+            self.rows_moved = g("rows_moved", shape=(2,), dtype="int32",
+                                init="zeros", grad_req="null")
 
     def cast(self, dtype):
         """The selection bias stays float32 and the counts int32 under a
@@ -159,12 +164,14 @@ class DeepseekMoE(HybridBlock):
         self.expert_load.cast("int32")
         self.slots_lost.cast("int32")
         self.blocks_run.cast("int32")
+        self.rows_moved.cast("int32")
 
     def hybrid_forward(self, F, x, router_rows=None, router_weight=None,
                        router_bias=None, gate_weight=None, up_weight=None,
                        down_weight=None, shared_gate_weight=None,
                        shared_up_weight=None, shared_down_weight=None,
-                       expert_load=None, slots_lost=None, blocks_run=None):
+                       expert_load=None, slots_lost=None, blocks_run=None,
+                       rows_moved=None):
         logits = None
         if router_rows is not None:
             logits = F.moe_router_logits(router_rows, router_weight)
@@ -179,6 +186,9 @@ class DeepseekMoE(HybridBlock):
         expert_load._set_data(expert_load.data + load.data)
         slots_lost._set_data(slots_lost.data + lost.data.reshape(1))
         blocks_run._set_data(blocks_run.data + ran.data.reshape(1))
+        rows_moved._set_data(_moe_rows_moved(
+            load.data, ran.data, math.prod(x.shape[:-1]), x.shape[-1], x.data.dtype,
+            self._static["top_k"], self._static["n_routed"]))
         return out
 
 
@@ -262,21 +272,26 @@ class DeepseekV3Model(HybridBlock):
 def moe_counts(model):
     """One read of the counts every expert layer of ``model`` keeps on the
     device: ``{"expert_load": [[slots of each held expert] per layer],
-    "slots_lost": total, "blocks_run": total}``, cumulative since the
-    parameters were made. A host sync: call it once a window (epoch end, a
+    "slots_lost": total, "blocks_run": total, "rows_moved": [[moved, laid
+    out] per layer]}``, the first three cumulative since the parameters were
+    made, the last what each layer's last call (a step's) moved between
+    tokens and experts, both passes, beside what it laid out
+    (``ops.moe.rows_moved``). A host sync: call it once a window (epoch end, a
     benchmark's teardown), never a step."""
     layers = model.moe_layers()
     load = [np.asarray(m.expert_load.data().data) for m in layers]  # sync-ok: windowed moe accounting read
     lost = sum(int(np.asarray(m.slots_lost.data().data)[0]) for m in layers)  # sync-ok: windowed moe accounting read
     ran = sum(int(np.asarray(m.blocks_run.data().data)[0]) for m in layers)  # sync-ok: windowed moe accounting read
+    rows = [np.asarray(m.rows_moved.data().data) for m in layers]  # sync-ok: windowed moe accounting read
     return {"expert_load": [[int(v) for v in row] for row in load],
-            "slots_lost": lost, "blocks_run": ran}
+            "slots_lost": lost, "blocks_run": ran,
+            "rows_moved": [[int(v) for v in row] for row in rows]}
 
 
 def publish_moe_counts(model):
     """``moe_counts`` into telemetry (``mxt_moe_expert_slots{layer,expert}``
-    gauges, ``mxt_moe_slots_lost`` and ``mxt_moe_blocks_run`` gauges);
-    returns the counts."""
+    gauges, ``mxt_moe_slots_lost`` and ``mxt_moe_blocks_run`` gauges,
+    ``mxt_moe_rows{layer,rows=moved|laid_out}`` gauges); returns the counts."""
     from ... import telemetry
 
     counts = moe_counts(model)

@@ -20,7 +20,8 @@ One pure function, :func:`moe_ffn`:
   ``experts_held=(first, count)``. Token-slots (token x chosen expert) are
   sorted by expert, held experts first; the rows of the slots held are
   gathered, at most a static bound of rows at a time
-  (``default_slots_bound`` of the shapes);
+  (``default_slots_bound`` of the shapes): one XLA gather of the rows laid
+  out, each moved once (``_take_rows``);
 * **experts** — ``W_down (act(W_gate x) * W_up x)`` of each held expert over
   its own rows, ``act`` SiLU (SwiGLU, the default) or ReLU (ReGLU,
   ``activation="relu"``): three grouped
@@ -29,7 +30,11 @@ One pure function, :func:`moe_ffn`:
   ``jax.lax.ragged_dot`` everywhere else);
 * **combine** — every token sums its held slots, weighted; what the experts
   that are NOT held would have added is left out (it is computed where they
-  live);
+  live). ``_sum_rows``: on a TPU, at whole 128-lane widths, the program's own
+  kernels (``ops/row_gather.py``), which read how many rows exist on the
+  device and move only those, one DMA a row; everywhere else XLA's gather of
+  a row for EVERY entry of (tokens, top_k), a zero row for the entries not
+  held;
 * **shared** — the shared expert's gated unit of every token is added.
 
 Nothing is dropped: where more slots are held than that bound, the
@@ -46,10 +51,19 @@ function returns the tokens each held expert got, the slots it did NOT
 compute, which must read 0 (the slots held less the rows that the blocks
 which ran handed to their grouped matmuls), and the further blocks it ran.
 
-The two gathers of rows are written so that no pass scatters: each is the
+The two movements of rows are written so that no pass scatters: each is the
 other's transpose (``_take_rows`` picks rows forward and is summed back
 through the inverse permutation; ``_sum_rows`` the reverse), so forward and
-backward are gathers alike.
+backward are gathers alike: a layer runs each twice a step. What they move:
+``take`` the rows laid out (``bound``, twice what an even routing holds: the
+chip's gather of 4 KB rows runs at the memory's rate, 0.1-0.2 ms a call, and
+neither a DMA a row nor chunks under the count beat it in a step), ``sum``
+the rows held where the kernels take it and ``tokens * top_k`` rows where XLA
+does (``rows_moved`` counts both from a call's own counts; which branch a
+traced movement took is ``telemetry.row_movement_branches()``). A gather
+costs the chip a row whatever the row's width, so nothing here gathers one
+scalar a slot: the chosen scores (``_pick``) and the experts' first sorted
+positions (``starts``) are comparisons and sums over the experts.
 """
 from __future__ import annotations
 
@@ -58,6 +72,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry as _telemetry
+from . import row_gather
 from .grouped_matmul import ROW_TILE, grouped_matmul
 from .registry import register
 
@@ -71,26 +87,41 @@ def _rows(x, idx):
 
 
 @jax.custom_vjp
-def _take_rows(x, idx, valid, back):
-    """``x[idx]`` where ``valid``, else 0: (M, H) -> (C, H). ``back`` (M, k)
+def _take_rows(x, slot, valid, back):
+    """``x[slot // k]`` where ``valid``, else 0: (M, H) -> (C, H). Row c is
+    copy ``slot[c] % k`` of row ``slot[c] // k`` of ``x``; ``back`` (M, k)
     holds, for every row of ``x``, where in 0..C-1 its copies went (C: no
     such copy)."""
-    return jnp.where(valid[:, None], _rows(x, idx), jnp.zeros((), x.dtype))
+    _telemetry.record_row_movement("take", "gather")
+    return jnp.where(valid[:, None], _rows(x, slot // back.shape[1]), jnp.zeros((), x.dtype))
 
 
-def _take_fwd(x, idx, valid, back):
-    return _take_rows(x, idx, valid, back), (idx, valid, back)
+def _take_fwd(x, slot, valid, back):
+    return _take_rows(x, slot, valid, back), (slot, valid, back)
 
 
 def _take_bwd(res, ct):
-    idx, valid, back = res
-    return _sum_rows(ct, idx, valid, back), None, None, None
+    slot, valid, back = res
+    return _sum_rows(ct, slot, valid, back), None, None, None
 
 
 @jax.custom_vjp
-def _sum_rows(o, idx, valid, back):
+def _sum_rows(o, slot, valid, back):
     """(C, H) -> (M, H): row m is the sum of ``o[back[m, j]]`` over j, a
-    position C standing for a zero row. The transpose of ``_take_rows``."""
+    position C standing for a zero row. The transpose of ``_take_rows``.
+    Where ``row_gather``'s rule takes the call, only the rows that hold a slot
+    are moved (the ``valid`` ones, which lead: row c is entry ``slot[c]`` of
+    ``back``); elsewhere XLA gathers a row for every entry of ``back``."""
+    kernel = row_gather.kernel_takes(o.shape[0], *back.shape, o.shape[1], o.dtype)
+    _telemetry.record_row_movement("sum", "kernel" if kernel else "gather")
+    if kernel:
+        return row_gather.sum_rows(o, slot, jnp.sum(valid, dtype=jnp.int32), *back.shape)
+    return _gathered_sum(o, back)
+
+
+def _gathered_sum(o, back):
+    """``_sum_rows`` by XLA: a gather of a row for every entry of ``back``, a
+    zero row for position C, float32 adds in column order, rounded once."""
     ext = jnp.concatenate([o, jnp.zeros((1,) + o.shape[1:], o.dtype)])
     total = _rows(ext, back[:, 0]).astype(F32)
     for j in range(1, back.shape[1]):
@@ -98,13 +129,13 @@ def _sum_rows(o, idx, valid, back):
     return total.astype(o.dtype)
 
 
-def _sum_fwd(o, idx, valid, back):
-    return _sum_rows(o, idx, valid, back), (idx, valid, back)
+def _sum_fwd(o, slot, valid, back):
+    return _sum_rows(o, slot, valid, back), (slot, valid, back)
 
 
 def _sum_bwd(res, ct):
-    idx, valid, back = res
-    return _take_rows(ct, idx, valid, back), None, None, None
+    slot, valid, back = res
+    return _take_rows(ct, slot, valid, back), None, None, None
 
 
 _take_rows.defvjp(_take_fwd, _take_bwd)
@@ -115,6 +146,16 @@ def router_product(x, router_w):
     """(..., H) rows -> (..., n_routed) float32 logits, the product in
     float32 at the highest precision."""
     return jnp.dot(x.astype(F32), router_w.astype(F32).T, precision=_HI)
+
+
+def _pick(scores, idx):
+    """``scores[n, idx[n, j]]``, (N, E) and (N, k) -> (N, k), as a comparison
+    with every expert's number and a sum over them, exact: one of the terms
+    is not zero. Not ``take_along_axis``: a gather costs the chip a row
+    whatever a row's width (0.50-0.67 ms a layer for 49-66 thousand scalars,
+    PERF.md Findings, PR 42), and its transpose is a scatter."""
+    hit = idx[:, :, None] == jnp.arange(scores.shape[-1], dtype=idx.dtype)
+    return jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), axis=-1)
 
 
 def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid",
@@ -141,7 +182,7 @@ def route(x, router_w, router_bias, top_k, scaling, scoring="sigmoid",
     if router_bias is not None:
         choice = scores + jax.lax.stop_gradient(router_bias.astype(F32))
     _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    chosen = _pick(scores, idx)
     weights = scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + sum_epsilon)
     return idx.astype(jnp.int32), weights
 
@@ -155,6 +196,23 @@ def default_slots_bound(tokens, top_k, n_routed, count):
         return slots
     even = -(-slots * count // n_routed)
     return min(slots, -(-2 * even // _BOUND_TILE) * _BOUND_TILE)
+
+
+def rows_moved(load, ran, tokens, hidden, dtype, top_k, n_routed):
+    """int32 (2,): the rows of ``hidden`` that the two movements of one layer
+    call moved, forward and backward, and the rows its blocks were laid out
+    for, from what the call returned (``load`` (count,), the slots each held
+    expert got; ``ran``, the blocks past the first). A block lays out ``bound``
+    rows for ``take`` and ``tokens * top_k`` entries for ``sum``, in either
+    pass, and XLA's gather reads a row for every one of them; where the
+    ``row_gather`` kernels take the sum, it moves a row for every slot held."""
+    slots = tokens * top_k
+    bound = min(slots, default_slots_bound(tokens, top_k, n_routed, load.shape[0]))
+    held, blocks = jnp.sum(load, dtype=jnp.int32), 1 + ran.astype(jnp.int32)
+    laid = blocks * (bound + slots)
+    kernel = row_gather.kernel_takes(bound, tokens, top_k, hidden, dtype)
+    moved = blocks * bound + (held if kernel else blocks * slots)
+    return 2 * jnp.stack([moved, laid]).astype(jnp.int32)
 
 
 _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -189,13 +247,13 @@ def _block(c, x, wflat, experts, *, bound, act, order, inv, is_held, starts):
         here = jnp.logical_and(is_held, jnp.logical_and(inv >= lo, inv < lo + bound))
         back = jnp.where(here, inv - lo, bound).astype(jnp.int32)
         sizes = jnp.clip(starts[1:], lo, lo + bound) - jnp.clip(starts[:-1], lo, lo + bound)
-        xs = _take_rows(x, slot // k, valid, back.reshape(n, k))
+        xs = _take_rows(x, slot, valid, back.reshape(n, k))
     with jax.named_scope("experts"):
         o = _glu_rows(xs, sizes, *experts, act)
     with jax.named_scope("combine"):
         ws = _take_rows(wflat, slot, valid, back.reshape(n * k, 1))
         o = jnp.where(valid[:, None], o.astype(F32) * ws, 0.0).astype(x.dtype)
-        y = _sum_rows(o, slot // k, valid, back.reshape(n, k))
+        y = _sum_rows(o, slot, valid, back.reshape(n, k))
     return y, jnp.sum(sizes, dtype=jnp.int32)
 
 
@@ -315,9 +373,11 @@ def moe_ffn_raw(x, router_w, router_bias, w_gate, w_up, w_down,
         key = jnp.where(is_held, local, count)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order).astype(jnp.int32)
-        # first sorted position of every held expert, and one past the last
-        starts = jnp.searchsorted(key[order], jnp.arange(count + 1, dtype=jnp.int32),
-                                  side="left").astype(jnp.int32)
+        # first sorted position of every held expert, and one past the last:
+        # the slots of the experts before it, counted (the sorted keys would
+        # be a gather of a scalar a slot, which costs what a row costs)
+        starts = jnp.sum(key[None, :] < jnp.arange(count + 1, dtype=jnp.int32)[:, None],
+                         axis=1, dtype=jnp.int32)
         load = starts[1:] - starts[:-1]
         order = jnp.pad(order, (0, blocks * bound - slots))
     y, done, ran = _routed(bound, activation, x, weights.reshape(slots, 1),

@@ -73,6 +73,7 @@ __all__ = [
     "record_flash_bwd", "flash_bwd_branches",
     "record_gated_conv", "gated_conv_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
+    "record_row_movement", "row_movement_branches",
     "record_flash_heads", "flash_heads_per_step",
     "record_flash_window_blocks", "flash_window_blocks",
     "record_flash_layout", "flash_layouts",
@@ -946,17 +947,44 @@ def grouped_matmul_branches():
     return _by_first_label("mxt_grouped_matmul_total")
 
 
-def record_moe_counts(expert_load, slots_lost, blocks_run):
+def record_row_movement(movement, branch):
+    """One traced movement of rows of the expert layer (``movement``: ``take``,
+    rows gathered to their experts, or ``sum``, rows summed back to their
+    tokens; each is the other's backward) by the branch it took
+    (``mxt_row_movement_total{movement, branch=kernel|gather}``: the
+    ``row_gather`` kernel, which moves the rows that exist, or XLA's gather of
+    every row laid out). Counted at trace time, as the flash branches:
+    nothing enters the compiled step."""
+    counter("mxt_row_movement_total", "Traced row movements of the expert layer by branch.",
+            ("movement", "branch")).labels(movement, branch).inc()
+
+
+def row_movement_branches():
+    """{movement: {branch: traces}} of :func:`record_row_movement` so far."""
+    return _by_first_label("mxt_row_movement_total")
+
+
+def record_moe_counts(expert_load, slots_lost, blocks_run, rows_moved=()):
     """The counts an expert-parallel model keeps on the device, as read once
     a window (``model_zoo.deepseek.publish_moe_counts``): token-slots each
     held expert of each expert layer got
     (``mxt_moe_expert_slots{layer,expert}``), slots held but not computed
     (``mxt_moe_slots_lost``, which must read 0) and blocks of rows run past
     each layer's first (``mxt_moe_blocks_run``: how often a routing overflowed
-    the rows its layer is laid out for). Cumulative, so gauges. A publication
-    replaces the one before it whole: a model of fewer layers or experts
-    leaves no child of an earlier one behind."""
+    the rows its layer is laid out for). Cumulative, so gauges. ``rows_moved``
+    is each layer's last call's ``[rows moved, rows laid out]``
+    (``mxt_moe_rows{layer,rows=moved|laid_out}``: what the two row movements
+    moved in both passes of a step beside what XLA's gathers of every row laid
+    out would have). A publication replaces the one before it whole: a model
+    of fewer layers or experts leaves no child of an earlier one behind."""
     _REGISTRY.unregister("mxt_moe_expert_slots")
+    _REGISTRY.unregister("mxt_moe_rows")
+    if rows_moved:
+        g = gauge("mxt_moe_rows", "Rows of the hidden width each expert layer's last "
+                  "call moved, and the rows it laid out.", ("layer", "rows"))
+        for layer, (moved, laid) in enumerate(rows_moved):
+            g.labels(str(layer), "moved").set(float(moved))  # sync-ok: host value
+            g.labels(str(layer), "laid_out").set(float(laid))  # sync-ok: host value
     g = gauge("mxt_moe_expert_slots",
               "Cumulative token-slots each held expert got (on-device "
               "accounting, read once a window).", ("layer", "expert"))
@@ -974,7 +1002,8 @@ def record_moe_counts(expert_load, slots_lost, blocks_run):
 def moe_counts():
     """What :func:`record_moe_counts` last published:
     ``{"expert_load": [[slots of each held expert] per layer],
-    "slots_lost": n, "blocks_run": n}``, or {}."""
+    "slots_lost": n, "blocks_run": n}``, with ``"rows_moved": [[moved, laid
+    out] per layer]`` where that was published, or {}."""
     fam = _REGISTRY.get("mxt_moe_expert_slots")
     lost = _REGISTRY.get("mxt_moe_slots_lost")
     ran = _REGISTRY.get("mxt_moe_blocks_run")
@@ -983,9 +1012,14 @@ def moe_counts():
     rows = {}
     for (layer, expert), ch in fam.children().items():
         rows.setdefault(int(layer), {})[int(expert)] = int(ch.value)
-    return {"expert_load": [[row[e] for e in sorted(row)]
-                            for _, row in sorted(rows.items())],
-            "slots_lost": int(lost.value), "blocks_run": int(ran.value)}
+    out = {"expert_load": [[row[e] for e in sorted(row)]
+                           for _, row in sorted(rows.items())],
+           "slots_lost": int(lost.value), "blocks_run": int(ran.value)}
+    moved = _by_first_label("mxt_moe_rows")
+    if moved:
+        out["rows_moved"] = [[moved[layer]["moved"], moved[layer]["laid_out"]]
+                             for layer in sorted(moved, key=int)]
+    return out
 
 
 def record_selection_counts(selected_pairs, rows_searched):

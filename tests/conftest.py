@@ -86,3 +86,19 @@ def grouped_matmul_kernels(monkeypatch):
                             tgmm(*a, interpret=True, **kw))
 
     return engage
+
+
+@pytest.fixture
+def row_gather_kernel(monkeypatch):
+    """``engage()``: from then on ``ops/row_gather.py`` answers as on a TPU,
+    its kernels in interpret mode (a test computes what XLA's gathers give
+    first, then engages)."""
+    from mxnet_tpu.ops import row_gather as RG
+
+    def engage():
+        kernels = RG._sum_pallas
+        monkeypatch.setattr(RG, "on_tpu", lambda: True)
+        monkeypatch.setattr(RG, "_sum_pallas", lambda *a, interpret=False, **kw:
+                            kernels(*a, interpret=True, **kw))
+
+    return engage

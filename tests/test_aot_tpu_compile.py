@@ -26,6 +26,7 @@ from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import bn_pallas
 from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
+from mxnet_tpu.ops import row_gather as RG
 
 
 @pytest.fixture(scope="module")
@@ -197,6 +198,16 @@ def _grouped(product, rows, k, n, dtype="bfloat16"):
     return case
 
 
+def _row_sum(rows, k, hidden, dtype="bfloat16"):
+    """The expert layer's sum of ``rows`` rows laid out back to 8192 tokens of
+    k slots, the rows that exist read on the device: both kernels."""
+    def case(chip):
+        return _compile(chip, functools.partial(RG.sum_rows, tokens=8192, k=k),
+                        ((rows, hidden), jnp.dtype(dtype)), ((rows,), jnp.int32),
+                        ((), jnp.int32))
+    return case
+
+
 _CASES = {
     # flash forward: BERT-base (batch 32 x 128) without and with a
     # padding bias, longer and ragged sequences, explicit big blocks, and
@@ -301,6 +312,14 @@ _CASES = {
                        (16384, 2048, 1536), (16384, 1536, 2048))},
     "grouped_fwd_16384x2048x768": _grouped("fwd", 16384, 2048, 768),
     "grouped_dw_f32_1024x2048x1536": _grouped("dw", 1024, 2048, 1536, "float32"),
+    # the expert layer's sum of rows at the four expert cells' shapes (rows
+    # laid out, slots a token, hidden): a row is a DMA's unit only as a tile
+    # of its own, so bfloat16 rows as (H / 256, 128) words
+    "row_sum_smallthinker": _row_sum(24576, 6, 2560),
+    "row_sum_keye": _row_sum(16384, 8, 2048),
+    "row_sum_kanana": _row_sum(12288, 6, 2048),
+    "row_sum_lfm2": _row_sum(16384, 4, 2048),
+    "row_sum_f32_12288x6x2048": _row_sum(12288, 6, 2048, "float32"),
     "indexer_select_8192": _indexer(1, 8192),
     "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the

@@ -15,12 +15,13 @@ traced window calls visit beside the causal call's (``flash_window_blocks``), th
 each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
 each traced gated short convolution took (``gated_conv_branches``) and each
-traced grouped matmul of an expert layer by product (``grouped_matmul_branches``),
+traced grouped matmul of an expert layer by product (``grouped_matmul_branches``)
+and each traced movement of rows between tokens and experts (``row_movement_branches``),
 the tuning
 table's entries (``tuning_entries``: the tiles each kernel shape ran with), what an
 expert-parallel model counted on the device
 (``moe_counts``: slots by layer and held expert, slots lost, blocks of rows
-run past a layer's first), what a model with a lightning indexer counted there
+run past a layer's first, the rows each layer's last call moved beside the rows it laid out), what a model with a lightning indexer counted there
 (``selection_counts``: the pairs selected and the rows searched, by layer),
 the set-up
 phases and the compile counters. ``--category NAME`` (an HLO category of the
@@ -65,6 +66,7 @@ class Context(bench.Context):
     flash_window = None
     gated_conv = None
     grouped_matmul = None
+    row_movement = None
     tuned = None
     moe = None
     selection = None
@@ -99,6 +101,7 @@ class Context(bench.Context):
         Context.flash_window = telemetry.flash_window_blocks()
         Context.gated_conv = telemetry.gated_conv_branches()
         Context.grouped_matmul = telemetry.grouped_matmul_branches()
+        Context.row_movement = telemetry.row_movement_branches()
         Context.tuned = tuning.table().entries()
         Context.moe = telemetry.moe_counts()
         Context.selection = telemetry.selection_counts()
@@ -174,6 +177,8 @@ def main(argv):
             row["gated_conv_branches"] = Context.gated_conv
         if Context.grouped_matmul:  # and which each grouped matmul, by product
             row["grouped_matmul_branches"] = Context.grouped_matmul
+        if Context.row_movement:  # and which each movement of rows around them
+            row["row_movement_branches"] = Context.row_movement
         if Context.tuned:  # the tiles each kernel shape ran with
             row["tuning_entries"] = Context.tuned
         if Context.moe:  # read once after the window by the cell's adapter
