@@ -18,10 +18,13 @@ from . import lfm2
 from .lfm2 import Lfm2MoeModel
 from . import smallthinker
 from .smallthinker import SmallThinkerModel
+from . import granite_hybrid
+from .granite_hybrid import GraniteHybridModel
 
 __all__ = ["vision", "get_model", "bert", "BERTModel", "BERTEncoder",
            "get_bert_model", "bert_12_768_12", "bert_6_512_8",
            "bert_3_64_2", "WideDeep", "wide_deep",
            "gpt", "GPTModel", "gpt_mini", "gpt_small",
            "deepseek", "DeepseekV3Model", "keye", "KeyeVL2Model",
-           "lfm2", "Lfm2MoeModel", "smallthinker", "SmallThinkerModel"]
+           "lfm2", "Lfm2MoeModel", "smallthinker", "SmallThinkerModel",
+           "granite_hybrid", "GraniteHybridModel"]

@@ -117,11 +117,13 @@ class GroupedQueryAttention(HybridBlock):
     heads, over the keys ``mask`` selects (all the earlier ones without).
     ``rope_theta=None`` is attention without positions, ``head_norm=False``
     without the RMSNorm over each head of q and k, ``window`` a static
-    window: a query sees itself and the ``window - 1`` keys before it."""
+    window: a query sees itself and the ``window - 1`` keys before it,
+    ``sm_scale`` what the scores are multiplied by (``head_dim ** -0.5``
+    where it is None)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rope_theta=10000.0, rms_norm_eps=1e-6, head_norm=True,
-                 window=None, prefix=None, params=None):
+                 window=None, sm_scale=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if num_heads % num_kv_heads:
             raise MXNetError("GroupedQueryAttention: %d query heads on %d K/V "
@@ -129,6 +131,7 @@ class GroupedQueryAttention(HybridBlock):
         self._heads, self._kv, self._dim = num_heads, num_kv_heads, head_dim
         self._theta = None if rope_theta is None else float(rope_theta)
         self._window = window
+        self._scale = 1.0 / math.sqrt(head_dim) if sm_scale is None else float(sm_scale)
         dense = dict(flatten=False, use_bias=False)
         with self.name_scope():
             self.q_proj = nn.Dense(num_heads * head_dim, in_units=units,
@@ -156,7 +159,7 @@ class GroupedQueryAttention(HybridBlock):
         out = F.flash_attention(
             F.transpose(q, axes=(0, 2, 1, 3)), F.transpose(k, axes=(0, 2, 1, 3)),
             F.transpose(v, axes=(0, 2, 1, 3)), None, mask, causal=True,
-            sm_scale=1.0 / math.sqrt(D), window=self._window)
+            sm_scale=self._scale, window=self._window)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
         return self.o_proj(out)
 

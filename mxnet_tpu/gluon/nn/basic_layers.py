@@ -11,7 +11,7 @@ from .layout import resolve_norm_axis
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
            "SyncBatchNorm",
            "Embedding", "Flatten", "Lambda", "HybridLambda", "Activation",
-           "LayerNorm", "InstanceNorm", "GroupNorm", "RMSNorm", "SwiGLU"]
+           "LayerNorm", "InstanceNorm", "GroupNorm", "RMSNorm", "GatedRMSNorm", "SwiGLU"]
 
 
 class Sequential(Block):
@@ -354,6 +354,25 @@ class RMSNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma=None):
         return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+
+
+class GatedRMSNorm(HybridBlock):
+    """``RMSNorm(x * silu(gate)) * gamma`` over the last axis (op
+    ``GatedRMSNorm``): the norm that closes a Mamba-2 mixer, the gate applied
+    before it."""
+
+    def __init__(self, epsilon=1e-6, in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,), init="ones",
+                                         allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape = (x.shape[-1],)
+
+    def hybrid_forward(self, F, x, gate, gamma=None):
+        return F.GatedRMSNorm(x, gate, gamma, eps=self._epsilon)
 
 
 class SwiGLU(HybridBlock):

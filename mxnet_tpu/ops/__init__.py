@@ -13,6 +13,7 @@ from . import loss_output
 from . import attention
 from . import indexer
 from . import gated_conv
+from . import ssd
 from . import moe
 from . import linalg
 from . import contrib_ops

@@ -20,6 +20,13 @@ once and write their results once. The two halves run under the scopes
 and every traced call is counted by the branch it took
 (``telemetry.gated_conv_branches()``: ``xla``; a later kernel counts
 ``kernel``).
+
+:func:`causal_conv_silu` is the same filter as Mamba-2's mixer wears it
+(transformers' ``GraniteMoeHybridMambaLayer``: ``Conv1d(groups=C, padding=K -
+1)`` cut to the sequence, then SiLU): ``silu(V + bias)`` of ``data`` (B, T, C)
+itself, no gates, over the same ``_filtered`` / ``_delay`` / ``_advance``,
+float32 inside, with a backward that keeps ``data`` alone; scopes
+``causal_conv`` / ``causal_conv_bwd``.
 """
 from __future__ import annotations
 
@@ -107,3 +114,47 @@ def gated_short_conv(data, weight):
         raise MXNetError("gated_short_conv: %d taps on a sequence of %d"
                          % (weight.shape[1], data.shape[1]))
     return _gated_conv_core(data, weight)
+
+
+@jax.custom_vjp
+def _conv_silu_core(data, weight, bias):
+    return _conv_silu_fwd(data, weight, bias)[0]
+
+
+@jax.named_scope("causal_conv")
+def _conv_silu_fwd(data, weight, bias):
+    v = _filtered(data.astype(F32), weight.astype(F32)) + bias.astype(F32)
+    return jax.nn.silu(v).astype(data.dtype), (data, weight, bias)
+
+
+@jax.named_scope("causal_conv_bwd")
+def _conv_silu_bwd(res, g):
+    data, weight, bias = res
+    z, w = data.astype(F32), weight.astype(F32)
+    k = w.shape[1]
+    v = _filtered(z, w) + bias.astype(F32)
+    gate = jax.nn.sigmoid(v)
+    dv = g.astype(F32) * gate * (1.0 + v * (1.0 - gate))  # silu's derivative
+    dw = jnp.stack([jnp.sum(dv * _delay(z, k - 1 - j), axis=(0, 1))
+                    for j in range(k)], axis=-1)
+    return (_filtered(dv, w, _advance).astype(data.dtype), dw.astype(weight.dtype),
+            jnp.sum(dv, axis=(0, 1)).astype(bias.dtype))
+
+
+_conv_silu_core.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+@register("causal_conv_silu")
+def causal_conv_silu(data, weight, bias):
+    """``silu(causal_depthwise_filter(data) + bias)`` of ``data`` (B, T, C)
+    with ``weight`` (C, K), K taps a channel, the last tap on the current
+    token, zeros before the sequence, and ``bias`` (C,). Returns (B, T, C)."""
+    if data.ndim != 3 or weight.ndim != 2 or bias.shape != weight.shape[:1] \
+            or data.shape[-1] != weight.shape[0]:
+        raise MXNetError("causal_conv_silu: data %s is not (B, T, C) for weight (C, K) "
+                         "= %s and bias %s" % (tuple(data.shape), tuple(weight.shape),
+                                               tuple(bias.shape)))
+    if weight.shape[1] > data.shape[1]:
+        raise MXNetError("causal_conv_silu: %d taps on a sequence of %d"
+                         % (weight.shape[1], data.shape[1]))
+    return _conv_silu_core(data, weight, bias)

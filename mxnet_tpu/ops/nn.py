@@ -486,6 +486,45 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
     return _rms_core(float(eps), axis % data.ndim, data, gamma)
 
 
+@jax.named_scope("gated_rmsnorm")
+def _gated_rms_fwd(eps, x, z, g):
+    u = x.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(u), axis=-1, keepdims=True) + eps)
+    return (u * inv * g.astype(jnp.float32)).astype(x.dtype), (x, z, g)
+
+
+@jax.named_scope("gated_rmsnorm_bwd")
+def _gated_rms_bwd(eps, res, ct):
+    x, z, g = res
+    x32, z32, ct32 = (a.astype(jnp.float32) for a in (x, z, ct))
+    gate = jax.nn.sigmoid(z32)
+    u = x32 * z32 * gate
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(u), axis=-1, keepdims=True) + eps)
+    uhat = u * inv
+    dy = ct32 * g.astype(jnp.float32)
+    du = inv * (dy - uhat * jnp.mean(dy * uhat, axis=-1, keepdims=True))
+    dg = jnp.sum(ct32 * uhat, axis=tuple(range(x.ndim - 1)))
+    dz = du * x32 * gate * (1.0 + z32 * (1.0 - gate))  # silu's derivative
+    return (du * z32 * gate).astype(x.dtype), dz.astype(z.dtype), dg.astype(g.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gated_rms_core(eps, x, z, g):
+    return _gated_rms_fwd(eps, x, z, g)[0]
+
+
+_gated_rms_core.defvjp(_gated_rms_fwd, _gated_rms_bwd)
+
+
+@register("GatedRMSNorm", aliases=("gated_rms_norm",))
+def gated_rms_norm(data, gate, gamma, eps=1e-6):
+    """``RMSNorm(data * silu(gate)) * gamma`` over the last axis, the gate
+    first and the norm after it (Mamba-2's ``RMSNormGated`` with
+    ``norm_before_gate=False``, one group): float32 inside, residuals in the
+    inputs' types (the backward recomputes the gated rows), as ``RMSNorm``."""
+    return _gated_rms_core(float(eps), data, gate, gamma)
+
+
 @register("rotary_embedding", aliases=("rope",))
 def rotary_embedding(data, theta=10000.0, interleaved=False, seq_axis=-2):
     """Rotary positions (Su et al. 2021) on the last axis of ``data``, the

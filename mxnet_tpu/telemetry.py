@@ -72,6 +72,7 @@ __all__ = [
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
     "record_gated_conv", "gated_conv_branches",
+    "record_ssd", "ssd_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
     "record_row_movement", "row_movement_branches",
     "record_flash_heads", "flash_heads_per_step",
@@ -929,6 +930,19 @@ def record_gated_conv(branch):
 def gated_conv_branches():
     """{branch: traces} of :func:`record_gated_conv` so far."""
     return _branches("mxt_gated_conv_total")
+
+
+def record_ssd(branch):
+    """One traced ``ssd_scan`` forward, by the branch it took
+    (``mxt_ssd_total{branch=xla|kernel}``). Counted at trace time, as the
+    flash branches: nothing enters the compiled step."""
+    counter("mxt_ssd_total", "Traced state-space scans by branch.",
+            ("branch",)).labels(branch).inc()
+
+
+def ssd_branches():
+    """{branch: traces} of :func:`record_ssd` so far."""
+    return _branches("mxt_ssd_total")
 
 
 def record_grouped_matmul(product, branch):

@@ -540,3 +540,118 @@ def test_a_call_without_a_window_lowers_to_the_kernels_it_was(cell, half):
     jaxpr = _OLDER_CELLS[cell]()[half == "bwd"]
     assert "window_attention" not in str(jaxpr)
     assert _digest(jaxpr) == _OLDER_CELLS_DIGESTS[(cell, half)]
+
+
+# -- the Mamba-2 mixer's ops and the Granite cell's whole step -------------------------
+_SSD_SHAPES = (((1, 8192, 64, 64), "x"), ((1, 8192, 64), "dt"), ((64,), "A_log"),
+               ((1, 8192, 1, 128), "B"), ((1, 8192, 1, 128), "C"), ((64,), "D"),
+               ((64,), "dt_bias"))
+
+
+def _entry_results(text):
+    """(type, dims) of every result of the optimized module's ENTRY
+    computation: what is written to the chip's memory, fusions' insides not."""
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry.split("\n")[1:]:
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) [a-z\-]+\(", line[:2000])
+        if m:
+            out += [(t, tuple(int(d) for d in dims.split(",") if d))
+                    for t, dims in re.findall(r"([a-z]+[0-9]+)\[([0-9,]*)\]", m.group(1))]
+    return out
+
+
+@pytest.mark.parametrize("half", ["fwd", "bwd"])
+def test_ssd_scan_compiles_and_no_float32_mask_leaves_its_fusion(chip, half):
+    """``ssd_scan`` at a Mamba layer of the Granite cell (1 x 8192 tokens, 64
+    heads of 64, one group, state 128, chunks of 256), forward alone and with
+    its hand-written backward: XLA's products and fusions, no kernel, and no
+    float32 tensor of (chunks x heads x 256 x 256) or more among the results
+    written to memory (0.54 GB each; autodiff through the formula keeps one a
+    layer)."""
+    from mxnet_tpu.ops.ssd import ssd_scan
+
+    dt = jnp.dtype("bfloat16")
+    shapes = tuple((s, dt) for s, _ in _SSD_SHAPES)
+    if half == "fwd":
+        text = _compile(chip, lambda *a: ssd_scan(*a, chunk=256), *shapes)
+    else:
+        text = _compile(
+            chip, jax.grad(lambda g, *a: jnp.sum(
+                ssd_scan(*a, chunk=256).astype(jnp.float32) * g), argnums=tuple(range(1, 8))),
+            ((1, 8192, 64, 64), jnp.float32), *shapes)
+    assert "tpu_custom_call" not in text
+    mask = 32 * 64 * 256 * 256
+    large = [(t, d) for t, d in _entry_results(text)
+             if t == "f32" and functools.reduce(lambda a, b: a * b, d, 1) >= mask]
+    assert not large, large
+    assert "bf16[1,8192,64,64]" in text  # y, or the gradient of x, written once
+
+
+def test_causal_conv_silu_compiles_to_fusions_without_a_convolution(chip):
+    """``causal_conv_silu`` with its hand-written backward at a Mamba layer of
+    the Granite cell (1 x 8192 tokens, 4352 channels, 4 taps and a bias):
+    shifted multiply-adds that XLA fuses, no convolution program, no kernel."""
+    from mxnet_tpu.ops.gated_conv import causal_conv_silu
+
+    dt = jnp.dtype("bfloat16")
+    text = _compile(
+        chip, jax.grad(lambda x, w, b, g: jnp.sum(
+            causal_conv_silu(x, w, b).astype(jnp.float32) * g), argnums=(0, 1, 2)),
+        ((1, 8192, 4352), dt), ((4352, 4), dt), ((4352,), dt),
+        ((1, 8192, 4352), jnp.float32))
+    assert " convolution(" not in text and "tpu_custom_call" not in text
+    assert "bf16[1,8192,4352]" in text  # the gradient of data, written once
+
+
+def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names(
+        chip, monkeypatch):
+    """``granite4_h_micro_train_s8192``'s step, 797,850,560 parameters under
+    Adam at (1, 8192) through ``ShardedTrainStep``, compiled for the described
+    chip as the cell builds it (both flash kernels in, ``remat`` from the
+    configuration's file): 11.40 GB live, 6.61 GB of it temporaries, under the
+    14 GB that leave room for the seeded copy (1.60 GB) and the batch pool.
+    Without recomputation it compiles to 16.51 GB (PERF.md section 4)."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.model_zoo import granite_hybrid as zoo
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", "granite4_h_micro_pp4.json")) as f:
+        config = json.load(f)
+    remat = config["assumed"]["recomputation"]["remat"]
+    net = zoo.GraniteHybridModel(config)
+    net.initialize(mx.init.Zero())  # shapes are what is compiled, not values
+    net.cast(config["dtype"])
+    opt = {k: v for k, v in config["optimizer"].items() if k != "name"}
+    step = parallel.ShardedTrainStep(
+        net, mx.gluon.loss.SoftmaxCrossEntropyLoss(), config["optimizer"]["name"], opt,
+        mesh=parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1]), remat=remat)
+    one = Mesh([chip._device], ("data",))
+    step.rebind_mesh(one, transfer=False)  # the shardings and the program, no value moved
+    monkeypatch.setattr(A, "on_tpu", lambda: True)  # dispatch as on the chip
+    whole = NamedSharding(one, P())
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole)
+
+    train, states, aux = step._gather()
+    assert sum(v.size for v in train) == 797850560
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=step._batch_sharding(2))
+    with jax.default_matmul_precision("default"):
+        compiled = step._jit.lower(
+            jax.tree.map(sds, train), jax.tree.map(sds, states), jax.tree.map(sds, aux),
+            x, x, sds(step._ensure_key()), sds(step._t_dev)).compile()
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+            - m.alias_size_in_bytes)
+    print("arguments %.2f + outputs %.2f + temporaries %.2f - aliased %.2f = %.2f GB"
+          % tuple(v / 1e9 for v in (m.argument_size_in_bytes, m.output_size_in_bytes,
+                                    m.temp_size_in_bytes, m.alias_size_in_bytes, held)))
+    assert m.alias_size_in_bytes >= 6 * 797850560 - 4096  # weights and state donated
+    assert held < 12.0e9 and held + 2 * 797850560 < 14e9
+    assert compiled.as_text().count("tpu_custom_call") >= 2  # both flash kernels

@@ -213,6 +213,12 @@ SPEC = {
     "rotary_embedding": ([_any(2, 5, 8)], {"interleaved": True}, None),
     "swiglu": ([_any(3, 4), _any(3, 4)], {}, None),
     "gated_short_conv": ([_any(2, 5, 6), _any(2, 3)], {}, None),
+    "causal_conv_silu": ([_any(2, 6, 3), _any(3, 4), _any(3)], {}, None),
+    "GatedRMSNorm": ([_any(3, 6), _any(3, 6), _pos(6)], {}, None),
+    # x, dt, A_log, B, C, D, dt_bias: two heads of 3 on one group of 4 states,
+    # six tokens in chunks of 4 (a ragged last chunk)
+    "ssd_scan": ([_unit(1, 6, 2, 3), _unit(1, 6, 2), _unit(2), _unit(1, 6, 1, 4),
+                  _unit(1, 6, 1, 4), _unit(2), _unit(2)], {"chunk": 4}, None),
     "GroupNorm": ([_any(2, 4, 3), _pos(4), _any(4)],
                   {"num_groups": 2}, None),
     "InstanceNorm": ([_any(2, 3, 4), _pos(3), _any(3)], {}, None),
@@ -363,6 +369,9 @@ F32_INTERNAL_TOL = {
     "rotary_embedding": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "swiglu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "gated_short_conv": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "causal_conv_silu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "GatedRMSNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "ssd_scan": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "moe_router_logits": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
 }
 

@@ -14,7 +14,8 @@ step of each traced flash kernel takes (``flash_heads_per_step``), the tiles the
 traced window calls visit beside the causal call's (``flash_window_blocks``), the layout
 each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
-each traced gated short convolution took (``gated_conv_branches``) and each
+each traced gated short convolution took (``gated_conv_branches``), each
+state-space scan (``ssd_branches``) and each
 traced grouped matmul of an expert layer by product (``grouped_matmul_branches``)
 and each traced movement of rows between tokens and experts (``row_movement_branches``),
 the tuning
@@ -44,13 +45,15 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 import run as bench  # noqa: E402 — benchmark/run.py
 
 # further single scopes quoted in PERF.md: latent and grouped-query attention,
-# the indexer, the expert layer, the gated short convolution, and attention
-# over a window beside attention over the whole past
+# the indexer, the expert layer, the gated short convolution, attention over a
+# window beside attention over the whole past, and the Mamba-2 mixer
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
          "moe", "router", "dispatch", "experts", "combine", "shared",
          "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select",
          "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj",
-         "grouped_matmul_bwd", "attn_full", "attn_window")
+         "grouped_matmul_bwd", "attn_full", "attn_window",
+         "mamba", "causal_conv", "causal_conv_bwd", "ssd", "ssd_bwd", "gated_rmsnorm",
+         "gated_rmsnorm_bwd", "mlp")
 
 
 class Context(bench.Context):
@@ -65,6 +68,7 @@ class Context(bench.Context):
     flash_layouts = None
     flash_window = None
     gated_conv = None
+    ssd = None
     grouped_matmul = None
     row_movement = None
     tuned = None
@@ -100,6 +104,7 @@ class Context(bench.Context):
         Context.flash_layouts = telemetry.flash_layouts()
         Context.flash_window = telemetry.flash_window_blocks()
         Context.gated_conv = telemetry.gated_conv_branches()
+        Context.ssd = telemetry.ssd_branches()
         Context.grouped_matmul = telemetry.grouped_matmul_branches()
         Context.row_movement = telemetry.row_movement_branches()
         Context.tuned = tuning.table().entries()
@@ -175,6 +180,8 @@ def main(argv):
             row["flash_window_blocks"] = Context.flash_window
         if Context.gated_conv:  # and which path each gated short convolution
             row["gated_conv_branches"] = Context.gated_conv
+        if Context.ssd:  # and which each state-space scan
+            row["ssd_branches"] = Context.ssd
         if Context.grouped_matmul:  # and which each grouped matmul, by product
             row["grouped_matmul_branches"] = Context.grouped_matmul
         if Context.row_movement:  # and which each movement of rows around them
