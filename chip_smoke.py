@@ -412,6 +412,7 @@ def phase_kernels(args, dev):
 
     out["grouped_matmul"] = grouped_matmul_table(args, key, interp)
     out["row_movement"] = row_movement_table(args, interp)
+    out["causal_conv"] = causal_conv_table(args, interp)
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks
@@ -602,6 +603,46 @@ def row_movement_table(args, interp):
         table[cell] = row
         check(row["equal"], "row movements %s: the kernels' sum is not XLA's" % cell)
     return table
+
+
+def causal_conv_table(args, interp):
+    """Mamba-2's filter under SiLU at the Granite cell's shape, (1, 8192, 4352)
+    bfloat16 under 4 taps and a bias: ``ops/causal_conv_pallas.py``'s two
+    kernels at the tiles the dispatch picks against the ``jax.numpy`` formula of
+    ``ops/gated_conv.py`` (both float32 inside, rounded once), forward and all
+    three gradients, and device ms a call of each, the kernels' operands turned
+    to (B, C, T) beforehand as XLA lays them out in the cell (PERF.md, Findings,
+    PR 44)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import causal_conv_pallas as CC
+    from mxnet_tpu.ops import gated_conv as G
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    b, t, c, k = (1, 512, 256, 4) if args.rehearse else (1, 8192, 4352, 4)
+    ks = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 4)
+    x, g = (jax.random.normal(s, (b, t, c), f32).astype(bf16) for s in ks[:2])
+    w = jax.random.uniform(ks[2], (c, k), f32, -0.5, 0.5).astype(bf16)
+    bias = (0.1 * jax.random.normal(ks[3], (c,), f32)).astype(bf16)
+    fwd = functools.partial(CC._fwd_pallas, begin=0, tiles=CC._tiles(t, c, 0), interpret=interp)
+    bwd = functools.partial(CC._bwd_pallas, begin=0, tiles=CC._tiles(t, c, 0), interpret=interp)
+    turn = functools.partial(jnp.swapaxes, axis1=1, axis2=2)  # the kernels' tokens lie along the lanes
+    xt, gt = turn(x), turn(g)
+    variants = {"fwd_xla": (G._filtered_silu, (x, w, bias)),
+                "bwd_xla": (G._filtered_silu_grads, (x, w, bias, g)),
+                "fwd_kernel": (fwd, (xt, w, bias)), "bwd_kernel": (bwd, (xt, w, bias, gt))}
+    dxt, dw, db = bwd(xt, w, bias, gt)
+    got = (turn(fwd(xt, w, bias)), turn(dxt), dw, db)
+    want = (jax.jit(G._filtered_silu)(x, w, bias),) + jax.jit(G._filtered_silu_grads)(x, w, bias, g)
+    ms, parts = device_ms(variants)
+    if not ms:  # no device line to read: the host's clock
+        ms = {name: inflight_ms(fn, *a) for name, (fn, a) in variants.items()}
+    row = dict(shape=[b, t, c, k], clock="device" if parts else "host",
+               rel_err=[rel_err(a, b_) for a, b_ in zip(got, want)],
+               **{"%s_ms" % n: v for n, v in ms.items()})
+    check(max(row["rel_err"]) < 1e-2, "causal filter's kernels against the formula: %s" % row)
+    return row
 
 
 # ---------------------------------------------------------------------------

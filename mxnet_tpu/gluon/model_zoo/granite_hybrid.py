@@ -74,8 +74,10 @@ class Mamba2Mixer(HybridBlock):
         zxbcdt = self.in_proj(u)
         cut = _columns(F, zxbcdt)
         z, dt = cut(0, inner), cut(2 * inner + 2 * G * N, None)
-        xbc = F.causal_conv_silu(cut(inner, 2 * inner + 2 * G * N), conv_weight,
-                                 conv_bias)
+        # the filter reads xBC where in_proj left it (a kernel is handed no slice
+        # that XLA does not first write out)
+        xbc = F.causal_conv_silu(zxbcdt, conv_weight, conv_bias,
+                                 columns=(inner, 2 * inner + 2 * G * N))
         cut = _columns(F, xbc)
         y = F.ssd_scan(
             F.reshape(cut(0, inner), shape=(0, 0, H, P)), dt, A_log,

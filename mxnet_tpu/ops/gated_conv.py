@@ -26,15 +26,23 @@ and every traced call is counted by the branch it took
 1)`` cut to the sequence, then SiLU): ``silu(V + bias)`` of ``data`` (B, T, C)
 itself, no gates, over the same ``_filtered`` / ``_delay`` / ``_advance``,
 float32 inside, with a backward that keeps ``data`` alone; scopes
-``causal_conv`` / ``causal_conv_bwd``.
+``causal_conv`` / ``causal_conv_bwd``. Where ``causal_conv_pallas.kernel_takes``
+accepts the call (a TPU, whole tiles of channels and tokens) both halves are
+that module's kernels, which hold a tile of tokens with its halo in VMEM and
+make no shifted copy; every other call is the formula here. Every traced call is
+counted by the branch it took (``telemetry.causal_conv_branches()``: ``kernel``
+or ``xla``).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from .. import telemetry as _telemetry
 from ..base import MXNetError
+from . import causal_conv_pallas as _kernels
 from .registry import register
 
 F32 = jnp.float32
@@ -116,20 +124,12 @@ def gated_short_conv(data, weight):
     return _gated_conv_core(data, weight)
 
 
-@jax.custom_vjp
-def _conv_silu_core(data, weight, bias):
-    return _conv_silu_fwd(data, weight, bias)[0]
-
-
-@jax.named_scope("causal_conv")
-def _conv_silu_fwd(data, weight, bias):
+def _filtered_silu(data, weight, bias):
     v = _filtered(data.astype(F32), weight.astype(F32)) + bias.astype(F32)
-    return jax.nn.silu(v).astype(data.dtype), (data, weight, bias)
+    return jax.nn.silu(v).astype(data.dtype)
 
 
-@jax.named_scope("causal_conv_bwd")
-def _conv_silu_bwd(res, g):
-    data, weight, bias = res
+def _filtered_silu_grads(data, weight, bias, g):
     z, w = data.astype(F32), weight.astype(F32)
     k = w.shape[1]
     v = _filtered(z, w) + bias.astype(F32)
@@ -141,20 +141,58 @@ def _conv_silu_bwd(res, g):
             jnp.sum(dv, axis=(0, 1)).astype(bias.dtype))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu_core(data, weight, bias, columns):
+    return _conv_silu_fwd(data, weight, bias, columns)[0]
+
+
+def _kernel_takes(data, weight, columns):
+    begin, end = columns
+    return _kernels.kernel_takes(data.shape[:2] + (end - begin,), weight.shape[1],
+                                 data.dtype, begin)
+
+
+@jax.named_scope("causal_conv")
+def _conv_silu_fwd(data, weight, bias, columns):
+    begin, end = columns
+    kernel = _kernel_takes(data, weight, columns)
+    _telemetry.record_causal_conv("kernel" if kernel else "xla")
+    if kernel:  # reads its columns where they lie
+        return _kernels.conv_silu(data, weight, bias, begin), (data, weight, bias)
+    return _filtered_silu(data[..., begin:end], weight, bias), (data, weight, bias)
+
+
+@jax.named_scope("causal_conv_bwd")
+def _conv_silu_bwd(columns, res, g):
+    data, weight, bias = res
+    begin, end = columns
+    if _kernel_takes(data, weight, columns):
+        dz, dw, db = _kernels.conv_silu_grads(data, weight, bias, g, begin)
+    else:
+        dz, dw, db = _filtered_silu_grads(data[..., begin:end], weight, bias, g)
+    return jnp.pad(dz, ((0, 0), (0, 0), (begin, data.shape[-1] - end))), dw, db
+
+
 _conv_silu_core.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 @register("causal_conv_silu")
-def causal_conv_silu(data, weight, bias):
+def causal_conv_silu(data, weight, bias, columns=None):
     """``silu(causal_depthwise_filter(data) + bias)`` of ``data`` (B, T, C)
     with ``weight`` (C, K), K taps a channel, the last tap on the current
-    token, zeros before the sequence, and ``bias`` (C,). Returns (B, T, C)."""
+    token, zeros before the sequence, and ``bias`` (C,). Returns (B, T, C).
+    With ``columns=(begin, end)`` ``data`` is a wider array (a fused
+    projection's result) and the op filters its columns ``begin`` to ``end``,
+    C of them: the kernels read them where they lie, which a slice handed to
+    them is copied for."""
+    begin, end = columns or (0, data.shape[-1])
     if data.ndim != 3 or weight.ndim != 2 or bias.shape != weight.shape[:1] \
-            or data.shape[-1] != weight.shape[0]:
-        raise MXNetError("causal_conv_silu: data %s is not (B, T, C) for weight (C, K) "
-                         "= %s and bias %s" % (tuple(data.shape), tuple(weight.shape),
-                                               tuple(bias.shape)))
+            or not 0 <= begin < end <= data.shape[-1] or end - begin != weight.shape[0]:
+        raise MXNetError("causal_conv_silu: columns %s of data %s are not (B, T, C) for "
+                         "weight (C, K) = %s and bias %s"
+                         % ((begin, end), tuple(data.shape), tuple(weight.shape),
+                            tuple(bias.shape)))
     if weight.shape[1] > data.shape[1]:
         raise MXNetError("causal_conv_silu: %d taps on a sequence of %d"
                          % (weight.shape[1], data.shape[1]))
-    return _conv_silu_core(data, weight, bias)
+    return _conv_silu_core(data, weight, bias, (int(begin), int(end)))

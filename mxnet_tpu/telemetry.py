@@ -72,6 +72,7 @@ __all__ = [
     "record_flash_fwd", "flash_fwd_branches",
     "record_flash_bwd", "flash_bwd_branches",
     "record_gated_conv", "gated_conv_branches",
+    "record_causal_conv", "causal_conv_branches",
     "record_ssd", "ssd_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
     "record_row_movement", "row_movement_branches",
@@ -930,6 +931,19 @@ def record_gated_conv(branch):
 def gated_conv_branches():
     """{branch: traces} of :func:`record_gated_conv` so far."""
     return _branches("mxt_gated_conv_total")
+
+
+def record_causal_conv(branch):
+    """One traced ``causal_conv_silu`` forward, by the branch it took
+    (``mxt_causal_conv_total{branch=xla|kernel}``). Counted at trace time, as
+    the flash branches: nothing enters the compiled step."""
+    counter("mxt_causal_conv_total", "Traced causal filters under SiLU by branch.",
+            ("branch",)).labels(branch).inc()
+
+
+def causal_conv_branches():
+    """{branch: traces} of :func:`record_causal_conv` so far."""
+    return _branches("mxt_causal_conv_total")
 
 
 def record_ssd(branch):

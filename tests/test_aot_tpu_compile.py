@@ -24,6 +24,7 @@ import pytest
 from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import bn_pallas
+from mxnet_tpu.ops import causal_conv_pallas as CC
 from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
 from mxnet_tpu.ops import row_gather as RG
@@ -588,30 +589,54 @@ def test_ssd_scan_compiles_and_no_float32_mask_leaves_its_fusion(chip, half):
     assert "bf16[1,8192,64,64]" in text  # y, or the gradient of x, written once
 
 
-def test_causal_conv_silu_compiles_to_fusions_without_a_convolution(chip):
+@pytest.mark.parametrize("dispatch", ["chip", "off_chip"])
+def test_causal_conv_silu_compiles_to_its_kernels_on_the_chip_and_to_fusions_off_it(
+        chip, monkeypatch, dispatch):
     """``causal_conv_silu`` with its hand-written backward at a Mamba layer of
-    the Granite cell (1 x 8192 tokens, 4352 channels, 4 taps and a bias):
-    shifted multiply-adds that XLA fuses, no convolution program, no kernel."""
+    the Granite cell (1 x 8192 tokens, columns 4096 to 8448 of ``in_proj``'s
+    8512, 4 taps and a bias). Dispatched as on the chip: the two kernels of
+    ``ops/causal_conv_pallas.py`` by their names, under the op's two scopes,
+    reading the columns where they lie (no slice of them is written), and no
+    float32 copy of the operand among what the program writes to memory. Off
+    it: shifted multiply-adds that XLA fuses, no kernel. Either way no
+    convolution program."""
     from mxnet_tpu.ops.gated_conv import causal_conv_silu
 
+    monkeypatch.setattr(CC, "on_tpu", lambda: dispatch == "chip")
     dt = jnp.dtype("bfloat16")
+    op = functools.partial(causal_conv_silu, columns=(4096, 8448))
+    shapes = (((1, 8192, 8512), dt), ((4352, 4), dt), ((4352,), dt))
     text = _compile(
-        chip, jax.grad(lambda x, w, b, g: jnp.sum(
-            causal_conv_silu(x, w, b).astype(jnp.float32) * g), argnums=(0, 1, 2)),
-        ((1, 8192, 4352), dt), ((4352, 4), dt), ((4352,), dt),
-        ((1, 8192, 4352), jnp.float32))
-    assert " convolution(" not in text and "tpu_custom_call" not in text
-    assert "bf16[1,8192,4352]" in text  # the gradient of data, written once
+        chip, jax.grad(lambda x, w, b, g: jnp.sum(op(x, w, b).astype(jnp.float32) * g),
+                       argnums=(0, 1, 2)), *shapes, ((1, 8192, 4352), jnp.float32))
+    assert " convolution(" not in text
+    assert "bf16[1,8192,8512]" in text  # the gradient of data, zeros around its columns
+    copies = sum(_entry_results(text).count(("f32", shape))  # either way round, less g itself
+                 for shape in ((1, 8192, 4352), (1, 4352, 8192))) - 1
+    if dispatch == "off_chip":
+        assert "tpu_custom_call" not in text and copies >= 2  # shifted operands
+        return
+    # the forward's result feeds nothing here: the gradient needs data alone
+    assert text.count("tpu_custom_call") == 1 and "causal_conv_silu_bwd" in text
+    assert "causal_conv_bwd))/jit(_bwd_pallas)/causal_conv_silu_bwd/" in text  # the scope
+    assert copies == 0
+    both = _compile(chip, lambda x, w, b: jax.vjp(op, x, w, b)[0], *shapes)
+    assert both.count("tpu_custom_call") == 1 and "causal_conv_silu_fwd" in both
+    assert "(causal_conv)/jit(_fwd_pallas)/causal_conv_silu_fwd/" in both
+    assert "f32[1,8192,4352]" not in both and "f32[1,4352,8192]" not in both
+    for program in (text, both):  # the columns are read in place: no slice is written
+        assert not re.search(r"= bf16\[1,(8192,4352|4352,8192)\]\S* slice(-done)?\(", program)
 
 
 def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names(
         chip, monkeypatch):
     """``granite4_h_micro_train_s8192``'s step, 797,850,560 parameters under
     Adam at (1, 8192) through ``ShardedTrainStep``, compiled for the described
-    chip as the cell builds it (both flash kernels in, ``remat`` from the
-    configuration's file): 11.40 GB live, 6.61 GB of it temporaries, under the
-    14 GB that leave room for the seeded copy (1.60 GB) and the batch pool.
-    Without recomputation it compiles to 16.51 GB (PERF.md section 4)."""
+    chip as the cell builds it (both flash kernels and the filter's two in,
+    ``remat`` from the configuration's file): 11.21 GB live, 6.42 GB of it
+    temporaries (11.40 and 6.61 with XLA's filter), under the 14 GB that leave
+    room for the seeded copy (1.60 GB) and the batch pool. Without recomputation
+    it compiled to 16.51 GB (PERF.md section 4)."""
     import json
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -633,7 +658,8 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
         mesh=parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1]), remat=remat)
     one = Mesh([chip._device], ("data",))
     step.rebind_mesh(one, transfer=False)  # the shardings and the program, no value moved
-    monkeypatch.setattr(A, "on_tpu", lambda: True)  # dispatch as on the chip
+    for module in (A, CC):  # dispatch as on the chip
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
     whole = NamedSharding(one, P())
 
     def sds(a):
@@ -653,5 +679,9 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
           % tuple(v / 1e9 for v in (m.argument_size_in_bytes, m.output_size_in_bytes,
                                     m.temp_size_in_bytes, m.alias_size_in_bytes, held)))
     assert m.alias_size_in_bytes >= 6 * 797850560 - 4096  # weights and state donated
-    assert held < 12.0e9 and held + 2 * 797850560 < 14e9
-    assert compiled.as_text().count("tpu_custom_call") >= 2  # both flash kernels
+    assert held < 11.41e9 and held + 2 * 797850560 < 14e9  # no more than before the kernels
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    # nine Mamba layers: the filter's forward, its second run, its backward
+    calls = re.findall(r"= [^=]*custom-call\([^\n]*causal_conv_silu_(fwd|bwd)/pallas_call", text)
+    assert (calls.count("fwd"), calls.count("bwd")) == (18, 9)

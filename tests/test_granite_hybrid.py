@@ -294,11 +294,23 @@ def test_causal_conv_silu_is_the_references_filter_keeps_data_alone_and_checks_s
     sym = mx.sym.causal_conv_silu(*[mx.sym.Variable(n) for n in "xwb"])
     exe = sym.bind(mx.cpu(), {"x": nd.NDArray(x), "w": nd.NDArray(w), "b": nd.NDArray(bias)})
     _close(exe.forward()[0].asnumpy(), _direct(x, w, bias), 1e-5)
-    _, res = G._conv_silu_fwd(x, w, bias)  # the operands, nothing computed
+    _, res = G._conv_silu_fwd(x, w, bias, (0, x.shape[-1]))  # the operands, nothing computed
     assert len(res) == 3 and res[0] is x and res[1] is w and res[2] is bias
     for bad in ((x[..., :-1], w, bias), (x, w, bias[:-1]), (x[:, :3], w, bias), (x[0], w, bias)):
         with pytest.raises(mx.base.MXNetError):
             G.causal_conv_silu(*bad)
+    # columns of a wider array: the op of the slice, and a gradient of the wide shape
+    wide = jnp.concatenate([x[..., :3] + 1.0, x, x[..., :2] - 1.0], axis=-1)
+    c = x.shape[-1]
+    got, pull = jax.vjp(lambda d: G.causal_conv_silu(d, w, bias, columns=(3, 3 + c)), wide)
+    want, pull_x = jax.vjp(lambda d: G.causal_conv_silu(d, w, bias), x)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    dwide, dx = pull(want)[0], pull_x(want)[0]
+    assert np.array_equal(np.asarray(dwide[..., 3:3 + c]), np.asarray(dx))
+    assert not np.asarray(dwide[..., :3]).any() and not np.asarray(dwide[..., 3 + c:]).any()
+    for columns in ((0, c + 1), (3, 2 + c), (6, 6 + c), (-1, c - 1)):
+        with pytest.raises(mx.base.MXNetError):
+            G.causal_conv_silu(wide, w, bias, columns=columns)
 
 
 @pytest.mark.parametrize("at", [0, 5, 11])
@@ -309,6 +321,16 @@ def test_filter_causality_a_token_moves_no_earlier_output_and_at_most_three_late
     assert np.array_equal(np.asarray(a[1, :at]), np.asarray(b[1, :at]))
     later = np.abs(np.asarray(a[1, at:]) - np.asarray(b[1, at:])).sum(-1)
     assert later[0] > 0 and not later[4:].any()
+
+
+def test_causal_conv_counts_one_traced_call_by_branch():
+    x, w, bias, _ = _conv_inputs("float32")
+    before = telemetry.causal_conv_branches().get("xla", 0)
+    f = jax.jit(G.causal_conv_silu)
+    f(x, w, bias), f(x, w, bias), f(x, w, bias)
+    assert telemetry.causal_conv_branches()["xla"] == before + 1  # 12 rows: no whole tile
+    assert 'mxt_causal_conv_total{branch="xla"}' in telemetry.render_prometheus()
+    assert {"record_causal_conv", "causal_conv_branches"} <= set(telemetry.__all__)
 
 
 def _digest(jaxpr):

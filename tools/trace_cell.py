@@ -15,6 +15,7 @@ traced window calls visit beside the causal call's (``flash_window_blocks``), th
 each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
 each traced gated short convolution took (``gated_conv_branches``), each
+causal filter under SiLU (``causal_conv_branches``), each
 state-space scan (``ssd_branches``) and each
 traced grouped matmul of an expert layer by product (``grouped_matmul_branches``)
 and each traced movement of rows between tokens and experts (``row_movement_branches``),
@@ -68,6 +69,7 @@ class Context(bench.Context):
     flash_layouts = None
     flash_window = None
     gated_conv = None
+    causal_conv = None
     ssd = None
     grouped_matmul = None
     row_movement = None
@@ -104,6 +106,7 @@ class Context(bench.Context):
         Context.flash_layouts = telemetry.flash_layouts()
         Context.flash_window = telemetry.flash_window_blocks()
         Context.gated_conv = telemetry.gated_conv_branches()
+        Context.causal_conv = telemetry.causal_conv_branches()
         Context.ssd = telemetry.ssd_branches()
         Context.grouped_matmul = telemetry.grouped_matmul_branches()
         Context.row_movement = telemetry.row_movement_branches()
@@ -180,6 +183,8 @@ def main(argv):
             row["flash_window_blocks"] = Context.flash_window
         if Context.gated_conv:  # and which path each gated short convolution
             row["gated_conv_branches"] = Context.gated_conv
+        if Context.causal_conv:  # and which each causal filter under SiLU
+            row["causal_conv_branches"] = Context.causal_conv
         if Context.ssd:  # and which each state-space scan
             row["ssd_branches"] = Context.ssd
         if Context.grouped_matmul:  # and which each grouped matmul, by product
