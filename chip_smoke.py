@@ -413,6 +413,7 @@ def phase_kernels(args, dev):
     out["grouped_matmul"] = grouped_matmul_table(args, key, interp)
     out["row_movement"] = row_movement_table(args, interp)
     out["causal_conv"] = causal_conv_table(args, interp)
+    out["embedding_grad"] = embedding_grad_table(args, interp)
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
     #    every block the candidate generator offers, and the one it picks
@@ -643,6 +644,70 @@ def causal_conv_table(args, interp):
                **{"%s_ms" % n: v for n, v in ms.items()})
     check(max(row["rel_err"]) < 1e-2, "causal filter's kernels against the formula: %s" % row)
     return row
+
+
+def embedding_ids(draw, rs, vocab, tokens):
+    """``tokens`` ids of a ``vocab``-row table: ``uniform``, ``zipf`` (exponent
+    1.1: a few rows take most tokens) or ``equal`` (one row takes them all)."""
+    import numpy as np
+
+    if draw == "uniform":
+        return rs.randint(0, vocab, tokens)
+    if draw == "zipf":
+        return np.minimum(rs.zipf(1.1, tokens) - 1, vocab - 1)
+    return np.full(tokens, vocab // 3)
+
+
+def embedding_grad_table(args, interp, shapes=None, draws=("uniform",), dtype="bfloat16"):
+    """The gradient of an embedding table, (vocab, width) looked up by
+    ``tokens`` ids, at the SmallThinker cell's shape (``shapes``: {name:
+    (vocab, width, tokens)}): ``ops/embedding_grad.py``'s grouped product over
+    the table's tiles against XLA's scatter-add, each against the scatter-add
+    of the float32 cotangent (the kernel's equal to one rounding; XLA's own
+    rounds at every repeat), and device ms a call of each, the kernel's own
+    beside (what is left is the sort, the gather of the sorted rows, the walk
+    and the cut to ``vocab`` rows) (PERF.md, Findings, PR 45: the stand-alone
+    table)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import embedding_grad as EG
+
+    f32, dt = jnp.float32, jnp.dtype(dtype)
+    if shapes is None:
+        shapes = {"tiny": (300, 256, 512)} if args.rehearse else {
+            "smallthinker_a3b_train_s8192": (37984, 2560, 8192)}
+    table = {}
+    for name, (vocab, width, tokens) in shapes.items():
+        ours = jax.jit(functools.partial(EG.table_grad, vocab=vocab, interpret=interp))
+
+        @jax.jit
+        def theirs(ids, dy, vocab=vocab):  # what jnp.take transposes to
+            zeros = jnp.zeros((vocab, dy.shape[1]), dy.dtype)
+            return jax.vjp(lambda w: jnp.take(w, ids, axis=0), zeros)[1](dy)[0]
+
+        for draw in draws:
+            rs = np.random.RandomState(args.seed % (2 ** 31))
+            ids = jnp.asarray(embedding_ids(draw, rs, vocab, tokens), jnp.int32)
+            dy = jax.random.normal(jax.random.PRNGKey(args.seed % (2 ** 31)),
+                                   (tokens, width), f32).astype(dt)
+            want = theirs(ids, dy.astype(f32))
+            row = dict(shape=[vocab, width, tokens], rel_err=rel_err(ours(ids, dy), want),
+                       xla_rel_err=rel_err(theirs(ids, dy), want))
+            del want
+            variants = {"xla": (theirs, (ids, dy)), "kernel": (ours, (ids, dy))}
+            ms, parts = device_ms(variants)
+            if not ms:  # no device line to read: the host's clock
+                ms = {n: inflight_ms(fn, *a) for n, (fn, a) in variants.items()}
+            row.update(clock="device" if parts else "host", xla_ms=ms["xla"],
+                       kernel_ms=ms["kernel"], **{"%s_ms" % n: v for n, v in parts.items()})
+            table["%s.%s" % (name, draw)] = row
+            # float32 is read, not held: the op leaves it to XLA (the MXU rounds it)
+            check(dt != jnp.bfloat16 or row["rel_err"] < 5e-3,
+                  "embedding gradient %s %s: the kernel is not the float32 sum rounded "
+                  "once: %s" % (name, draw, row))
+    return table
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,14 @@ src/operator/sequence_*.cc).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from . import embedding_grad as _embedding_grad
 from .registry import register
+from .. import telemetry as _telemetry
 
 
 @register("take")
@@ -20,16 +24,48 @@ def take(a, indices, axis=0, mode="clip"):
     return jnp.take(a, idx, axis=axis)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lookup(weight, idx, vocab):
+    """``jnp.take(weight, idx, axis=0)`` whose gradient is
+    ``ops/embedding_grad.py``'s grouped product and not a scatter."""
+    del vocab
+    return jnp.take(weight, idx, axis=0)
+
+
+def _lookup_fwd(weight, idx, vocab):
+    return _lookup(weight, idx, vocab), idx
+
+
+def _lookup_bwd(vocab, idx, dy):
+    _telemetry.record_embedding_grad("kernel")
+    with jax.named_scope("embedding_bwd"):
+        dw = _embedding_grad.table_grad(idx.reshape(-1), dy.reshape(idx.size, -1), vocab)
+    return dw, None
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
+
+
 @register("Embedding")
 def embedding(data, weight, input_dim=None, output_dim=None, dtype=None,
               sparse_grad=False):
     """Embedding lookup (ref: src/operator/tensor/indexing_op.cc — Embedding).
 
     On TPU this is a gather feeding the MXU-free path; the row_sparse
-    gradient variant lives in the sparse module.
+    gradient variant lives in the sparse module. The weight's gradient is
+    ``ops/embedding_grad.py``'s grouped product over the table's tiles where
+    ``embedding_grad.kernel_takes`` admits the call (its docstring has the
+    rule and the table that set it) and ``sparse_grad`` is off; every other
+    call is ``jnp.take`` with its own transpose, a scatter-add. Counted at
+    trace time (``telemetry.embedding_grad_branches()``: ``kernel`` a traced
+    backward, ``xla`` a traced call whose backward, if taken, is XLA's).
     """
-    del input_dim, output_dim, dtype, sparse_grad
+    del input_dim, output_dim, dtype
     idx = jnp.clip(data.astype(jnp.int32), 0, weight.shape[0] - 1)
+    if not sparse_grad and weight.ndim == 2 and _embedding_grad.kernel_takes(
+            *weight.shape, idx.size, weight.dtype):
+        return _lookup(weight, idx, weight.shape[0])
+    _telemetry.record_embedding_grad("xla")
     return jnp.take(weight, idx, axis=0)
 
 

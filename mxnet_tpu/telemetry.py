@@ -74,6 +74,7 @@ __all__ = [
     "record_gated_conv", "gated_conv_branches",
     "record_causal_conv", "causal_conv_branches",
     "record_ssd", "ssd_branches",
+    "record_embedding_grad", "embedding_grad_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
     "record_row_movement", "row_movement_branches",
     "record_flash_heads", "flash_heads_per_step",
@@ -957,6 +958,22 @@ def record_ssd(branch):
 def ssd_branches():
     """{branch: traces} of :func:`record_ssd` so far."""
     return _branches("mxt_ssd_total")
+
+
+def record_embedding_grad(branch):
+    """One traced ``Embedding`` by the form its weight's gradient takes
+    (``mxt_embedding_grad_total{branch=xla|kernel}``): ``kernel`` a traced
+    backward that is ``ops/embedding_grad.py``'s grouped product, ``xla`` a
+    traced call that is ``jnp.take`` whole, its backward (if one is taken)
+    JAX's scatter-add. Counted at trace time, as the flash branches: nothing
+    enters the compiled step."""
+    counter("mxt_embedding_grad_total", "Traced embedding gradients by branch.",
+            ("branch",)).labels(branch).inc()
+
+
+def embedding_grad_branches():
+    """{branch: traces} of :func:`record_embedding_grad` so far."""
+    return _branches("mxt_embedding_grad_total")
 
 
 def record_grouped_matmul(product, branch):

@@ -25,6 +25,7 @@ from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import bn_pallas
 from mxnet_tpu.ops import causal_conv_pallas as CC
+from mxnet_tpu.ops import embedding_grad as EG
 from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
 from mxnet_tpu.ops import row_gather as RG
@@ -209,6 +210,16 @@ def _row_sum(rows, k, hidden, dtype="bfloat16"):
     return case
 
 
+def _embedding_grad(vocab, width, tokens, dtype="bfloat16"):
+    """The gradient of a (vocab, width) embedding table looked up by ``tokens``
+    ids: the sort, the gather of the sorted rows, the walk over the table's
+    128-row tiles and ``grouped_matmul_dw`` over them, cut to ``vocab`` rows."""
+    def case(chip):
+        return _compile(chip, functools.partial(EG.table_grad, vocab=vocab),
+                        ((tokens,), jnp.int32), ((tokens, width), jnp.dtype(dtype)))
+    return case
+
+
 _CASES = {
     # flash forward: BERT-base (batch 32 x 128) without and with a
     # padding bias, longer and ragged sequences, explicit big blocks, and
@@ -321,6 +332,17 @@ _CASES = {
     "row_sum_kanana": _row_sum(12288, 6, 2048),
     "row_sum_lfm2": _row_sum(16384, 4, 2048),
     "row_sum_f32_12288x6x2048": _row_sum(12288, 6, 2048, "float32"),
+    # the embedding's gradient at the tables tools/embedding_grad_table.py times
+    # (the language-model cells' and SmallThinker's published vocabulary; the
+    # op's rule takes the two over 128 MiB): 1 to 1187 groups of one walk
+    "embedding_grad_16032x2048_8192": _embedding_grad(16032, 2048, 8192),
+    "embedding_grad_16384x2048_8192": _embedding_grad(16384, 2048, 8192),
+    "embedding_grad_18992x2048_8192": _embedding_grad(18992, 2048, 8192),
+    "embedding_grad_25088x2048_8192": _embedding_grad(25088, 2048, 8192),
+    "embedding_grad_30522x768_16384": _embedding_grad(30522, 768, 16384),
+    "embedding_grad_2x768_16384": _embedding_grad(2, 768, 16384),
+    "embedding_grad_37984x2560_8192": _embedding_grad(37984, 2560, 8192),
+    "embedding_grad_151936x2560_8192": _embedding_grad(151936, 2560, 8192),
     "indexer_select_8192": _indexer(1, 8192),
     "indexer_select_2x3000_top512": _indexer(2, 3000, topk=512),
     # paged decode at the BERT-base/GPT-2 geometry, block as the
@@ -626,6 +648,30 @@ def test_causal_conv_silu_compiles_to_its_kernels_on_the_chip_and_to_fusions_off
     assert "f32[1,8192,4352]" not in both and "f32[1,4352,8192]" not in both
     for program in (text, both):  # the columns are read in place: no slice is written
         assert not re.search(r"= bf16\[1,(8192,4352|4352,8192)\]\S* slice(-done)?\(", program)
+
+
+@pytest.mark.parametrize("dispatch", ["chip", "off_chip"])
+def test_the_embeddings_gradient_is_the_grouped_product_on_the_chip_and_xlas_off_it(
+        chip, monkeypatch, dispatch):
+    """``jax.grad`` through the ``Embedding`` op at the SmallThinker cell's
+    table, (37984, 2560) bfloat16 looked up by 1 x 8192 ids. Dispatched as on
+    the chip: no scatter in the compiled program, one sort, the one kernel
+    (``grouped_matmul_dw``) under the scope ``embedding_bwd``, and the table's
+    gradient among the results. Off it: XLA's sorted scatter-add, no kernel."""
+    from mxnet_tpu.ops.indexing import embedding
+
+    monkeypatch.setattr(EG, "on_tpu", lambda: dispatch == "chip")
+    dt = jnp.dtype("bfloat16")
+    text = _compile(
+        chip, jax.grad(lambda w, ids, g: jnp.sum(embedding(ids, w).astype(jnp.float32) * g)),
+        ((37984, 2560), dt), ((1, 8192), jnp.float32), ((1, 8192, 2560), jnp.float32))
+    assert "bf16[37984,2560]" in text
+    if dispatch == "off_chip":
+        assert "tpu_custom_call" not in text and " scatter(" in text
+        return
+    assert not re.search(r"\bscatter\b", text) and len(re.findall(r" sort\(", text)) == 1
+    assert text.count("tpu_custom_call") == 1 and "grouped_matmul_dw" in text
+    assert "(embedding_bwd))/jit(_tgmm_pallas)/grouped_matmul_dw" in text  # the scope
 
 
 def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names(

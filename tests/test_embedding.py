@@ -576,3 +576,173 @@ def test_embedding_server_kill_remap_rejoin():
             rejoined.close()
         for h in handles[:1]:
             h.close()
+
+
+# --------------------------------------------------------------------------
+# the Embedding op's weight gradient (ops/embedding_grad.py): the grouped
+# product over the table's tiles in interpret mode on the CPU, against a
+# float32 reference and against XLA's scatter-add; the rule; the counter
+# --------------------------------------------------------------------------
+import hashlib  # noqa: E402
+import re  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from mxnet_tpu import telemetry  # noqa: E402
+from mxnet_tpu.ops import embedding_grad as EG  # noqa: E402
+from mxnet_tpu.ops.indexing import embedding as embedding_op  # noqa: E402
+
+# (vocab, width, tokens, draw) by what the walk over the tiles has to get right
+GRAD_CASES = {
+    "uniform": (1000, 256, 512, "uniform"),
+    "zipf": (1000, 256, 512, "zipf"),
+    "every_id_equal": (1000, 256, 512, "equal"),
+    "out_of_range": (1000, 256, 512, "out_of_range"),
+    "vocab_37984_not_whole_tiles": (37984, 128, 512, "uniform"),
+    "vocab_under_a_tile": (50, 128, 256, "uniform"),
+    "tied": (300, 128, 256, "uniform"),
+}
+
+
+def _ids(draw, vocab, tokens, seed=0):
+    rs = np.random.RandomState(seed)
+    if draw == "zipf":
+        return np.minimum(rs.zipf(1.1, tokens) - 1, vocab - 1)
+    if draw == "equal":
+        return np.full(tokens, vocab // 3)
+    if draw == "out_of_range":  # clipped to [0, vocab) as the forward clips
+        return rs.randint(-vocab, 2 * vocab, tokens)
+    return rs.randint(0, vocab, tokens)
+
+
+def _engage(monkeypatch, grouped_matmul_kernels):
+    """From here on the op dispatches as on a TPU, the kernel interpreted, a
+    table of any size taken (the CPU holds none over the rule's 128 MiB)."""
+    grouped_matmul_kernels()
+    monkeypatch.setattr(EG, "on_tpu", lambda: True)
+    monkeypatch.setattr(EG, "TABLE_BYTES", 0)
+
+
+def _far(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape
+    return np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(GRAD_CASES))
+def test_embedding_grad_by_the_kernel_against_float32_and_xlas_scatter(
+        case, dtype, monkeypatch, grouped_matmul_kernels):
+    """``jax.grad`` through the ``Embedding`` op where the rule engages: each
+    row the float32 sum of its tokens' cotangents rounded once (ids out of
+    range clipped as the forward clips them, a last tile of 96 rows, a table
+    under one tile), no further from the float32 reference than XLA's
+    scatter-add, which rounds at every repeat; a tied table (the head's
+    product beside the look-up) gets the sum of both cotangents. In float32
+    the op stays XLA's (on the chip the product would round the cotangent), so
+    there the walk is held through ``table_grad`` itself, to float32's digits."""
+    vocab, width, tokens, draw = GRAD_CASES[case]
+    dt, f32 = jnp.dtype(dtype), jnp.float32
+    ids = jnp.asarray(_ids(draw, vocab, tokens).reshape(2, tokens // 2), f32)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    w = jax.random.normal(ks[0], (vocab, width), f32).astype(dt)
+    g = jax.random.normal(ks[1], (2, tokens // 2, width), f32).astype(dt)
+    x = jax.random.normal(ks[2], (64, width), f32).astype(dt)
+    tied = case == "tied"
+
+    def loss(w, g=g, x=x):
+        out = jnp.sum(embedding_op(ids, w, vocab, width) * g)
+        return out + jnp.sum(jnp.dot(x, w.T)) if tied else out
+
+    xla = jax.grad(loss)(w)  # here: a CPU, so jnp.take's own transpose
+    want = np.asarray(jax.grad(lambda w: loss(w, g.astype(f32), x.astype(f32)))(w.astype(f32)))
+    clipped = np.clip(np.asarray(ids, np.int64).reshape(-1), 0, vocab - 1)
+    rows = np.zeros((vocab, width), np.float64)
+    np.add.at(rows, clipped, np.asarray(g, np.float64).reshape(tokens, width))
+    if not tied:
+        assert _far(want, rows) < 1e-5  # the reference is the sum it says
+
+    _engage(monkeypatch, grouped_matmul_kernels)
+    before = telemetry.embedding_grad_branches()
+    if dt == f32:
+        assert "scatter-add" in str(jax.make_jaxpr(jax.grad(loss))(w))  # the op: XLA's
+        assert telemetry.embedding_grad_branches()["xla"] == before.get("xla", 0) + 1
+        got = EG.table_grad(jnp.asarray(clipped, jnp.int32), g.reshape(tokens, width), vocab,
+                            interpret=True)
+        assert got.dtype == dt and _far(got, rows) < 1e-5
+        return
+    text = str(jax.make_jaxpr(jax.grad(loss))(w))
+    assert "grouped_matmul_dw" in text and "scatter" not in text
+    assert "layout_constraint" in text  # the cotangent held as rows, as the scatter held it
+    got = jax.grad(loss)(w)
+    assert telemetry.embedding_grad_branches()["kernel"] == before.get("kernel", 0) + 2
+    assert got.dtype == dt and got.shape == (vocab, width)
+    assert _far(got, want) <= 2.0 ** -8 * (2 if tied else 1)  # one rounding (the head's too)
+    assert _far(got, want) <= _far(xla, want) + 1e-6
+    assert np.array_equal(np.asarray(embedding_op(ids, w)), np.asarray(jnp.take(
+        w, jnp.asarray(clipped.reshape(2, -1)), axis=0)))  # the forward is jnp.take
+
+
+# what each ineligible call traced to before the op had a backward of its own
+_TAKE_DIGESTS = {"cpu": "09bbe45db5da7826", "width_100": "c162dc0d44153666",
+                 "float32": "1d0a1a359290ca81", "tokens_100": "1d8fb51b8ceaac0e",
+                 "sparse_grad": "09bbe45db5da7826", "table_under_128_mib": "09bbe45db5da7826"}
+_TAKE_CALLS = {"cpu": (512, 256, 512, False, "bfloat16"),
+               "width_100": (512, 100, 512, False, "bfloat16"),
+               "tokens_100": (512, 256, 100, False, "bfloat16"),
+               "float32": (512, 256, 512, False, "float32"),
+               "sparse_grad": (512, 256, 512, True, "bfloat16"),
+               "table_under_128_mib": (512, 256, 512, False, "bfloat16")}
+
+
+@pytest.mark.parametrize("case", sorted(_TAKE_CALLS))
+def test_an_ineligible_embedding_traces_to_jnp_take_and_counts_xla(case, monkeypatch):
+    """The CPU, a width of 100, 100 tokens, float32, ``sparse_grad=True``, a
+    table XLA's form is cheap at: the call and
+    its gradient trace to the jaxpr they traced to before (``jnp.take`` and
+    JAX's transpose of it, letter for letter), and count ``xla``."""
+    vocab, width, tokens, sparse, dtype = _TAKE_CALLS[case]
+    if case != "cpu":
+        monkeypatch.setattr(EG, "on_tpu", lambda: True)
+    if case != "table_under_128_mib":
+        monkeypatch.setattr(EG, "TABLE_BYTES", 0)
+    w = jnp.zeros((vocab, width), dtype)
+    ids = jnp.zeros((2, tokens // 2), jnp.float32)
+    g = jnp.zeros(ids.shape + (width,), dtype)
+    before = telemetry.embedding_grad_branches()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda w: jnp.sum(
+        embedding_op(ids, w, sparse_grad=sparse).astype(jnp.float32)
+        * g.astype(jnp.float32))))(w)
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jaxpr))
+    assert "custom_vjp" not in text and "pallas_call" not in text and "scatter-add" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _TAKE_DIGESTS[case]
+    after = telemetry.embedding_grad_branches()
+    assert after["xla"] == before.get("xla", 0) + 1
+    assert after.get("kernel", 0) == before.get("kernel", 0)
+    assert 'mxt_embedding_grad_total{branch="xla"}' in telemetry.render_prometheus()
+
+
+_RULE = {
+    "smallthinker_cell": ((37984, 2560, 8192, "bfloat16"), True),
+    "published_vocabulary": ((151936, 2560, 8192, "bfloat16"), True),
+    "lfm2_published": ((65536, 2048, 8192, "bfloat16"), True),
+    "granite_cell_under_128_mib": ((25088, 2048, 8192, "bfloat16"), False),
+    "bert_word_table_under_128_mib": ((30522, 768, 16384, "bfloat16"), False),
+    "bert_token_types": ((2, 768, 16384, "bfloat16"), False),
+    "float32": ((30522, 768, 16384, "float32"), False),
+    "odd_width": ((37984, 100, 8192, "bfloat16"), False),
+    "few_tokens": ((37984, 2560, 100, "bfloat16"), False),
+    "no_tokens": ((37984, 2560, 0, "bfloat16"), False),
+    "at_128_mib": ((32768, 2048, 8192, "bfloat16"), False),
+    "float16": ((37984, 2560, 8192, "float16"), False),
+    "blocks_past_vmem": ((37984, 131072, 8192, "bfloat16"), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE))
+def test_the_embedding_rule_reads_the_shapes_and_the_device(case, monkeypatch):
+    shape, want = _RULE[case]
+    assert not EG.kernel_takes(*shape)  # here: a CPU
+    monkeypatch.setattr(EG, "on_tpu", lambda: True)
+    assert EG.kernel_takes(*shape) == want
