@@ -9,8 +9,7 @@ widths with random weights made from ``--seed``:
 2. ``kernels`` — every Pallas kernel in mxnet_tpu/ops, compiled (never
                  interpreted) against its plain reference: flash forward,
                  the expert layer's grouped matmuls (with ms a call beside
-                 ``ragged_dot``'s), paged decode, BN backward; plus one
-                 on-device autotune pass.
+                 ``ragged_dot``'s), paged decode.
 3. ``resnet50``— ResNet-50 v1, bf16, NHWC, batch 64, 224x224, through the
                  Gluon loop fused into one launch (``Trainer.fuse_step``).
 4. ``bert``    — BERT-base MLM, vocabulary 30522, batch 32 x 128, bf16,
@@ -74,12 +73,18 @@ def rel_err(a, b):
 
 
 def median_ms(fn, *args):
-    """Median wall milliseconds of fn(*args) to block_until_ready after one
-    warm call — the autotuner's own timing loop. One cold process on a
-    shared host: a reading, not a benchmark."""
-    from mxnet_tpu.tuning import autotune
+    """Median wall milliseconds of five ``fn(*args)`` to block_until_ready
+    after one warm call (the median resists the one scheduling hiccup). One
+    cold process on a shared host: a reading, not a benchmark."""
+    import jax
 
-    return autotune._time(functools.partial(fn, *args), 5) * 1e3
+    jax.block_until_ready(fn(*args))
+    samples = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        samples.append(time.perf_counter() - t0)
+    return sorted(samples)[len(samples) // 2] * 1e3
 
 
 def inflight_ms(fn, *args, calls=20, repeats=3):
@@ -231,11 +236,10 @@ def phase_kernels(args, dev):
 
     from mxnet_tpu import tuning
     from mxnet_tpu.ops import attention as A
-    from mxnet_tpu.ops import bn_pallas
 
     interp = bool(args.rehearse)
     key = jax.random.PRNGKey(args.seed)
-    out = dict(phase="kernels", interpret=interp, flash={}, paged={}, bn={})
+    out = dict(phase="kernels", interpret=interp, flash={}, paged={})
 
     # -- flash forward, blocks as the tuning table's cost model picks them,
     #    and the backward kernel wherever _flash_bwd would take it, at the
@@ -443,51 +447,6 @@ def phase_kernels(args, dev):
         out["paged"]["block_h=%d" % bh] = err
         check(err < 2e-2, "paged decode block_h=%d: rel err %.4f" % (bh, err))
     out["paged"]["picked_block_h"] = picked["block_h"]
-
-    # -- BN backward at two ResNet-50 shapes against the XLA formulas
-    bn_cases = [(512, 64), (392, 256)] if args.rehearse \
-        else [(200704, 64), (3136, 2048)]
-    for m, c in bn_cases:
-        ks = jax.random.split(jax.random.fold_in(key, m), 2)
-        x = jax.random.normal(ks[0], (m, c), jnp.bfloat16)
-        dy = jax.random.normal(ks[1], (m, c), jnp.bfloat16)
-        g = jnp.full((c,), 1.3, jnp.float32)
-        x32 = x.astype(jnp.float32)
-        mean = jnp.mean(x32, axis=0)
-        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32 - mean), axis=0) + 1e-5)
-        kernel = functools.partial(bn_pallas.bn_bwd_pallas, interpret=interp)
-
-        @jax.jit
-        def xla(x, dy, mean, inv, g, m=m):
-            dyf, xhat = dy.astype(jnp.float32), (x.astype(jnp.float32) - mean) * inv
-            db, dg = jnp.sum(dyf, axis=0), jnp.sum(dyf * xhat, axis=0)
-            return (g * inv) * (dyf - db / m - xhat * dg / m), dg, db
-
-        got, ref = kernel(x, dy, mean, inv, g), xla(x, dy, mean, inv, g)
-        errs = {n: rel_err(a, b) for n, a, b in zip(("dx", "dg", "db"), got, ref)}
-        check(max(errs.values()) < 2e-2, "bn backward (%d,%d): %s" % (m, c, errs))
-        out["bn"]["%dx%d" % (m, c)] = dict(
-            errs, kernel_ms=median_ms(kernel, x, dy, mean, inv, g),
-            xla_ms=median_ms(xla, x, dy, mean, inv, g))
-
-    # -- one autotune pass on the device, as an eager first call would make
-    #    it: every candidate the generators call legal must compile and run
-    #    (a refusal raises), and the timings say which backend wins here
-    shape = flash_cases[0][1]
-    ks = jax.random.split(key, 3)
-    q, k, v = (jax.random.normal(s, shape, jnp.bfloat16) for s in ks)
-    t0 = time.perf_counter()
-    ent = tuning.measure_attention(q, k, v, None, False, 1.0 / math.sqrt(shape[3]),
-                                   interpret=interp, iters=3)
-    out["measure_attention"] = dict(shape=shape, entry=ent,
-                                    seconds=time.perf_counter() - t0)
-    m, c = bn_cases[0]
-    x = jax.random.normal(ks[0], (m, c), jnp.bfloat16)
-    dy = jax.random.normal(ks[1], (m, c), jnp.bfloat16)
-    chan = jnp.ones((c,), jnp.float32)
-    t0 = time.perf_counter()
-    ent = tuning.measure_bn(x, dy, 0.0 * chan, chan, chan, interpret=interp, iters=3)
-    out["measure_bn"] = dict(shape=(m, c), entry=ent, seconds=time.perf_counter() - t0)
     return out
 
 
@@ -1048,9 +1007,7 @@ def main():
 
     from mxnet_tpu import config, native, tuning
 
-    # kernel choices come from the cost model, so that the programs under
-    # test are the same on every run; the measuring alternative ('auto' on a
-    # TPU) is run once, in the kernels phase, and its verdict printed
+    # kernel choices from the table and the cost model: the default, stated
     config.set_default("MXT_TUNE_MODE", "heuristic")
     cache_dir = tuning.setup_compile_cache(os.path.join(HERE, ".jax_cache"))
     emit(phase="start", device=device, seed=args.seed, rehearse=args.rehearse,

@@ -73,33 +73,20 @@ _declare("MXT_KVSTORE_SECRET", str, None,
          "any non-loopback server bind; see async_server.py threat "
          "model.")
 
-_declare("MXT_FLASH_BLOCK_Q", int, 128,
-         "Flash-attention query block rows. Setting it (env or "
-         "set_default) pins ALL shapes to this block — the A/B knob for "
-         "the chip runbook; leave unset to let the tuning table pick a "
-         "shape-aware config per call (tuning/autotune.py). Re-read on "
-         "every kernel dispatch, so sweeps can change it without a "
-         "fresh process.")
-_declare("MXT_FLASH_BLOCK_K", int, 128,
-         "Flash-attention key/value block rows (same pinning/override "
-         "semantics as MXT_FLASH_BLOCK_Q).")
-
 _declare("MXT_TUNE_TABLE", str, None,
          "Path of the persistent kernel-tuning table (tuning/table.py): "
          "per-(op, shape-bucket, dtype, device) block configs, "
          "XLA-vs-Pallas decisions, and recorded warmup shape "
          "signatures, as versioned JSON. Unset keeps the table "
          "in-memory only (decisions still cached for the process).")
-_declare("MXT_TUNE_MODE", str, "auto",
-         "Kernel autotuner policy (ref: MXNET_CUDNN_AUTOTUNE_DEFAULT): "
-         "'auto' = timed micro-benchmarks on a real TPU, deterministic "
-         "heuristic cost model elsewhere (CPU/CI); 'heuristic' = never "
-         "measure; 'measure' = measure even off-TPU (tests/sweeps); "
-         "'off' = bypass the tuning table entirely (legacy global "
-         "MXT_FLASH_BLOCK_* / MXT_BN_PALLAS behavior).")
-_declare("MXT_TUNE_ITERS", int, 10,
-         "Timing iterations per candidate config in the autotuner's "
-         "measurement loop.")
+_declare("MXT_TUNE_MODE", str, "heuristic",
+         "Where a kernel's tiles come from (ref: "
+         "MXNET_CUDNN_AUTOTUNE_DEFAULT; nothing is timed here): "
+         "'heuristic' = the tuning table's entry for the shape, else the "
+         "deterministic cost model's choice, recorded there "
+         "(tuning/autotune.py); 'off' = the cost model alone, the table "
+         "neither read nor written, in every resolver alike. Any other "
+         "value is an MXNetError at the first kernel dispatch.")
 
 _declare("MXT_COMPILE_CACHE_DIR", str, None,
          "Directory for JAX's persistent compilation cache. When set, "
@@ -110,12 +97,6 @@ _declare("MXT_COMPILE_CACHE_DIR", str, None,
          "JAX_COMPILATION_CACHE_DIR is set: the cache then lives there "
          "and no directory is set in code. Give it one fixed path (the "
          "path is part of the cache key).")
-
-_declare("MXT_BN_PALLAS", bool, False,
-         "Use the fused Pallas BatchNorm backward on channel-last "
-         "activations (ops/bn_pallas.py): both reductions in one joint "
-         "read of (x, dy). Default off until chip-measured vs the XLA "
-         "custom-VJP path (the A/B is staged in the recovery runbook).")
 
 _declare("MXT_MAX_INFLIGHT", int, 2,
          "Depth of the async dispatch window (engine.py): the host may "

@@ -162,8 +162,8 @@ def default_context() -> Context:
 
 
 def on_tpu() -> bool:
-    """The one device predicate: compiled Pallas kernels, on-device
-    autotune measurement and the TPU feature flag all ask this."""
+    """The one device predicate: compiled Pallas kernels and the TPU
+    feature flag ask this."""
     import jax
 
     return jax.default_backend() == "tpu"

@@ -367,7 +367,7 @@ def wait_all():
     """Drain every live stream's in-flight window (the host half of
     ``Engine::WaitForAll``; ``mx.nd.waitall()`` calls this first). The
     barrier is also the durability point for the kernel-tuning table:
-    decisions the autotuner recorded since the last save hit disk here,
+    decisions the cost model recorded since the last save hit disk here,
     so a process killed mid-epoch still leaves its tuning work behind
     for the next one (the same contract waitall gives the telemetry
     JSONL sink)."""

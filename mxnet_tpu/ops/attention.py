@@ -80,82 +80,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 from ..context import on_tpu
+from . import chip as _chip
 from .registry import register
 
-# block sizes come from the config registry (MXT_FLASH_BLOCK_Q/K) or,
-# when neither is pinned, from the per-shape tuning table
-# (tuning/autotune.py) — a bad value fails the attention call with a
-# typed error instead of breaking package import
-from .. import config as _config
-from .. import telemetry as _telemetry
 
-
-def _block_cfg(name):
-    v = int(_config.get(name))
-    if v < 8 or v % 8:
-        raise MXNetError("%s must be a positive multiple of 8 (TPU "
-                         "sublane), got %d" % (name, v))
-    return v
-
-
-def default_blocks():
-    """(block_q, block_k) from MXT_FLASH_BLOCK_Q/K, re-read on every
-    call — the old first-use memo latched one value for the process
-    lifetime, so tests and block sweeps could never change blocks
-    without a fresh interpreter. The values are plain ints, so jit keys
-    stay stable as long as the config does.
-
-    These blocks cover the *training/prefill* flash kernel only. Decode
-    shapes (one query token per sequence over a paged KV cache) resolve
-    through the tuning table's decode-shape buckets instead —
-    ``tuning.resolve_paged`` keys on (batch, heads, head_dim, page_size,
-    max-pages bucket) and picks a head-block config for
-    :func:`ragged_paged_attention`; MXT_FLASH_BLOCK_Q/K never apply
-    there (a Tq=1 query has no query block to tile)."""
-    return (_block_cfg("MXT_FLASH_BLOCK_Q"),
-            _block_cfg("MXT_FLASH_BLOCK_K"))
-
-
-def blocks_pinned():
-    """True when the user pinned the blocks (env var or set_default) —
-    the A/B-sweep override that bypasses the tuning table."""
-    return (_config.is_set("MXT_FLASH_BLOCK_Q")
-            or _config.is_set("MXT_FLASH_BLOCK_K"))
-
-
-def _tuned_config(q, k, v, bias, causal, sm_scale, mask=None):
-    """Per-shape kernel decision: pinned blocks win (legacy/global
-    behavior), otherwise the tuning table answers — a table hit, or a
-    measured/heuristic autotune pass recorded under this shape bucket
-    (the bucket names the K/V head count where it is not the query's, and
-    a selection mask where there is one: an entry tuned for a dense call is
-    never taken for a masked one). The returned dict carries the
-    XLA-vs-Pallas choice per shape; the device gate (context.on_tpu) still
-    applies on top."""
+def _tuned_config(q, k, causal, mask=None):
+    """Per-shape kernel decision: the tuning table answers, a hit or the
+    cost model's choice recorded under this shape bucket (the bucket names
+    the K/V head count where it is not the query's, and a selection mask
+    where there is one: an entry written for a dense call is never taken
+    for a masked one). The returned dict carries the XLA-vs-Pallas choice
+    per shape; the device gate (context.on_tpu) still applies on top."""
     return _tuned_config_at(q.shape, k.shape[2], k.shape[1], str(q.dtype),
-                            causal, (q, k, v, bias, sm_scale), mask)
+                            causal, mask)
 
 
-def _tuned_config_at(q_shape, kv_len, kv_heads, dtype, causal, arrays, mask):
-    """``_tuned_config`` by the call's (B, H, Tq, D) shape; ``arrays`` (q, k,
-    v, bias, sm_scale) only where the table may measure on them."""
-    if str(_config.get("MXT_TUNE_MODE")).lower() == "off" \
-            or blocks_pinned():
-        bq, bk = default_blocks()
-        return {"backend": "pallas", "block_q": bq, "block_k": bk,
-                "source": "pinned"}
+def _tuned_config_at(q_shape, kv_len, kv_heads, dtype, causal, mask):
+    """``_tuned_config`` by the call's (B, H, Tq, D) shape."""
     from .. import tuning
 
     return tuning.resolve_attention(
-        q_shape, kv_len, dtype, causal, arrays=arrays, kv_heads=kv_heads,
-        mask=mask)
+        q_shape, kv_len, dtype, causal, kv_heads=kv_heads, mask=mask)
 
 
 _NEG_INF = -1e30
 # lanes of a vector register: a column is broadcast over them to turn it
-_LSE_LANES = 128
+_LSE_LANES = _chip.LANES
 
 
 def _kv_per_query_head(q, k, v):
@@ -177,17 +130,35 @@ def _sum_kv_group(dx, kv_heads):
                    .astype(jnp.float32), axis=2).astype(dx.dtype)
 
 
-def _in_window(row, col, shift, window):
-    """Whether key ``col`` lies inside query ``row``'s window: the query's
-    own position (``row + shift``, bottom-right aligned as ``causal``) and
-    the ``window - 1`` keys before it. Index arrays or scalars."""
-    return row + (shift - window) < col
+def _visible(row, col, shift, kv_len=None, causal=False, window=None):
+    """The conditions under which query ``row`` sees key ``col``, in the order
+    every branch combines them; all of them hold where it does. ``row`` and
+    ``col`` are index arrays that broadcast against each other, ``jnp.arange``s
+    or a kernel's iotas alike (``row`` is read only under ``causal``).
+
+    - the key is there: ``col < kv_len`` (``kv_len`` None where the caller
+      holds no padded key);
+    - ``causal``, bottom-right aligned: the query stands at ``row + shift``
+      (``shift = Tk - Tq``) and sees nothing after itself;
+    - ``window`` (under ``causal``): itself and the ``window - 1`` keys before.
+
+    The selection mask and the bias are data, and stay with each caller."""
+    seen = []
+    if kv_len is not None:
+        seen.append(col < kv_len)
+    if causal:
+        seen.append(col <= row + shift)
+        if window is not None:
+            seen.append(row + (shift - window) < col)
+    return seen
 
 
-def _window_band(tq, tk, window):
-    """(tq, tk) booleans of ``_in_window`` (the causal side is the caller's)."""
-    return _in_window(jnp.arange(tq)[:, None], jnp.arange(tk)[None, :],
-                      tk - tq, window)
+def _visible_band(tq, tk, window):
+    """(tq, tk) booleans of ``_visible`` under ``causal``: the XLA branches
+    that hold the whole score matrix."""
+    return functools.reduce(jnp.logical_and, _visible(
+        jnp.arange(tq)[:, None], jnp.arange(tk)[None, :], tk - tq,
+        causal=True, window=window))
 
 
 def window_blocks(tq, tk, block_q, block_k, window):
@@ -215,11 +186,8 @@ def _attention_reference(q, k, v, bias, causal, sm_scale, mask=None,
     if bias is not None:
         scores = scores + bias.astype(jnp.float32)
     if causal:
-        tq, tk = scores.shape[-2], scores.shape[-1]
-        tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        if window is not None:
-            tril = jnp.logical_and(tril, _window_band(tq, tk, window))
-        scores = jnp.where(tril, scores, _NEG_INF)
+        scores = jnp.where(_visible_band(*scores.shape[-2:], window), scores,
+                           _NEG_INF)
     if mask is not None:
         scores = jnp.where(mask[:, None] != 0, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -323,15 +291,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
             if masked:
                 col = k_off + jax.lax.broadcasted_iota(
                     i32, (block_q, block_k), 1)
-                if kv_pad != kv_len:  # tail-block padding
-                    masks.append(col < kv_len)
+                # tail-block padding, then (the row's iota after it, as the
+                # kernel always traced) the diagonal and the window
+                masks += _visible(None, col, shift,
+                                  kv_len if kv_pad != kv_len else None)
                 if causal:
-                    # query row i attends keys up to i + (Tk - Tq)
                     row = q_off + jax.lax.broadcasted_iota(
                         i32, (block_q, block_k), 0)
-                    masks.append(col <= row + shift)
-                    if window is not None:
-                        masks.append(_in_window(row, col, shift, window))
+                    masks += _visible(row, col, shift, causal=True, window=window)
             if mask_ref is not None:  # the selection: data, on every block
                 masks.append(mask_ref[0, 0, ik].astype(i32) != 0)
             if masks:
@@ -411,10 +378,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref,
 
 
 def _lanes(d):
-    return -(-d // 128) * 128
-
-
-_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call unasked
+    return -(-d // _chip.LANES) * _chip.LANES
 
 
 def _fwd_vmem_held(tk, d, dv, block_q, block_k, itemsize, heads=1,
@@ -546,7 +510,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     block_k = min(block_k, Tk)
     if not interpret and block_q < Tq and block_q % _LSE_LANES:
         # the chip's compiler takes a block of the lse row that is whole
-        # lanes wide, or the whole row: a narrower block (pinned, or the
+        # lanes wide, or the whole row: a narrower block (a caller's, or the
         # table's choice for a shorter sequence of the same bucket) widens
         block_q = min(-(-block_q // _LSE_LANES) * _LSE_LANES, Tq)
     # pad sequence dims to block multiples: partial blocks would otherwise
@@ -573,7 +537,7 @@ def _flash_forward_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         and (block_q, block_k) == (Tq, Tk),
         lambda g, u: _fwd_vmem_held(
             Tkp, D, Dv, block_q, block_k, q.dtype.itemsize, g, u)
-        <= 3 * _VMEM_SCOPED_DEFAULT // 4)
+        <= 3 * _chip.VMEM_SCOPED_DEFAULT // 4)
 
     # index maps return np.int32 zeros: under jax_enable_x64 a literal 0
     # traces as i64, which Mosaic rejects in the index-map signature
@@ -732,14 +696,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, st_ref, bias_ref, mask_ref,
                     jnp.int32, (block_k, block_q), 0)
                 qrow = q_off + jax.lax.broadcasted_iota(
                     jnp.int32, (block_k, block_q), 1)
-                if kv_pad != kv_len:  # tail-block padding
-                    masks.append(kcol < kv_len)
+                # tail-block padding of the keys, of the queries (this
+                # kernel's own: it holds the padded Q whole), the rest
+                masks += _visible(qrow, kcol, shift,
+                                  kv_len if kv_pad != kv_len else None)
                 if tq_pad != q_len:
                     masks.append(qrow < q_len)
-                if causal:
-                    masks.append(kcol <= qrow + shift)
-                    if window is not None:
-                        masks.append(_in_window(qrow, kcol, shift, window))
+                masks += _visible(qrow, kcol, shift, causal=causal, window=window)
             if mask_ref is not None:  # the selection: data, on every tile
                 masks.append(mask_ref[0, 0, iq].astype(jnp.int32) != 0)
             if masks:
@@ -830,7 +793,7 @@ def _bwd_vmem_limit(tq, dk, dv, block_q, block_k, itemsize, mask=False,
             + in_flight * tq * lanes(dk) * 4 + 6 * block_q * block_k * 4)
     if mask:
         held += 2 * tq * block_k
-    if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
+    if held <= 3 * _chip.VMEM_SCOPED_DEFAULT // 4:
         return None
     return held + held // 4
 
@@ -1163,7 +1126,7 @@ def _in_place_vmem_limit(kernel, rows, tokens, width, heads, itemsize, bias):
         held += 2 * rows * bias.shape[1] * 8 * tokens * bias.dtype.itemsize
         if kernel == "bwd":
             held += 2 * rows * heads * 8 * tokens * 4
-    if held <= 3 * _VMEM_SCOPED_DEFAULT // 4:
+    if held <= 3 * _chip.VMEM_SCOPED_DEFAULT // 4:
         return None
     return held + held // 4
 
@@ -1369,14 +1332,8 @@ def _attention_scan_fwd(q, k, v, bias, causal, sm_scale, chunk=LONG_CHUNK,
         if bias is not None:
             s = s + xs[2].astype(jnp.float32)
         col = idx * chunk + jnp.arange(chunk)
-        valid = col[None, :] < Tk
-        if causal:
-            row = jnp.arange(Tq)
-            valid = jnp.logical_and(
-                valid, col[None, :] <= row[:, None] + (Tk - Tq))
-            if window is not None:
-                valid = jnp.logical_and(valid, _in_window(
-                    row[:, None], col[None, :], Tk - Tq, window))
+        valid = functools.reduce(jnp.logical_and, _visible(
+            jnp.arange(Tq)[:, None], col[None, :], Tk - Tq, Tk, causal, window))
         valid = valid[None, None]
         if mask is not None:
             valid = jnp.logical_and(valid, xs[-2])
@@ -1430,14 +1387,8 @@ def _bwd_chunked(q, k, v, bias, out, lse, do, causal, sm_scale,
         if bias is not None:
             s = s + xs[2].astype(f32)
         col = idx * chunk + jnp.arange(chunk)
-        valid = col[None, :] < Tk
-        if causal:
-            row = jnp.arange(Tq)
-            valid = jnp.logical_and(
-                valid, col[None, :] <= row[:, None] + (Tk - Tq))
-            if window is not None:
-                valid = jnp.logical_and(valid, _in_window(
-                    row[:, None], col[None, :], Tk - Tq, window))
+        valid = functools.reduce(jnp.logical_and, _visible(
+            jnp.arange(Tq)[:, None], col[None, :], Tk - Tq, Tk, causal, window))
         valid = valid[None, None]
         if mask is not None:
             valid = jnp.logical_and(valid, xs[-2])
@@ -1521,7 +1472,7 @@ def _flash_fwd(q, k, v, bias, mask, causal, sm_scale, window=None):
         out, lse = _attention_scan_fwd(q, k, v, bias, causal, sm_scale,
                                        mask=mask, window=window)
     else:
-        cfg = _tuned_config(q, k, v, bias, causal, sm_scale, mask)
+        cfg = _tuned_config(q, k, causal, mask)
         if cfg.get("backend") == "pallas" and on_tpu():
             _count_branch("fwd", "kernel", window)
             out, lse = _flash_forward_pallas(
@@ -1627,11 +1578,7 @@ def _flash_bwd(causal, sm_scale, res, do, window=None):
     if bias is not None:
         s = s + bias.astype(jnp.float32)
     if causal:
-        tq, tk = s.shape[-2], s.shape[-1]
-        tril = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        if window is not None:
-            tril = jnp.logical_and(tril, _window_band(tq, tk, window))
-        s = jnp.where(tril, s, _NEG_INF)
+        s = jnp.where(_visible_band(*s.shape[-2:], window), s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     if lse is not None:
@@ -1774,7 +1721,7 @@ def _qkv_bwd(heads, sm_scale, res, do):
 _qkv_core.defvjp(_qkv_fwd, _qkv_bwd)
 
 
-def _in_place(qkv, heads, head_dim, bias, causal, sm_scale):
+def _in_place(qkv, heads, head_dim, bias, causal):
     """Whether a ``flash_attention_qkv`` call runs the in-place kernels: on
     a TPU, outside a sequence scope, where a head is one plain tile by the
     rule ``_heads_per_step`` states (no ``causal``; ``T`` whole 128-lane
@@ -1796,12 +1743,8 @@ def _in_place(qkv, heads, head_dim, bias, causal, sm_scale):
                 bias.ndim != 4 or bias.shape[0] != B or bias.shape[1] not in
                 (1, heads) or bias.shape[2:] != (1, T)))):
         return False
-    # live arrays only where the table may measure on them (an eager call)
-    arrays = None
-    if not isinstance(qkv, jax.core.Tracer):
-        arrays = _heads_major(qkv, heads, head_dim) + (bias, sm_scale)
     cfg = _tuned_config_at((B, heads, T, head_dim), T, heads, str(qkv.dtype),
-                           False, arrays, None)
+                           False, None)
     return (cfg.get("backend") == "pallas"
             and min(int(cfg["block_q"]), int(cfg["block_k"])) >= T)
 
@@ -1847,7 +1790,7 @@ def flash_attention_qkv(qkv, bias=None, num_heads=None, causal=False,
     head_dim = qkv.shape[2] // (3 * heads)
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(head_dim))
-    if _in_place(qkv, heads, head_dim, bias, causal, float(sm_scale)):
+    if _in_place(qkv, heads, head_dim, bias, causal):
         return _qkv_core(qkv, bias, heads, float(sm_scale))
     q, k, v = _heads_major(qkv, heads, head_dim)
     out = flash_attention(q, k, v, bias, causal=causal, sm_scale=sm_scale)
@@ -1996,7 +1939,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, context_lens,
     P, S, Hk, Dk = k_pages.shape
     max_pages = page_table.shape[1]
     block_h = max(1, min(int(block_h), H))
-    while H % block_h:  # candidates are divisors; pinned values may not be
+    while H % block_h:  # candidates are divisors; a caller's value may not be
         block_h -= 1
     page_table = page_table.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)

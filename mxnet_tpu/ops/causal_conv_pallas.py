@@ -62,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..context import on_tpu
+from . import chip as _chip
 
 F32 = jnp.float32
 TOKENS = (8192, 4096, 2048, 1024, 512)  # tokens a grid step holds: the first that divides T
@@ -75,9 +76,8 @@ _CHANNELS = (256, 128, 64, 32, 16)  # channels a grid step holds: the first that
 # 100 cycles a turn that nothing hides (a rotation along the lanes is slow to
 # arrive): (16, 512) 0.735 / 1.17 looped and 0.330 / 0.74 written out.
 _WALK = (16, 2048)
-_LANES = 128  # a tile's lanes: a halo block's tokens, and what a rotation turns
+_LANES = _chip.LANES  # a tile's lanes: a halo block's tokens, and what a rotation turns
 _MAX_TAPS = 16  # the taps are written out, a rotation each
-_VMEM_CEILING = 96 * 2 ** 20  # of a v5e core's 128 MiB
 _Z = np.int32(0)  # in an index map: under jax_enable_x64 a literal 0 is 64 bits wide
 
 
@@ -317,7 +317,7 @@ def kernel_takes(shape, k, dtype, begin=0):
     tiles = _tiles(t, c, begin)
     if b == 0 or t == 0 or c == 0 or not tiles or k > _MAX_TAPS:
         return False
-    return _vmem(*tiles[0], k, dtype.itemsize) <= _VMEM_CEILING
+    return _vmem(*tiles[0], k, dtype.itemsize) <= _chip.VMEM_CEILING
 
 
 def conv_silu(data, weight, bias, begin=0):

@@ -68,11 +68,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from . import grouped_matmul as _gm
 from ..context import on_tpu
+from . import chip as _chip
+from . import grouped_matmul as _gm
 
 TILE = 128  # rows of the table a group of the product
-TABLE_BYTES = 128 * 2 ** 20  # a v5e core's VMEM: XLA's form is taken up to a table of this size
+TABLE_BYTES = _chip.VMEM_BYTES  # a core's VMEM: XLA's form is taken up to a table of this size
 
 
 def kernel_takes(vocab, width, tokens, dtype):
@@ -84,9 +85,9 @@ def kernel_takes(vocab, width, tokens, dtype):
     step around it (the module's docstring has both readings)."""
     if not on_tpu() or jnp.dtype(dtype) != jnp.dtype(jnp.bfloat16):
         return False
-    if 2 * vocab * width <= TABLE_BYTES or width % 128 or tokens == 0 or tokens % _gm._DW_ROWS:
+    if 2 * vocab * width <= TABLE_BYTES or width % _chip.LANES or tokens == 0 or tokens % _gm._DW_ROWS:
         return False
-    return _gm._tgmm_vmem(_gm._DW_ROWS, TILE, width, 2) <= _gm._VMEM_CEILING
+    return _gm._tgmm_vmem(_gm._DW_ROWS, TILE, width, 2) <= _chip.VMEM_CEILING
 
 
 def table_grad(ids, dy, vocab, interpret=False):

@@ -61,12 +61,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry as _telemetry
 from ..context import on_tpu
+from . import chip as _chip
 
 F32 = jnp.float32
 ROW_TILE = 512  # a call's rows are whole tiles of this many (ops/moe.py's bound)
 _PIECE = 128  # rows a product inside a visit of ``grouped_matmul``
 _DW_ROWS = 256  # rows a visit of ``grouped_matmul_dw``
-_VMEM_CEILING = 96 * 2 ** 20  # of a v5e core's 128 MiB
 
 _Z = np.int32(0)  # in an index map: under jax_enable_x64 a literal 0 is 64 bits wide
 _NN = (((1,), (0,)), ((), ()))  # (m, k) x (k, n) -> (m, n)
@@ -238,12 +238,12 @@ def _kernel_takes(product, rows, k, n, dtype):
     dtype = jnp.dtype(dtype)
     if not on_tpu() or dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
         return False
-    if rows == 0 or rows % ROW_TILE or k % 128 or n % 128:
+    if rows == 0 or rows % ROW_TILE or k % _chip.LANES or n % _chip.LANES:
         return False
     size = dtype.itemsize
     held = {"fwd": _gmm_vmem(ROW_TILE, k, n, size), "dx": _gmm_vmem(ROW_TILE, n, k, size),
             "dw": _tgmm_vmem(_DW_ROWS, k, n, size)}[product]
-    return held <= _VMEM_CEILING
+    return held <= _chip.VMEM_CEILING
 
 
 def _kernel(product, x, w, dy, sizes, **kw):

@@ -36,6 +36,7 @@ import numpy as np
 
 from ..base import MXNetError
 from ..context import on_tpu
+from . import chip as _chip
 from .registry import register
 
 F32, I32 = jnp.float32, jnp.int32
@@ -263,7 +264,7 @@ def _select_pallas(q, k, w, topk, interpret, block_q=_BLOCK_Q, block_k=_BLOCK_K)
         scratch_shapes=[pltpu.VMEM((Tp, block_q), I32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=max(held + held // 4, 16 * 1024 * 1024)),
+            vmem_limit_bytes=max(held + held // 4, _chip.VMEM_SCOPED_DEFAULT)),
         interpret=interpret,
         name="indexer_select",
     )(q, k, w.reshape(B, Hi, 1, Tp))

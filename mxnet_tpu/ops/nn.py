@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from .registry import register
 from .. import random as _random
-from ..context import on_tpu
 
 
 @register("FullyConnected", aliases=("fully_connected",))
@@ -324,25 +323,6 @@ def _bn_core_bwd(eps, red, res, cts):
     n = 1
     for i in red:
         n *= x.shape[i]
-    if ax == x.ndim - 1:  # channel-last (NHWC): the Pallas fast path
-        from . import bn_pallas
-        if on_tpu():  # the compiled kernel exists only there
-            c = x.shape[ax]
-            # per-shape choice (tuning table / MXT_BN_PALLAS override);
-            # an eager backward passes its concrete arrays so an
-            # on-device first call can feed the autotuner's timed path
-            x2d = x.reshape(-1, c)
-            dy2d = ct_out.reshape(-1, c)
-            arrays = None
-            if not isinstance(x, jax.core.Tracer):
-                arrays = (x2d, dy2d, mean, inv, g)
-            use_pallas, block_rows = bn_pallas.choose(n, c, x.dtype,
-                                                      arrays=arrays)
-            if use_pallas:
-                dx2, dg, db = bn_pallas.bn_bwd_pallas(
-                    x2d, dy2d, mean, inv, g, block_rows=block_rows)
-                return (dx2.reshape(x.shape), dg.astype(g.dtype),
-                        db.astype(g.dtype))
     dy = ct_out.astype(jnp.float32)
     xhat = (x.astype(jnp.float32) - mean.reshape(shape)) * inv.reshape(shape)
     db = jnp.sum(dy, axis=red)

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..context import on_tpu
+from . import chip as _chip
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -55,7 +56,6 @@ U32 = jnp.uint32
 ROWS = 128  # tokens a grid step of ``row_gather``
 _PACK_ROWS = 256  # rows a grid step of ``rows_as_words``
 _MAX_COLUMNS = 16  # a listed row's column takes 4 bits, its token in the tile 7
-_VMEM_CEILING = 96 * 2 ** 20  # of a v5e core's 128 MiB
 _Z = np.int32(0)  # in an index map: under jax_enable_x64 a literal 0 is 64 bits wide
 
 
@@ -63,7 +63,8 @@ def _whole_tiles(lengths):
     """Lane-lengths of a row rounded up to whole sublane tiles of 8: a row of
     10 lengths in tiles it only part fills was laid out 40 times slower (my
     chip run, PR 42)."""
-    return -(-lengths // 8) * 8
+    tile = _chip.SUBLANES[4]
+    return -(-lengths // tile) * tile
 
 
 def _pack(x):
@@ -222,12 +223,12 @@ def kernel_takes(rows, tokens, k, width, dtype):
     dtype = jnp.dtype(dtype)
     if not on_tpu() or dtype not in (jnp.dtype(BF16), jnp.dtype(F32)):
         return False
-    lanes = 256 if dtype == jnp.dtype(BF16) else 128
+    lanes = _chip.LANES * 4 // dtype.itemsize  # a row is whole lane-lengths of 32-bit words
     if tokens == 0 or tokens % ROWS or rows == 0 or rows % _PACK_ROWS or width % lanes:
         return False
     if k > _MAX_COLUMNS or rows >= 2 ** 20:
         return False
-    return _vmem(k, width, dtype.itemsize) <= _VMEM_CEILING
+    return _vmem(k, width, dtype.itemsize) <= _chip.VMEM_CEILING
 
 
 def sum_rows(src, slot, held, tokens, k):

@@ -38,15 +38,11 @@ def _detect():
     add("INT64_TENSOR_SIZE", True)
     # TPU-build-specific capabilities
     from . import native, tuning
-    from .ops import bn_pallas
 
     add("NATIVE_RECORDIO", native.available())
     add("FLASH_ATTENTION", on_tpu())  # the compiled Pallas kernels
     add("SEQUENCE_PARALLEL", True)
     add("INT8_QUANTIZATION", True)  # contrib.quantization, s8 MXU kernels
-    # enabled(): flag + TPU backend — the condition under which the
-    # fused BN backward actually runs
-    add("BN_PALLAS", bn_pallas.enabled())
     # usable == decisions survive the process (a path is configured)
     add("KERNEL_AUTOTUNE", tuning.table().path is not None)
     add("COMPILE_CACHE", tuning.cache_dir() is not None)

@@ -792,8 +792,8 @@ def record_compile_cache(hit):
 
 def record_tune_lookup(hit):
     """One tuning-table lookup outcome (mxt_tune_cache_hits_total /
-    mxt_tune_cache_misses_total — a miss means the autotuner ran a
-    measurement or cost-model pass for a new shape bucket)."""
+    mxt_tune_cache_misses_total — a miss means the cost model chose for
+    a new shape bucket)."""
     global _tune_cache_c
     if _tune_cache_c is None:
         _tune_cache_c = (

@@ -1,53 +1,62 @@
-"""Block-size autotuner for the Pallas kernels + per-shape backend choice.
+"""The cost model that picks each Pallas kernel's tiles, and XLA or the kernel
+by shape.
 
-Two halves, per TVM's split (PAPERS.md arXiv 1802.04799 — search-based
-config selection beats fixed heuristics), scoped to block/grid configs:
+Candidate generation and a deterministic cost model (TVM's split, PAPERS.md
+arXiv 1802.04799, scoped to block/grid configs, without its search).
+Candidates are TPU-tiling-legal by construction: multiples of 8 in the
+sublane dimension, lane-friendly (128-multiple preferred) in the key
+dimension, VMEM-budgeted; tests/test_aot_tpu_compile.py holds the generators
+to it by compiling what they emit for the described chip. The cost model
+charges padded work (the kernels pad-and-mask partial blocks, so a block that
+divides the padded shape badly wastes real MXU cycles), per-grid-step
+overhead, and tile-shape penalties. It is a pure function of the shape: same
+inputs, same config, so a program built twice is the same program.
 
-1. **Candidate generation + deterministic cost model** (always
-   available, CPU/CI path). Candidates are TPU-tiling-legal by
-   construction: multiples of 8 in the sublane dimension, lane-friendly
-   (128-multiple preferred) in the key dimension, VMEM-budgeted — and
-   tests/test_aot_tpu_compile.py holds the generators to it by
-   compiling what they emit for the described chip. The cost model
-   charges padded work (the kernels pad-and-mask partial blocks, so a
-   block that divides the padded shape badly wastes real MXU cycles),
-   per-grid-step overhead, and tile-shape penalties. It is a pure
-   function of the shape: same inputs, same config, no measurement
-   noise in CI.
-
-2. **Timed micro-benchmarks on device** (`measure=True`, the default
-   under ``MXT_TUNE_MODE=auto`` on a real TPU): each candidate runs a
-   short timed loop and the empirical winner is recorded as
-   ``source="measured"`` — which the table never lets a later heuristic
-   overwrite. Measurement also settles the **XLA-vs-Pallas** choice per
-   shape (the per-call replacement for the global ``MXT_BN_PALLAS`` /
-   reference-path switches), per the fusion-analysis motivation (arXiv
-   2301.13062): small shapes often lose to XLA's fused reference.
-
-Measurement loops block on device results by design — they are the
-tuning path, not the training hot path, and every sync is marked for
-tools/check_host_syncs.py.
+Nothing is timed here. A call inside a jitted step is traced, with nothing to
+time; where the forward's candidates were timed by hand, at three cells'
+shapes, the model's tiles held (PERF.md Findings, PR 29). A
+``source="measured"`` entry reaches the table from a sweep that writes it
+(``tuning.table().record``, or the table's file), and no resolver ever
+overwrites one. ``MXT_TUNE_MODE=off`` means the same in every resolver: the
+cost model's answer, the table neither read nor written.
 """
 from __future__ import annotations
 
 import math
-import time
 
-from ..context import on_tpu
+from ..base import MXNetError
+from ..ops import chip as _chip
 from . import table as _table_mod
 
-# leave headroom under the chip compiler's 16 MiB scoped-VMEM default;
+# leave headroom under the scoped VMEM the chip's compiler gives unasked;
 # every candidate the generators below emit under this budget compiles
 # for the described v5e at the shapes in tests/test_aot_tpu_compile.py
-_VMEM_BUDGET = 12 * 1024 * 1024
-_LANE = 128
-_SUBLANE = 8
+_VMEM_BUDGET = 3 * _chip.VMEM_SCOPED_DEFAULT // 4
+_LANE = _chip.LANES
+_SUBLANE = _chip.SUBLANES[4]
+
+_MODES = ("heuristic", "off")  # what MXT_TUNE_MODE may say
 
 
-def _config():
+def _mode():
     from .. import config
 
-    return config
+    mode = str(config.get("MXT_TUNE_MODE")).lower()
+    if mode not in _MODES:
+        raise MXNetError("MXT_TUNE_MODE=%r: one of %s" % (mode, ", ".join(_MODES)))
+    return mode
+
+
+def _resolve(key, choose):
+    """What every resolver below does with its cost model ``choose``: the
+    table's entry under ``key``, or ``choose()`` recorded there (a
+    ``measured`` entry, written by hand, is never downgraded by a record).
+    ``MXT_TUNE_MODE=off``: ``choose()``, the table neither read nor written."""
+    if _mode() == "off":
+        return choose()
+    tab = _table_mod.table()
+    ent = tab.lookup(key)
+    return tab.record(key, choose()) if ent is None else ent
 
 
 def _round8(n):
@@ -133,35 +142,13 @@ def heuristic_attention(q_shape, kv_len, dtype, causal):
             "source": "heuristic", "score": round(best_cost, 3)}
 
 
-def measure_attention(q, k, v, bias, causal, sm_scale, interpret=False,
-                      iters=None, candidates=None, mask=None):
-    """Time each candidate (and the XLA reference) on the live arrays;
-    returns the winning entry dict. Runs OUTSIDE the training hot path
-    (first call per shape bucket, or an explicit sweep). A candidate
-    the compiler refuses raises: the generator called it legal, so a
-    refusal is a bug to see, not a row to drop."""
-    from ..ops import attention as A
-
-    iters = iters or int(_config().get("MXT_TUNE_ITERS"))
-    tq, d = q.shape[2], q.shape[3]
-    tk = k.shape[2]
-    cands = candidates or attention_candidates(tq, tk, d, q.dtype)
-    timings = {}
-    for bq, bk in cands:
-        def run(bq=bq, bk=bk):
-            out, _ = A._flash_forward_pallas(
-                q, k, v, bias, causal, sm_scale, bq, bk,
-                interpret=interpret, mask=mask)
-            return out
-        timings[("pallas", bq, bk)] = _time(run, iters)
-
-    def ref():
-        return A._attention_reference(q, k, v, bias, causal, sm_scale, mask)
-    timings[("xla", 0, 0)] = _time(ref, iters)
-
-    (backend, bq, bk), score = min(timings.items(), key=lambda kv: kv[1])
-    return {"backend": backend, "block_q": bq, "block_k": bk,
-            "source": "measured", "score": round(score * 1e3, 6)}
+def resolve_attention(q_shape, kv_len, dtype, causal, kv_heads=None, mask=None):
+    """The per-call decision the flash kernel consumes (``_resolve``). The
+    key carries the K/V head count and whether a selection mask is there."""
+    return _resolve(
+        _table_mod.attn_key(q_shape, kv_len, dtype, causal, kv_heads=kv_heads,
+                            masked=mask is not None),
+        lambda: heuristic_attention(q_shape, kv_len, dtype, causal))
 
 
 # --------------------------------------------------------------------------
@@ -215,23 +202,10 @@ def heuristic_paged(q_shape, page_size, max_pages, dtype):
 
 
 def resolve_paged(q_shape, page_size, max_pages, dtype):
-    """The per-call decision the paged decode kernel consumes: table
-    hit, else the cost model, recorded under the decode-shape bucket.
-    Decode dispatches happen inside the jitted serving step (tracers —
-    nothing to time), so unlike the flash kernel there is no inline
-    measurement path: measured entries arrive via offline sweeps writing
-    the table, and are never downgraded by this heuristic re-record.
-    ``MXT_TUNE_MODE=off`` bypasses the table (pure cost model), matching
-    the flash kernel's legacy-global semantics."""
-    if _mode() == "off":
-        return heuristic_paged(q_shape, page_size, max_pages, dtype)
-    tab = _table_mod.table()
-    key = _table_mod.paged_key(q_shape, page_size, max_pages, dtype)
-    ent = tab.lookup(key)
-    if ent is not None:
-        return ent
-    return tab.record(key, heuristic_paged(q_shape, page_size, max_pages,
-                                           dtype))
+    """The per-call decision the paged decode kernel consumes, under the
+    decode-shape bucket (``_resolve``)."""
+    return _resolve(_table_mod.paged_key(q_shape, page_size, max_pages, dtype),
+                    lambda: heuristic_paged(q_shape, page_size, max_pages, dtype))
 
 
 # --------------------------------------------------------------------------
@@ -262,172 +236,7 @@ def heuristic_quant(op, k, n, dtype):
 
 def resolve_quant(op, k, n, dtype):
     """The per-shape quantized-vs-float decision a serving engine's
-    weight quantization consults (TinyDecoder.quantize_params): table
-    hit, else the cost model, recorded under the pow2 (k, n) bucket.
-    Like resolve_paged there is no inline measurement (the decision is
-    made at engine build, not dispatch) — measured entries arrive via
-    offline sweeps writing the table and are never downgraded here.
-    ``MXT_TUNE_MODE=off`` bypasses the table entirely."""
-    if _mode() == "off":
-        return heuristic_quant(op, k, n, dtype)
-    tab = _table_mod.table()
-    key = _table_mod.quant_key(op, k, n, dtype)
-    ent = tab.lookup(key)
-    if ent is not None:
-        return ent
-    return tab.record(key, heuristic_quant(op, k, n, dtype))
-
-
-# --------------------------------------------------------------------------
-# BN backward
-# --------------------------------------------------------------------------
-def bn_candidates(m, c):
-    """Legal block_rows values for a (M, C) BN backward: sublane
-    multiples, bounded by the padded row count and a per-buffer VMEM
-    budget (two f32 (bm, C) buffers resident per pass)."""
-    m8 = _round8(m)
-    out = []
-    for bm in (8, 16, 32, 64, 128, 256, 512, 1024):
-        bm = min(bm, m8)
-        if 2 * bm * int(c) * 4 > _VMEM_BUDGET // 2:
-            continue
-        if bm not in out:
-            out.append(bm)
-    return out or [_SUBLANE]
-
-
-def bn_cost(m, c, bm):
-    pm = _pad_to(m, bm)
-    cost = 1.0 * pm * c
-    cost *= 1.0 + 0.004 * (pm // bm)
-    if bm < 64:
-        cost *= 1.0 + (64 - bm) / 256.0
-    return cost
-
-
-def heuristic_bn(m, c, dtype):
-    """Cost-model block_rows; backend stays 'xla' until a measurement
-    says otherwise (the fused BN backward is opt-in per shape via
-    measured entries or the MXT_BN_PALLAS global override)."""
-    best, best_cost = None, math.inf
-    for bm in bn_candidates(m, c):
-        cc = bn_cost(m, c, bm)
-        if cc < best_cost:
-            best, best_cost = bm, cc
-    return {"backend": "xla", "block_rows": best,
-            "source": "heuristic", "score": round(best_cost, 3)}
-
-
-def measure_bn(x2d, dy2d, mean, inv, g, interpret=False, iters=None,
-               candidates=None):
-    """Time candidate block_rows for the fused BN backward plus the XLA
-    custom-VJP formulas; returns the winning entry dict. Like
-    measure_attention, a candidate the compiler refuses raises."""
-    import jax.numpy as jnp
-
-    from ..ops import bn_pallas
-
-    iters = iters or int(_config().get("MXT_TUNE_ITERS"))
-    m, c = x2d.shape
-    timings = {}
-    for bm in (candidates or bn_candidates(m, c)):
-        def run(bm=bm):
-            return bn_pallas.bn_bwd_pallas(
-                x2d, dy2d, mean, inv, g, interpret=interpret,
-                block_rows=bm)
-        timings[("pallas", bm)] = _time(run, iters)
-
-    def ref():
-        dy = dy2d.astype(jnp.float32)
-        xhat = (x2d.astype(jnp.float32) - mean.reshape(1, c)) \
-            * inv.reshape(1, c)
-        db = jnp.sum(dy, axis=0)
-        dg = jnp.sum(dy * xhat, axis=0)
-        dx = (g.reshape(1, c) * inv.reshape(1, c)) * (
-            dy - db.reshape(1, c) / m - xhat * dg.reshape(1, c) / m)
-        return dx, dg, db
-    timings[("xla", 0)] = _time(ref, iters)
-
-    (backend, bm), score = min(timings.items(), key=lambda kv: kv[1])
-    return {"backend": backend, "block_rows": bm,
-            "source": "measured", "score": round(score * 1e3, 6)}
-
-
-# --------------------------------------------------------------------------
-# shared timing loop
-# --------------------------------------------------------------------------
-def _block(res):
-    """Synchronize a result pytree (measurement only — never hot path)."""
-    import jax
-
-    for leaf in jax.tree_util.tree_leaves(res):
-        if hasattr(leaf, "block_until_ready"):  # sync-ok: measurement loop
-            leaf.block_until_ready()  # sync-ok: autotuner measurement loop
-
-
-def _time(fn, iters):
-    """Median-of-iters wall time of ``fn`` after one warm (compile)
-    call. Median resists the one-off scheduling hiccup that would
-    otherwise misrank close candidates."""
-    _block(fn())  # compile + warm  # sync-ok: autotuner measurement loop
-    samples = []
-    for _ in range(max(1, iters)):
-        t0 = time.perf_counter()
-        _block(fn())
-        samples.append(time.perf_counter() - t0)
-    samples.sort()
-    return samples[len(samples) // 2]
-
-
-# --------------------------------------------------------------------------
-# resolution: table -> measure/heuristic -> record
-# --------------------------------------------------------------------------
-def _mode():
-    return str(_config().get("MXT_TUNE_MODE")).lower()
-
-
-def _may_measure(arrays):
-    """Measurement needs concrete arrays (not tracers — inside a jit
-    trace there is nothing to time) and an allowing mode: 'measure'
-    anywhere, 'auto' only on a real TPU."""
-    import jax
-
-    mode = _mode()
-    if not (mode == "measure" or (mode == "auto" and on_tpu())):
-        return False
-    return not any(isinstance(a, jax.core.Tracer)
-                   for a in arrays if a is not None)
-
-
-def resolve_attention(q_shape, kv_len, dtype, causal, arrays=None,
-                      kv_heads=None, mask=None):
-    """The per-call decision the flash kernel consumes: table hit, else
-    measure (when allowed) or cost model, recorded either way. The key
-    carries the K/V head count and whether a selection mask is there."""
-    tab = _table_mod.table()
-    key = _table_mod.attn_key(q_shape, kv_len, dtype, causal,
-                              kv_heads=kv_heads, masked=mask is not None)
-    ent = tab.lookup(key)
-    if ent is not None:
-        return ent
-    if arrays is not None and _may_measure(arrays):
-        q, k, v, bias, sm_scale = arrays
-        ent = measure_attention(q, k, v, bias, causal, sm_scale,
-                                interpret=not on_tpu(), mask=mask)
-    else:
-        ent = heuristic_attention(q_shape, kv_len, dtype, causal)
-    return tab.record(key, ent)
-
-
-def resolve_bn(m, c, dtype, arrays=None):
-    tab = _table_mod.table()
-    key = _table_mod.bn_key(m, c, dtype)
-    ent = tab.lookup(key)
-    if ent is not None:
-        return ent
-    if arrays is not None and _may_measure(arrays):
-        x2d, dy2d, mean, inv, g = arrays
-        ent = measure_bn(x2d, dy2d, mean, inv, g, interpret=not on_tpu())
-    else:
-        ent = heuristic_bn(m, c, dtype)
-    return tab.record(key, ent)
+    weight quantization consults (TinyDecoder.quantize_params), under the
+    pow2 (k, n) bucket (``_resolve``)."""
+    return _resolve(_table_mod.quant_key(op, k, n, dtype),
+                    lambda: heuristic_quant(op, k, n, dtype))

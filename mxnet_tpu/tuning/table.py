@@ -3,9 +3,10 @@
 The reference framework ships MXNET_CUDNN_AUTOTUNE_DEFAULT: the first
 convolution at a new shape races every cuDNN algo and the winner is
 memoized per shape for the life of the process. This module is that
-memo made durable and explicit: every decision the autotuner makes —
-flash-attention (block_q, block_k), BN-backward block_rows, and the
-XLA-vs-Pallas backend choice — is keyed by
+memo made durable and explicit: every decision the cost model makes
+(``autotune.py``; nothing is raced here) or a sweep's author writes by
+hand as ``measured`` — flash-attention (block_q, block_k), the paged
+kernel's head block, and the XLA-vs-Pallas backend choice — is keyed by
 
     (op, shape-bucket, dtype, causal, device_kind)
 
@@ -16,7 +17,7 @@ signatures** recorded at kernel/step dispatch, which
 ``tuning.warmup()`` replays to AOT-compile a fresh process's hot path.
 
 Shape bucketing bounds table growth: query/key sequence lengths round
-up to the next multiple of 64 (exact below 64), BN row counts to the
+up to the next multiple of 64 (exact below 64), row counts to the
 next power of two. A config chosen for the bucket is tiling-legal for
 every shape inside it because the kernels pad-and-mask to block
 multiples — bucketing only costs (bounded, modeled) padding waste.
@@ -67,8 +68,8 @@ def bucket_seq(t):
 
 
 def bucket_rows(m):
-    """BN row bucket: next power of two (rows = batch*spatial can be
-    anything; pow2 keeps the table tiny)."""
+    """Row bucket: next power of two (batch x heads, decode slots and
+    page-table widths can be anything; pow2 keeps the table tiny)."""
     m = int(m)
     p = 1
     while p < m:
@@ -91,11 +92,6 @@ def attn_key(q_shape, kv_len, dtype, causal, kind=None, kv_heads=None,
     if masked:
         key += "|m1"
     return "%s|%s" % (key, kind or device_kind())
-
-
-def bn_key(m, c, dtype, kind=None):
-    return "bn_bwd|m%d|c%d|%s|%s" % (bucket_rows(m), int(c), str(dtype),
-                                     kind or device_kind())
 
 
 def paged_key(q_shape, page_size, max_pages, dtype, kind=None):

@@ -23,7 +23,6 @@ import pytest
 
 from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
-from mxnet_tpu.ops import bn_pallas
 from mxnet_tpu.ops import causal_conv_pallas as CC
 from mxnet_tpu.ops import embedding_grad as EG
 from mxnet_tpu.ops import grouped_matmul as GM
@@ -174,17 +173,6 @@ def _paged(batch, heads, dim, block_h=None, dtype="bfloat16", page=16,
                 q, k, v, pt, cl, dim ** -0.5, bh, False),
             ((batch, heads, dim), dt), pool, pool,
             ((batch, max_pages), jnp.int32), ((batch,), jnp.int32))
-    return case
-
-
-def _bn(m, c, block_rows=None):
-    def case(chip):
-        chan = ((c,), jnp.float32)
-        act = ((m, c), jnp.bfloat16)
-        return _compile(
-            chip, lambda x, dy, mean, inv, g: bn_pallas.bn_bwd_pallas(
-                x, dy, mean, inv, g, block_rows=block_rows),
-            act, act, chan, chan, chan)
     return case
 
 
@@ -349,9 +337,6 @@ _CASES = {
     # repaired generator picks it
     "paged_8x12x64": _paged(8, 12, 64),
     "paged_16x12x64": _paged(16, 12, 64),
-    # BN backward at two ResNet-50 (batch 64, NHWC) activations
-    "bn_200704x64": _bn(200704, 64),
-    "bn_3136x2048": _bn(3136, 2048),
 }
 
 
@@ -455,19 +440,13 @@ def test_every_paged_candidate_compiles(chip):
             _paged(8, heads, 64, block_h=bh, max_pages=16)(chip)
 
 
-def test_every_bn_candidate_compiles(chip):
-    for m, c in ((200704, 64), (3136, 2048)):
-        for bm in tuning.bn_candidates(m, c):
-            _bn(m, c, block_rows=bm)(chip)
-
-
 @pytest.mark.parametrize("shape,causal,dv", [
     ((32, 12, 512, 64), False, None), ((128, 12, 128, 64), False, None),
     ((2, 32, 4096, 192), True, 128), ((2, 4, 300, 64), True, None)])
 def test_every_attention_candidate_compiles(chip, shape, causal, dv):
-    """tuning.measure_attention sweeps what attention_candidates emits and
-    lets a refusal raise: at the three cells' shapes and a ragged one, the
-    chip's compiler takes every candidate."""
+    """Whatever attention_candidates calls legal the cost model may pick:
+    at the three cells' shapes and a ragged one, the chip's compiler takes
+    every candidate."""
     cands = tuning.attention_candidates(shape[2], shape[2], shape[3],
                                         "bfloat16")
     assert len(cands) >= 3
@@ -488,11 +467,14 @@ def test_extreme_attention_candidates_compile(chip):
 
 
 # -- a call without ``window`` is the call it was ----------------------------------
-def _heads_major_calls(shape, kv_heads, dv=None, masked=False):
+def _heads_major_calls(shape, kv_heads, dv=None, masked=False, window=None,
+                       sm_scale=None):
     """(forward, backward) jaxprs of ``flash_attention``'s two kernels at a
-    decoder cell's shapes and the blocks the dispatch picks, causal."""
+    decoder cell's shapes and the blocks the dispatch picks, causal, under a
+    static ``window`` where the cell's layer has one."""
     B, H, T, D = shape
-    dt, sm = jnp.dtype("bfloat16"), D ** -0.5
+    dt, sm = jnp.dtype("bfloat16"), sm_scale or D ** -0.5
+    windowed = {} if window is None else {"window": window}
     S = jax.ShapeDtypeStruct
     q, k, v = S(shape, dt), S((B, kv_heads, T, D), dt), S((B, kv_heads, T, dv or D), dt)
     o = S((B, H, T, dv or D), dt)
@@ -500,11 +482,12 @@ def _heads_major_calls(shape, kv_heads, dv=None, masked=False):
     cfg = tuning.heuristic_attention(shape, T, "bfloat16", True)
     fwd = jax.make_jaxpr(lambda q, k, v, *m: A._flash_forward_pallas(
         q, k, v, None, True, sm, cfg["block_q"], cfg["block_k"], False,
-        mask=m[0] if m else None))(q, k, v, *mask)
+        mask=m[0] if m else None, **windowed))(q, k, v, *mask)
     bq, bk = A._bwd_blocks(T, T)
     bwd = jax.make_jaxpr(lambda q, k, v, out, lse, do, *m: A._flash_backward_pallas(
         q, k, v, None, out, lse, do, True, sm, bq, bk, False,
-        mask=m[0] if m else None))(q, k, v, o, S((B, H, T), jnp.float32), o, *mask)
+        mask=m[0] if m else None, **windowed))(
+            q, k, v, o, S((B, H, T), jnp.float32), o, *mask)
     return fwd, bwd
 
 
@@ -528,11 +511,19 @@ _OLDER_CELLS = {
     "keye_vl2_a3b_train_s8192": lambda: _heads_major_calls((1, 32, 8192, 128), 4,
                                                            masked=True),
     "lfm2_a2b_train_s8192": lambda: _heads_major_calls((1, 32, 8192, 64), 8),
+    # SmallThinker's full layer and its window layers (28 heads on 4), and
+    # Granite's one attention layer: LFM2's shapes at the file's own score scale
+    "smallthinker_a3b_train_s8192": lambda: _heads_major_calls((1, 28, 8192, 128), 4),
+    "smallthinker_a3b_train_s8192_w4096": lambda: _heads_major_calls(
+        (1, 28, 8192, 128), 4, window=4096),
+    "granite4_h_micro_train_s8192": lambda: _heads_major_calls(
+        (1, 32, 8192, 64), 8, sm_scale=0.015625),
 }
 
 # sha256 (16 hex digits) of the traced call, kernel body, grid, blocks, names
 # and compiler parameters, as the tree BEFORE the window was built (PR 40's,
-# e2462b4) traces it. A PR that means to change one of these kernels changes
+# e2462b4) traces it; the SmallThinker and Granite cells' as PR 45's tree
+# (08bf814) does. A PR that means to change one of these kernels changes
 # its line here, and says so; one that does not, cannot.
 _OLDER_CELLS_DIGESTS = {
     ("bert_base_train_s128", "fwd"): "c6312a91c046b45d",
@@ -545,6 +536,12 @@ _OLDER_CELLS_DIGESTS = {
     ("keye_vl2_a3b_train_s8192", "bwd"): "134eae970437245c",
     ("lfm2_a2b_train_s8192", "fwd"): "83ad77ac89dac598",
     ("lfm2_a2b_train_s8192", "bwd"): "4c8e31996160df79",
+    ("smallthinker_a3b_train_s8192", "fwd"): "05af1c83b22b7139",
+    ("smallthinker_a3b_train_s8192", "bwd"): "ae3b854965ac224a",
+    ("smallthinker_a3b_train_s8192_w4096", "fwd"): "8cd051788ec9c8fa",
+    ("smallthinker_a3b_train_s8192_w4096", "bwd"): "dd4e994c317cd9eb",
+    ("granite4_h_micro_train_s8192", "fwd"): "e1e75faff236e998",
+    ("granite4_h_micro_train_s8192", "bwd"): "bd1205ee3fd7785b",
 }
 
 
@@ -555,13 +552,14 @@ def _digest(jaxpr):
 
 @pytest.mark.parametrize("half", ["fwd", "bwd"])
 @pytest.mark.parametrize("cell", sorted(_OLDER_CELLS))
-def test_a_call_without_a_window_lowers_to_the_kernels_it_was(cell, half):
-    """The attention calls of the five older cells (both BERT cells in place,
-    Kanana's latent heads, Keye's under a selection mask, LFM2's 64-wide
-    grouped heads) trace to the programs they traced to before ``window``
-    was an argument, letter for letter."""
+def test_a_cells_attention_lowers_to_the_kernels_it_was(cell, half):
+    """The attention calls of the seven cells that have any (both BERT cells
+    in place, Kanana's latent heads, Keye's under a selection mask, LFM2's
+    64-wide grouped heads, SmallThinker's full and window layers, Granite's at
+    its own score scale) trace to the programs they traced to, letter for
+    letter; only a call with a window goes by the window kernels' names."""
     jaxpr = _OLDER_CELLS[cell]()[half == "bwd"]
-    assert "window_attention" not in str(jaxpr)
+    assert ("window_attention" in str(jaxpr)) == cell.endswith("_w4096")
     assert _digest(jaxpr) == _OLDER_CELLS_DIGESTS[(cell, half)]
 
 

@@ -575,7 +575,10 @@ def test_dense_equal_head_calls_trace_to_the_kernels_they_traced_to():
     to the jaxpr it lowered to before either was built (``tests/data``: the
     text the parent commit gave, addresses and paths taken out): both
     kernels with a bias and with latent widths under ``causal``, and the op's
-    gradient on the CPU."""
+    gradient on the CPU (that one text re-recorded at PR 46: the XLA branches
+    now compare indices for ``causal`` by the rule the kernels use,
+    ``_visible``, where they built ``jnp.tril`` of ones; the four kernels'
+    texts are the parent commit's still)."""
     import json
     import os
     import re
@@ -976,7 +979,7 @@ def test_in_place_rule_as_a_table(case, call, tpu, in_place, monkeypatch):
     with scope:
         said = []
         jax.eval_shape(lambda x, b: said.append(
-            A._in_place(x, H, D, b, causal, D ** -0.5)), qkv, bias)
+            A._in_place(x, H, D, b, causal)), qkv, bias)
         assert said == [in_place]
         if call.get("scope"):
             return  # the dispatch under a scope: tests/test_sequence_scope.py

@@ -40,7 +40,7 @@ def test_chip_smoke_last_line_is_the_contract(monkeypatch, capsys):
                                       "--phases", "sync"])
     monkeypatch.setattr(mx.tuning, "setup_compile_cache", lambda d: None)
     rc = chip_smoke.main()
-    mx.config.set_default("MXT_TUNE_MODE", "auto")  # main pinned the cost model
+    mx.config.set_default("MXT_TUNE_MODE", "heuristic")  # what main set, the default
     last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc != 0 and last["ok"] is False and last["failed"] == []
     assert set(last["device"]) == {"platform", "kind", "count"}

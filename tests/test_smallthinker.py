@@ -108,6 +108,34 @@ def test_window_kernel_with_more_keys_than_queries():
         _close(got, want, 2e-5)
 
 
+@pytest.mark.parametrize("causal,window,tq,tk,pad", [
+    (False, None, 5, 7, 0), (False, None, 5, 7, 3), (True, None, 6, 6, 0),
+    (True, None, 4, 9, 0), (True, None, 4, 9, 2), (True, 3, 6, 6, 0),
+    (True, 3, 4, 9, 3), (True, 1, 7, 7, 1)])
+def test_who_sees_whom_is_one_rule_against_a_loop(causal, window, tq, tk, pad):
+    """``A._visible``, the one statement of the keys a query sees that all
+    six branches of the attention combine, against a loop over every pair:
+    ``tk`` keys padded by ``pad``, the query at ``row + tk - tq``; from
+    ``arange``s (the XLA branches) and from a kernel's iotas alike."""
+    import functools
+
+    want = np.array([[c < tk and (not causal or (
+        c <= r + tk - tq and (window is None or r + tk - tq - c < window)))
+        for c in range(tk + pad)] for r in range(tq)])
+    kv_len = tk if pad else None  # a caller with no padded key passes none
+    shape = (tq, tk + pad)
+    for row, col in (
+            (jnp.arange(tq)[:, None], jnp.arange(tk + pad)[None, :]),
+            (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+             jax.lax.broadcasted_iota(jnp.int32, shape, 1))):
+        seen = A._visible(row, col, tk - tq, kv_len, causal, window)
+        assert len(seen) == bool(pad) + causal + (window is not None)
+        got = functools.reduce(jnp.logical_and, seen, jnp.ones(shape, bool))
+        np.testing.assert_array_equal(np.broadcast_to(got, shape), want)
+    if causal and not pad:
+        np.testing.assert_array_equal(A._visible_band(tq, tk, window), want)
+
+
 def test_the_window_call_visits_fewer_blocks_than_the_causal_one():
     """From shapes and blocks alone; at the cell's shapes 108 of 136 tiles
     (79.4 %) for 75.0 % of the pairs; counted a traced call, both kernels."""

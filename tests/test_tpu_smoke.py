@@ -604,43 +604,6 @@ def test_sparse_adam_lazy_update_hardware():
                                   np.ones(shape, "f4")[other])
 
 
-def test_bn_pallas_backward_hardware():
-    """Compiled (Mosaic) fused BN backward vs the XLA custom-VJP path on
-    the chip — interpret-mode parity is NOT sufficient (round-2 lesson)."""
-    from mxnet_tpu.ops import bn_pallas
-    key = jax.random.PRNGKey(0)
-    m, c = 8 * 56 * 56, 64  # resnet stage-1 NHWC flattened
-    kx, kd = jax.random.split(key)
-    x = jax.random.normal(kx, (m, c), jnp.bfloat16)
-    dy = jax.random.normal(kd, (m, c), jnp.bfloat16)
-    g = jnp.ones((c,), jnp.float32) * 1.3
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=0)
-    var = jnp.mean(jnp.square(x32 - mean), axis=0)
-    inv = jax.lax.rsqrt(var + 1e-5)
-
-    dx, dg, db = bn_pallas.bn_bwd_pallas(x, dy, mean, inv, g)
-
-    # the oracle must take the XLA path — with MXT_BN_PALLAS=1 exported
-    # (the A/B env) _bn_core_bwd would otherwise route the oracle through
-    # the very kernel under test
-    import os
-    prev = os.environ.pop("MXT_BN_PALLAS", None)
-    try:
-        from mxnet_tpu.ops.nn import _bn_core
-        (out, m_, v_), vjp = jax.vjp(
-            lambda xx, gg, bb: _bn_core(1e-5, (0,), xx, gg, bb),
-            x, g, jnp.zeros_like(g))
-        odx, odg, odb = vjp((dy.astype(out.dtype), jnp.zeros_like(m_),
-                             jnp.zeros_like(v_)))
-    finally:
-        if prev is not None:
-            os.environ["MXT_BN_PALLAS"] = prev
-    assert _maxerr(db, odb) < 1.0          # f32 sums over 25k rows
-    assert _maxerr(dg, odg) < 1.0
-    assert _maxerr(dx, odx) < 0.05         # bf16 elementwise
-
-
 def test_quantized_conv_fc_hardware():
     """s8xs8->s32 conv + matmul on the MXU (ops/quantization.py): the
     int8 path must lower and match the f32 reference on chip."""

@@ -2,6 +2,8 @@
 steps, a block's own name, the hand-written ops' scopes and the kernels'
 names, as the compiled program's ``op_name``s carry them (profiler_trace.py
 reads the same strings back from a device trace as ``tf_op``)."""
+import ast
+import os
 import re
 
 import jax
@@ -167,23 +169,44 @@ def test_eager_forward_enters_no_scope(monkeypatch):
     assert "stem" in entered and "conv2d0" in entered
 
 
-def test_kernels_have_names():
-    """Every ``pallas_call`` of ops/ has a ``name=``: the trace then shows the
-    kernel under its own name and not as ``custom-call.N``."""
-    import inspect
+# the names the trace's readers look kernels up by (profiler_trace.py, the
+# benchmark's roofline metrics, tools/trace_cell.py), by the module that
+# launches them; the in-place flash kernels go by the heads-major ones' names
+_KERNEL_NAMES = {
+    "attention": ("flash_attention_fwd", "flash_attention_bwd", "window_attention_fwd",
+                  "window_attention_bwd", "paged_decode"),
+    "causal_conv_pallas": ("causal_conv_silu_fwd", "causal_conv_silu_bwd"),
+    "grouped_matmul": ("grouped_matmul", "grouped_matmul_dw"),
+    "indexer": ("indexer_select",),
+    "row_gather": ("rows_as_words", "row_gather"),
+}
+_OPS_DIR = os.path.join(os.path.dirname(mx.__file__), "ops")
 
-    from mxnet_tpu.ops import attention, bn_pallas
 
-    for mod, names in ((attention, ("flash_attention_fwd", "flash_attention_bwd",
-                                    "paged_decode")),
-                       (bn_pallas, ("bn_bwd_reduce", "bn_bwd_dx"))):
-        src = inspect.getsource(mod)
-        # the in-place flash kernels carry the names of the heads-major ones:
-        # the trace's readers find either under flash_attention_fwd / _bwd
-        assert src.count("pl.pallas_call(") == sum(
-            src.count('name="%s"' % n) for n in names)
-        for n in names:
-            assert 'name="%s"' % n in src
+def _kernel_modules():
+    found = []
+    for name in sorted(os.listdir(_OPS_DIR)):
+        if name.endswith(".py"):
+            with open(os.path.join(_OPS_DIR, name)) as f:
+                if "pl.pallas_call(" in f.read():
+                    found.append(name[:-3])
+    return found
+
+
+@pytest.mark.parametrize("module", _kernel_modules())
+def test_kernels_have_names(module):
+    """Every ``pallas_call`` of an ops/ module has a ``name=``: the trace then
+    shows the kernel under its own name and not as ``custom-call.N``. A
+    module that launches a kernel lists its names above."""
+    with open(os.path.join(_OPS_DIR, module + ".py")) as f:
+        src = f.read()
+    calls = [n for n in ast.walk(ast.parse(src)) if isinstance(n, ast.Call)
+             and isinstance(n.func, ast.Attribute) and n.func.attr == "pallas_call"]
+    assert len(calls) == src.count("pl.pallas_call(")
+    for call in calls:
+        assert any(k.arg == "name" for k in call.keywords), "line %d" % call.lineno
+    quoted = set(re.findall(r'"(\w+)"', src))
+    assert set(_KERNEL_NAMES[module]) <= quoted
 
 
 def test_a_block_with_no_prefix_of_its_own_goes_by_its_type():

@@ -1,4 +1,4 @@
-"""Kernel autotuner + persistent compile cache (mxnet_tpu/tuning/).
+"""Kernel tuning (cost model + table) + persistent compile cache (mxnet_tpu/tuning/).
 
 Covers the PR-6 acceptance surface on CPU: shape-aware tiling-legal
 configs for arbitrary (odd) shapes with interpret-mode parity against
@@ -20,8 +20,6 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import config, nd, tuning
 from mxnet_tpu.ops import attention as A
-from mxnet_tpu.ops import bn_pallas
-from mxnet_tpu.ops.nn import _bn_core
 from mxnet_tpu.test_utils import with_seed
 
 
@@ -78,72 +76,90 @@ def test_flash_odd_shapes_match_reference(causal, tq, tk):
                                rtol=1e-6, atol=1e-6)
 
 
-@with_seed()
-@pytest.mark.parametrize("m,c", [(257, 100), (100, 100), (72, 24)])
-def test_bn_odd_shapes_match_reference(m, c):
-    """BN backward at non-multiple (rows, channels) through the tuned
-    block_rows path matches the XLA custom-VJP formulas."""
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.normal(size=(m, c)).astype("f4"))
-    dy = jnp.asarray(rng.normal(size=(m, c)).astype("f4"))
-    mean = jnp.mean(x, axis=0)
-    var = jnp.mean(jnp.square(x - mean), axis=0)
-    inv = jax.lax.rsqrt(var + 1e-5)
-    g = jnp.asarray(rng.normal(size=(c,)).astype("f4")) + 1.5
-
-    ent = tuning.resolve_bn(m, c, "float32")
-    bm = ent["block_rows"]
-    assert bm % 8 == 0 and bm >= 8
-    dx, dg, db = bn_pallas.bn_bwd_pallas(x, dy, mean, inv, g,
-                                         interpret=True, block_rows=bm)
-    b0 = jnp.zeros_like(g)
-    (out, mn, vr), vjp = jax.vjp(
-        lambda xx, gg, bb: _bn_core(1e-5, (0,), xx, gg, bb), x, g, b0)
-    odx, odg, odb = vjp((dy, jnp.zeros_like(mn), jnp.zeros_like(vr)))
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(odx),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(dg), np.asarray(odg),
-                               rtol=1e-6, atol=5e-6)
-    np.testing.assert_allclose(np.asarray(db), np.asarray(odb),
-                               rtol=1e-6, atol=5e-6)
-
-
-def test_bn_bwd_rejects_illegal_block():
-    x = jnp.ones((16, 8))
-    with pytest.raises(ValueError):
-        bn_pallas.bn_bwd_pallas(x, x, jnp.zeros(8), jnp.ones(8),
-                                jnp.ones(8), interpret=True, block_rows=12)
-
-
 # ---------------------------------------------------------------------------
-# default_blocks: resettable, config-change aware (satellite 1)
+# MXT_TUNE_MODE: two values, one meaning each, in every resolver alike
 # ---------------------------------------------------------------------------
-def test_default_blocks_config_change_aware(monkeypatch):
-    monkeypatch.delenv("MXT_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("MXT_FLASH_BLOCK_K", raising=False)
-    assert A.default_blocks() == (128, 128)
-    assert not A.blocks_pinned()
-    # set_default takes effect WITHOUT a fresh process (the old memo
-    # latched the first read forever)
-    config.set_default("MXT_FLASH_BLOCK_Q", 64)
-    try:
-        assert A.default_blocks() == (64, 128)
-        assert A.blocks_pinned()
-        monkeypatch.setenv("MXT_FLASH_BLOCK_K", "32")
-        assert A.default_blocks() == (64, 32)
-        # a pinned config bypasses the tuning table entirely
-        cfg = A._tuned_config(jnp.zeros((1, 1, 256, 32)),
-                              jnp.zeros((1, 1, 256, 32)), None, None,
-                              False, 0.125)
-        assert cfg["source"] == "pinned"
-        assert (cfg["block_q"], cfg["block_k"]) == (64, 32)
-    finally:
-        config._overrides.pop("MXT_FLASH_BLOCK_Q", None)
-    monkeypatch.setenv("MXT_FLASH_BLOCK_Q", "20")  # not a multiple of 8
+_RESOLVERS = {
+    "flash": (lambda: tuning.resolve_attention((1, 2, 192, 32), 192, "float32", True),
+              lambda: tuning.heuristic_attention((1, 2, 192, 32), 192, "float32", True)),
+    "paged": (lambda: tuning.resolve_paged((8, 12, 64), 16, 64, "bfloat16"),
+              lambda: tuning.heuristic_paged((8, 12, 64), 16, 64, "bfloat16")),
+    "quant": (lambda: tuning.resolve_quant("decode_matmul", 768, 3072, "float32"),
+              lambda: tuning.heuristic_quant("decode_matmul", 768, 3072, "float32")),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_RESOLVERS))
+def test_tune_mode_off_is_the_cost_model_and_leaves_no_table_entry(family, monkeypatch):
+    resolve, cost_model = _RESOLVERS[family]
+    monkeypatch.setenv("MXT_TUNE_MODE", "off")
+    assert resolve() == cost_model()
+    assert tuning.table().entries() == {}
+    # an entry written by hand is what 'heuristic' serves and 'off' does not read
+    monkeypatch.setenv("MXT_TUNE_MODE", "heuristic")
+    resolve()
+    (key,) = tuning.table().entries()
+    tuning.table().record(key, dict(cost_model(), source="measured"))
+    assert resolve()["source"] == "measured"
+    monkeypatch.setenv("MXT_TUNE_MODE", "off")
+    assert resolve() == cost_model()
+
+
+def test_tune_mode_refuses_a_value_it_does_not_know(monkeypatch):
     from mxnet_tpu.base import MXNetError
 
-    with pytest.raises(MXNetError):
-        A.default_blocks()
+    config.set_default("MXT_TUNE_MODE", "heuristic")  # what both harnesses set
+    for gone in ("auto", "measure", "pinned"):
+        monkeypatch.setenv("MXT_TUNE_MODE", gone)
+        for resolve, _ in _RESOLVERS.values():
+            with pytest.raises(MXNetError, match="heuristic, off"):
+                resolve()
+    assert tuning.table().entries() == {}
+
+
+# ---------------------------------------------------------------------------
+# the kernels' rules: platform, type and shape, and ONE statement of the chip
+# ---------------------------------------------------------------------------
+def _rules():
+    from mxnet_tpu.ops import causal_conv_pallas as CC
+    from mxnet_tpu.ops import embedding_grad as EG
+    from mxnet_tpu.ops import grouped_matmul as GM
+    from mxnet_tpu.ops import row_gather as RG
+
+    # module, the rule at a cell's shape, the bytes that call holds in VMEM
+    return {
+        "grouped_matmul": (  # LFM2's gate / up product, 16384 rows laid out
+            GM, lambda: GM._kernel_takes("fwd", 16384, 2048, 1536, "bfloat16"),
+            lambda: GM._gmm_vmem(GM.ROW_TILE, 2048, 1536, 2)),
+        "row_gather": (  # SmallThinker's sum of 24576 rows back to 8192 x 6
+            RG, lambda: RG.kernel_takes(24576, 8192, 6, 2560, "bfloat16"),
+            lambda: RG._vmem(6, 2560, 2)),
+        "causal_conv": (  # Granite's filter: 4352 columns after the gate's 4096
+            CC, lambda: CC.kernel_takes((1, 8192, 4352), 4, "bfloat16", begin=4096),
+            lambda: CC._vmem(*CC._tiles(8192, 4352, 4096)[0], 4, 2)),
+        "embedding_grad": (  # SmallThinker's 37984-row table, 8192 tokens
+            EG, lambda: EG.kernel_takes(37984, 2560, 8192, "bfloat16"),
+            lambda: GM._tgmm_vmem(GM._DW_ROWS, EG.TILE, 2560, 2)),
+    }
+
+
+@pytest.mark.parametrize("family", ["causal_conv", "embedding_grad", "grouped_matmul",
+                                    "row_gather"])
+def test_a_kernels_rule_reads_the_chip_from_one_place(family, monkeypatch):
+    """Each ``kernel_takes`` admits its cell's shape where it can see a TPU
+    and refuses it on a chip whose ceiling is one byte under what the call
+    holds: ``ops/chip.py`` is the one statement every rule reads."""
+    from mxnet_tpu.ops import chip
+
+    module, takes, held = _rules()[family]
+    assert not takes()  # the CPU
+    monkeypatch.setattr(module, "on_tpu", lambda: True)
+    assert takes()
+    assert held() <= chip.VMEM_CEILING
+    monkeypatch.setattr(chip, "VMEM_CEILING", held())
+    assert takes()
+    monkeypatch.setattr(chip, "VMEM_CEILING", held() - 1)
+    assert not takes()
 
 
 # ---------------------------------------------------------------------------
@@ -225,23 +241,6 @@ def test_resolve_records_and_hits_counters():
     h2, m2 = counts()
     assert h2 == h1 + 1 and m2 == m1  # second: table hit
     assert ent1 == ent2  # same decision both times
-
-
-def test_measure_mode_records_measured(monkeypatch):
-    """MXT_TUNE_MODE=measure forces the timed path even on CPU (tiny
-    shapes, interpret-mode pallas candidates + XLA reference)."""
-    monkeypatch.setenv("MXT_TUNE_MODE", "measure")
-    monkeypatch.setenv("MXT_TUNE_ITERS", "1")
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.normal(size=(1, 1, 16, 8)).astype("f4"))
-    ent = tuning.resolve_attention(
-        q.shape, 16, "float32", False,
-        arrays=(q, q, q, None, 0.3535))
-    assert ent["source"] == "measured"
-    assert ent["backend"] in ("pallas", "xla")
-    # the measured entry is served (not re-measured) on the next call
-    again = tuning.resolve_attention(q.shape, 16, "float32", False)
-    assert again == ent
 
 
 # ---------------------------------------------------------------------------
