@@ -20,6 +20,8 @@ from . import smallthinker
 from .smallthinker import SmallThinkerModel
 from . import granite_hybrid
 from .granite_hybrid import GraniteHybridModel
+from . import solar_open2
+from .solar_open2 import SolarOpen2Model
 
 __all__ = ["vision", "get_model", "bert", "BERTModel", "BERTEncoder",
            "get_bert_model", "bert_12_768_12", "bert_6_512_8",
@@ -27,4 +29,5 @@ __all__ = ["vision", "get_model", "bert", "BERTModel", "BERTEncoder",
            "gpt", "GPTModel", "gpt_mini", "gpt_small",
            "deepseek", "DeepseekV3Model", "keye", "KeyeVL2Model",
            "lfm2", "Lfm2MoeModel", "smallthinker", "SmallThinkerModel",
-           "granite_hybrid", "GraniteHybridModel"]
+           "granite_hybrid", "GraniteHybridModel",
+           "solar_open2", "SolarOpen2Model"]

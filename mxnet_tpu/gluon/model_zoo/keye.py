@@ -119,11 +119,14 @@ class GroupedQueryAttention(HybridBlock):
     without the RMSNorm over each head of q and k, ``window`` a static
     window: a query sees itself and the ``window - 1`` keys before it,
     ``sm_scale`` what the scores are multiplied by (``head_dim ** -0.5``
-    where it is None)."""
+    where it is None), ``output_gate=True`` a gate on every element of every
+    head before the output projection, ``o_proj(attn * sigmoid(gate_proj(x)))``
+    (the G1 form of Qiu et al., arXiv:2505.06708)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rope_theta=10000.0, rms_norm_eps=1e-6, head_norm=True,
-                 window=None, sm_scale=None, prefix=None, params=None):
+                 window=None, sm_scale=None, output_gate=False, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         if num_heads % num_kv_heads:
             raise MXNetError("GroupedQueryAttention: %d query heads on %d K/V "
@@ -142,6 +145,10 @@ class GroupedQueryAttention(HybridBlock):
             self.qk_norm = None
             if head_norm:
                 self.qk_norm = _HeadNorm(head_dim, rms_norm_eps, prefix="qk_norm_")
+            self.gate_proj = None
+            if output_gate:
+                self.gate_proj = nn.Dense(num_heads * head_dim, in_units=units,
+                                          prefix="gate_proj_", **dense)
             self.o_proj = nn.Dense(units, in_units=num_heads * head_dim,
                                    prefix="o_proj_", **dense)
 
@@ -161,6 +168,8 @@ class GroupedQueryAttention(HybridBlock):
             F.transpose(v, axes=(0, 2, 1, 3)), None, mask, causal=True,
             sm_scale=self._scale, window=self._window)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
+        if self.gate_proj is not None:
+            out = out * F.sigmoid(self.gate_proj(x))
         return self.o_proj(out)
 
 

@@ -11,7 +11,8 @@ from .layout import resolve_norm_axis
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
            "SyncBatchNorm",
            "Embedding", "Flatten", "Lambda", "HybridLambda", "Activation",
-           "LayerNorm", "InstanceNorm", "GroupNorm", "RMSNorm", "GatedRMSNorm", "SwiGLU"]
+           "LayerNorm", "InstanceNorm", "GroupNorm", "RMSNorm", "GatedRMSNorm",
+           "RMSNormSigmoidGate", "SwiGLU"]
 
 
 class Sequential(Block):
@@ -373,6 +374,17 @@ class GatedRMSNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gate, gamma=None):
         return F.GatedRMSNorm(x, gate, gamma, eps=self._epsilon)
+
+
+class RMSNormSigmoidGate(GatedRMSNorm):
+    """``RMSNorm(x) * gamma * sigmoid(gate)`` over the last axis (op
+    ``RMSNormSigmoidGate``): the norm first and a sigmoid gate after it, what
+    closes a head of Kimi's delta attention. The other order and the other
+    gate from ``GatedRMSNorm``; the same arguments and the one parameter
+    ``gamma``."""
+
+    def hybrid_forward(self, F, x, gate, gamma=None):
+        return F.RMSNormSigmoidGate(x, gate, gamma, eps=self._epsilon)
 
 
 class SwiGLU(HybridBlock):

@@ -14,6 +14,7 @@ from . import attention
 from . import indexer
 from . import gated_conv
 from . import ssd
+from . import delta_rule
 from . import moe
 from . import linalg
 from . import contrib_ops

@@ -505,6 +505,47 @@ def gated_rms_norm(data, gate, gamma, eps=1e-6):
     return _gated_rms_core(float(eps), data, gate, gamma)
 
 
+@jax.named_scope("rmsnorm_gate")
+def _rms_gate_fwd(eps, x, z, g):
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    out = x32 * inv * g.astype(jnp.float32) * jax.nn.sigmoid(z.astype(jnp.float32))
+    return out.astype(x.dtype), (x, z, g)
+
+
+@jax.named_scope("rmsnorm_gate_bwd")
+def _rms_gate_bwd(eps, res, ct):
+    x, z, g = res
+    x32, z32, ct32 = (a.astype(jnp.float32) for a in (x, z, ct))
+    gate = jax.nn.sigmoid(z32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    xhat = x32 * inv
+    dn = ct32 * gate  # the normed rows' gradient, before the weight
+    dy = dn * g.astype(jnp.float32)
+    dx = inv * (dy - xhat * jnp.mean(dy * xhat, axis=-1, keepdims=True))
+    dg = jnp.sum(dn * xhat, axis=tuple(range(x.ndim - 1)))
+    dz = ct32 * xhat * g.astype(jnp.float32) * gate * (1.0 - gate)
+    return dx.astype(x.dtype), dz.astype(z.dtype), dg.astype(g.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rms_gate_core(eps, x, z, g):
+    return _rms_gate_fwd(eps, x, z, g)[0]
+
+
+_rms_gate_core.defvjp(_rms_gate_fwd, _rms_gate_bwd)
+
+
+@register("RMSNormSigmoidGate", aliases=("rms_norm_sigmoid_gate",))
+def rms_norm_sigmoid_gate(data, gate, gamma, eps=1e-6):
+    """``RMSNorm(data) * gamma * sigmoid(gate)`` over the last axis, the norm
+    first and the gate after it (Kimi's delta attention closes a head so; the
+    other order and the other gate from ``GatedRMSNorm``): float32 inside,
+    residuals in the inputs' types, as ``RMSNorm``. Scopes ``rmsnorm_gate`` /
+    ``rmsnorm_gate_bwd``."""
+    return _rms_gate_core(float(eps), data, gate, gamma)
+
+
 @register("rotary_embedding", aliases=("rope",))
 def rotary_embedding(data, theta=10000.0, interleaved=False, seq_axis=-2):
     """Rotary positions (Su et al. 2021) on the last axis of ``data``, the

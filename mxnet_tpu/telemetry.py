@@ -74,6 +74,7 @@ __all__ = [
     "record_gated_conv", "gated_conv_branches",
     "record_causal_conv", "causal_conv_branches",
     "record_ssd", "ssd_branches",
+    "record_delta_rule", "delta_rule_branches",
     "record_embedding_grad", "embedding_grad_branches",
     "record_grouped_matmul", "grouped_matmul_branches",
     "record_row_movement", "row_movement_branches",
@@ -958,6 +959,19 @@ def record_ssd(branch):
 def ssd_branches():
     """{branch: traces} of :func:`record_ssd` so far."""
     return _branches("mxt_ssd_total")
+
+
+def record_delta_rule(branch):
+    """One traced ``gated_delta_rule`` forward, by the branch it took
+    (``mxt_delta_rule_total{branch=xla|kernel}``). Counted at trace time, as
+    the flash branches: nothing enters the compiled step."""
+    counter("mxt_delta_rule_total", "Traced gated delta rules by branch.",
+            ("branch",)).labels(branch).inc()
+
+
+def delta_rule_branches():
+    """{branch: traces} of :func:`record_delta_rule` so far."""
+    return _branches("mxt_delta_rule_total")
 
 
 def record_embedding_grad(branch):

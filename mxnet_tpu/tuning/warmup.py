@@ -111,6 +111,28 @@ def _warm_bn(spec):
     return "batch_norm"
 
 
+def _warm_delta_rule(spec):
+    """AOT-compile the gated delta rule's custom-VJP forward and backward
+    programs for one recorded signature."""
+    import jax
+
+    from ..ops import delta_rule as D
+
+    q = _sds(spec["q_shape"], spec["dtype"])
+    v = _sds(spec["v_shape"], spec["dtype"])
+    g = _sds(spec["q_shape"], spec["g_dtype"])
+    beta = _sds(spec["q_shape"][:3], spec["beta_dtype"])
+    chunk = int(spec["chunk"])  # sync-ok: host number from JSON
+
+    def fwd(q_, k_, v_, g_, beta_):
+        return D._delta_core(chunk, q_, k_, v_, g_, beta_)
+
+    jax.jit(fwd).lower(q, q, v, g, beta).compile()
+    jax.jit(jax.grad(lambda *a: fwd(*a).sum(),
+                     argnums=(0, 1, 2, 3, 4))).lower(q, q, v, g, beta).compile()
+    return "gated_delta_rule"
+
+
 def _warm_paged(spec):
     """AOT-compile the ragged paged attention decode program for one
     recorded signature (both the jitted dispatch a serving step traces
@@ -171,7 +193,8 @@ def warmup(steps=(), kernels=True, include_live=True, reason=None):
     if kernels:
         for kind, fn in (("flash_attention", _warm_flash),
                          ("batch_norm", _warm_bn),
-                         ("paged_attention", _warm_paged)):
+                         ("paged_attention", _warm_paged),
+                         ("gated_delta_rule", _warm_delta_rule)):
             for spec in signatures(kind):
                 try:
                     warmed.append(fn(spec))

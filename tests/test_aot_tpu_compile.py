@@ -729,3 +729,135 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
     # nine Mamba layers: the filter's forward, its second run, its backward
     calls = re.findall(r"= [^=]*custom-call\([^\n]*causal_conv_silu_(fwd|bwd)/pallas_call", text)
     assert (calls.count("fwd"), calls.count("bwd")) == (18, 9)
+
+
+# -- Kimi's delta attention and the Solar Open 2 cell's whole step ---------------------
+_KDA = (1, 8192, 8, 128)  # a delta-attention layer of the cell: 8 heads of 128 held
+
+
+def _delta_rule_shapes():
+    bf = jnp.dtype("bfloat16")
+    return ((_KDA, bf), (_KDA, bf), (_KDA, bf), (_KDA, jnp.float32), (_KDA[:3], bf))
+
+
+@pytest.mark.parametrize("half", ["fwd", "bwd"])
+def test_gated_delta_rule_compiles_and_keeps_no_pairwise_tensor(chip, half):
+    """``gated_delta_rule`` at a delta-attention layer of the Solar Open 2 cell
+    (1 x 8192 tokens, 8 heads of 128, chunks of 64), forward alone and with
+    its own backward: XLA's products and fusions under two ``while`` loops (the
+    carry each way), no kernel, and no (chunks x heads x 64 x 64 x 128) tensor
+    among the results written to memory (2.1 GB in float32). The op's own
+    backward holds 0.741 GB of temporaries (``jax.checkpoint`` of the forward
+    under plain autodiff, the other way ISSUE 47 named, compiled to 0.775 and
+    is not built: PERF.md, Findings, PR 47)."""
+    from mxnet_tpu.ops import delta_rule as D
+
+    shapes = _delta_rule_shapes()
+    own = functools.partial(D.gated_delta_rule, chunk=64)
+    if half == "fwd":
+        text = _compile(chip, own, *shapes)
+        assert text.count(" while(") == 1
+    else:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=chip)
+                for s, d in ((_KDA, jnp.float32),) + shapes]
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(jax.grad(
+                lambda g, *a: jnp.sum(own(*a).astype(jnp.float32) * g),
+                argnums=tuple(range(1, 6)))).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count(" while(") == 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.76e9
+    assert "tpu_custom_call" not in text
+    pairwise = 128 * 8 * 64 * 64 * 128
+    large = [(t, d) for t, d in _entry_results(text)
+             if functools.reduce(lambda a, b: a * b, d, 1) >= pairwise // 4]
+    assert not large, large
+    assert "bf16[1,8192,8,128]" in text  # o, or a gradient, written once
+
+
+def test_the_solar_cells_filter_and_attention_take_their_kernel_branches(chip, monkeypatch):
+    """``causal_conv_silu`` over all 3072 columns of the fused projection's
+    result from column 0 (no other cell's call) compiles to the two kernels of
+    ``ops/causal_conv_pallas.py``, and attention at (1, 8 query heads on ONE
+    K/V head of 128, 8192 rows) to both flash kernels."""
+    from mxnet_tpu.ops.gated_conv import causal_conv_silu
+
+    monkeypatch.setattr(CC, "on_tpu", lambda: True)
+    dt = jnp.dtype("bfloat16")
+    assert CC.kernel_takes((1, 8192, 3072), 4, dt, 0)
+    op = functools.partial(causal_conv_silu, columns=(0, 3072))
+    text = _compile(
+        chip, jax.grad(lambda x, w, b, g: jnp.sum(op(x, w, b).astype(jnp.float32) * g),
+                       argnums=(0, 1)), ((1, 8192, 3072), dt), ((3072, 4), dt),
+        ((3072,), dt), ((1, 8192, 3072), jnp.float32))
+    assert text.count("tpu_custom_call") == 1 and "causal_conv_silu_bwd" in text
+    both = _compile(chip, lambda x, w, b: jax.vjp(op, x, w, b)[0], ((1, 8192, 3072), dt),
+                    ((3072, 4), dt), ((3072,), dt))
+    assert both.count("tpu_custom_call") == 1 and "causal_conv_silu_fwd" in both
+    fwd, bwd = _heads_major_calls((1, 8, 8192, 128), 1)
+    assert "flash_attention_fwd" in str(fwd) and "flash_attention_bwd" in str(bwd)
+
+
+def test_the_solar_cells_whole_step_fits_without_recomputation(chip, monkeypatch):
+    """``solar_open2_train_s8192``'s step, 840,875,672 parameters (835,631,512
+    of them trained: the routers are frozen) under Adam at (1, 8192) through
+    ``ShardedTrainStep``, compiled for the described chip as the cell builds it
+    (both flash kernels, the filter's two, the expert layer's and the embedding
+    gradient's in, ``remat`` from the configuration's file: none): 11.21 GB
+    live, 6.19 GB of it temporaries, under the 14 GB that leave room for the
+    seeded copy (1.68 GB) and the batch pool."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.model_zoo import solar_open2 as zoo
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "solar_open2_250b_ep40_tp8.json")) as f:
+        config = json.load(f)
+    remat = config["assumed"]["recomputation"]["remat"]
+    assert remat is None
+    cfg = dict(config, n_routed_experts=config["published"]["n_routed_experts"])
+    net = zoo.SolarOpen2Model(cfg, experts_held=tuple(config["experts_held"]),
+                              chunk=config["assumed"]["chunk"])
+    net.initialize(mx.init.Zero())  # shapes are what is compiled, not values
+    net.cast(config["dtype"])
+    net.collect_params(".*router_weight").setattr("grad_req", "null")
+    opt = {k: v for k, v in config["optimizer"].items() if k != "name"}
+    step = parallel.ShardedTrainStep(
+        net, mx.gluon.loss.SoftmaxCrossEntropyLoss(), config["optimizer"]["name"], opt,
+        mesh=parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1]), remat=remat)
+    one = Mesh([chip._device], ("data",))
+    step.rebind_mesh(one, transfer=False)  # the shardings and the program, no value moved
+    for module in (A, CC, EG, GM, RG):  # dispatch as on the chip
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    whole = NamedSharding(one, P())
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole)
+
+    train, states, aux = step._gather()
+    trained = sum(v.size for v in train)
+    assert trained == 840875672 - 4 * (320 * 4096 + 320) == 835631512  # less routers, biases
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=step._batch_sharding(2))
+    with jax.default_matmul_precision("default"):
+        compiled = step._jit.lower(
+            jax.tree.map(sds, train), jax.tree.map(sds, states), jax.tree.map(sds, aux),
+            x, x, sds(step._ensure_key()), sds(step._t_dev)).compile()
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+            - m.alias_size_in_bytes)
+    print("arguments %.2f + outputs %.2f + temporaries %.2f - aliased %.2f = %.2f GB"
+          % tuple(v / 1e9 for v in (m.argument_size_in_bytes, m.output_size_in_bytes,
+                                    m.temp_size_in_bytes, m.alias_size_in_bytes, held)))
+    assert m.alias_size_in_bytes >= 6 * trained - 4096  # weights and state donated
+    assert held < 11.4e9 and held + 2 * 840875672 < 14e9
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    # three delta-attention layers: the filter's forward and its backward, once each
+    calls = re.findall(r"= [^=]*custom-call\([^\n]*causal_conv_silu_(fwd|bwd)/pallas_call", text)
+    assert (calls.count("fwd"), calls.count("bwd")) == (3, 3)
+    assert "grouped_matmul" in text and "delta_rule_bwd" in text

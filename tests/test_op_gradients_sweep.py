@@ -354,6 +354,13 @@ SPEC.update({
     "count_sketch": ([_any(3, 6), np.array([0.0, 2, 1, 3, 0, 2]),
                       np.array([1.0, -1, 1, -1, 1, 1])],
                      dict(out_dim=4), [0]),
+    # Kimi's delta attention (last, so that no earlier entry's draw moves): q,
+    # k, v, log-decays a channel (<= 0), beta in (0, 2): two heads of 4 on
+    # values of 3, six tokens in chunks of 4 (a ragged last chunk)
+    "gated_delta_rule": ([_unit(1, 6, 2, 4), _unit(1, 6, 2, 4), _unit(1, 6, 2, 3),
+                          -0.3 * _pos(1, 6, 2, 4), _pos(1, 6, 2)], {"chunk": 4}, None),
+    "kda_log_decay": ([_any(1, 5, 6), _unit(2), _unit(6)], {}, None),
+    "RMSNormSigmoidGate": ([_any(3, 6), _any(3, 6), _pos(6)], {}, None),
 })
 del SPEC["one_hot_like_ops"]
 
@@ -372,6 +379,9 @@ F32_INTERNAL_TOL = {
     "causal_conv_silu": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "GatedRMSNorm": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "ssd_scan": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "gated_delta_rule": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "kda_log_decay": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
+    "RMSNormSigmoidGate": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
     "moe_router_logits": dict(eps=1e-2, rtol=2e-2, atol=1e-3),
 }
 

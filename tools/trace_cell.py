@@ -16,7 +16,8 @@ each traced pass read its operands in (``flash_layouts``: ``in_place`` from the
 fused projection, ``heads_major`` turned), the branch
 each traced gated short convolution took (``gated_conv_branches``), each
 causal filter under SiLU (``causal_conv_branches``), each
-state-space scan (``ssd_branches``), the form each traced embedding's weight
+state-space scan (``ssd_branches``), each gated delta rule
+(``delta_rule_branches``), the form each traced embedding's weight
 gradient took (``embedding_grad_branches``) and each
 traced grouped matmul of an expert layer by product (``grouped_matmul_branches``)
 and each traced movement of rows between tokens and experts (``row_movement_branches``),
@@ -48,15 +49,18 @@ import run as bench  # noqa: E402 — benchmark/run.py
 
 # further single scopes quoted in PERF.md: latent and grouped-query attention,
 # the indexer, the expert layer, the gated short convolution, attention over a
-# window beside attention over the whole past, the Mamba-2 mixer, and the
-# embedding table's gradient
+# window beside attention over the whole past, the Mamba-2 mixer, the
+# embedding table's gradient, and Kimi's delta attention beside a gated
+# attention layer
 PARTS = ("mla", "q_proj", "kv_a", "kv_b", "rope", "o_proj", "rmsnorm", "rmsnorm_bwd",
          "moe", "router", "dispatch", "experts", "combine", "shared",
          "gqa", "kv_proj", "qk_norm", "indexer", "k_proj", "weights", "scores", "select",
          "short_conv", "in_proj", "gated_conv", "gated_conv_bwd", "out_proj",
          "grouped_matmul_bwd", "attn_full", "attn_window",
          "mamba", "causal_conv", "causal_conv_bwd", "ssd", "ssd_bwd", "gated_rmsnorm",
-         "gated_rmsnorm_bwd", "mlp", "embedding_bwd")
+         "gated_rmsnorm_bwd", "mlp", "embedding_bwd",
+         "kda", "qkv_proj", "log_decay", "delta_rule", "delta_rule_bwd", "o_norm",
+         "rmsnorm_gate", "rmsnorm_gate_bwd", "gate_proj")
 
 
 class Context(bench.Context):
@@ -73,6 +77,7 @@ class Context(bench.Context):
     gated_conv = None
     causal_conv = None
     ssd = None
+    delta_rule = None
     embedding_grad = None
     grouped_matmul = None
     row_movement = None
@@ -111,6 +116,7 @@ class Context(bench.Context):
         Context.gated_conv = telemetry.gated_conv_branches()
         Context.causal_conv = telemetry.causal_conv_branches()
         Context.ssd = telemetry.ssd_branches()
+        Context.delta_rule = telemetry.delta_rule_branches()
         Context.embedding_grad = telemetry.embedding_grad_branches()
         Context.grouped_matmul = telemetry.grouped_matmul_branches()
         Context.row_movement = telemetry.row_movement_branches()
@@ -191,6 +197,8 @@ def main(argv):
             row["causal_conv_branches"] = Context.causal_conv
         if Context.ssd:  # and which each state-space scan
             row["ssd_branches"] = Context.ssd
+        if Context.delta_rule:  # and which each gated delta rule
+            row["delta_rule_branches"] = Context.delta_rule
         if Context.embedding_grad:  # and which form each embedding's weight gradient
             row["embedding_grad_branches"] = Context.embedding_grad
         if Context.grouped_matmul:  # and which each grouped matmul, by product
