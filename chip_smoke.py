@@ -417,6 +417,7 @@ def phase_kernels(args, dev):
     out["grouped_matmul"] = grouped_matmul_table(args, key, interp)
     out["row_movement"] = row_movement_table(args, interp)
     out["causal_conv"] = causal_conv_table(args, interp)
+    out["ssd"] = ssd_table(args, interp)
     out["embedding_grad"] = embedding_grad_table(args, interp)
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
@@ -602,6 +603,70 @@ def causal_conv_table(args, interp):
                rel_err=[rel_err(a, b_) for a, b_ in zip(got, want)],
                **{"%s_ms" % n: v for n, v in ms.items()})
     check(max(row["rel_err"]) < 1e-2, "causal filter's kernels against the formula: %s" % row)
+    return row
+
+
+def ssd_table(args, interp):
+    """Mamba-2's scan at the Granite cell's shape, (1, 8192) tokens of 64 heads
+    of 64 in one group with a state of 128 in chunks of 256, bfloat16:
+    ``ops/ssd_pallas.py``'s two kernels and the ``jax.numpy`` formula of
+    ``ops/ssd.py`` (both float32 inside, their products' operands bfloat16),
+    each against the formula on the same values in float32 at the highest
+    matmul precision, the result and all seven gradients (the running sums'
+    gradient is what is left of rows less columns, and on the chip the
+    bfloat16 formula loses digits there that the kernels keep: PERF.md,
+    Findings, PR 48), and device ms a call of each: the whole op on (B, T, H,
+    P) operands both ways (stand-alone the kernels' ``swapaxes`` are transposes
+    XLA runs; in the cell they are layouts) and the kernels alone on operands
+    turned beforehand."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import ssd as S
+    from mxnet_tpu.ops import ssd_pallas as K
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    b, t, h, p, n, chunk = (1, 256, 8, 16, 128, 128) if args.rehearse \
+        else (1, 8192, 64, 64, 128, 256)
+    ks = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 8)
+    x, dy = (jax.random.normal(s, (b, t, h, p), f32).astype(bf16) for s in ks[:2])
+    B, C = (jax.random.normal(s, (b, t, 1, n), f32).astype(bf16) for s in ks[2:4])
+    dt = jax.random.normal(ks[4], (b, t, h), f32).astype(bf16)
+    A_log = jnp.log(jax.random.uniform(ks[5], (h,), f32, 1.0, 16.0)).astype(bf16)
+    D = jax.random.normal(ks[6], (h,), f32).astype(bf16)
+    dt_bias = (jax.random.normal(ks[7], (h,), f32) - 2.0).astype(bf16)
+    args7 = (x, dt, A_log, B, C, D, dt_bias)
+    dts, cum, _ = K._decays(dt, A_log, dt_bias, chunk)
+    turned = tuple(K._turned(z, chunk) for z in (x, dy, B, C))
+    fwd = functools.partial(K._fwd_pallas, groups=1, chunk=chunk, interpret=interp)
+    bwd = functools.partial(K._bwd_pallas, groups=1, chunk=chunk, interpret=interp)
+    formula, formula_grads = (functools.partial(f, chunk) for f in (S._scan, S._scan_grads))
+    xla_y, opening = jax.jit(formula)(*args7)
+    xla = (xla_y,) + jax.jit(formula_grads)(*args7, opening, dy)
+    with jax.default_matmul_precision("highest"):
+        exact7 = tuple(a.astype(f32) for a in args7)
+        want_y, want_opening = jax.jit(formula)(*exact7)
+        want = (want_y,) + jax.jit(formula_grads)(*exact7, want_opening, dy.astype(f32))
+    del want_opening
+    scan, grads = (functools.partial(f, chunk, interpret=interp) for f in (K.scan, K.scan_grads))
+    got_y, got_opening = jax.jit(scan)(*args7)
+    got = (got_y,) + jax.jit(grads)(*args7, got_opening, dy)
+    variants = {"fwd_xla": (formula, args7), "bwd_xla": (formula_grads, args7 + (opening, dy)),
+                "fwd_op": (scan, args7), "bwd_op": (grads, args7 + (got_opening, dy)),
+                "fwd_kernel": (fwd, (turned[0],) + turned[2:] + (dts, cum, D.astype(f32))),
+                "bwd_kernel": (bwd, turned + (dts, cum, got_opening.reshape(b, -1, h * p, n),
+                                              D.astype(f32)))}
+    ms, parts = device_ms(variants)
+    if not ms:  # no device line to read: the host's clock
+        ms = {name: inflight_ms(fn, *a) for name, (fn, a) in variants.items()}
+    row = dict(shape=[b, t, h, p, n, chunk], clock="device" if parts else "host",
+               rel_err=[rel_err(a, b_) for a, b_ in zip(got, want)],
+               xla_rel_err=[rel_err(a, b_) for a, b_ in zip(xla, want)],
+               opening_rel_err=rel_err(got_opening, opening),
+               **{"%s_ms" % k: v for k, v in ms.items()})
+    # no farther from the float32 formula than the bfloat16 formula itself is
+    check(all(e < max(2e-2, 1.5 * x) for e, x in zip(row["rel_err"], row["xla_rel_err"])),
+          "the scan's kernels against the float32 formula: %s" % row)
     return row
 
 

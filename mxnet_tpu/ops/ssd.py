@@ -25,12 +25,25 @@ sum of ``dt A`` inside a chunk,
 
 ``softplus``, the decays, the running sums, the masks and the states are
 float32; the operands of the four products are in ``x``'s type and accumulate
-in float32; the result is in ``x``'s type. A float32 (chunks x heads x chunk x
-chunk) tensor never leaves the fusion that makes it: the mask is built where
-it multiplies the scores and stored in the operands' type, and the backward's
-per-head product of the same shape is stored in that type too. T is padded to
-whole chunks inside (``dt = 0`` and ``x = 0`` change nothing); a sequence
-shorter than a chunk is one chunk of its own length.
+in float32; the result is in ``x``'s type. T is padded to whole chunks inside
+(``dt = 0`` and ``x = 0`` change nothing); a sequence shorter than a chunk is
+one chunk of its own length.
+
+Two branches compute that, one algorithm at one precision; what differs is
+where a chunk's (chunk x chunk) tiles live. Where
+``ssd_pallas.kernel_takes`` accepts the call (a TPU, bfloat16 or float32, a
+chunk of whole lane tiles, P and N whole tiles, groups of whole blocks of
+heads) both halves are that module's kernels, ``ssd_chunk_fwd`` and
+``ssd_chunk_bwd``: scores, mask and masked scores stay in VMEM, the carry
+rides in scratch over the grid's chunk axis, and XLA keeps only ``softplus``,
+the running sums and, after the backward, the sums' reverse walk and the
+per-head sums, all on (B, H, T) float32 rows. Every other call (the CPU, a
+chunk of 8 in the tests, a float32 reference's shapes that are no whole
+tiles) is the ``jax.numpy`` formula here (``_scan`` / ``_scan_grads``), in
+which a float32 (chunks x heads x chunk x chunk) tensor never leaves the
+fusion that makes it: the mask is built where it multiplies the scores and
+stored in the operands' type, and the backward's per-head product of the same
+shape is stored in that type too.
 
 The backward is the op's own (``jax.custom_vjp``): the forward keeps its
 inputs and every chunk's opening state, the backward builds the masks again,
@@ -39,9 +52,9 @@ With ``dx^`` the gradient of ``dt x``, the running sums' gradient needs no
 pair of its own: a token's row of the masked products sums to ``dy_i . (y_i -
 D x_i)`` and its column to ``dt_j x_j . dx^_j``.
 
-The two halves run under the scopes ``ssd`` / ``ssd_bwd`` and every traced
-call is counted by the branch it took (``telemetry.ssd_branches()``: ``xla``;
-a later kernel counts ``kernel``).
+The two halves run under the scopes ``ssd`` / ``ssd_bwd``, kernels and all,
+and every traced call is counted by the branch it took
+(``telemetry.ssd_branches()``: ``kernel`` or ``xla``).
 """
 from __future__ import annotations
 
@@ -52,6 +65,7 @@ import jax.numpy as jnp
 
 from .. import telemetry as _telemetry
 from ..base import MXNetError
+from . import ssd_pallas as _kernels
 from .registry import register
 
 F32 = jnp.float32
@@ -122,7 +136,14 @@ def _ssd_core(chunk, x, dt, A_log, B, C, D, dt_bias):
 
 @jax.named_scope("ssd")
 def _ssd_fwd(chunk, x, dt, A_log, B, C, D, dt_bias):
-    _telemetry.record_ssd("xla")
+    kernel = _kernels.kernel_takes(x.shape, B.shape, chunk, x.dtype)
+    _telemetry.record_ssd("kernel" if kernel else "xla")
+    y, opening = (_kernels.scan if kernel else _scan)(chunk, x, dt, A_log, B, C, D, dt_bias)
+    return y, (x, dt, A_log, B, C, D, dt_bias, opening)
+
+
+def _scan(chunk, x, dt, A_log, B, C, D, dt_bias):
+    """The formula's forward: the result and every chunk's opening state."""
     t, g = x.shape[1], B.shape[2]
     xc, Bc, Cc = _by_chunk(x, chunk, g), _by_chunk(B, chunk), _by_chunk(C, chunk)
     dtc, cum, _ = _decays(dt, A_log, dt_bias, chunk, g)
@@ -134,12 +155,18 @@ def _ssd_fwd(chunk, x, dt, A_log, B, C, D, dt_bias):
         * jnp.exp(cum)[..., None]
     y = y + D.astype(F32).reshape(dtc.shape[-2:])[:, :, None] * xc.astype(F32)
     y = y.reshape((x.shape[0], -1) + x.shape[2:])[:, :t]
-    return y.astype(x.dtype), (x, dt, A_log, B, C, D, dt_bias, opening)
+    return y.astype(x.dtype), opening
 
 
 @jax.named_scope("ssd_bwd")
 def _ssd_bwd(chunk, res, dy):
-    x, dt, A_log, B, C, D, dt_bias, opening = res
+    x, B = res[0], res[3]
+    kernel = _kernels.kernel_takes(x.shape, B.shape, chunk, x.dtype)
+    return (_kernels.scan_grads if kernel else _scan_grads)(chunk, *res, dy)
+
+
+def _scan_grads(chunk, x, dt, A_log, B, C, D, dt_bias, opening, dy):
+    """The formula's backward: the gradient of every input under ``dy``."""
     kind, (b, t, h, _), g = x.dtype, x.shape, B.shape[2]
     r = h // g
     xc, dyc = _by_chunk(x, chunk, g), _by_chunk(dy, chunk, g)

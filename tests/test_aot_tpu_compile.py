@@ -28,6 +28,7 @@ from mxnet_tpu.ops import embedding_grad as EG
 from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
 from mxnet_tpu.ops import row_gather as RG
+from mxnet_tpu.ops import ssd_pallas as SP
 
 
 @pytest.fixture(scope="module")
@@ -582,16 +583,43 @@ def _entry_results(text):
     return out
 
 
+def _copies(text, elements):
+    """(type, dims) of every copy or transpose of ``elements`` elements or more
+    that the optimized module's ENTRY computation writes to memory, a fusion
+    whose root is one among them."""
+    roots = dict(re.findall(r"\n%?([\w.\-]+) \([^\n]*\{\n(?:[^}][^\n]*\n)*?\s+ROOT [^\n]*? = \S+ "
+                            r"([a-z\-]+)\(", text))
+    out = []
+    for line in text[text.index("\nENTRY "):].split("\n")[1:]:
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = ([a-z]+[0-9]+)\[([0-9,]*)\]\S* ([a-z\-]+)\(",
+                     line[:2000])
+        if not m:
+            continue
+        op = m.group(3)
+        if op == "fusion":
+            op = roots.get(re.search(r"calls=%?([\w.\-]+)", line).group(1))
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if op in ("copy", "transpose") and functools.reduce(lambda a, b: a * b, dims, 1) >= elements:
+            out.append((m.group(1), dims))
+    return out
+
+
+@pytest.mark.parametrize("dispatch", ["chip", "off_chip"])
 @pytest.mark.parametrize("half", ["fwd", "bwd"])
-def test_ssd_scan_compiles_and_no_float32_mask_leaves_its_fusion(chip, half):
+def test_ssd_scan_compiles_and_no_float32_mask_leaves_its_fusion(chip, monkeypatch, half,
+                                                                 dispatch):
     """``ssd_scan`` at a Mamba layer of the Granite cell (1 x 8192 tokens, 64
     heads of 64, one group, state 128, chunks of 256), forward alone and with
-    its hand-written backward: XLA's products and fusions, no kernel, and no
-    float32 tensor of (chunks x heads x 256 x 256) or more among the results
-    written to memory (0.54 GB each; autodiff through the formula keeps one a
-    layer)."""
+    its hand-written backward. Off the chip: XLA's products and fusions, no
+    kernel, and no float32 tensor of (chunks x heads x 256 x 256) or more among
+    the results written to memory (0.54 GB each; autodiff through the formula
+    keeps one a layer). Dispatched as on the chip: the two kernels of
+    ``ops/ssd_pallas.py`` by their names under the op's two scopes, and no
+    tensor of that many elements of ANY type written to memory: scores, mask
+    and masked scores stay in VMEM."""
     from mxnet_tpu.ops.ssd import ssd_scan
 
+    monkeypatch.setattr(SP, "on_tpu", lambda: dispatch == "chip")
     dt = jnp.dtype("bfloat16")
     shapes = tuple((s, dt) for s, _ in _SSD_SHAPES)
     if half == "fwd":
@@ -601,12 +629,23 @@ def test_ssd_scan_compiles_and_no_float32_mask_leaves_its_fusion(chip, half):
             chip, jax.grad(lambda g, *a: jnp.sum(
                 ssd_scan(*a, chunk=256).astype(jnp.float32) * g), argnums=tuple(range(1, 8))),
             ((1, 8192, 64, 64), jnp.float32), *shapes)
-    assert "tpu_custom_call" not in text
     mask = 32 * 64 * 256 * 256
-    large = [(t, d) for t, d in _entry_results(text)
-             if t == "f32" and functools.reduce(lambda a, b: a * b, d, 1) >= mask]
-    assert not large, large
-    assert "bf16[1,8192,64,64]" in text  # y, or the gradient of x, written once
+    sizes = [(t, d) for t, d in _entry_results(text)
+             if functools.reduce(lambda a, b: a * b, d, 1) >= mask]
+    if dispatch == "off_chip":
+        assert "tpu_custom_call" not in text
+        large = [(t, d) for t, d in sizes if t == "f32"]
+        assert not large, large
+        assert "bf16[1,8192,64,64]" in text  # y, or the gradient of x, written once
+        return
+    assert not sizes, sizes
+    # the backward's program runs the forward too: its opening states are the residual
+    assert text.count("tpu_custom_call") == (1 if half == "fwd" else 2)
+    assert "ssd%s/jit(_fwd_pallas)/ssd_chunk_fwd/" % ("" if half == "fwd" else ")") in text  # the scope
+    assert "f32[1,32,4096,128]" in text  # a (P, N) float32 state a head a chunk
+    if half == "bwd":
+        assert "(ssd_bwd))/jit(_bwd_pallas)/ssd_chunk_bwd/" in text
+    assert re.search(r"bf16\[1,(8192,64,64|4096,8192)\]", text)  # y or dx, written once
 
 
 @pytest.mark.parametrize("dispatch", ["chip", "off_chip"])
@@ -676,11 +715,12 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
         chip, monkeypatch):
     """``granite4_h_micro_train_s8192``'s step, 797,850,560 parameters under
     Adam at (1, 8192) through ``ShardedTrainStep``, compiled for the described
-    chip as the cell builds it (both flash kernels and the filter's two in,
-    ``remat`` from the configuration's file): 11.21 GB live, 6.42 GB of it
-    temporaries (11.40 and 6.61 with XLA's filter), under the 14 GB that leave
-    room for the seeded copy (1.60 GB) and the batch pool. Without recomputation
-    it compiled to 16.51 GB (PERF.md section 4)."""
+    chip as the cell builds it (both flash kernels, the filter's two and the
+    scan's two in, ``remat`` from the configuration's file): 11.11 GB live,
+    6.32 GB of it temporaries (11.21 and 6.42 with XLA's scan, 11.40 and 6.61
+    with XLA's filter too), under the 14 GB that leave room for the seeded copy
+    (1.60 GB) and the batch pool, and not one copy of a mixer-sized array.
+    Without recomputation it compiled to 16.51 GB (PERF.md section 4)."""
     import json
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -702,7 +742,7 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
         mesh=parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1]), remat=remat)
     one = Mesh([chip._device], ("data",))
     step.rebind_mesh(one, transfer=False)  # the shardings and the program, no value moved
-    for module in (A, CC):  # dispatch as on the chip
+    for module in (A, CC, SP):  # dispatch as on the chip
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     whole = NamedSharding(one, P())
 
@@ -723,12 +763,20 @@ def test_the_granite_cells_whole_step_fits_with_the_recomputation_its_file_names
           % tuple(v / 1e9 for v in (m.argument_size_in_bytes, m.output_size_in_bytes,
                                     m.temp_size_in_bytes, m.alias_size_in_bytes, held)))
     assert m.alias_size_in_bytes >= 6 * 797850560 - 4096  # weights and state donated
-    assert held < 11.41e9 and held + 2 * 797850560 < 14e9  # no more than before the kernels
+    # no more than with the scan as XLA's formula (PR 47's tree: 11.208 GB, 6.421 of them
+    # temporaries)
+    assert held < 11.21e9 and m.temp_size_in_bytes < 6.43e9 and held + 2 * 797850560 < 14e9
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
-    # nine Mamba layers: the filter's forward, its second run, its backward
-    calls = re.findall(r"= [^=]*custom-call\([^\n]*causal_conv_silu_(fwd|bwd)/pallas_call", text)
-    assert (calls.count("fwd"), calls.count("bwd")) == (18, 9)
+    # nine Mamba layers: the forward of the filter and of the scan, its second run, the backward
+    for kernel in ("causal_conv_silu", "ssd_chunk"):
+        calls = re.findall(r" custom-call\([^\n]*%s_(fwd|bwd)/pallas_call" % kernel, text)
+        assert (calls.count("fwd"), calls.count("bwd")) == (18, 9), kernel
+    # the mixer's arrays lie tokens-minor from in_proj to the gated norm and the kernels take
+    # them so: no copy or transpose of a mixer-sized array (8192 x 4096 elements) is written
+    # (with the formula 63 were: 27 f32[512,8,32,256], 18 of the scan's float32 result turned
+    # for the gated norm's two passes, 18 bfloat16 ones)
+    assert not _copies(text, 8192 * 4096), _copies(text, 8192 * 4096)
 
 
 # -- Kimi's delta attention and the Solar Open 2 cell's whole step ---------------------

@@ -13,6 +13,7 @@ operands of the products bfloat16) and the model-level run in bfloat16, which
 is held as the benchmark holds a cell.
 """
 import copy
+import functools
 import hashlib
 import os
 import re
@@ -30,6 +31,7 @@ from mxnet_tpu.gluon.model_zoo.keye import GroupedQueryAttention
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import gated_conv as G
 from mxnet_tpu.ops import ssd as S
+from mxnet_tpu.ops import ssd_pallas as K
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                      "benchmark")
@@ -230,6 +232,146 @@ def test_ssd_counts_one_traced_call_by_branch():
     f(*args), f(*args), f(*args)
     assert telemetry.ssd_branches()["xla"] == before + 1
     assert 'mxt_ssd_total{branch="xla"}' in telemetry.render_prometheus()
+
+
+# -- the scan's kernels (ops/ssd_pallas.py), interpreted, dispatched as on the chip ----
+@pytest.fixture
+def ssd_kernels(monkeypatch):
+    """``engage()``: from then on ``ops/ssd_pallas.py`` answers as on a TPU, its
+    kernels in interpret mode (a test computes what the ``jax.numpy`` formula
+    gives first, then engages)."""
+    def engage():
+        fwd, bwd = K._fwd_pallas, K._bwd_pallas
+        monkeypatch.setattr(K, "on_tpu", lambda: True)
+        monkeypatch.setattr(K, "_fwd_pallas", lambda *a, interpret=False, **kw:
+                            fwd(*a, interpret=True, **kw))
+        monkeypatch.setattr(K, "_bwd_pallas", lambda *a, interpret=False, **kw:
+                            bwd(*a, interpret=True, **kw))
+
+    return engage
+
+
+def _kernel_inputs(t, groups, dtype="float32"):
+    """Shapes ``kernel_takes`` accepts: 8 heads of 16 a group, state 128."""
+    return _ssd_inputs(t, groups, dtype, h=8 * groups, n=128)
+
+
+def _scan128(*a):
+    return S.ssd_scan(*a, chunk=128)
+
+
+@pytest.mark.parametrize("groups,t,chunk,what", [
+    (1, 256, 128, "whole_chunks_of_one_tile"),
+    (2, 300, 256, "a_ragged_last_chunk_two_groups_and_a_tile_under_the_diagonal")])
+def test_ssd_kernels_forward_and_every_gradient_against_recurrence_and_formula(
+        ssd_kernels, groups, t, chunk, what):
+    """Both kernels, float32: the result and the gradient of all seven inputs
+    against ``jax.grad`` of the token-by-token recurrence and against the
+    ``jax.numpy`` formula of ``ops/ssd.py`` on the same inputs: the scores
+    shared by a group's heads, the masks by tile, the carry in scratch both
+    ways, the heads' sum of ``dscores``, the rows XLA finishes, the padding."""
+    args, g = _kernel_inputs(t, groups)
+    scan = functools.partial(S.ssd_scan, chunk=chunk)
+    want = _recurrence(*args)
+    _, want_grads = _all_gradients(_recurrence, args, g)
+    formula = scan(*args)
+    _, formula_grads = _all_gradients(scan, args, g)
+    before = dict(telemetry.ssd_branches())
+    ssd_kernels()
+    got = scan(*args)
+    assert got.shape == want.shape and got.dtype == F32
+    _close(got, want, 2e-5)
+    _close(got, formula, 2e-6)
+    _, got_grads = _all_gradients(scan, args, g)
+    for name, a, b, c in zip(SSD_INPUTS, got_grads, want_grads, formula_grads):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        # A_log's gradient is what is left of rows less columns: a thousandth
+        _close(a, b, 2e-3 if name == "A_log" else 2e-4)
+        _close(a, c, 2e-3 if name == "A_log" else 2e-4)
+    after = telemetry.ssd_branches()
+    assert after["kernel"] > before.get("kernel", 0) and after.get("xla") == before.get("xla")
+
+
+def test_ssd_kernels_in_bfloat16_keep_decays_and_states_in_float32(ssd_kernels):
+    """bfloat16 operands at the kernels' branch: a bfloat16 result and gradients
+    in the inputs' types within the rounding of the products' operands,
+    ``A_log``'s (the cancelling sums on the same rounded operands) among them;
+    the formula's on the same inputs to a rounding of the result; the opening
+    states the forward keeps float32, the first of them zero."""
+    args, g = _kernel_inputs(256, 1, "bfloat16")
+    _, want = _all_gradients(_recurrence, tuple(a.astype(F32) for a in args), g)
+    formula = _scan128(*args)
+    _, formula_grads = _all_gradients(_scan128, args, g)
+    ssd_kernels()
+    got = _scan128(*args)
+    assert got.dtype == jnp.bfloat16
+    _close(got.astype(F32), _recurrence(*args), 2e-2)
+    _close(got.astype(F32), formula.astype(F32), 1e-2)
+    _, grads = _all_gradients(_scan128, args, g)
+    for name, a, b, c, arg in zip(SSD_INPUTS, grads, want, formula_grads, args):
+        assert a.dtype == arg.dtype, name
+        _close(a.astype(F32), b, 4e-2)
+        _close(a.astype(F32), c.astype(F32), 2e-2)
+    _, res = S._ssd_fwd(128, *args)
+    assert res[-1].dtype == F32 and res[-1].shape == (2, 2, 1, 8, 16, 128)
+    assert not np.asarray(res[-1][:, 0]).any() and np.asarray(res[-1][:, 1]).any()
+
+
+@pytest.mark.parametrize("at", [0, 120, 299])
+def test_ssd_kernels_causality_a_token_moves_no_earlier_output(ssd_kernels, at):
+    args, _ = _kernel_inputs(300, 1)
+    ssd_kernels()
+    moved = (args[0].at[1, at].add(1.0),) + args[1:]
+    a, b = _scan128(*args), _scan128(*moved)
+    assert np.array_equal(np.asarray(a[0]), np.asarray(b[0]))            # the other sequence
+    assert np.array_equal(np.asarray(a[1, :at]), np.asarray(b[1, :at]))  # the past
+    later = np.abs(np.asarray(a[1, at:]) - np.asarray(b[1, at:])).sum((-2, -1))
+    assert later[0] > 0 and (at != 120 or later[8:].any())  # and past its chunk's end
+
+
+def test_which_scans_the_kernels_take_is_a_function_of_what_the_call_sees(monkeypatch):
+    """``kernel_takes`` at the edges of its rule: a TPU, bfloat16 or float32, a
+    chunk of whole lane tiles, P and N whole tiles, groups of whole blocks of
+    heads, the tiles within the chip's VMEM (shrunk through ``ops/chip.py``);
+    a call it refuses runs the formula and is counted ``xla``."""
+    cell = ((1, 8192, 64, 64), (1, 8192, 1, 128), 256, "bfloat16")
+    assert not K.kernel_takes(*cell)  # the CPU
+    monkeypatch.setattr(K, "on_tpu", lambda: True)
+    assert K.kernel_takes(*cell)
+    assert K.kernel_takes((1, 8192, 64, 64), (1, 8192, 1, 128), 256, "float32")
+    assert K.kernel_takes((2, 300, 16, 16), (2, 300, 2, 128), 128, "bfloat16")  # padded inside
+    for x, b, chunk, dtype in (
+            (cell[0], cell[1], 256, "float16"),                 # neither of the two types
+            (cell[0], cell[1], 8, "bfloat16"),                  # the tests' chunk
+            (cell[0], cell[1], 192, "bfloat16"),                # no whole lane tiles
+            ((1, 8192, 64, 64), (1, 8192, 1, 64), 256, "bfloat16"),   # N half a tile
+            ((1, 8192, 64, 8), (1, 8192, 1, 128), 256, "bfloat16"),   # P half a bfloat16 tile
+            ((1, 8192, 12, 64), (1, 8192, 1, 128), 256, "bfloat16"),  # 12 heads: no blocks of 8
+            ((1, 8192, 16, 64), (1, 8192, 4, 128), 256, "bfloat16"),  # 4 heads a group
+            ((0, 8192, 64, 64), (0, 8192, 1, 128), 256, "bfloat16")):
+        assert not K.kernel_takes(x, b, chunk, dtype), (x, b, chunk, dtype)
+    assert K.kernel_takes((1, 8192, 64, 8), (1, 8192, 1, 128), 256, "float32")  # 8 rows: a tile
+    need = K._vmem(64, 128, 256, 2, states=8)
+    monkeypatch.setattr(K._chip, "VMEM_CEILING", need)
+    assert K.kernel_takes(*cell)
+    monkeypatch.setattr(K._chip, "VMEM_CEILING", need - 1)
+    assert not K.kernel_takes(*cell)
+    args, _ = _ssd_inputs(16, 1)  # on the shrunk chip the op still answers, through XLA
+    before = dict(telemetry.ssd_branches())
+    _close(S.ssd_scan(*args, chunk=8), _recurrence(*args), 2e-5)
+    after = telemetry.ssd_branches()
+    assert after["xla"] == before.get("xla", 0) + 1 and after.get("kernel") == before.get("kernel")
+
+
+def test_ssd_counts_the_kernels_branch_once_a_trace(ssd_kernels):
+    args, _ = _kernel_inputs(256, 1)
+    ssd_kernels()
+    before = dict(telemetry.ssd_branches())
+    f = jax.jit(_scan128)
+    f(*args), f(*args)
+    after = telemetry.ssd_branches()
+    assert after["kernel"] == before.get("kernel", 0) + 1 and after.get("xla") == before.get("xla")
+    assert 'mxt_ssd_total{branch="kernel"}' in telemetry.render_prometheus()
 
 
 # -- the filter --------------------------------------------------------------------
@@ -697,7 +839,7 @@ def _first_steps(faults=(), sequence=None):
     runner = loader.load_module("runners", c["runner"])
     opt = train_reference.effective_optimizer(config, traffic)
     params, pool = ref.init(config, 5), ref.batches(config, traffic, 5)
-    counted = telemetry.ssd_branches().get("xla", 0)
+    counted = dict(telemetry.ssd_branches())
     prog = model.build(config, traffic, params, jax.devices()[:1], opt)
     first, later = runner.first_steps(prog, [prog.batch(x, y) for x, y in pool], params,
                                       traffic)
@@ -744,8 +886,9 @@ def test_the_adapter_publishes_the_chunks_and_the_branch_counter(first_steps):
     published = first_steps["published"]
     assert published["ssd_chunks"] == 4  # 32 tokens under chunks of 8
     # two Mamba layers traced once in the step: one count each, the XLA branch
-    assert published["ssd_branches"]["xla"] >= first_steps["counted"] + 2
-    assert set(published["ssd_branches"]) == {"xla"}
+    counted = first_steps["counted"]  # the process's counts before the model was built
+    assert published["ssd_branches"]["xla"] >= counted.get("xla", 0) + 2
+    assert published["ssd_branches"].get("kernel") == counted.get("kernel")  # none on the CPU
     for name in ("ssm_share.train", "ssd_scan_roofline.train", "causal_conv_roofline.train"):
         reader = loader.load_module("layer_metrics", name)
         assert reader.NAME == name and reader.read({"trace_dir": None}) is None

@@ -179,6 +179,7 @@ _KERNEL_NAMES = {
     "grouped_matmul": ("grouped_matmul", "grouped_matmul_dw"),
     "indexer": ("indexer_select",),
     "row_gather": ("rows_as_words", "row_gather"),
+    "ssd_pallas": ("ssd_chunk_fwd", "ssd_chunk_bwd"),
 }
 _OPS_DIR = os.path.join(os.path.dirname(mx.__file__), "ops")
 
