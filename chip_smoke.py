@@ -418,6 +418,7 @@ def phase_kernels(args, dev):
     out["row_movement"] = row_movement_table(args, interp)
     out["causal_conv"] = causal_conv_table(args, interp)
     out["ssd"] = ssd_table(args, interp)
+    out["delta_rule"] = delta_rule_table(args, interp)
     out["embedding_grad"] = embedding_grad_table(args, interp)
 
     # -- paged decode: 12 heads x 64, page 16, 64 pages per sequence, bf16;
@@ -667,6 +668,60 @@ def ssd_table(args, interp):
     # no farther from the float32 formula than the bfloat16 formula itself is
     check(all(e < max(2e-2, 1.5 * x) for e, x in zip(row["rel_err"], row["xla_rel_err"])),
           "the scan's kernels against the float32 formula: %s" % row)
+    return row
+
+
+def delta_rule_table(args, interp):
+    """The gated delta rule at the Solar Open 2 cell's shape, (1, 8192) tokens
+    of 8 heads of 128 in chunks of 64, bfloat16 with float32 log-decays of up to
+    1.6 a token: ``ops/delta_rule_pallas.py``'s two kernels and the
+    ``jax.numpy`` formula of ``ops/delta_rule.py`` (both float32 in their sums,
+    lifts, solve and states, their other products' operands bfloat16), each
+    against the formula on the same values in float32 at the highest matmul
+    precision, the result and all five gradients, and device ms a call of each:
+    the whole op on (B, T, H, .) operands both ways and the kernels alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import delta_rule as D
+    from mxnet_tpu.ops import delta_rule_pallas as K
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    b, t, h, d, chunk = (1, 128, 8, 128, 64) if args.rehearse else (1, 8192, 8, 128, 64)
+    ks = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 6)
+    q, k, v, do = (jax.random.normal(s, (b, t, h, d), f32).astype(bf16) for s in ks[:4])
+    g = -1.6 * jax.random.uniform(ks[4], (b, t, h, d), f32)
+    beta = (2.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, h), f32))).astype(bf16)
+    args5 = (q, k, v, g, beta)
+    formula, formula_grads = (functools.partial(f, chunk) for f in (D._forward, D._grads))
+    xla_o, opening = jax.jit(formula)(*args5)
+    xla = (xla_o,) + jax.jit(formula_grads)(*args5, opening, do)
+    with jax.default_matmul_precision("highest"):
+        exact5 = tuple(a.astype(f32) for a in args5)
+        want_o, want_opening = jax.jit(formula)(*exact5)
+        want = (want_o,) + jax.jit(formula_grads)(*exact5, want_opening, do.astype(f32))
+    del want_opening
+    rule, grads = (functools.partial(f, chunk, interpret=interp) for f in (K.rule, K.rule_grads))
+    got_o, kept = jax.jit(rule)(*args5)
+    got = (got_o,) + jax.jit(grads)(*args5, kept, do)
+    flat = tuple(K._flat(z, chunk) for z in (q, k, v, g)) + (K._by_block(beta, chunk),)
+    fwd = functools.partial(K._fwd_pallas, chunk=chunk, interpret=interp)
+    bwd = functools.partial(K._bwd_pallas, chunk=chunk, interpret=interp)
+    variants = {"fwd_xla": (formula, args5), "bwd_xla": (formula_grads, args5 + (opening, do)),
+                "fwd_op": (rule, args5), "bwd_op": (grads, args5 + (kept, do)),
+                "fwd_kernel": (fwd, flat),
+                "bwd_kernel": (bwd, flat + kept + (K._flat(do, chunk),))}
+    ms, parts = device_ms(variants)
+    if not ms:  # no device line to read: the host's clock
+        ms = {name: inflight_ms(fn, *a) for name, (fn, a) in variants.items()}
+    row = dict(shape=[b, t, h, d, chunk], clock="device" if parts else "host",
+               rel_err=[rel_err(a, b_) for a, b_ in zip(got, want)],
+               xla_rel_err=[rel_err(a, b_) for a, b_ in zip(xla, want)],
+               opening_rel_err=rel_err(jnp.swapaxes(kept[0], -1, -2), opening),
+               **{"%s_ms" % n: v for n, v in ms.items()})
+    # no farther from the float32 formula than the bfloat16 formula itself is
+    check(all(e < max(2e-2, 1.5 * x) for e, x in zip(row["rel_err"], row["xla_rel_err"])),
+          "the delta rule's kernels against the float32 formula: %s" % row)
     return row
 
 

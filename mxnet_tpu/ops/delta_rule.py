@@ -60,10 +60,23 @@ forward under plain autodiff, runs the carry a second time and compiled to
 more bytes (0.775 against 0.741 GB of temporaries for the described v5e:
 PERF.md, Findings, PR 47); it is not built here.
 
-The two halves run under the scopes ``delta_rule`` / ``delta_rule_bwd`` and
-every traced call is counted by the branch it took
-(``telemetry.delta_rule_branches()``: ``xla``; a later kernel counts
-``kernel``).
+Two branches compute that, one algorithm at one precision; what differs is
+where a chunk's work lives. Where ``delta_rule_pallas.kernel_takes`` accepts
+the call (a TPU, bfloat16 or float32, K and V whole lane tiles, a chunk of
+whole sub-blocks of 16 and whole sublane tiles, heads in whole blocks of 8)
+both halves are that module's kernels, ``kda_chunk_fwd`` and
+``kda_chunk_bwd``: a grid step holds a chunk of a block of heads, the running
+sums, lifts, pairs, ``T``, ``W``, ``U`` and ``V'`` stay in VMEM, the carry rides
+in scratch over the grid's chunk axis both ways, and the forward keeps, beside
+each chunk's opening state (transposed, (V, K)), its ``T`` (float32, (chunk,
+chunk)) for the backward to read and not to solve again. XLA keeps the pads and
+the reshapes. Every other call (the CPU, K of 16 in the tests, a chunk of 8 or
+20, a float32 reference's shapes that are no whole tiles) is the ``jax.numpy``
+formula here (``_forward`` / ``_grads``).
+
+The two halves run under the scopes ``delta_rule`` / ``delta_rule_bwd``,
+kernels and all, and every traced call is counted by the branch it took
+(``telemetry.delta_rule_branches()``: ``kernel`` or ``xla``).
 """
 from __future__ import annotations
 
@@ -75,6 +88,7 @@ import jax.numpy as jnp
 
 from .. import telemetry as _telemetry
 from ..base import MXNetError
+from . import delta_rule_pallas as _kernels
 from .registry import register
 from .ssd import _by_chunk  # (b, t, ...) padded to whole chunks -> (b, n, c, ...)
 
@@ -218,14 +232,21 @@ def _delta_core(chunk, q, k, v, g, beta):
 
 @jax.named_scope("delta_rule")
 def _delta_fwd(chunk, q, k, v, g, beta):
-    _telemetry.record_delta_rule("xla")
-    o, opening = _forward(chunk, q, k, v, g, beta)
+    kernel = _kernels.kernel_takes(q.shape, v.shape, chunk, q.dtype)
+    _telemetry.record_delta_rule("kernel" if kernel else "xla")
+    o, opening = (_kernels.rule if kernel else _forward)(chunk, q, k, v, g, beta)
     return o, (q, k, v, g, beta, opening)
 
 
 @jax.named_scope("delta_rule_bwd")
 def _delta_bwd(chunk, res, do):
-    q, k, v, g, beta, opening = res
+    q, v = res[0], res[2]
+    kernel = _kernels.kernel_takes(q.shape, v.shape, chunk, q.dtype)
+    return (_kernels.rule_grads if kernel else _grads)(chunk, *res, do)
+
+
+def _grads(chunk, q, k, v, g, beta, opening, do):
+    """The formula's backward: the gradient of every input under ``do``."""
     kind, t = q.dtype, q.shape[1]
     parts = [_by_chunk(z, chunk) for z in (q, k, v, g, beta)]
     (q_open, k_end, p, w, u, closing), pull = jax.vjp(

@@ -24,6 +24,7 @@ import pytest
 from mxnet_tpu import tuning
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.ops import causal_conv_pallas as CC
+from mxnet_tpu.ops import delta_rule_pallas as DP
 from mxnet_tpu.ops import embedding_grad as EG
 from mxnet_tpu.ops import grouped_matmul as GM
 from mxnet_tpu.ops import indexer as X
@@ -788,23 +789,30 @@ def _delta_rule_shapes():
     return ((_KDA, bf), (_KDA, bf), (_KDA, bf), (_KDA, jnp.float32), (_KDA[:3], bf))
 
 
+@pytest.mark.parametrize("dispatch", ["chip", "off_chip"])
 @pytest.mark.parametrize("half", ["fwd", "bwd"])
-def test_gated_delta_rule_compiles_and_keeps_no_pairwise_tensor(chip, half):
+def test_gated_delta_rule_compiles_and_keeps_no_pairwise_tensor(chip, monkeypatch, half,
+                                                                dispatch):
     """``gated_delta_rule`` at a delta-attention layer of the Solar Open 2 cell
     (1 x 8192 tokens, 8 heads of 128, chunks of 64), forward alone and with
-    its own backward: XLA's products and fusions under two ``while`` loops (the
-    carry each way), no kernel, and no (chunks x heads x 64 x 64 x 128) tensor
-    among the results written to memory (2.1 GB in float32). The op's own
-    backward holds 0.741 GB of temporaries (``jax.checkpoint`` of the forward
-    under plain autodiff, the other way ISSUE 47 named, compiled to 0.775 and
-    is not built: PERF.md, Findings, PR 47)."""
+    its own backward. Off the chip: XLA's products and fusions under two
+    ``while`` loops (the carry each way), no kernel, and 0.741 GB of temporaries
+    (``jax.checkpoint`` of the forward under plain autodiff, the other way ISSUE
+    47 named, compiled to 0.775 and is not built: PERF.md, Findings, PR 47).
+    Dispatched as on the chip: the two kernels of ``ops/delta_rule_pallas.py``
+    by their names under the op's two scopes, no loop, and under a sixth of
+    those temporaries (the opening states and ``T`` the forward keeps, the
+    gradients' pads). Either way no (chunks x heads x 64 x 64 x 128) tensor
+    among the results written to memory (2.1 GB in float32)."""
     from mxnet_tpu.ops import delta_rule as D
 
+    monkeypatch.setattr(DP, "on_tpu", lambda: dispatch == "chip")
     shapes = _delta_rule_shapes()
     own = functools.partial(D.gated_delta_rule, chunk=64)
+    kernels = dispatch == "chip"
     if half == "fwd":
         text = _compile(chip, own, *shapes)
-        assert text.count(" while(") == 1
+        assert text.count(" while(") == (0 if kernels else 1)
     else:
         args = [jax.ShapeDtypeStruct(s, d, sharding=chip)
                 for s, d in ((_KDA, jnp.float32),) + shapes]
@@ -813,14 +821,23 @@ def test_gated_delta_rule_compiles_and_keeps_no_pairwise_tensor(chip, half):
                 lambda g, *a: jnp.sum(own(*a).astype(jnp.float32) * g),
                 argnums=tuple(range(1, 6)))).lower(*args).compile()
         text = compiled.as_text()
-        assert text.count(" while(") == 2
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.76e9
-    assert "tpu_custom_call" not in text
+        assert text.count(" while(") == (0 if kernels else 2)
+        assert compiled.memory_analysis().temp_size_in_bytes < (0.12e9 if kernels else 0.76e9)
     pairwise = 128 * 8 * 64 * 64 * 128
     large = [(t, d) for t, d in _entry_results(text)
              if functools.reduce(lambda a, b: a * b, d, 1) >= pairwise // 4]
     assert not large, large
-    assert "bf16[1,8192,8,128]" in text  # o, or a gradient, written once
+    assert re.search(r"bf16\[1,8192,(8,128|1024)\]", text)  # o, or a gradient, written once
+    if not kernels:
+        assert "tpu_custom_call" not in text
+        return
+    # the backward's program runs the forward too: its states and T are the residual
+    assert text.count("tpu_custom_call") == (1 if half == "fwd" else 2)
+    assert "delta_rule%s/jit(_fwd_pallas)/kda_chunk_fwd/" % ("" if half == "fwd" else ")") in text
+    assert "f32[1,128,8,128,128]" in text  # a (V, K) float32 state a head a chunk
+    if half == "bwd":
+        assert "(delta_rule_bwd))/jit(_bwd_pallas)/kda_chunk_bwd/" in text
+        assert "f32[1,128,8,64,64]" in text  # and its T
 
 
 def test_the_solar_cells_filter_and_attention_take_their_kernel_branches(chip, monkeypatch):
@@ -850,10 +867,13 @@ def test_the_solar_cells_whole_step_fits_without_recomputation(chip, monkeypatch
     """``solar_open2_train_s8192``'s step, 840,875,672 parameters (835,631,512
     of them trained: the routers are frozen) under Adam at (1, 8192) through
     ``ShardedTrainStep``, compiled for the described chip as the cell builds it
-    (both flash kernels, the filter's two, the expert layer's and the embedding
-    gradient's in, ``remat`` from the configuration's file: none): 11.21 GB
-    live, 6.19 GB of it temporaries, under the 14 GB that leave room for the
-    seeded copy (1.68 GB) and the batch pool."""
+    (both flash kernels, the filter's two, the delta rule's two, the expert
+    layer's and the embedding gradient's in, ``remat`` from the configuration's
+    file: none): 9.19 GB live, 4.16 GB of it temporaries (11.21 and 6.19 with the
+    delta rule as XLA's formula), under the 14 GB that leave room for the seeded
+    copy (1.68 GB) and the batch pool, and 36 copies or transposes of a
+    mixer-sized array (8192 x 1024 elements or more) where the formula's step
+    wrote 60."""
     import json
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -880,7 +900,7 @@ def test_the_solar_cells_whole_step_fits_without_recomputation(chip, monkeypatch
         mesh=parallel.make_mesh((1,), ("data",), devices=jax.devices()[:1]), remat=remat)
     one = Mesh([chip._device], ("data",))
     step.rebind_mesh(one, transfer=False)  # the shardings and the program, no value moved
-    for module in (A, CC, EG, GM, RG):  # dispatch as on the chip
+    for module in (A, CC, DP, EG, GM, RG):  # dispatch as on the chip
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     whole = NamedSharding(one, P())
 
@@ -902,10 +922,19 @@ def test_the_solar_cells_whole_step_fits_without_recomputation(chip, monkeypatch
           % tuple(v / 1e9 for v in (m.argument_size_in_bytes, m.output_size_in_bytes,
                                     m.temp_size_in_bytes, m.alias_size_in_bytes, held)))
     assert m.alias_size_in_bytes >= 6 * trained - 4096  # weights and state donated
-    assert held < 11.4e9 and held + 2 * 840875672 < 14e9
+    # no more than with the delta rule as XLA's formula (PR 48's tree: 11.213 GB, 6.188 of
+    # them temporaries)
+    assert held < 9.4e9 and m.temp_size_in_bytes < 4.4e9 and held + 2 * 840875672 < 14e9
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     # three delta-attention layers: the filter's forward and its backward, once each
     calls = re.findall(r"= [^=]*custom-call\([^\n]*causal_conv_silu_(fwd|bwd)/pallas_call", text)
     assert (calls.count("fwd"), calls.count("bwd")) == (3, 3)
+    calls = re.findall(r"= [^=]*custom-call\([^\n]*kda_chunk_(fwd|bwd)/pallas_call", text)
+    assert (calls.count("fwd"), calls.count("bwd")) == (3, 3)
     assert "grouped_matmul" in text and "delta_rule_bwd" in text
+    # the kernels take rows (tokens along the sublanes) and the filter's kernels give tokens
+    # along the lanes: what is left are the turns between them (a layer: the filter's result
+    # once, dq, dk, dv once each) and the gated norm's float32 passes; the formula's step
+    # wrote 60 such copies (48 under kda: heads_first, by_sub, the carry's operands)
+    assert len(_copies(text, 8192 * 1024)) <= 36, _copies(text, 8192 * 1024)

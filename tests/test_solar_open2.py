@@ -29,7 +29,9 @@ from mxnet_tpu import nd, telemetry
 from mxnet_tpu.gluon.model_zoo import solar_open2 as zoo
 from mxnet_tpu.gluon.model_zoo.deepseek import DeepseekMoE
 from mxnet_tpu.gluon.model_zoo.keye import GroupedQueryAttention
+from mxnet_tpu.ops import chip as CHIP
 from mxnet_tpu.ops import delta_rule as D
+from mxnet_tpu.ops import delta_rule_pallas as K
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                      "benchmark")
@@ -261,6 +263,134 @@ def test_the_delta_rule_counts_one_traced_call_by_branch():
 
 
 # -- the head's norm-then-gate ------------------------------------------------------------
+# -- the rule's kernels (ops/delta_rule_pallas.py), interpreted, dispatched as on the chip
+@pytest.fixture
+def kda_kernels(monkeypatch):
+    """``engage()``: from then on ``ops/delta_rule_pallas.py`` answers as on a TPU,
+    its kernels in interpret mode (a test computes what the ``jax.numpy`` formula
+    gives first, then engages)."""
+    def engage():
+        fwd, bwd = K._fwd_pallas, K._bwd_pallas
+        monkeypatch.setattr(K, "on_tpu", lambda: True)
+        monkeypatch.setattr(K, "_fwd_pallas", lambda *a, interpret=False, **kw:
+                            fwd(*a, interpret=True, **kw))
+        monkeypatch.setattr(K, "_bwd_pallas", lambda *a, interpret=False, **kw:
+                            bwd(*a, interpret=True, **kw))
+
+    return engage
+
+
+def _rule64(*a):
+    return D.gated_delta_rule(*a, chunk=64)
+
+
+def _whole():
+    """A jitted (result, what the forward keeps, the five gradients) of the op as
+    it dispatches when first called: one trace and one compile a shape."""
+    @jax.jit
+    def whole(args, dy):
+        o, res = D._delta_fwd(64, *args)
+        return o, res[5], _all_gradients(_rule64, args, dy)[1]
+
+    return whole
+
+
+_FORMULA, _KERNELS = _whole(), _whole()  # a cache each: the dispatch is no part of a key
+
+
+def _kernel_inputs(t, dtype="float32", h=8, case="plain"):
+    """Shapes ``kernel_takes`` accepts, (1, t, h, 128). ``overflow``: log-decays
+    of 1.44-1.6 a token in every channel, under -100 within a chunk. ``solve``:
+    beta 1.99 and the same key on tokens 16-31 and 72-87 with hardly a decay, so
+    ``A``'s entries there are near 2 and ``T``'s alternate in sign."""
+    args, dy = _inputs(t, dtype, b=1, h=h, k=128, v=128)
+    q, k, v, g, beta = args
+    if case == "overflow":
+        g = -1.6 * jax.random.uniform(jax.random.PRNGKey(t), g.shape, F32, 0.9, 1.0)
+    elif case == "solve":
+        for at in (16, 72):
+            k = k.at[:, at:at + 16].set(k[:, at:at + 1])
+        g, beta = g * 0.01, jnp.full_like(beta, 1.99)
+    return (q, k, v, g, beta), dy
+
+
+@pytest.mark.parametrize("dtype,t,h,case,tol", [
+    ("float32", 256, 8, "plain", 2e-5),
+    ("float32", 100, 16, "plain", 2e-5),  # a ragged last chunk, two blocks of heads
+    ("float32", 256, 8, "overflow", 2e-5),
+    ("float32", 256, 8, "solve", 2e-4),
+    ("bfloat16", 256, 8, "plain", 2e-2),
+    ("bfloat16", 256, 8, "overflow", 2e-2)])
+def test_kda_kernels_result_states_and_every_gradient_against_the_formula(
+        kda_kernels, dtype, t, h, case, tol):
+    """Both kernels against ``ops/delta_rule.py``'s formula on the same inputs:
+    the result, every chunk's opening state (the kernels keep a state
+    transposed, and the chunk's ``T`` beside it) and the gradient of all five
+    inputs, whose formula is ``jax.vjp`` of ``_within`` around the carry's
+    transposes: the pairs by sub-block under their anchors, the substitution and
+    the series, the carry in scratch both ways, ``dG``'s rows less columns and
+    its reverse running sum, the norms, the padding, a second block of heads."""
+    args, dy = _kernel_inputs(t, dtype, h, case)
+    want, want_opening, want_grads = _FORMULA(args, dy)
+    before = dict(telemetry.delta_rule_branches())
+    kda_kernels()
+    got, (opening, solved), got_grads = _KERNELS(args, dy)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.all(jnp.isfinite(got.astype(F32))))
+    _close(got.astype(F32), want.astype(F32), tol)
+    opening = jnp.swapaxes(opening, -1, -2)
+    assert opening.dtype == solved.dtype == F32 and not np.any(np.asarray(opening[:, 0]))
+    assert solved.shape == opening.shape[:3] + (64, 64)
+    _close(opening, want_opening, tol)
+    for name, a, b in zip(INPUTS, got_grads, want_grads):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(a.astype(F32)))), name
+        _close(a.astype(F32), b.astype(F32), 5 * tol)
+    # counted where traced: a case after the first of its shape runs the program it compiled
+    after = telemetry.delta_rule_branches()
+    assert after["kernel"] >= max(before.get("kernel", 0), 1)
+    assert after.get("xla") == before.get("xla")
+
+
+def test_kda_kernels_gradients_are_plain_autodiffs_of_the_formula(kda_kernels):
+    """The backward kernel's five gradients against plain autodiff through the
+    formula's forward (``jax.vjp`` of every line of it, no hand-written backward
+    anywhere), float32."""
+    args, dy = _kernel_inputs(256)
+    _, plain = jax.jit(lambda *a: _all_gradients(lambda *b: D._forward(64, *b)[0], a, dy))(*args)
+    kda_kernels()
+    for name, a, b in zip(INPUTS, _KERNELS(args, dy)[2], plain):
+        _close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("what", ["the_cpu", "k_of_64", "a_chunk_of_24", "float16",
+                                  "a_vmem_ceiling_shrunk"])
+def test_kernel_takes_refuses_and_the_refused_call_is_the_formula(monkeypatch, what):
+    """``kernel_takes`` reads the platform, the type, the shapes and the chip's
+    VMEM: each call it refuses traces the ``jax.numpy`` formula, both halves
+    (no kernel in the program), and counts ``xla``."""
+    shape, chunk, dtype = (1, 128, 8, 128), 64, "bfloat16"
+    monkeypatch.setattr(K, "on_tpu", lambda: what != "the_cpu")
+    assert what == "the_cpu" or K.kernel_takes(shape, shape, chunk, dtype)
+    if what == "k_of_64":
+        shape = (1, 128, 8, 64)
+    elif what == "a_chunk_of_24":
+        chunk = 24
+    elif what == "float16":
+        dtype = "float16"
+    elif what == "a_vmem_ceiling_shrunk":
+        monkeypatch.setattr(CHIP, "VMEM_CEILING", 2 ** 20)
+    assert not K.kernel_takes(shape, shape, chunk, dtype)
+    assert not K.kernel_takes((1, 128, 4, 128), (1, 128, 4, 128), 64, "bfloat16")  # half a block
+    args, dy = _inputs(shape[1], dtype, b=1, h=8, k=shape[3], v=shape[3])
+    before = dict(telemetry.delta_rule_branches())
+    program = str(jax.make_jaxpr(lambda *a: _all_gradients(
+        lambda *b: D.gated_delta_rule(*b, chunk=chunk), a, dy))(*args))
+    assert "pallas_call" not in program and "scan[" in program
+    after = telemetry.delta_rule_branches()
+    assert after["xla"] == before.get("xla", 0) + 1 and after.get("kernel") == before.get("kernel")
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_heads_norm_comes_first_and_the_sigmoid_gate_after_it(dtype):
     """``RMSNorm(o) * w * sigmoid(z)`` a head against the plain formula under
@@ -776,7 +906,8 @@ def test_the_adapter_publishes_the_chunks_the_slots_and_the_branch_counter(first
     assert published["delta_rule_chunks"] == 6  # 44 tokens under chunks of 8
     # two delta-attention layers traced once in the step: one count each
     assert published["delta_rule_branches"]["xla"] >= first_steps["counted"] + 2
-    assert set(published["delta_rule_branches"]) == {"xla"}
+    # the CPU traces the formula; "kernel" is there only from this file's interpreted tests
+    assert set(published["delta_rule_branches"]) <= {"xla", "kernel"}
     slots = published["expert_slots"]
     assert len(slots) == 3 and all(len(row) == 4 and sum(row) > 0 for row in slots)
     for name in ("linear_attention_share.train", "delta_rule_roofline.train",

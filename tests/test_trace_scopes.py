@@ -176,6 +176,7 @@ _KERNEL_NAMES = {
     "attention": ("flash_attention_fwd", "flash_attention_bwd", "window_attention_fwd",
                   "window_attention_bwd", "paged_decode"),
     "causal_conv_pallas": ("causal_conv_silu_fwd", "causal_conv_silu_bwd"),
+    "delta_rule_pallas": ("kda_chunk_fwd", "kda_chunk_bwd"),
     "grouped_matmul": ("grouped_matmul", "grouped_matmul_dw"),
     "indexer": ("indexer_select",),
     "row_gather": ("rows_as_words", "row_gather"),
